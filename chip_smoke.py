@@ -1,0 +1,565 @@
+"""Quickest proof that horovod_tpu starts on the attached TPU.
+
+    python chip_smoke.py            # one chip: every phase below
+    python chip_smoke.py --chips 4  # one four-chip host: cross-chip only
+
+One process, the only one that touches JAX.  It drives the public
+surface the way a user script does — ``hvd.init()``, ``make_mesh``,
+``hvd.DistributedOptimizer`` inside ``shard_map``, ``Transformer`` /
+``lm_loss``, ``ResNet50``, ``run_parallel`` + the eager collectives — and
+fails (non-zero exit, no ``"ok"`` line) at the first phase that does not
+hold.  There is no CPU branch: without a TPU it refuses to run.
+
+Default run, in order, one JSON line each:
+
+- ``device``        the platform is ``tpu``; versions and compile cache
+- ``kernels``       flash attention, LayerNorm and softmax-xent compiled
+                    (``interpret=False``), forward and backward, at the
+                    language model's widths against their references
+- ``train_lm``      the full-width language model, 5 ``adamw`` steps
+- ``train_resnet``  ResNet-50 bf16 batch 128, 3 SGD-momentum steps
+- ``eager``         allreduce / fused group / allgather / broadcast on
+                    device arrays through the negotiated plane
+
+``--chips 4`` runs ``device``, then ``dp_lm`` (the language-model step
+data-parallel over four chips against the same batch on one chip) and
+``eager`` with four device-ranks, and nothing else.
+
+Times, compile seconds and peak memory on the phase lines are
+information for whoever reads the log, not benchmark metrics.
+"""
+
+import argparse
+import contextlib
+import json
+import math
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+# The repository's full-width language model (bench.py's transformer
+# leg): widths and sequence are what the kernels are built for.
+LM = {"vocab": 32768, "layers": 8, "d_model": 1024, "heads": 8,
+      "d_ff": 4096, "seq": 2048, "batch": 8}
+RESNET_BATCH = 128
+SEED = 0  # weights and data are random, made from this
+# Largest |kernel - reference| over largest |reference|, references
+# traced at "highest" matmul precision.  Measured on v5e (CHANGES.md,
+# PR 21) with a margin of about three.
+KERNEL_TOL = {"float32": 2e-2, "bfloat16": 3e-2}
+# The cross-chip gradient against the one-chip gradient of the same
+# batch: bf16 activations, rows summed in another order.
+DP_TOL = 5e-2
+
+
+class SmokeFailure(Exception):
+    """A phase did not hold; the message says what was seen."""
+
+
+def compile_cache_dir():
+    """Where compiled programs are kept: the directory the environment
+    names, else ``<checkout>/.jax_cache`` — never both."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(HERE, ".jax_cache"))
+
+
+def emit(phase, **fields):
+    """One line for a phase that held.  Only the last line of a run that
+    held throughout carries ``"ok"``."""
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+def require(cond, message):
+    if not cond:
+        raise SmokeFailure(message)
+
+
+@contextlib.contextmanager
+def count_compiles():
+    """Counts backend compilations inside the block (``jax.monitoring``
+    reports one duration event per compiled program)."""
+    from jax import monitoring
+
+    seen = []
+
+    def listener(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(duration)
+
+    monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield seen
+    finally:
+        monitoring.unregister_event_duration_listener(listener)
+
+
+def memory(device, compiled=None):
+    """The allocator's peak so far and, for a compiled step, XLA's own
+    account of it.  On v5e the allocator's peak leaves out a program's
+    temporaries (PERF.md, PR 21), so the second is the one to size by."""
+    out = {"peak_bytes_in_use": (device.memory_stats() or {}).get(
+        "peak_bytes_in_use")}
+    if compiled is not None:
+        analysis = compiled.memory_analysis()
+        out.update(argument_bytes=analysis.argument_size_in_bytes,
+                   temp_bytes=analysis.temp_size_in_bytes,
+                   output_bytes=analysis.output_size_in_bytes,
+                   alias_bytes=analysis.alias_size_in_bytes)
+    return out
+
+
+# --------------------------------------------------------------- device
+def phase_device(chips):
+    import importlib.metadata
+
+    import jax
+    import jaxlib
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    require(platform == "tpu",
+            f"JAX found platform {platform!r}, not a TPU; this script has "
+            f"no CPU path")
+    require(len(devices) == chips,
+            f"{len(devices)} chips attached but --chips {chips} asked for")
+    jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    try:
+        libtpu = importlib.metadata.version("libtpu")
+    except importlib.metadata.PackageNotFoundError:
+        libtpu = None
+    emit("device", platform=platform, kind=devices[0].device_kind,
+         count=len(devices), jax=jax.__version__,
+         jaxlib=jaxlib.__version__, libtpu=libtpu,
+         compile_cache=compile_cache_dir())
+    return devices
+
+
+# -------------------------------------------------------------- kernels
+def phase_kernels():
+    """Each Pallas kernel compiled for the chip, forward and backward,
+    against its own reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.pallas.flash_attention import flash_attention
+    from horovod_tpu.ops.pallas.layer_norm import (layer_norm,
+                                                   layer_norm_reference)
+    from horovod_tpu.ops.pallas.softmax_xent import (
+        softmax_xent, softmax_xent_reference)
+    from horovod_tpu.parallel import reference_attention
+
+    # widths are the model's; two sequences are enough rows to compare
+    b, t, h, d = 2, LM["seq"], LM["heads"], LM["d_model"] // LM["heads"]
+    keys = jax.random.split(jax.random.PRNGKey(SEED), 10)
+
+    @jax.jit
+    def rel_err(got, want):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        return jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))
+
+    def compare(kernel, reference, args, argnums, weight):
+        """Largest relative error over the output and the gradients of
+        ``vdot(output, weight)`` with respect to ``argnums``."""
+        def run(fn):
+            def weighed(*a):
+                out = fn(*a)
+                return jnp.vdot(out.astype(jnp.float32),
+                                weight.astype(jnp.float32)), out
+
+            (_, out), grads = jax.jit(jax.value_and_grad(
+                weighed, argnums, has_aux=True))(*args)
+            return [out, *grads]
+
+        got = run(kernel)
+        with jax.default_matmul_precision("highest"):
+            want = run(reference)
+        return max(float(rel_err(g, w)) for g, w in zip(got, want))
+
+    errors = {}
+    for dtype in (jnp.float32, jnp.bfloat16):
+        name = jnp.dtype(dtype).name
+        q, k, v, do = (jax.random.normal(keys[i], (b, t, h, d), dtype)
+                       for i in range(4))
+        errors[f"flash_attention/{name}"] = compare(
+            lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            interpret=False),
+            lambda q, k, v: reference_attention(q, k, v, causal=True),
+            (q, k, v), (0, 1, 2), do)
+
+        x = jax.random.normal(keys[4], (b, t, LM["d_model"]), dtype)
+        gamma = 1 + 0.1 * jax.random.normal(keys[5], (LM["d_model"],))
+        beta = 0.1 * jax.random.normal(keys[6], (LM["d_model"],))
+        errors[f"layer_norm/{name}"] = compare(
+            lambda x, g, bt: layer_norm(x, g, bt, 1e-6, False),
+            layer_norm_reference, (x, gamma, beta), (0, 1, 2),
+            jax.random.normal(keys[7], x.shape, dtype))
+
+        logits = 5 * jax.random.normal(keys[8], (b, t, LM["vocab"]), dtype)
+        labels = jax.random.randint(keys[9], (b, t), 0, LM["vocab"])
+        errors[f"softmax_xent/{name}"] = compare(
+            lambda lg: softmax_xent(lg, labels, False),
+            lambda lg: softmax_xent_reference(lg, labels),
+            (logits,), (0,), jnp.full((b, t), 1.0 / (b * t)))
+
+    bad = {k: e for k, e in errors.items()
+           if not e <= KERNEL_TOL[k.split("/")[1]]}
+    require(not bad, f"kernels off their references: {bad} of {errors}, "
+                     f"tolerances {KERNEL_TOL}")
+    emit("kernels", shape={"batch": b, "seq": t, "heads": h, "head_dim": d,
+                           "d_model": LM["d_model"], "vocab": LM["vocab"]},
+         rel_err={k: float(f"{e:.3g}") for k, e in errors.items()},
+         tolerance=KERNEL_TOL)
+
+
+# ------------------------------------------------------- language model
+def lm_model():
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import Transformer, TransformerConfig
+
+    return Transformer(TransformerConfig(
+        vocab_size=LM["vocab"], n_layers=LM["layers"],
+        d_model=LM["d_model"], n_heads=LM["heads"], d_ff=LM["d_ff"],
+        max_len=LM["seq"], dtype=jnp.bfloat16))
+
+
+def lm_step(model, opt, mesh):
+    """The user's training step: per-rank loss and ``jax.grad``, the
+    gradient exchange inside ``opt.update``, one program over ``mesh``.
+    ``tests/test_chip_compile.py`` compiles this same function for a
+    described v5e."""
+    import jax
+    import optax
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.models import lm_loss
+    from horovod_tpu.parallel._compat import shard_map
+
+    def per_shard(params, opt_state, tokens):
+        def loss_fn(p):
+            return lm_loss(model.apply({"params": p}, tokens), tokens)
+
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    return jax.jit(shard_map(
+        per_shard, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+        out_specs=(P(), P(), P())), donate_argnums=(0, 1))
+
+
+def lm_inputs(model, mesh):
+    """Parameters from ``SEED`` replicated over ``mesh`` and one batch of
+    random tokens split over it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    params = jax.jit(
+        model.init, out_shardings=NamedSharding(mesh, P()))(
+        jax.random.PRNGKey(SEED),
+        jnp.zeros((1, LM["seq"]), jnp.int32))["params"]
+    tokens = np.random.RandomState(SEED).randint(
+        0, LM["vocab"], (LM["batch"], LM["seq"]))
+    return params, jax.device_put(tokens, NamedSharding(mesh, P("hvd")))
+
+
+def phase_train_lm(devices):
+    import jax
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"hvd": 1}, devices=devices[:1])
+    model = lm_model()
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-4))
+    params, tokens = lm_inputs(model, mesh)
+    opt_state = opt.init(params)
+
+    start = time.perf_counter()
+    step = lm_step(model, opt, mesh).lower(
+        params, opt_state, tokens).compile()
+    compile_s = time.perf_counter() - start
+    # the Pallas branch of models/transformer.py, not the references
+    n_kernels = step.as_text().count("tpu_custom_call")
+    require(n_kernels > 0, "compiled step has no tpu_custom_call: the "
+                           "model took its reference branch")
+
+    losses, ms_ready, ms_get = [], [], []
+    with count_compiles() as compiles:
+        for i in range(5):
+            if i == 1:
+                del compiles[:]  # steps 2..5 must compile nothing
+            start = time.perf_counter()
+            params, opt_state, loss = step(params, opt_state, tokens)
+            jax.block_until_ready(loss)
+            ms_ready.append((time.perf_counter() - start) * 1e3)
+            losses.append(float(jax.device_get(loss)))
+            ms_get.append((time.perf_counter() - start) * 1e3)
+    require(all(math.isfinite(x) for x in losses),
+            f"loss not finite: {losses}")
+    require(abs(losses[0] - math.log(LM["vocab"])) < 1.0,
+            f"first loss {losses[0]} is not near ln(vocab) = "
+            f"{math.log(LM['vocab']):.2f} for random weights")
+    require(losses[-1] < losses[0], f"loss did not fall: {losses}")
+    require(not compiles, f"{len(compiles)} compiles after the first step")
+    emit("train_lm", config=LM, tpu_custom_call=n_kernels,
+         losses=[round(x, 4) for x in losses], compile_s=round(compile_s, 2),
+         compiles_after_first_step=len(compiles),
+         step_ms_block_until_ready=[round(x, 2) for x in ms_ready[1:]],
+         step_ms_device_get=[round(x, 2) for x in ms_get[1:]],
+         memory=memory(devices[0], step))
+
+
+# --------------------------------------------------------------- resnet
+def phase_train_resnet(devices):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.models import ResNet50
+    from horovod_tpu.parallel import make_mesh
+    from horovod_tpu.parallel._compat import shard_map
+
+    mesh = make_mesh({"hvd": 1}, devices=devices[:1])
+    model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)
+    # placed as the step returns them, or the second call compiles anew
+    variables = jax.jit(
+        lambda r, x: model.init(r, x, train=True),
+        out_shardings=NamedSharding(mesh, P()))(
+        jax.random.PRNGKey(SEED),
+        jnp.zeros((1, 224, 224, 3), jnp.float32))
+    params, stats = variables["params"], variables["batch_stats"]
+    stats_before = jax.device_get(stats)
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
+    opt_state = opt.init(params)
+
+    def per_shard(params, stats, opt_state, x, y):
+        def loss_fn(p):
+            logits, updates = model.apply(
+                {"params": p, "batch_stats": stats}, x, train=True,
+                mutable=["batch_stats"])
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, y).mean()
+            return loss, updates["batch_stats"]
+
+        (loss, stats), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
+        stats = jax.tree.map(lambda s: jax.lax.pmean(s, "hvd"), stats)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return (optax.apply_updates(params, updates), stats, opt_state,
+                jax.lax.pmean(loss, "hvd"))
+
+    step = jax.jit(shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(P(), P(), P(), P("hvd"), P("hvd")),
+        out_specs=(P(), P(), P(), P())), donate_argnums=(0, 1, 2))
+    rng = np.random.RandomState(SEED)
+    sharded = NamedSharding(mesh, P("hvd"))
+    x = jax.device_put(rng.randn(RESNET_BATCH, 224, 224, 3).astype(
+        np.float32), sharded)
+    y = jax.device_put(rng.randint(0, 1000, (RESNET_BATCH,)), sharded)
+
+    losses, ms = [], []
+    with count_compiles() as compiles:
+        for i in range(3):
+            if i == 1:
+                del compiles[:]
+            start = time.perf_counter()
+            params, stats, opt_state, loss = step(
+                params, stats, opt_state, x, y)
+            losses.append(float(jax.device_get(loss)))
+            ms.append((time.perf_counter() - start) * 1e3)
+    require(all(math.isfinite(v) for v in losses),
+            f"loss not finite: {losses}")
+    require(not compiles, f"{len(compiles)} compiles after the first step")
+    moved = [not np.allclose(a, b) for a, b in zip(
+        jax.tree.leaves(stats_before), jax.tree.leaves(
+            jax.device_get(stats)))]
+    require(all(moved), f"{moved.count(False)} of {len(moved)} batch "
+                        f"statistics never updated")
+    emit("train_resnet", batch=RESNET_BATCH,
+         losses=[round(v, 4) for v in losses],
+         first_step_with_compile_s=round(ms[0] / 1e3, 2),
+         step_ms_device_get=[round(v, 2) for v in ms[1:]],
+         compiles_after_first_step=len(compiles),
+         batch_stats_updated=len(moved), memory=memory(devices[0]))
+
+
+# ---------------------------------------------------------------- eager
+def phase_eager(devices):
+    """The negotiated plane: every device is a rank on its own thread,
+    each handing in device-resident arrays of its own."""
+    import jax
+    import numpy as np
+
+    import horovod_tpu as hvd
+    from horovod_tpu.common import basics
+
+    n = hvd.size()
+    require(n == len(devices), f"hvd.size() is {n}, not {len(devices)}")
+
+    def contribution(rank, i):
+        return np.arange(64 * (i + 1), dtype=np.float32) * (rank + 1) + i
+
+    def per_rank(rank):
+        mine = [jax.device_put(contribution(rank, i), devices[rank])
+                for i in range(4)]
+        rows = jax.device_put(
+            np.full((rank + 1, 3), float(rank), np.float32), devices[rank])
+        return {
+            "sum": hvd.allreduce(mine[0], op=hvd.Sum, name="smoke.sum"),
+            "group": hvd.grouped_allreduce(
+                mine[1:], op=hvd.Average, name="smoke.group"),
+            "gather": hvd.allgather(rows, name="smoke.gather"),
+            "bcast": hvd.broadcast(mine[0], n - 1, name="smoke.bcast"),
+        }
+
+    results = basics.run_parallel(per_rank)
+    want_gather = np.concatenate(
+        [np.full((r + 1, 3), float(r), np.float32) for r in range(n)])
+    for rank, out in enumerate(results):
+        np.testing.assert_allclose(
+            out["sum"], sum(contribution(r, 0) for r in range(n)))
+        for i, got in enumerate(out["group"], start=1):
+            np.testing.assert_allclose(
+                got, np.mean([contribution(r, i) for r in range(n)], 0),
+                rtol=1e-6)
+        np.testing.assert_array_equal(out["gather"], want_gather)
+        np.testing.assert_array_equal(out["bcast"], contribution(n - 1, 0))
+        # each rank's result stays on that rank's chip
+        for got in (out["sum"], *out["group"], out["gather"],
+                    out["bcast"]):
+            require(got.devices() == {devices[rank]},
+                    f"rank {rank}'s output is on {got.devices()}, not "
+                    f"{devices[rank]}")
+    emit("eager", ranks=n,
+         controller=type(basics._get_state().controller).__name__,
+         outputs_on_own_device=True)
+
+
+# ------------------------------------------------ four chips: dp vs one
+def phase_dp_lm(devices):
+    """Two plain-SGD steps of the language model on the same 8 sequences
+    from the same weights: data-parallel over the four chips, and on
+    chip 0 alone.  The parameter change must agree — it is 4 x larger
+    where the exchange sums instead of averaging, which ``adamw`` would
+    hide."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import make_mesh
+    from horovod_tpu.parallel._compat import shard_map
+
+    model = lm_model()
+    opt = hvd.DistributedOptimizer(optax.sgd(1e-2))
+
+    def two_steps(mesh):
+        params, tokens = lm_inputs(model, mesh)
+        start_params = jax.tree.map(jnp.copy, params)
+        opt_state = opt.init(params)
+        step = lm_step(model, opt, mesh).lower(
+            params, opt_state, tokens).compile()
+        losses = []
+        for _ in range(2):
+            params, opt_state, loss = step(params, opt_state, tokens)
+            losses.append(float(jax.device_get(loss)))
+        delta = jax.tree.map(lambda a, b: a - b, params, start_params)
+        return step.as_text(), params, delta, losses
+
+    mesh4 = make_mesh({"hvd": len(devices)}, devices=devices)
+    text4, params4, delta4, losses4 = two_steps(mesh4)
+    n_allreduce = text4.count("all-reduce(") + text4.count(
+        "all-reduce-start(")
+    require(n_allreduce > 0, "four-chip step compiled without all-reduce")
+    require("tpu_custom_call" in text4,
+            "four-chip step has no tpu_custom_call")
+
+    # every chip reads its OWN replica here: a spread of 0 means the four
+    # copies of each parameter are bit-identical
+    spread = jax.jit(shard_map(
+        lambda p: jax.tree.map(
+            lambda w: jnp.max(jax.lax.pmax(w, "hvd")
+                              - jax.lax.pmin(w, "hvd")), p),
+        mesh=mesh4, in_specs=P(), out_specs=P()))(params4)
+    worst_spread = max(float(s) for s in jax.tree.leaves(spread))
+    require(worst_spread == 0.0,
+            f"parameters differ across chips by up to {worst_spread}")
+
+    del params4, spread
+    _, _, delta1, losses1 = two_steps(
+        make_mesh({"hvd": 1}, devices=devices[:1]))
+    # chip 0 holds a shard of every four-chip array; compare there
+    delta4 = jax.tree.map(
+        lambda a: a.addressable_shards[0].data, delta4)
+    delta1 = jax.tree.map(
+        lambda a: a.addressable_shards[0].data, delta1)
+
+    @jax.jit
+    def compare(d4, d1):
+        errs = jax.tree.map(
+            lambda a, b: jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)),
+            d4, d1)
+        return (jnp.max(jnp.stack(jax.tree.leaves(errs))),
+                optax.global_norm(d4) / optax.global_norm(d1))
+
+    worst, ratio = (float(v) for v in compare(delta4, delta1))
+    require(worst <= DP_TOL and abs(ratio - 1) <= DP_TOL,
+            f"four-chip update is not the one-chip update: worst leaf "
+            f"error {worst:.3g}, norm ratio {ratio:.4f} (1 is a mean, "
+            f"{len(devices)} a sum), tolerance {DP_TOL}")
+    emit("dp_lm", config=LM, chips=len(devices), all_reduces=n_allreduce,
+         replica_spread=worst_spread,
+         losses_4chip=[round(x, 4) for x in losses4],
+         losses_1chip=[round(x, 4) for x in losses1],
+         update_norm_ratio=round(ratio, 5),
+         worst_leaf_rel_err=float(f"{worst:.3g}"), tolerance=DP_TOL)
+
+
+# ----------------------------------------------------------------- main
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                        help="4: only the cross-chip phases, on one "
+                             "four-chip host")
+    args = parser.parse_args(argv)
+
+    # before any output: next to nothing else of the repository this
+    # fails here, having printed nothing
+    import horovod_tpu as hvd
+
+    try:
+        devices = phase_device(args.chips)
+        hvd.init()
+        try:
+            if args.chips == 1:
+                phase_kernels()
+                phase_train_lm(devices)
+                phase_train_resnet(devices)
+            else:
+                phase_dp_lm(devices)
+            phase_eager(devices)
+        finally:
+            hvd.shutdown()
+    except SmokeFailure as exc:
+        sys.stderr.write(f"chip_smoke: FAILED: {exc}\n")
+        return 1
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

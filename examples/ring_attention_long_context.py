@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.parallel import make_mesh
-from horovod_tpu.parallel._compat import shard_map_kernel_body as shard_map
+from horovod_tpu.parallel._compat import shard_map
 from horovod_tpu.parallel.ring_attention import (reference_attention,
                                                  ring_attention)
 from horovod_tpu.parallel.ulysses import ulysses_attention

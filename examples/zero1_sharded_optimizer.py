@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import horovod_tpu as hvd
 from horovod_tpu.models import MLP
 from horovod_tpu.parallel import make_mesh
-from horovod_tpu.parallel._compat import shard_map_unchecked
+from horovod_tpu.parallel._compat import shard_map
 
 
 def parse_args():
@@ -61,9 +61,9 @@ def main():
         return optax.apply_updates(p, updates), \
             hvd.sharded_state_wrap(s2), jax.lax.pmean(loss, "hvd")
 
-    init_j = jax.jit(shard_map_unchecked(
+    init_j = jax.jit(shard_map(
         init_fn, mesh=mesh, in_specs=P(), out_specs=P("hvd")))
-    step_j = jax.jit(shard_map_unchecked(
+    step_j = jax.jit(shard_map(
         step, mesh=mesh,
         in_specs=(P(), P("hvd"), P("hvd"), P("hvd")),
         out_specs=(P(), P("hvd"), P())))

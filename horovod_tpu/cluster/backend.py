@@ -81,8 +81,7 @@ class ProcessBackend(Backend):
 
         def wrapper(*a, **kw):
             if platform is not None:
-                # must happen before hvd.init() touches jax (some TPU
-                # plugins ignore the JAX_PLATFORMS env var)
+                # must happen before hvd.init() touches jax
                 import jax
 
                 jax.config.update("jax_platforms", platform)

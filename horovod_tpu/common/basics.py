@@ -171,9 +171,11 @@ def init(comm=None, controller=None):
                 # the native core writes the timeline itself
                 timeline = Timeline(None)
             except (ImportError, OSError) as exc:
-                get_logger().debug(
-                    "native core unavailable (%s); falling back to the "
-                    "python controller", exc)
+                # loud: whoever measures the eager plane must know which
+                # controller served it
+                get_logger().warning(
+                    "native core unavailable (%s); the python controller "
+                    "serves this process instead", exc)
         if impl is None:
             timeline = Timeline(config.timeline_path,
                                 config.timeline_mark_cycles)

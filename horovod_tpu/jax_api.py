@@ -12,7 +12,9 @@ schedules it on ICI, which subsumes the reference's tensor-fusion machinery
 Two usage styles:
 
 - **shard_map / explicit SPMD** (default): pass the mesh axis names the
-  gradients are sharded over; the wrapper inserts the collective.
+  gradients are sharded over; the wrapper inserts the collective.  Wrap
+  the step in ``horovod_tpu.parallel._compat.shard_map`` — each rank
+  differentiates its own loss and hands over its LOCAL gradient.
 - **GSPMD / jit-with-shardings**: pass ``named_axes=None``; XLA already
   inserts gradient reductions, and the wrapper contributes compression and
   local gradient aggregation only.
@@ -48,13 +50,45 @@ def _single_axis(named_axes, what):
         f"compression")
 
 
+def _require_local_gradients(grads, named_axes):
+    """Refuse a gradient that autodiff has already reduced.
+
+    Under a checked ``jax.shard_map`` the gradient of a replicated
+    (``P()``) parameter comes out of ``jax.grad`` already summed over
+    the mesh axis; ``pmean`` of it is the identity, so the optimizer
+    would apply N x the mean, and int8 / Adasum would see a sum where
+    they need this rank's gradient.  Whether the value is a sum or a
+    mean depends on the user's loss, so it cannot be corrected here.
+    Checking is on exactly where ``axis_index`` is typed as varying;
+    under the framework's own ``shard_map`` nothing is."""
+    axes = (named_axes,) if isinstance(named_axes, str) else named_axes
+    checked = [a for a in axes
+               if a in jax.typeof(jax.lax.axis_index(a)).vma]
+    if not checked:
+        return
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        reduced = [a for a in checked if a not in jax.typeof(g).vma]
+        if reduced:
+            raise ValueError(
+                f"gradient {jax.tree_util.keystr(path)} is already "
+                f"reduced over mesh axes {reduced}: the step runs under "
+                f"a checked jax.shard_map, whose autodiff sums the "
+                f"gradient of a replicated parameter.  Wrap the step in "
+                f"horovod_tpu.parallel._compat.shard_map (or pass "
+                f"check_vma=False) so every rank hands its local "
+                f"gradient to the reduction")
+
+
 def allreduce_gradients(grads, named_axes=("hvd",), op=Average,
                         compression=Compression.none):
-    """Reduce a gradient pytree across the given mesh axes.
+    """Reduce a gradient pytree of per-rank (local) gradients across the
+    given mesh axes.
 
     Must be called inside a context where ``named_axes`` are bound
-    (``shard_map`` / ``pmap``).  Cast compression (bf16/fp16) narrows
-    leaves before the collective and restores dtype after, trading
+    (``horovod_tpu.parallel._compat.shard_map`` / ``pmap``); gradients
+    that a checked ``jax.shard_map`` has already reduced are refused
+    (:func:`_require_local_gradients`).  Cast compression (bf16/fp16)
+    narrows leaves before the collective and restores dtype after, trading
     HBM/ICI bandwidth for precision exactly like the reference's fp16
     compression (``horovod/torch/compression.py:45``) — but bf16-native.
     ``Compression.int8`` runs the block-scaled quantized decomposition
@@ -62,6 +96,7 @@ def allreduce_gradients(grads, named_axes=("hvd",), op=Average,
     allgather): per-rank block scales cannot ride a plain ``psum``.
     """
     op = ReduceOp(op)
+    _require_local_gradients(grads, named_axes)
     if op == Adasum:
         from horovod_tpu.ops.adasum import adasum_reduce_pytree
         return adasum_reduce_pytree(grads, named_axes=named_axes,
@@ -79,7 +114,7 @@ def allreduce_gradients(grads, named_axes=("hvd",), op=Average,
                         else jax.lax.psum(g, named_axes))
             red = quantized_allreduce(g.reshape(-1), axis, block)
             if op == Average:
-                red = red / jax.lax.psum(1, axis)
+                red = red / jax.lax.axis_size(axis)
             return red.astype(g.dtype).reshape(g.shape)
 
         return jax.tree.map(reduce_quantized, grads)

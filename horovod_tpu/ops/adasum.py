@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.common.compression import Compression
-from horovod_tpu.parallel._compat import axis_size
 
 
 def _pair_coefficients(dot, norm_a, norm_b):
@@ -104,7 +103,7 @@ def adasum_vhdd(x, axis_name, scalar_axes=()):
     reduction communicators likewise span the intra-node ranks holding the
     other chunks (adasum_gpu_operations.cc start_level=local_size).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n & (n - 1):
         raise ValueError(f"Adasum VHDD requires power-of-two ranks, got {n}")
     if n == 1:
@@ -161,7 +160,7 @@ def adasum_reduce_hierarchical(x, local_axis="local", cross_axis="cross"):
     reduce-scatter (sum) within the fast local group, Adasum VHDD across
     the cross axis, allgather back, with the reference's ``local_size``
     divisor folded in (``torch/mpi_ops.py:110``)."""
-    local_size = axis_size(local_axis)
+    local_size = jax.lax.axis_size(local_axis)
     flat = x.astype(jnp.float32).reshape(-1)
     pad = (-flat.size) % local_size
     if pad:

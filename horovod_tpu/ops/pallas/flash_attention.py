@@ -79,15 +79,10 @@ def _pick_block_n(n, d, slabs=1):
 
 
 def _sds(shape, dtype, like):
-    """ShapeDtypeStruct carrying the varying-manual-axes of ``like`` so the
-    kernel composes with new-style shard_map (check_vma=True)."""
-    try:
-        vma = getattr(jax.typeof(like), "vma", None)
-        if vma is not None:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except Exception:  # pragma: no cover
-        pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    """ShapeDtypeStruct carrying the varying-manual-axes of ``like``, so a
+    forward call also composes with a caller's checked ``jax.shard_map``
+    (empty under the framework's own, unchecked one)."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 # ----------------------------------------------------- row-scalar packing
@@ -489,8 +484,8 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _default_interpret()
-    # HVD_FLASH_BLOCK_Q/K: measured-default overrides (bank-tpu's
-    # flash_blocks sweep is the evidence source).  block_q=128 keeps
+    # HVD_FLASH_BLOCK_Q/K override the 128 x 128 default, which no
+    # sweep on a chip has confirmed yet (ROADMAP D4).  block_q=128 keeps
     # the packed lse/delta layout; other values fall back to the
     # broadcast layout.
     if block_q is None:

@@ -28,12 +28,8 @@ import math
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from horovod_tpu.common.compression import (INT8_BLOCK,
                                             quantized_all_gather,
@@ -54,12 +50,8 @@ FUSION_ALIGN_ELEMS = 64
 def _shard_map_gathered(body, mesh, in_specs, out_specs):
     """shard_map whose body returns an all-gathered (hence device-invariant,
     but not statically-inferrable-as-replicated) value."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+    return _shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)
 
 
 def _prod(shape):

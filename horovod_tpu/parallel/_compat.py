@@ -1,49 +1,22 @@
-"""JAX version compatibility shims shared by the parallel subsystem."""
+"""The framework's ``shard_map``: ``jax.shard_map`` without varying-axes
+checking.
 
-import inspect
+Every SPMD step in this repository (``DistributedOptimizer``, ZeRO, the
+sequence/pipeline-parallel wrappers, the Pallas kernels) is written in
+the per-rank style of the reference: each rank differentiates ITS loss
+with ``jax.grad`` and the framework reduces the local gradients.  A
+checked ``jax.shard_map`` changes that contract — autodiff already sums
+the gradient of a replicated (``P()``) parameter over the mesh axis, so
+a following ``pmean`` is the identity and the optimizer would apply N x
+the mean — and its type rule rejects the Pallas ``custom_vjp`` backward
+rules (a per-rank ``gamma`` cotangent for an unvarying ``gamma``).
+``allreduce_gradients`` refuses gradients that arrive already reduced,
+so a step wrapped in a checked ``jax.shard_map`` fails at trace time
+rather than training with a wrong scale.
+"""
 
-try:
-    from jax import shard_map as _shard_map_mod  # jax >= 0.6
-    shard_map = _shard_map_mod.shard_map if hasattr(
-        _shard_map_mod, "shard_map") else _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+import functools
 
-try:
-    _check_kw = next(
-        (kw for kw in ("check_vma", "check_rep")
-         if kw in inspect.signature(shard_map).parameters), None)
-except (TypeError, ValueError):  # pragma: no cover
-    _check_kw = None
+import jax
 
-
-def shard_map_unchecked(*args, **kwargs):
-    """shard_map with replication/varying-axes checking disabled — the
-    keyword is ``check_vma`` on current jax, ``check_rep`` on older."""
-    if _check_kw:
-        kwargs.setdefault(_check_kw, False)
-    return shard_map(*args, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` where it exists (newer jax); ``psum(1, axis)``
-    on older releases — equally constant-folded inside shard_map/pmap,
-    so call sites can treat the result as a static int either way."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map_kernel_body(*args, **kwargs):
-    """shard_map for bodies that may call Pallas kernels: checking stays ON
-    when lowering for real TPU, and is disabled only on the CPU backend,
-    where kernels run in interpret mode and pallas_call trips the
-    varying-manual-axes checker (dynamic_slice mixing varying and unvarying
-    operands)."""
-    import jax
-
-    if _check_kw and jax.default_backend() == "cpu":
-        kwargs.setdefault(_check_kw, False)
-    return shard_map(*args, **kwargs)
+shard_map = functools.partial(jax.shard_map, check_vma=False)

@@ -72,12 +72,7 @@ def _constrain_ep(y, mesh):
     if mesh is not None:
         from horovod_tpu.parallel.tensor_parallel import constrain
         return constrain(y, mesh, None, "ep", None, None)
-    try:
-        ambient = jax.sharding.get_abstract_mesh()
-        ambient_axes = ambient.axis_names if ambient is not None else ()
-    except AttributeError:  # older jax: no ambient-mesh introspection
-        ambient_axes = ()
-    if "ep" not in ambient_axes:
+    if "ep" not in jax.sharding.get_abstract_mesh().axis_names:
         return y
     return jax.lax.with_sharding_constraint(y, P(None, "ep", None, None))
 

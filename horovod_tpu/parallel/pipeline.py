@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from horovod_tpu.parallel._compat import axis_size, shard_map_unchecked
+from horovod_tpu.parallel._compat import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, microbatches, *, axis_name="pp"):
@@ -34,7 +34,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, *, axis_name="pp"):
     Returns ``[M, mb, ...]`` outputs, valid on every shard (the last
     stage's results are broadcast back with a masked psum).
     """
-    s = axis_size(axis_name)
+    s = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = microbatches.shape[0]
     ticks = m + s - 1
@@ -45,9 +45,8 @@ def pipeline_apply(stage_fn, stage_params, microbatches, *, axis_name="pp"):
     perm = [(i, (i + 1) % s) for i in range(s)]
     state0 = jnp.zeros_like(microbatches[0])
     out0 = jnp.zeros_like(microbatches)
-    if hasattr(lax, "pcast"):
-        state0 = lax.pcast(state0, (axis_name,), to="varying")
-        out0 = lax.pcast(out0, (axis_name,), to="varying")
+    state0 = lax.pcast(state0, (axis_name,), to="varying")
+    out0 = lax.pcast(out0, (axis_name,), to="varying")
 
     def tick(carry, t):
         state, outs = carry
@@ -99,7 +98,7 @@ def pipelined(stage_fn, mesh, *, axis_name="pp", stage_param_specs=None,
     def run(stacked_params, microbatches):
         specs_params = jax.tree_util.tree_map(
             lambda _: stage_param_specs, stacked_params)
-        fn = shard_map_unchecked(
+        fn = shard_map(
             lambda p, x: pipeline_apply(stage_fn, p, x,
                                         axis_name=axis_name),
             mesh=mesh,

@@ -28,11 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from horovod_tpu.parallel._compat import axis_size
-# unchecked: jax's replication checker mis-infers through the
-# grad-of-cond in the ring step on some releases (the error text
-# itself prescribes check_rep=False as the workaround)
-from horovod_tpu.parallel._compat import shard_map_unchecked as shard_map
+from horovod_tpu.parallel._compat import shard_map
 
 
 _NEG_INF = -1e30
@@ -91,7 +87,7 @@ def ring_attention(q, k, v, *, axis_name, causal=False, scale=None,
     streaming-softmax combine as the dense path, so results are exact
     either way.
     """
-    p_size = axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name) if query_chunk_idx is None \
         else query_chunk_idx
     b, tq, h, d = q.shape
@@ -105,13 +101,10 @@ def ring_attention(q, k, v, *, axis_name, causal=False, scale=None,
     o0 = jnp.zeros((b, tq, h, d), jnp.float32)
     l0 = jnp.zeros((b, h, tq), jnp.float32)
     m0 = jnp.full((b, h, tq), _NEG_INF, jnp.float32)
-    # Newer shard_map tracks varying-manual-axes: the accumulators become
+    # Under a caller's checked shard_map the accumulators become
     # device-varying inside the loop, so the initial carry must be too.
-    if hasattr(lax, "pcast"):
-        o0, l0, m0 = (lax.pcast(x, (axis_name,), to="varying")
-                      for x in (o0, l0, m0))
-    elif hasattr(lax, "pvary"):  # pragma: no cover
-        o0, l0, m0 = (lax.pvary(x, (axis_name,)) for x in (o0, l0, m0))
+    o0, l0, m0 = (lax.pcast(x, (axis_name,), to="varying")
+                  for x in (o0, l0, m0))
 
     perm = [(i, (i + 1) % p_size) for i in range(p_size)]
 
@@ -128,11 +121,8 @@ def ring_attention(q, k, v, *, axis_name, causal=False, scale=None,
                 causal=is_causal, scale=scale, return_lse=True)
             # represent as (numerator, denom, max): normalized out with
             # denom=1 in lse units plugs into the same _combine rule
-            ones = jnp.ones((b, h, tq), jnp.float32)
-            if hasattr(lax, "pcast"):
-                ones = lax.pcast(ones, (axis_name,), to="varying")
-            elif hasattr(lax, "pvary"):  # pragma: no cover
-                ones = lax.pvary(ones, (axis_name,))
+            ones = lax.pcast(jnp.ones((b, h, tq), jnp.float32),
+                             (axis_name,), to="varying")
             return (out.astype(jnp.float32), ones, lse)
 
         if causal:

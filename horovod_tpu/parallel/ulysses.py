@@ -23,8 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from horovod_tpu.parallel._compat import axis_size
-from horovod_tpu.parallel._compat import shard_map_kernel_body as shard_map
+from horovod_tpu.parallel._compat import shard_map
 from horovod_tpu.parallel.ring_attention import reference_attention
 
 
@@ -53,7 +52,7 @@ def ulysses_attention(q, k, v, *, axis_name, causal=False, scale=None,
     if attn_fn is None:
         attn_fn = reference_attention
     h = q.shape[2]
-    p_size = axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     if h % p_size != 0:
         raise ValueError(
             f"Ulysses needs heads ({h}) divisible by axis size ({p_size}); "

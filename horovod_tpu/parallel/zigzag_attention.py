@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from horovod_tpu.parallel._compat import axis_size, shard_map
+from horovod_tpu.parallel._compat import shard_map
 from horovod_tpu.parallel.ring_attention import (_NEG_INF, _block_attend,
                                                  _combine)
 
@@ -94,11 +94,8 @@ def _attend(q, k, v, *, scale, causal, use_flash, axis_name):
         out, lse = flash_attention(q, k.astype(q.dtype),
                                    v.astype(q.dtype), causal=causal,
                                    scale=scale, return_lse=True)
-        ones = jnp.ones((b, h, tq), jnp.float32)
-        if hasattr(lax, "pcast"):
-            ones = lax.pcast(ones, (axis_name,), to="varying")
-        elif hasattr(lax, "pvary"):  # pragma: no cover
-            ones = lax.pvary(ones, (axis_name,))
+        ones = lax.pcast(jnp.ones((b, h, tq), jnp.float32),
+                         (axis_name,), to="varying")
         return out.astype(jnp.float32), ones, lse
     if causal:
         msk = (jnp.arange(tq)[:, None]
@@ -119,7 +116,7 @@ def zigzag_ring_attention(q, k, v, *, axis_name, scale=None,
     causal — for the non-causal case the plain ring is already
     balanced; use :func:`ring_attention`.
     """
-    p_size = axis_size(axis_name)
+    p_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, t2, h, d = q.shape
     if t2 % 2:
@@ -140,12 +137,8 @@ def zigzag_ring_attention(q, k, v, *, axis_name, scale=None,
         o = jnp.zeros((b, tq, h, d), jnp.float32)
         l = jnp.zeros((b, h, tq), jnp.float32)
         m = jnp.full((b, h, tq), _NEG_INF, jnp.float32)
-        if hasattr(lax, "pcast"):
-            o, l, m = (lax.pcast(x, (axis_name,), to="varying")
-                       for x in (o, l, m))
-        elif hasattr(lax, "pvary"):  # pragma: no cover
-            o, l, m = (lax.pvary(x, (axis_name,)) for x in (o, l, m))
-        return o, l, m
+        return tuple(lax.pcast(x, (axis_name,), to="varying")
+                     for x in (o, l, m))
 
     # Resident step (kv from this rank): q_lo/q_hi diagonal-causal on
     # their own chunks + q_hi attends kv_lo fully (chunk 2P-1-i is

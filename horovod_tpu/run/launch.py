@@ -75,6 +75,13 @@ def slot_env(slot, rendezvous_addr, rendezvous_port, extra_env=None):
         env_util.HVD_RENDEZVOUS_ADDR: rendezvous_addr,
         env_util.HVD_RENDEZVOUS_PORT: str(rendezvous_port),
     }
+    if slot.local_size > 1:
+        # A TPU chip belongs to one process: ranks that share a host
+        # would each claim every chip at ``jax.local_devices()`` and all
+        # but one abort inside libtpu.  Process-rank mode is the CPU
+        # configuration, so these ranks run on the CPU backend whatever
+        # the host's own JAX_PLATFORMS says (docs/running.md).
+        env["JAX_PLATFORMS"] = "cpu"
     if extra_env:
         env.update(extra_env)
     return env

@@ -76,7 +76,7 @@ def ShardedDistributedOptimizer(optimizer, axis_name="hvd", op=Average,
     Both ``init`` and ``update`` must run INSIDE ``shard_map`` over
     ``axis_name`` (init the state in a jitted sharded step — see
     ``tests/test_spmd.py``).  Use
-    ``horovod_tpu.parallel._compat.shard_map_unchecked``: the gathered
+    ``horovod_tpu.parallel._compat.shard_map``: the gathered
     updates ARE replicated, but jax's varying-manual-axes checker cannot
     infer replication through ``all_gather`` (no public un-vary
     annotation exists), so the check must be off for the step.  Average

@@ -1,0 +1,184 @@
+"""The chip's compiler, without the chip: the main path's kernels and the
+whole language-model step compile for a described TPU v5e at the sizes
+``chip_smoke.py`` runs (the ``on-chip-measurement`` guide, section 2.3).
+
+Nothing executes, so these say nothing about results or speed; they
+catch what interpret mode cannot — a tiling the lowering refuses, a
+``custom_vjp`` rule the tracer rejects, a step that does not fit 16 GB,
+a step the compiler cannot partition over four chips.
+
+In-process and serial by construction: two processes describing the
+topology at once collide on libtpu's lock file.  Code that asks
+``jax.default_backend()`` sees the CPU here, so the tests steer it to
+"tpu" themselves; the program has no option for that.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+import chip_smoke
+
+HBM_BYTES = 16 * 2 ** 30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Four described (not attached) v5e chips."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu, no rehearsal
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc!r}")
+    return list(topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def _chip_branch_no_cache(monkeypatch):
+    """Take the TPU branch of the model, and keep these compiles out of
+    the persistent cache: an entry written for a described chip cannot
+    be read back without one, and every later run would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _on(device, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+LM = chip_smoke.LM
+B, T, H, D = LM["batch"], LM["seq"], LM["heads"], \
+    LM["d_model"] // LM["heads"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_attention_compiles_for_v5e(v5e, dtype):
+    from horovod_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    qkv = [_on(v5e[0], (B, T, H, D), dtype)] * 3
+    text = _compile(fwd_bwd, *qkv).as_text()
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+
+
+def test_layer_norm_compiles_for_v5e(v5e):
+    from horovod_tpu.ops.pallas.layer_norm import layer_norm
+
+    def fwd_bwd(x, gamma, beta):
+        return jax.value_and_grad(lambda x, g, b: jnp.sum(layer_norm(
+            x, g, b, 1e-6, False).astype(jnp.float32)),
+            (0, 1, 2))(x, gamma, beta)
+
+    d = LM["d_model"]
+    text = _compile(fwd_bwd, _on(v5e[0], (B, T, d), jnp.bfloat16),
+                    _on(v5e[0], (d,), jnp.float32),
+                    _on(v5e[0], (d,), jnp.float32)).as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+def test_softmax_xent_compiles_for_v5e(v5e):
+    from horovod_tpu.ops.pallas.softmax_xent import softmax_xent
+
+    def fwd_bwd(logits, labels):
+        return jax.value_and_grad(lambda lg: jnp.mean(
+            softmax_xent(lg, labels, False)))(logits)
+
+    text = _compile(fwd_bwd,
+                    _on(v5e[0], (B, T, LM["vocab"]), jnp.bfloat16),
+                    _on(v5e[0], (B, T), jnp.int32)).as_text()
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_lm_step_compiles_for_v5e(v5e, chips):
+    """``chip_smoke.py``'s own training step — ``DistributedOptimizer``
+    inside the framework's ``shard_map``, Pallas ``custom_vjp`` rules
+    under it — at full width on one and on four described chips."""
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import make_mesh
+
+    mesh = make_mesh({"hvd": chips}, devices=v5e[:chips])
+    model = chip_smoke.lm_model()
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-4))
+
+    def shaped(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32)
+    params = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), tokens)["params"]
+    compiled = chip_smoke.lm_step(model, opt, mesh).lower(
+        shaped(params, P()), shaped(jax.eval_shape(opt.init, params), P()),
+        shaped(tokens, P("hvd"))).compile()
+
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("all-reduce" in text) == (chips > 1)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("kind", ["ring", "zigzag", "ulysses"])
+def test_sequence_parallel_attention_compiles_for_v5e(v5e, kind):
+    """The three sequence-parallel attentions with the flash kernel as
+    their local block, forward and backward, over four described chips.
+    A compile only: none of them has run on a chip."""
+    import functools
+
+    from horovod_tpu import parallel
+    from horovod_tpu.ops.pallas.flash_attention import flash_attention
+    from horovod_tpu.parallel._compat import shard_map
+
+    body = {
+        "ring": functools.partial(parallel.ring_attention,
+                                  axis_name="sp", causal=True),
+        "zigzag": functools.partial(parallel.zigzag_ring_attention,
+                                    axis_name="sp"),
+        "ulysses": functools.partial(parallel.ulysses_attention,
+                                     axis_name="sp", causal=True,
+                                     attn_fn=flash_attention),
+    }[kind]
+    mesh = parallel.make_mesh({"sp": 4}, devices=v5e)
+    spec = P(None, "sp", None, None)
+    attend = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                       out_specs=spec)
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+    qkv = [jax.ShapeDtypeStruct((2, 4 * T, H, D), jnp.bfloat16,
+                                sharding=NamedSharding(mesh, spec))] * 3
+    text = _compile(fwd_bwd, *qkv).as_text()
+    assert "tpu_custom_call" in text
+    moves = "all-to-all" if kind == "ulysses" else "collective-permute"
+    assert moves in text

@@ -372,7 +372,7 @@ def test_sharded_optimizer_int8_reduce_scatter(hvd):
     import optax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.parallel._compat import shard_map_unchecked
+    from horovod_tpu.parallel._compat import shard_map
 
     mesh = hvd.mesh()
     n = mesh.devices.size
@@ -388,7 +388,7 @@ def test_sharded_optimizer_int8_reduce_scatter(hvd):
         updates, _ = opt.update({"w": g[0]}, state, params)
         return updates["w"][None]
 
-    out = jax.jit(shard_map_unchecked(
+    out = jax.jit(shard_map(
         per_shard, mesh=mesh, in_specs=P("hvd"), out_specs=P("hvd")))(
             jnp.asarray(grads))
     _assert_block_bound(-np.asarray(out)[0], expected)

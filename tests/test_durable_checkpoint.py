@@ -372,7 +372,7 @@ def test_termination_grace_window_env(monkeypatch):
     assert safe_shell_exec.termination_grace_seconds() == 9.5
 
 
-def test_drained_sentinel_and_error_taxonomy():
+def test_drained_sentinel_and_error_classes():
     import horovod_tpu as hvd
 
     assert not hvd.elastic.DRAINED             # falsy ...

@@ -170,20 +170,10 @@ def test_flash_in_ring_attention(causal):
                for _ in range(3))
 
     spec = P(None, "sp", None, None)
-    # interpret-mode pallas inside strict-vma shard_map trips a jax
-    # hlo_interpreter limitation; real-TPU runs use check_vma=True fine
-    try:
-        fn = shard_map(
-            functools.partial(ring_attention, axis_name="sp",
-                              causal=causal, use_flash=True),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_vma=False)
-    except TypeError:
-        fn = shard_map(
-            functools.partial(ring_attention, axis_name="sp",
-                              causal=causal, use_flash=True),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+    fn = shard_map(
+        functools.partial(ring_attention, axis_name="sp",
+                          causal=causal, use_flash=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     sharding = NamedSharding(mesh, spec)
     out = fn(jax.device_put(q, sharding), jax.device_put(k, sharding),
              jax.device_put(v, sharding))

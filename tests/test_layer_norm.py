@@ -1,5 +1,6 @@
 """Fused Pallas LayerNorm vs the XLA oracle (interpret mode on CPU;
-the same kernels compile on TPU — see KERNEL_VALIDATION.md)."""
+the same kernels compile on TPU — see tests/test_chip_compile.py and
+chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp

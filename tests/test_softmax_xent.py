@@ -1,5 +1,6 @@
 """Fused Pallas softmax cross-entropy vs the XLA oracle (interpret mode
-on CPU; compiled Pallas on TPU — see KERNEL_VALIDATION.md)."""
+on CPU; compiled Pallas on TPU — see tests/test_chip_compile.py and
+chip_smoke.py)."""
 
 import jax
 import jax.numpy as jnp

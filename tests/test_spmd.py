@@ -9,13 +9,8 @@ import optax
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
-
 import horovod_tpu as hvd
-from horovod_tpu.parallel._compat import shard_map_unchecked
+from horovod_tpu.parallel._compat import shard_map
 from horovod_tpu.models import MLP
 from horovod_tpu.parallel import make_mesh
 
@@ -96,6 +91,104 @@ def test_distributed_optimizer_matches_manual_pmean(hvd_init, mesh):
                                np.arange(8.0) - mean_grad, rtol=1e-6)
 
 
+# ---- the gradient exchange is a MEAN of per-rank gradients ----------
+#
+# CPU witness of the N x gradient defect: under a checked jax.shard_map,
+# jax.grad already sums the gradient of a P() parameter over the axis, so
+# the optimizer's pmean was the identity and a 4-rank step applied 4 x
+# the mean (tests that only watch the loss go down cannot see it).
+
+_N = 4
+_DIM = 512   # >= one int8 scale block, so Compression.int8 quantizes
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return make_mesh({"hvd": _N}, devices=jax.devices()[:_N])
+
+
+@pytest.fixture(scope="module")
+def exchange_case():
+    """Replicated ``w``, a global batch, and this loss's gradients: the
+    single-device gradient of the whole batch and each rank's own."""
+    rng = np.random.RandomState(3)
+    x = rng.randn(4 * _N, _DIM).astype(np.float32)
+    w = rng.randn(_DIM).astype(np.float32)
+
+    def loss(w, x):
+        return jnp.mean(jnp.tanh(x @ w))
+
+    global_grad = np.asarray(jax.grad(loss)(w, x))
+    rank_grads = [np.asarray(jax.grad(loss)(w, xs))
+                  for xs in np.split(x, _N)]
+    return loss, w, x, global_grad, rank_grads
+
+
+def _one_update(mesh, sm, opt, case):
+    loss, w, x, _, _ = case
+
+    def per_shard(w, x):
+        grads = jax.grad(loss)(w, x)
+        updates, _ = opt.update(grads, opt.init(w), w)
+        return updates
+
+    fn = jax.jit(sm(per_shard, mesh=mesh, in_specs=(P(), P("hvd")),
+                    out_specs=P()))
+    return np.asarray(fn(w, jax.device_put(
+        x, NamedSharding(mesh, P("hvd")))))
+
+
+@pytest.mark.parametrize("op,scale", [(hvd.Average, 1.0),
+                                      (hvd.Sum, float(_N))],
+                         ids=["average", "sum"])
+def test_update_is_mean_of_rank_gradients(hvd_init, mesh4, exchange_case,
+                                          op, scale):
+    """sgd(1.0) under DistributedOptimizer: the update is minus the
+    single-device gradient of the global batch (Average), N x (Sum)."""
+    global_grad = exchange_case[3]
+    opt = hvd.DistributedOptimizer(optax.sgd(1.0), op=op)
+    np.testing.assert_allclose(
+        _one_update(mesh4, shard_map, opt, exchange_case),
+        -scale * global_grad, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["int8", "adasum"])
+def test_int8_and_adasum_see_rank_gradients(hvd_init, mesh4,
+                                            exchange_case, kind):
+    """The quantized and Adasum reductions combine each rank's OWN
+    gradient: int8 lands on the mean within its quantization bound (a
+    pre-summed input would land on N x), Adasum on the pairing-tree
+    oracle of the per-rank gradients (identical inputs would return the
+    input)."""
+    from horovod_tpu.common.compression import Compression
+    from horovod_tpu.ops.adasum import adasum_reference
+
+    _, _, _, global_grad, rank_grads = exchange_case
+    if kind == "int8":
+        opt = hvd.DistributedOptimizer(optax.sgd(1.0),
+                                       compression=Compression.int8)
+        expected = global_grad
+        # two quantizations of block-max/127 each, averaged over N
+        tol = 2 * np.abs(np.stack(rank_grads)).max() / 127
+    else:
+        opt = hvd.DistributedOptimizer(optax.sgd(1.0), op=hvd.Adasum)
+        expected, tol = adasum_reference(rank_grads), 1e-6
+    got = -_one_update(mesh4, shard_map, opt, exchange_case)
+    np.testing.assert_allclose(got, expected, atol=tol, rtol=1e-4)
+    assert np.abs(got - expected).max() < \
+        0.1 * np.abs(got - _N * global_grad).max()
+
+
+def test_checked_shard_map_gradients_are_refused(hvd_init, mesh4,
+                                                 exchange_case):
+    """A step wrapped in plain (checked) jax.shard_map hands over a
+    gradient autodiff already summed; the reduction refuses it at trace
+    time instead of applying N x the mean."""
+    opt = hvd.DistributedOptimizer(optax.sgd(1.0))
+    with pytest.raises(ValueError, match="already reduced over mesh axes"):
+        _one_update(mesh4, jax.shard_map, opt, exchange_case)
+
+
 def test_backward_passes_per_step_aggregation(hvd_init, mesh):
     """Gradients accumulate locally for k passes, one reduction per k
     (reference: gradient_aggregation.py semantics)."""
@@ -146,7 +239,7 @@ def test_adasum_spmd_matches_reference(hvd_init, mesh):
 
     data = jax.device_put(jnp.asarray(per_rank),
                           NamedSharding(mesh, P("hvd")))
-    out = jax.jit(shard_map_unchecked(
+    out = jax.jit(shard_map(
         body, mesh=mesh, in_specs=(P("hvd"),), out_specs=P(),
     ))(data.reshape(8, 1, 16))
     np.testing.assert_allclose(np.asarray(out).reshape(-1), expected,
@@ -166,7 +259,7 @@ def test_adasum_vhdd_matches_reference(hvd_init):
         per_rank = rng.randn(8, n).astype(np.float32)
         expected = adasum_reference(list(per_rank))
 
-        out = jax.jit(shard_map_unchecked(
+        out = jax.jit(shard_map(
             lambda g: adasum_vhdd(g[0], "x")[None],
             mesh=mesh, in_specs=(P("x"),), out_specs=P(),
         ))(jnp.asarray(per_rank).reshape(8, 1, n))
@@ -189,7 +282,7 @@ def test_adasum_hierarchical_matches_reference(hvd_init):
     group_b = per_rank[4:].sum(axis=0) / 4.0
     expected = adasum_reference([group_a, group_b])
 
-    out = jax.jit(shard_map_unchecked(
+    out = jax.jit(shard_map(
         lambda g: adasum_reduce_hierarchical(g[0])[None],
         mesh=mesh, in_specs=(P(("cross", "local")),), out_specs=P(),
     ))(jnp.asarray(per_rank).reshape(8, 1, 33))
@@ -240,11 +333,11 @@ def test_sharded_optimizer_matches_unsharded(hvd_init, mesh):
         updates, state = plain.update(grads, state, params)
         return optax.apply_updates(params, updates)
 
-    sharded_fn = jax.jit(shard_map_unchecked(
+    sharded_fn = jax.jit(shard_map(
         sharded_step, mesh=mesh,
         in_specs=(P(), P("hvd"), P("hvd")),
         out_specs=(P(), P("hvd"))))
-    plain_fn = jax.jit(shard_map_unchecked(
+    plain_fn = jax.jit(shard_map(
         plain_step, mesh=mesh,
         in_specs=(P(), P(), P("hvd"), P("hvd")),
         out_specs=P()))
@@ -287,11 +380,11 @@ def test_sharded_optimizer_trains(hvd_init, mesh):
         return optax.apply_updates(params, updates), \
             hvd.sharded_state_wrap(state), jax.lax.pmean(loss, "hvd")
 
-    init_fn = jax.jit(shard_map_unchecked(
+    init_fn = jax.jit(shard_map(
         init_state, mesh=mesh, in_specs=P(), out_specs=P("hvd")))
 
     state = init_fn(params)
-    step_fn = jax.jit(shard_map_unchecked(
+    step_fn = jax.jit(shard_map(
         step, mesh=mesh,
         in_specs=(P(), P("hvd"), P("hvd"), P("hvd")),
         out_specs=(P(), P("hvd"), P())))
@@ -320,11 +413,11 @@ def test_sharded_optimizer_compiles_to_one_rs_one_ag(hvd_init, mesh):
         u, s2 = opt.update(g, hvd.sharded_state_unwrap(s), p)
         return optax.apply_updates(p, u), hvd.sharded_state_wrap(s2)
 
-    init_j = jax.jit(shard_map_unchecked(
+    init_j = jax.jit(shard_map(
         lambda p: hvd.sharded_state_wrap(opt.init(p)), mesh=mesh,
         in_specs=P(), out_specs=P("hvd")))
     state = init_j(params)
-    step_j = jax.jit(shard_map_unchecked(
+    step_j = jax.jit(shard_map(
         step, mesh=mesh, in_specs=(P(), P("hvd"), P("hvd"), P("hvd")),
         out_specs=(P(), P("hvd"))))
 

@@ -62,12 +62,8 @@ def test_zigzag_single_rank_degenerate():
 
 
 def test_zigzag_with_flash_blocks():
-    """Flash kernel (interpret mode) computing each zigzag block.
-
-    interpret-mode pallas inside strict-vma shard_map trips a jax
-    hlo_interpreter limitation (same as the ring-attention test);
-    real-TPU runs use check_vma=True fine — build the shard_map with
-    check_vma=False here."""
+    """Flash kernel (interpret mode) computing each zigzag block, under
+    the framework's (unchecked) shard_map."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from horovod_tpu.parallel._compat import shard_map
@@ -82,12 +78,8 @@ def test_zigzag_with_flash_blocks():
     spec = P(None, "sp", None, None)
     fn = functools.partial(zigzag_ring_attention, axis_name="sp",
                            use_flash=True)
-    try:
-        sm = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=False)
-    except TypeError:
-        sm = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+    sm = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec)
     sharding = NamedSharding(mesh, spec)
     args = [jax.device_put(zigzag_shard(x, 4), sharding)
             for x in (q, k, v)]
