@@ -21,6 +21,7 @@ import threading
 from horovod_tpu.common import topology as topology_mod
 from horovod_tpu.common.config import Config
 from horovod_tpu.utils import env as env_util
+from horovod_tpu.utils import trace
 from horovod_tpu.utils.logging import get_logger
 from horovod_tpu.utils.timeline import Timeline
 
@@ -195,6 +196,8 @@ def init(comm=None, controller=None):
         # groups (docs/groups.md): the registry belongs to ONE init
         from horovod_tpu import groups as groups_mod
         groups_mod.reset()
+        # and hvd.eager_stats() counts from here
+        trace.reset()
         _maybe_install_drain(config)
 
 

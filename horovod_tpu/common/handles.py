@@ -9,6 +9,8 @@ API fidelity.
 import json
 import threading
 
+from horovod_tpu.utils import trace
+
 
 class HvdError(RuntimeError):
     """Raised when a collective fails (reference: Response::ERROR path)."""
@@ -118,13 +120,19 @@ def make_abort_error(origin_rank, reason):
 class Handle:
     """Completion handle for one rank's view of one collective."""
 
-    __slots__ = ("_event", "_result", "_error", "name")
+    # the last five: the request's stamps for utils/trace.py's log,
+    # 0 until the eager plane sets them (a join's handle never has any)
+    __slots__ = ("_event", "_result", "_error", "name", "request_id",
+                 "response_id", "t_submit", "t_enqueued",
+                 "t_execute_start")
 
     def __init__(self, name=""):
         self._event = threading.Event()
         self._result = None
         self._error = None
         self.name = name
+        self.request_id = self.response_id = 0
+        self.t_submit = self.t_enqueued = self.t_execute_start = 0
 
     def set_result(self, result):
         # first completion wins: an abort broadcast and the op's own
@@ -132,6 +140,9 @@ class Handle:
         if self._event.is_set():
             return
         self._result = result
+        if self.t_execute_start:
+            # logged before the waiter is released: it may read the log
+            trace.finished(self)
         self._event.set()
 
     def set_error(self, message):

@@ -12,6 +12,7 @@ from horovod_tpu.common import basics
 from horovod_tpu.common.handles import Handle
 from horovod_tpu.common.ops_enum import Adasum, Average, ReduceOp, RequestType, Sum
 from horovod_tpu.ops.python_controller import EagerRequest
+from horovod_tpu.utils import trace
 
 _tls = threading.local()
 
@@ -57,65 +58,69 @@ def _require_rank_context(state, name):
 def _submit(req_type, tensor, name, *, op=Sum, root_rank=-1,
             prescale_factor=1.0, postscale_factor=1.0, splits=None,
             compression=None, group=None) -> Handle:
-    state = basics._get_state()
-    _require_rank_context(state, name)
-    from horovod_tpu import groups as groups_mod
-    from horovod_tpu.common.compression import resolve_compression
+    with trace.span("hvd.submit"):
+        t_submit = trace.now()
+        state = basics._get_state()
+        _require_rank_context(state, name)
+        from horovod_tpu import groups as groups_mod
+        from horovod_tpu.common.compression import resolve_compression
 
-    # group scoping (docs/groups.md): resolve the handle to its CURRENT
-    # incarnation — unsatisfiable groups fail typed here, before
-    # anything reaches a controller — and require membership (a
-    # collective from a non-member can never complete)
-    gid, granks = groups_mod.resolve(group)
-    if gid:
-        me = basics.rank()
-        if me not in granks:
-            raise ValueError(
-                f"collective '{name}': rank {me} is not a member of "
-                f"process group {group.name!r} (ranks {list(granks)})")
+        # group scoping (docs/groups.md): resolve the handle to its CURRENT
+        # incarnation — unsatisfiable groups fail typed here, before
+        # anything reaches a controller — and require membership (a
+        # collective from a non-member can never complete)
+        gid, granks = groups_mod.resolve(group)
+        if gid:
+            me = basics.rank()
+            if me not in granks:
+                raise ValueError(
+                    f"collective '{name}': rank {me} is not a member of "
+                    f"process group {group.name!r} (ranks {list(granks)})")
 
-    # None -> the configured default (HVD_TPU_COMPRESSION / autotune);
-    # accepts a canonical name or a Compression class.  Adasum combines
-    # full-precision vectors by construction, so it never compresses.
-    compression = resolve_compression(
-        compression, default=getattr(state.config, "compression", "none"))
-    if req_type == RequestType.ADASUM:
-        compression = "none"
-    # rank indexes the executor's device list (global in gmesh mode, local
-    # otherwise).  The tcp plane keeps tensors as numpy: a device commit
-    # there would let jax narrow 64-bit dtypes before the exact numpy
-    # transport ever sees them.
-    if tensor is None:
-        committed = None
-    elif state.config.controller == "tcp":
-        import numpy as _np
+        # None -> the configured default (HVD_TPU_COMPRESSION / autotune);
+        # accepts a canonical name or a Compression class.  Adasum combines
+        # full-precision vectors by construction, so it never compresses.
+        compression = resolve_compression(
+            compression, default=getattr(state.config, "compression", "none"))
+        if req_type == RequestType.ADASUM:
+            compression = "none"
+        # rank indexes the executor's device list (global in gmesh mode, local
+        # otherwise).  The tcp plane keeps tensors as numpy: a device commit
+        # there would let jax narrow 64-bit dtypes before the exact numpy
+        # transport ever sees them.
+        if tensor is None:
+            committed = None
+        elif state.config.controller == "tcp":
+            import numpy as _np
 
-        # copy, not a view: capture-at-call semantics — the caller may
-        # legally reuse its buffer before the coordinator cycle runs,
-        # and different ranks racing that mutation would reduce
-        # inconsistent snapshots.  NOTE the device path's contract is
-        # weaker for MUTABLE framework tensors: jax.Array inputs are
-        # immutable (capture-at-call for free), but a torch tensor
-        # staged zero-copy via DLPack is aliased until the cycle reads
-        # it — do not mutate between an async submit and synchronize
-        # (the reference's adapters have the same rule,
-        # torch/adapter_v2.h:42).
-        committed = _np.array(tensor, copy=True)
-    elif gid:
-        # group-local commit: the entry executes on the group's
-        # sub-executor, whose device list is indexed by group rank
-        committed = state.executor.subset(granks).commit(
-            tensor, granks.index(basics.rank()))
-    else:
-        committed = state.executor.commit(tensor, basics.rank())
-    handle = Handle(name)
-    state.controller.enqueue(EagerRequest(
-        rank=basics.rank(), req_type=req_type, name=name, tensor=committed,
-        handle=handle, op=op, root_rank=root_rank,
-        prescale_factor=prescale_factor, postscale_factor=postscale_factor,
-        splits=splits, compression=compression,
-        schedule=getattr(state.config, "schedule", "auto"),
-        group=gid, group_ranks=granks))
+            # copy, not a view: capture-at-call semantics — the caller may
+            # legally reuse its buffer before the coordinator cycle runs,
+            # and different ranks racing that mutation would reduce
+            # inconsistent snapshots.  NOTE the device path's contract is
+            # weaker for MUTABLE framework tensors: jax.Array inputs are
+            # immutable (capture-at-call for free), but a torch tensor
+            # staged zero-copy via DLPack is aliased until the cycle reads
+            # it — do not mutate between an async submit and synchronize
+            # (the reference's adapters have the same rule,
+            # torch/adapter_v2.h:42).
+            committed = _np.array(tensor, copy=True)
+        elif gid:
+            # group-local commit: the entry executes on the group's
+            # sub-executor, whose device list is indexed by group rank
+            committed = state.executor.subset(granks).commit(
+                tensor, granks.index(basics.rank()))
+        else:
+            committed = state.executor.commit(tensor, basics.rank())
+        handle = Handle(name)
+        trace.submitted(handle, t_submit)
+        state.controller.enqueue(EagerRequest(
+            rank=basics.rank(), req_type=req_type, name=name, tensor=committed,
+            handle=handle, op=op, root_rank=root_rank,
+            prescale_factor=prescale_factor, postscale_factor=postscale_factor,
+            splits=splits, compression=compression,
+            schedule=getattr(state.config, "schedule", "auto"),
+            group=gid, group_ranks=granks))
+        handle.t_enqueued = trace.now()
     return handle
 
 
@@ -262,7 +267,8 @@ def barrier(group=None, name=None):
 def synchronize(handle: Handle, timeout=None):
     """Block until the async op completes and return its result
     (reference: mpi_ops.synchronize / HandleManager.WaitForCompletion)."""
-    return handle.wait(timeout)
+    with trace.span("hvd.wait"):
+        return handle.wait(timeout)
 
 
 def poll(handle: Handle) -> bool:

@@ -22,6 +22,7 @@ import threading
 from horovod_tpu.common import wire
 from horovod_tpu.common.ops_enum import ReduceOp, ResponseType
 from horovod_tpu.ops.python_controller import GroupEntry, PythonController
+from horovod_tpu.utils import trace
 from horovod_tpu.utils.logging import get_logger
 
 _LIB_PATH = os.path.join(os.path.dirname(os.path.dirname(__file__)),
@@ -351,7 +352,11 @@ class NativeController:
     # ------------------------------------------------------------- dispatcher
     def _next_batch(self):
         length = ctypes.c_size_t(0)
-        ptr = self._lib.hvd_core_next_batch(self._core, ctypes.byref(length))
+        # the dispatcher blocked in the core: queue, cycle sleep,
+        # negotiation, fusion plan, publish
+        with trace.span("hvd.wait_batch"):
+            ptr = self._lib.hvd_core_next_batch(self._core,
+                                                ctypes.byref(length))
         try:
             return bytes(ctypes.cast(
                 ptr, ctypes.POINTER(ctypes.c_uint8 * length.value)).contents)
@@ -361,8 +366,9 @@ class NativeController:
     def _dispatch_loop(self):
         autotune = bool(self._config.autotune)
         while True:
-            batch_id, is_shutdown, responses = wire.decode_batch(
-                self._next_batch())
+            batch = self._next_batch()
+            with trace.span("hvd.decode"):
+                batch_id, is_shutdown, responses = wire.decode_batch(batch)
             if is_shutdown:
                 return
             if autotune:
@@ -378,15 +384,17 @@ class NativeController:
             error = None
             for resp in responses:
                 try:
-                    self._execute_response(resp)
+                    with trace.span("hvd.execute"):
+                        self._execute_response(resp)
                 except Exception as exc:  # noqa: BLE001 — surface on handles
                     self._log.error("collective execution failed: %s", exc)
                     error = str(exc)
                     self._fail_response(resp,
                                         f"collective execution failed: {exc}")
-            self._lib.hvd_core_mark_done(
-                self._core, batch_id,
-                error.encode() if error is not None else None)
+            with trace.span("hvd.mark_done"):
+                self._lib.hvd_core_mark_done(
+                    self._core, batch_id,
+                    error.encode() if error is not None else None)
 
     def _take(self, req_id):
         with self._lock:
@@ -400,6 +408,7 @@ class NativeController:
                     request.handle.set_error(message)
 
     def _execute_response(self, resp):
+        start = trace.now()
         rtype = ResponseType(resp["type"])
 
         if rtype == ResponseType.ERROR:
@@ -444,6 +453,7 @@ class NativeController:
                 compression=PythonController.resolve_group_compression(
                     getattr(r, "compression", "none")
                     for r in requests.values())))
+        trace.executing(groups, start)
 
         try:
             if rtype in (ResponseType.ALLREDUCE,):

@@ -34,6 +34,7 @@ from horovod_tpu.common.fusion import plan_buckets
 from horovod_tpu.common.handles import HvdAbortedError
 from horovod_tpu.common.ops_enum import ReduceOp, RequestType
 from horovod_tpu.common.response_cache import SignatureCache
+from horovod_tpu.utils import trace
 from horovod_tpu.utils.logging import get_logger
 
 
@@ -674,28 +675,32 @@ class PythonController:
 
     def _execute_allreduce_bucket(self, groups):
         first = groups[0]
-        self._timeline_begin_groups(groups, "ALLREDUCE")
-        self._exec_for(first).allreduce_fused(
-            groups, op=first.op,
-            prescale_factor=first.prescale_factor,
-            postscale_factor=first.postscale_factor,
-            compression=first.compression)
-        self._timeline_end_groups(groups)
+        with trace.span("hvd.execute"):
+            trace.executing(groups, trace.now())
+            self._timeline_begin_groups(groups, "ALLREDUCE")
+            self._exec_for(first).allreduce_fused(
+                groups, op=first.op,
+                prescale_factor=first.prescale_factor,
+                postscale_factor=first.postscale_factor,
+                compression=first.compression)
+            self._timeline_end_groups(groups)
 
     def _execute_single(self, req_type, group):
-        self._timeline_begin_groups([group], req_type.name)
-        executor = self._exec_for(group)
-        if req_type == RequestType.ALLGATHER:
-            executor.allgather(group)
-        elif req_type == RequestType.BROADCAST:
-            executor.broadcast(group)
-        elif req_type == RequestType.ALLTOALL:
-            executor.alltoall(group)
-        elif req_type == RequestType.ADASUM:
-            executor.adasum(group)
-        elif req_type == RequestType.REDUCE_SCATTER:
-            executor.reduce_scatter(group)
-        self._timeline_end_groups([group])
+        with trace.span("hvd.execute"):
+            trace.executing([group], trace.now())
+            self._timeline_begin_groups([group], req_type.name)
+            executor = self._exec_for(group)
+            if req_type == RequestType.ALLGATHER:
+                executor.allgather(group)
+            elif req_type == RequestType.BROADCAST:
+                executor.broadcast(group)
+            elif req_type == RequestType.ALLTOALL:
+                executor.alltoall(group)
+            elif req_type == RequestType.ADASUM:
+                executor.adasum(group)
+            elif req_type == RequestType.REDUCE_SCATTER:
+                executor.reduce_scatter(group)
+            self._timeline_end_groups([group])
 
     def _timeline_begin_groups(self, groups, phase):
         for g in groups:

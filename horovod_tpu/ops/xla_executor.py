@@ -37,6 +37,7 @@ from horovod_tpu.common.compression import (INT8_BLOCK,
                                             resolve_compression)
 from horovod_tpu.common.ops_enum import ReduceOp, is_float_dtype
 from horovod_tpu.utils import env as env_util
+from horovod_tpu.utils import trace
 from horovod_tpu.utils.logging import get_logger
 
 AXIS = "hvd"
@@ -182,26 +183,28 @@ class XlaExecutor:
         folds programs over empty/trivial shards, and folded outputs land
         on the DEFAULT device regardless of input placement (no-op when
         already resident)."""
-        per_rank_bufs = [
-            jax.device_put(buf, self.devices[rank])
-            for buf, rank in zip(per_rank_bufs, self.local_ranks)]
-        global_shape = (self.num_ranks,) + tuple(shard_shape[1:])
-        return jax.make_array_from_single_device_arrays(
-            global_shape, self._sharded, per_rank_bufs)
+        with trace.span("hvd.exec.stack"):
+            per_rank_bufs = [
+                jax.device_put(buf, self.devices[rank])
+                for buf, rank in zip(per_rank_bufs, self.local_ranks)]
+            global_shape = (self.num_ranks,) + tuple(shard_shape[1:])
+            return jax.make_array_from_single_device_arrays(
+                global_shape, self._sharded, per_rank_bufs)
 
     # ------------------------------------------------------- fusion buffer in
     def _fuse_in(self, tensors, sizes, dtype):
         """Concat one rank's tensors into a flat [1, total] buffer on its
         device (reference: MemcpyInFusionBuffer)."""
-        key = (tuple(sizes), np.dtype(dtype).name)
-        fn = self._fuse_in_cache.get(key)
-        if fn is None:
-            def fuse(*ts):
-                return jnp.concatenate(
-                    [t.reshape(-1) for t in ts]).reshape(1, -1)
-            fn = jax.jit(fuse)
-            self._fuse_in_cache[key] = fn
-        return fn(*tensors)
+        with trace.span("hvd.exec.fuse_in"):
+            key = (tuple(sizes), np.dtype(dtype).name)
+            fn = self._fuse_in_cache.get(key)
+            if fn is None:
+                def fuse(*ts):
+                    return jnp.concatenate(
+                        [t.reshape(-1) for t in ts]).reshape(1, -1)
+                fn = jax.jit(fuse)
+                self._fuse_in_cache[key] = fn
+            return fn(*tensors)
 
     def _zeros_buf(self, total, dtype, rank):
         """Zero stand-in buffer for a joined rank (reference:
@@ -266,108 +269,116 @@ class XlaExecutor:
                 bufs.append(self._fuse_in(tensors, sizes, dtype))
         garr = self._stack(bufs, (1, total), dtype)
 
-        hierarchical = bool(self.hierarchical_allreduce
-                            and self.hier_mesh is not None)
-        key = (shapes, np.dtype(dtype).name, int(op),
-               float(prescale_factor), float(postscale_factor), hierarchical,
-               comp)
-        fn = self._allreduce_cache.get(key)
-        if fn is None and comp == "int8":
-            fn = self._build_int8_allreduce(
-                shapes, sizes, total, dtype, op, prescale_factor,
-                postscale_factor, hierarchical)
-            self._allreduce_cache[key] = fn
-        if fn is None:
-            num_ranks = self.num_ranks
-            axis = self.axis
-            # Cast compression (bf16/fp16): the collective itself runs in
-            # the narrow dtype — XLA fuses the casts into the program and
-            # every leg (ICI and DCN) moves half the bytes (reference:
-            # fp16 compression, horovod/torch/compression.py:45).
-            wire_dt = {"bf16": jnp.bfloat16,
-                       "fp16": jnp.float16}.get(comp)
-            # Integer tensors: the reduction stays exact in the integer
-            # dtype and ALL scaling (pre x post x 1/n, which commutes
-            # with the sum) happens once in float32 with a cast back —
-            # casting a fractional factor to an int dtype would truncate
-            # it to 0 and silently zero every result, and int/int true
-            # division would silently change the output dtype.
-            int_dtype = not np.issubdtype(np.dtype(dtype), np.floating)
+        with trace.span("hvd.exec.lookup"):
+            hierarchical = bool(self.hierarchical_allreduce
+                                and self.hier_mesh is not None)
+            key = (shapes, np.dtype(dtype).name, int(op),
+                   float(prescale_factor), float(postscale_factor),
+                   hierarchical, comp)
+            fn = self._allreduce_cache.get(key)
+            if fn is None:  # a miss: the build lands in this span
+                args = (shapes, sizes, total, dtype, op, prescale_factor,
+                        postscale_factor, hierarchical)
+                fn = (self._build_int8_allreduce(*args) if comp == "int8"
+                      else self._build_allreduce(*args, comp))
+                self._allreduce_cache[key] = fn
 
-            def flat_body(shard):  # shard: [1, total] on one rank
-                x = shard
-                if prescale_factor != 1.0 and not int_dtype:
-                    x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
-                if wire_dt is not None:
-                    x = x.astype(wire_dt)
-                return jax.lax.psum(x, axis)
+        with trace.span("hvd.exec.launch"):
+            outs = fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for entry, out in zip(entries, outs):
+                for rank, handle in entry.handles.items():
+                    handle.set_result(self._shard_for(out, rank))
 
-            def hier_body(shard):
-                # reduce-scatter on ICI -> cross allreduce on DCN ->
-                # allgather on ICI (reference: nccl_operations.cc:162-289:
-                # ncclReduceScatter -> MPI allreduce -> ncclAllgather).
-                x = shard.reshape(-1)
-                if prescale_factor != 1.0 and not int_dtype:
-                    x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
-                if wire_dt is not None:
-                    x = x.astype(wire_dt)
-                local = self.hier_mesh.shape["local"]
-                align = local * FUSION_ALIGN_ELEMS
-                padded = -(-total // align) * align
-                if padded != total:
-                    x = jnp.pad(x, (0, padded - total))
-                chunk = jax.lax.psum_scatter(x, "local", scatter_dimension=0,
-                                             tiled=True)
-                chunk = jax.lax.psum(chunk, "cross")
-                full = jax.lax.all_gather(chunk, "local", tiled=True)
-                return full[:total][None]
+    def _build_allreduce(self, shapes, sizes, total, dtype, op,
+                         prescale_factor, postscale_factor, hierarchical,
+                         comp):
+        """Compile the fused allreduce of one signature: exact, or with
+        the collective run in a narrower dtype (``comp`` bf16 / fp16)."""
+        num_ranks = self.num_ranks
+        axis = self.axis
+        # Cast compression (bf16/fp16): the collective itself runs in
+        # the narrow dtype — XLA fuses the casts into the program and
+        # every leg (ICI and DCN) moves half the bytes (reference:
+        # fp16 compression, horovod/torch/compression.py:45).
+        wire_dt = {"bf16": jnp.bfloat16,
+                   "fp16": jnp.float16}.get(comp)
+        # Integer tensors: the reduction stays exact in the integer
+        # dtype and ALL scaling (pre x post x 1/n, which commutes
+        # with the sum) happens once in float32 with a cast back —
+        # casting a fractional factor to an int dtype would truncate
+        # it to 0 and silently zero every result, and int/int true
+        # division would silently change the output dtype.
+        int_dtype = not np.issubdtype(np.dtype(dtype), np.floating)
 
-            def fused(g):
-                if hierarchical:
-                    red = _shard_map_gathered(
-                        hier_body, self.hier_mesh,
-                        P(("cross", "local")), P())(g)
-                else:
-                    red = _shard_map(flat_body, mesh=self.mesh,
-                                     in_specs=P(axis), out_specs=P())(g)
-                flat = red.reshape(-1)
-                if wire_dt is not None:
-                    flat = flat.astype(dtype)
-                if int_dtype:
-                    factor = prescale_factor * postscale_factor
-                    if op == ReduceOp.AVERAGE:
-                        factor /= num_ranks
-                    if factor != 1.0:
-                        # float64 when x64 is on; otherwise f32 caps
-                        # exactness at 2**24 — large int sums can lose
-                        # low bits (the tcp plane scales in f64)
-                        sdt = (jnp.float64 if jax.config.jax_enable_x64
-                               else jnp.float32)
-                        flat = (flat.astype(sdt)
-                                * factor).astype(flat.dtype)
-                else:
-                    if op == ReduceOp.AVERAGE:
-                        flat = flat / jnp.asarray(num_ranks,
-                                                  dtype=flat.dtype)
-                    if postscale_factor != 1.0:
-                        flat = flat * jnp.asarray(postscale_factor,
-                                                  dtype=flat.dtype)
-                outs = []
-                offset = 0
-                for size, shape in zip(sizes, shapes):
-                    outs.append(
-                        jax.lax.slice(flat, (offset,),
-                                      (offset + size,)).reshape(shape))
-                    offset += size
-                return tuple(outs)
+        def flat_body(shard):  # shard: [1, total] on one rank
+            x = shard
+            if prescale_factor != 1.0 and not int_dtype:
+                x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
+            if wire_dt is not None:
+                x = x.astype(wire_dt)
+            return jax.lax.psum(x, axis)
 
-            fn = jax.jit(fused, donate_argnums=0)
-            self._allreduce_cache[key] = fn
+        def hier_body(shard):
+            # reduce-scatter on ICI -> cross allreduce on DCN ->
+            # allgather on ICI (reference: nccl_operations.cc:162-289:
+            # ncclReduceScatter -> MPI allreduce -> ncclAllgather).
+            x = shard.reshape(-1)
+            if prescale_factor != 1.0 and not int_dtype:
+                x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
+            if wire_dt is not None:
+                x = x.astype(wire_dt)
+            local = self.hier_mesh.shape["local"]
+            align = local * FUSION_ALIGN_ELEMS
+            padded = -(-total // align) * align
+            if padded != total:
+                x = jnp.pad(x, (0, padded - total))
+            chunk = jax.lax.psum_scatter(x, "local", scatter_dimension=0,
+                                         tiled=True)
+            chunk = jax.lax.psum(chunk, "cross")
+            full = jax.lax.all_gather(chunk, "local", tiled=True)
+            return full[:total][None]
 
-        outs = fn(garr)
-        for entry, out in zip(entries, outs):
-            for rank, handle in entry.handles.items():
-                handle.set_result(self._shard_for(out, rank))
+        def fused(g):
+            if hierarchical:
+                red = _shard_map_gathered(
+                    hier_body, self.hier_mesh,
+                    P(("cross", "local")), P())(g)
+            else:
+                red = _shard_map(flat_body, mesh=self.mesh,
+                                 in_specs=P(axis), out_specs=P())(g)
+            flat = red.reshape(-1)
+            if wire_dt is not None:
+                flat = flat.astype(dtype)
+            if int_dtype:
+                factor = prescale_factor * postscale_factor
+                if op == ReduceOp.AVERAGE:
+                    factor /= num_ranks
+                if factor != 1.0:
+                    # float64 when x64 is on; otherwise f32 caps
+                    # exactness at 2**24 — large int sums can lose
+                    # low bits (the tcp plane scales in f64)
+                    sdt = (jnp.float64 if jax.config.jax_enable_x64
+                           else jnp.float32)
+                    flat = (flat.astype(sdt)
+                            * factor).astype(flat.dtype)
+            else:
+                if op == ReduceOp.AVERAGE:
+                    flat = flat / jnp.asarray(num_ranks,
+                                              dtype=flat.dtype)
+                if postscale_factor != 1.0:
+                    flat = flat * jnp.asarray(postscale_factor,
+                                              dtype=flat.dtype)
+            outs = []
+            offset = 0
+            for size, shape in zip(sizes, shapes):
+                outs.append(
+                    jax.lax.slice(flat, (offset,),
+                                  (offset + size,)).reshape(shape))
+                offset += size
+            return tuple(outs)
+
+        return jax.jit(fused, donate_argnums=0)
 
     def _build_int8_allreduce(self, shapes, sizes, total, dtype, op,
                               prescale_factor, postscale_factor,
@@ -483,9 +494,11 @@ class XlaExecutor:
         pad_fn, gather_fn = fn
         bufs = [pad_fn(entry.tensors[r]) for r in self.local_ranks]
         garr = self._stack(bufs, (1, max0) + rest, dtype)
-        out = gather_fn(garr)
-        for rank, handle in entry.handles.items():
-            handle.set_result(self._shard_for(out, rank))
+        with trace.span("hvd.exec.launch"):
+            out = gather_fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for rank, handle in entry.handles.items():
+                handle.set_result(self._shard_for(out, rank))
 
     # --------------------------------------------------------- reduce_scatter
     def reduce_scatter(self, entry):
@@ -586,9 +599,11 @@ class XlaExecutor:
             fn = jax.jit(fused, donate_argnums=0)
             self._reduce_scatter_cache[key] = fn
 
-        outs = fn(garr)
-        for rank, handle in entry.handles.items():
-            handle.set_result(self._shard_for(outs[rank], rank))
+        with trace.span("hvd.exec.launch"):
+            outs = fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for rank, handle in entry.handles.items():
+                handle.set_result(self._shard_for(outs[rank], rank))
 
     # -------------------------------------------------------------- broadcast
     def broadcast(self, entry):
@@ -602,9 +617,12 @@ class XlaExecutor:
         the host control plane)."""
         if not self.multiprocess:
             src = entry.tensors[entry.root_rank]
-            replicated = jax.device_put(src, NamedSharding(self.mesh, P()))
-            for rank, handle in entry.handles.items():
-                handle.set_result(self._shard_for(replicated, rank))
+            with trace.span("hvd.exec.launch"):
+                replicated = jax.device_put(src,
+                                            NamedSharding(self.mesh, P()))
+            with trace.span("hvd.exec.complete"):
+                for rank, handle in entry.handles.items():
+                    handle.set_result(self._shard_for(replicated, rank))
             return
 
         shape = tuple(entry.shape)
@@ -639,9 +657,11 @@ class XlaExecutor:
             fn = jax.jit(fused, donate_argnums=0)
             self._allreduce_cache[key] = fn
 
-        out = fn(garr)
-        for rank, handle in entry.handles.items():
-            handle.set_result(self._shard_for(out, rank))
+        with trace.span("hvd.exec.launch"):
+            out = fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for rank, handle in entry.handles.items():
+                handle.set_result(self._shard_for(out, rank))
 
     # ----------------------------------------------------------------- adasum
     def adasum(self, entry):
@@ -698,9 +718,11 @@ class XlaExecutor:
             fn = jax.jit(fused, donate_argnums=0)
             self._allreduce_cache[key] = fn
 
-        out = fn(garr)
-        for rank, handle in entry.handles.items():
-            handle.set_result(self._shard_for(out, rank))
+        with trace.span("hvd.exec.launch"):
+            out = fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for rank, handle in entry.handles.items():
+                handle.set_result(self._shard_for(out, rank))
 
     # --------------------------------------------------------------- alltoall
     def alltoall(self, entry):
@@ -777,9 +799,12 @@ class XlaExecutor:
         pad_fns, exchange_fn, unpack_fns = fns
         bufs = [pad_fns[r](entry.tensors[r]) for r in self.local_ranks]
         garr = self._stack(bufs, (1, num_ranks, max_split) + rest, dtype)
-        out = exchange_fn(garr)
-        for rank, handle in entry.handles.items():
-            recv_splits = [splits_matrix[src][rank]
-                           for src in range(num_ranks)]
-            shard = self._shard_for(out, rank)[0]  # [N, max_split, *rest]
-            handle.set_result((unpack_fns[rank](shard), recv_splits))
+        with trace.span("hvd.exec.launch"):
+            out = exchange_fn(garr)
+        with trace.span("hvd.exec.complete"):
+            for rank, handle in entry.handles.items():
+                recv_splits = [splits_matrix[src][rank]
+                               for src in range(num_ranks)]
+                # [N, max_split, *rest]
+                shard = self._shard_for(out, rank)[0]
+                handle.set_result((unpack_fns[rank](shard), recv_splits))

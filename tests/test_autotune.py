@@ -169,24 +169,20 @@ def test_parameter_manager_warmup_windows_discarded():
     assert observations == 1  # exactly one tuning step after 5 windows
 
 
-def test_native_core_exposes_tuned_params():
+def test_native_core_exposes_tuned_params(hvd):
     """The embedded core publishes live tuned values through the controller
-    (reference: SynchronizeParameters makes tuned values visible)."""
-    import horovod_tpu as hvd
-
-    hvd.init()
-    try:
-        from horovod_tpu.common import basics
-        controller = basics._state.controller
-        if not hasattr(controller, "tuned_params"):
-            pytest.skip("controller without native core")
-        params = controller.tuned_params()
-        assert params["fusion_threshold_bytes"] > 0
-        assert params["cycle_time_ms"] > 0
-        assert params["cache_enabled"] in (True, False)
-        assert params["tuning"] is False  # autotune off by default
-    finally:
-        hvd.shutdown()
+    (reference: SynchronizeParameters makes tuned values visible).  On the
+    session's runtime: a ``hvd.shutdown()`` here would end it under every
+    later test of this worker that holds the ``hvd`` fixture."""
+    from horovod_tpu.common import basics
+    controller = basics._state.controller
+    if not hasattr(controller, "tuned_params"):
+        pytest.skip("controller without native core")
+    params = controller.tuned_params()
+    assert params["fusion_threshold_bytes"] > 0
+    assert params["cycle_time_ms"] > 0
+    assert params["cache_enabled"] in (True, False)
+    assert params["tuning"] is False  # autotune off by default
 
 
 def test_parameter_manager_converges_on_synthetic_bandwidth():

@@ -1,0 +1,252 @@
+"""The eager plane's own record of a request's life
+(``horovod_tpu/utils/trace.py``): spans in the profiler's file and the
+request log, under the native and the Python controller.
+
+Each controller serves one process of its own (``hvd.init`` and
+``hvd.shutdown`` are its to call; the session's ``hvd`` fixture may live
+in this one): eight ranks hand in one allreduce each outside any trace,
+then again under ``jax.profiler``, and the process reports what the
+trace and the log hold.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from horovod_tpu.common.handles import Handle
+from horovod_tpu.utils import trace
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RANKS = 8
+
+DRIVER = """
+import glob, json, os, sys, time
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp
+import horovod_tpu as hvd
+from horovod_tpu.common import basics
+from horovod_tpu.utils import trace
+
+controller, out_dir = sys.argv[1], sys.argv[2]
+hvd.init(controller=controller)
+
+
+def one_allreduce(rank):
+    return hvd.synchronize(hvd.allreduce_async(
+        jnp.full((4,), float(rank)), op=hvd.Sum, name="t"))
+
+
+basics.run_parallel(one_allreduce)  # compiles; no trace is active
+report = {{"served_by": type(basics._get_state().controller).__name__,
+           "log_untraced": len(trace.LOG),
+           "files_untraced": os.listdir(out_dir)}}
+trace.reset()
+options = jax.profiler.ProfileOptions()
+options.python_tracer_level = 0
+options.host_tracer_level = 1
+jax.profiler.start_trace(out_dir, profiler_options=options)
+basics.run_parallel(one_allreduce)
+# the dispatcher leaves hvd.execute after it has handed out the results
+time.sleep(0.3)
+jax.profiler.stop_trace()
+report["log"] = list(trace.LOG)
+report["stats"] = hvd.eager_stats()
+path = glob.glob(os.path.join(out_dir, "plugins", "profile", "*",
+                              "*.xplane.pb"))[-1]
+report["lines"] = [
+    [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+     for e in line.events if e.name.startswith("hvd.")]
+    for plane in jax.profiler.ProfileData.from_file(path).planes
+    if plane.name == "/host:CPU" for line in plane.lines]
+hvd.shutdown()
+report["log_after_shutdown"] = len(trace.LOG)
+trace.reset()
+report["log_after_reset"] = len(trace.LOG)
+print(json.dumps(report))
+"""
+
+CALLER = {"hvd.submit", "hvd.wait"}
+EXECUTE = {"hvd.execute", "hvd.exec.fuse_in", "hvd.exec.stack",
+           "hvd.exec.lookup", "hvd.exec.launch", "hvd.exec.complete"}
+CORE = {"hvd.wait_batch", "hvd.decode", "hvd.mark_done"}
+
+
+@pytest.fixture(scope="module", params=["native", "python"])
+def report(request, tmp_path_factory):
+    out_dir = tmp_path_factory.mktemp("trace_" + request.param)
+    done = subprocess.run(
+        [sys.executable, "-c", DRIVER.format(repo=REPO), request.param,
+         str(out_dir)],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert done.returncode == 0, done.stderr[-2000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    out["asked_for"] = request.param
+    out["lines"] = [line for line in out["lines"] if line]
+    return out
+
+
+def test_the_controller_asked_for_serves(report):
+    assert report["served_by"] == {
+        "native": "NativeController",
+        "python": "PythonController"}[report["asked_for"]]
+
+
+def test_every_span_of_the_table_is_in_the_trace(report):
+    names = {name for line in report["lines"] for name, _, _ in line}
+    core = CORE if report["asked_for"] == "native" else set()
+    assert names == CALLER | EXECUTE | core
+
+
+def test_exec_spans_lie_inside_an_execute_span(report):
+    seen = 0
+    for line in report["lines"]:
+        executes = [(s, e) for name, s, e in line if name == "hvd.execute"]
+        for name, start, end in line:
+            if name.startswith("hvd.exec."):
+                seen += 1
+                assert any(s <= start and end <= e for s, e in executes), name
+    # one response: fuse_in per rank, the other four once
+    assert seen == RANKS + 4
+
+
+def test_callers_and_the_dispatcher_are_threads_apart(report):
+    callers = [line for line in report["lines"]
+               if any(name == "hvd.submit" for name, _, _ in line)]
+    assert len(callers) == RANKS
+    for line in callers:
+        assert [name for name, _, _ in sorted(
+            line, key=lambda e: e[1])] == ["hvd.submit", "hvd.wait"]
+    (dispatcher,) = [line for line in report["lines"]
+                     if line not in callers]
+    assert {name for name, _, _ in dispatcher} >= EXECUTE
+
+
+def test_nothing_is_recorded_with_no_trace_active(report):
+    """The untraced allreduces ran the same spans: none is in the file,
+    and no file was there before the trace began."""
+    assert report["files_untraced"] == []
+    submits = sum(name == "hvd.submit" for line in report["lines"]
+                  for name, _, _ in line)
+    assert submits == RANKS
+
+
+def test_the_log_has_one_ordered_record_a_request(report):
+    # always on: the untraced requests were logged too
+    assert report["log_untraced"] == RANKS
+    assert len(report["log"]) == RANKS
+    assert len({record[0] for record in report["log"]}) == RANKS
+    for record in report["log"]:
+        assert len(record) == 6
+        assert sorted(record[2:]) == record[2:] and record[2] > 0
+
+
+def test_requests_of_one_response_share_its_id(report):
+    """Eight ranks' requests for one tensor are one response."""
+    assert len({record[1] for record in report["log"]}) == 1
+    assert len({record[4] for record in report["log"]}) == 1
+    executes = sum(name == "hvd.execute" for line in report["lines"]
+                   for name, _, _ in line)
+    assert executes == 1
+
+
+def test_the_log_survives_shutdown_and_reset_empties_it(report):
+    assert report["log_after_shutdown"] == RANKS
+    assert report["log_after_reset"] == 0
+
+
+def test_eager_stats_counts_and_stages(report):
+    stats = report["stats"]
+    assert set(stats) == {"requests", "responses", "submit_us",
+                          "queue_wait_us", "execute_us"}
+    assert (stats["requests"], stats["responses"]) == (RANKS, 1)
+    start, done = report["log"][0][4], max(r[5] for r in report["log"])
+    assert stats["execute_us"] == pytest.approx((done - start) / 1e3)
+
+
+# ------------------------------------------- the log alone, no plane
+@pytest.fixture
+def log():
+    trace.reset()
+    yield trace.LOG
+    trace.reset()
+
+
+def stamped(t_submit, t_enqueued, t_execute_start, response_id=7):
+    handle = Handle("t")
+    trace.submitted(handle, t_submit)
+    handle.t_enqueued = t_enqueued
+    handle.response_id, handle.t_execute_start = response_id, t_execute_start
+    return handle
+
+
+@pytest.mark.parametrize("t_enqueued,logged", [
+    (20, 20),   # back from enqueue before the dispatcher took it up
+    (0, 30),    # taken up before the caller stamped it
+    (35, 30),   # stamped after it was taken up
+])
+def test_the_enqueue_stamp_is_held_to_the_start_of_execution(
+        t_enqueued, logged, log):
+    handle = stamped(10, t_enqueued, 30)
+    handle.set_result("r")
+    (record,) = log
+    assert record[:5] == (handle.request_id, 7, 10, logged, 30)
+    assert record[5] >= trace.now() - 10**9
+
+
+def test_a_handle_no_response_took_up_is_not_logged(log):
+    """A join's handle, or one a test hands to the executor itself."""
+    Handle("join").set_result(3)
+    assert not log
+
+
+def test_a_failed_request_is_not_logged(log):
+    handle = stamped(10, 20, 30)
+    handle.set_error("no")
+    handle.set_result("late")  # first completion wins
+    assert not log
+
+
+def test_a_result_is_logged_once_and_before_the_waiter_wakes(log):
+    handle = stamped(10, 20, 30)
+    handle.set_result("r")
+    handle.set_result("again")
+    assert len(log) == 1 and handle.wait(0) == "r"
+
+
+def test_executing_stamps_every_handle_of_a_response(log):
+    from horovod_tpu.ops.python_controller import GroupEntry
+
+    entries = [GroupEntry(name, (4,), "float32", {}, {
+        rank: Handle(name) for rank in range(2)}) for name in "ab"]
+    trace.executing(entries, 50)
+    trace.executing(entries[:1], 60)
+    first, second = (entry.handles for entry in entries)
+    assert {h.t_execute_start for h in first.values()} == {60}
+    assert {h.t_execute_start for h in second.values()} == {50}
+    assert (first[0].response_id == first[1].response_id
+            == second[0].response_id + 1)
+
+
+def test_eager_stats_of_an_empty_log(log):
+    assert trace.eager_stats() == {"requests": 0, "responses": 0}
+
+
+def test_eager_stats_means(log):
+    """Two responses: one of two requests that took 40 and 60 us to the
+    last result, one of one request that took 10."""
+    log.extend([(1, 1, 0, 2_000, 10_000, 50_000),
+                (2, 1, 1_000, 5_000, 10_000, 70_000),
+                (3, 2, 80_000, 81_000, 90_000, 100_000)])
+    assert trace.eager_stats() == {
+        "requests": 3, "responses": 2,
+        "submit_us": pytest.approx((2 + 4 + 1) / 3),
+        "queue_wait_us": pytest.approx((8 + 5 + 9) / 3),
+        "execute_us": pytest.approx((60 + 10) / 2)}
+
+
+def test_the_log_is_bounded():
+    assert trace.LOG.maxlen == 65536
