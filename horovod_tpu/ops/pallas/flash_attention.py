@@ -5,19 +5,28 @@ framework has no kernels of its own — SURVEY §2.2 "No CUDA kernels... GPU
 work is cudaMemcpyAsync + NCCL"; on TPU the hot op IS the kernel, so this
 framework ships one).  Design per the TPU architecture:
 
-- the q/k score and p/v matmuls run on the MXU in fp32 accumulation
-  (``preferred_element_type``), activations may be bf16;
+- every product runs on the MXU with **operands in the input dtype** and
+  float32 accumulation (``preferred_element_type``): bfloat16 q, k, v, dO
+  go in as they arrive, and ``p`` / ``ds`` are rounded to that dtype at
+  their product only; float32 inputs keep float32 operands.  The running
+  max and sum, ``lse``, ``delta``, the softmax and every accumulator are
+  float32 for any input;
 - online-softmax streaming over K blocks keeps the working set in VMEM —
   O(T) memory instead of the O(T²) score matrix;
 - grid = (batch*heads, q-blocks); the K-block loop is a ``fori_loop``
   inside the kernel over K/V resident in VMEM (for sequences too long for
   VMEM, the ring-attention layer shards the sequence first — each shard's
   local block then fits);
-- causal masking skips *whole* K blocks past the diagonal (``@pl.when``),
-  so the MXU never sees fully-masked tiles;
+- causal masking is in the **loop bounds**: K blocks past the diagonal are
+  never visited, blocks every row sees whole run with no mask work at all
+  (no iota, compare or select), and only the blocks the diagonal crosses
+  run the masked body;
 - backward recomputes the forward blockwise from the saved logsumexp
   (flash-attention-2 style): one kernel accumulates dq over K blocks, one
-  accumulates dk/dv over Q blocks.
+  accumulates dk/dv over Q blocks.  The dk/dv kernel holds its scores
+  transposed (``k @ q.T -> [block_k, block_q]``), so the per-row scalars
+  broadcast from their lane rows as stored and no product has a
+  transposed left operand.
 
 Layout: public API takes ``[B, T, H, D]`` (framework convention);
 kernels run on ``[B*H, T, D]``.
@@ -88,16 +97,19 @@ def _sds(shape, dtype, like):
 # ----------------------------------------------------- row-scalar packing
 #
 # Per-row scalars (logsumexp, delta) are natural [rows, 1] columns inside
-# the kernels (rows = sublanes) but must not be stored to HBM broadcast
-# across a 128-lane tile — that costs 128x the necessary bandwidth and
-# capped long-sequence backward (the bundled jax.experimental kernel
-# pays exactly this).  When block_q == 128 the scalars are packed dense:
-# HBM shape [bh, t/128, 1, 128], one q-block's column per lane row (the
-# singleton sublane axis satisfies the TPU block-shape rule — the last
-# two block dims must divide (8, 128) or equal the array dims).  The
-# lane<->sublane conversion uses an MXU identity contraction — bit-exact
-# for fp32 (one nonzero term per output) and guaranteed to lower on any
-# Mosaic version, unlike a reshape across the minor-two dims.
+# the forward and dq kernels (rows = sublanes) but must not be stored to
+# HBM broadcast across a 128-lane tile — that costs 128x the necessary
+# bandwidth and capped long-sequence backward (the bundled
+# jax.experimental kernel pays exactly this).  They are stored dense, one
+# q-block's scalars per lane row: HBM shape [bh, t/block_q, 1, block_q],
+# the bytes of [bh, t] (the singleton sublane axis satisfies the TPU
+# block-shape rule — the last two block dims must divide (8, 128) or equal
+# the array dims).  The dk/dv kernel, whose scores are [block_k, block_q],
+# broadcasts such a row as it is.  The forward and dq kernels turn column
+# into row and back once a q block with an MXU identity contraction, 128
+# rows at a time — bit-exact for fp32 (one nonzero term per output) and
+# guaranteed to lower on any Mosaic version, unlike a reshape across the
+# minor-two dims.
 
 def _eye(n):
     return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
@@ -117,69 +129,117 @@ def _row_to_col(r):
                                preferred_element_type=jnp.float32)
 
 
-_PACK = 128  # lane width: one q-block of row scalars per packed lane row
+_PACK = 128  # lane width: the transposes above go 128 rows at a time
+_BLOCK = 512  # default block_q and block_k: see flash_attention()
+
+
+def _store_row(ref, col):
+    """Write the [block_q, 1] column ``col`` into the [1, block_q] lane
+    row ``ref`` views."""
+    if col.shape[0] % _PACK:  # an odd block: whole, no lane slice
+        ref[...] = _col_to_row(col)
+        return
+    for start in range(0, col.shape[0], _PACK):
+        ref[:, start:start + _PACK] = _col_to_row(col[start:start + _PACK])
+
+
+def _load_col(ref):
+    """The [1, block_q] lane row ``ref`` views, as a [block_q, 1] column."""
+    if ref.shape[1] % _PACK:  # an odd block: whole, no lane slice
+        return _row_to_col(ref[...])
+    pieces = [_row_to_col(ref[:, start:start + _PACK])
+              for start in range(0, ref.shape[1], _PACK)]
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=0)
+
+
+def _is_pow2(scale):
+    """A power-of-two ``scale`` (1/8 at heads of 64) multiplies exactly
+    in any dtype, so it can move onto an operand or an accumulator and
+    off the ``[block_q, block_k]`` tile, bit for bit."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _dot(a, b, contract):
+    """MXU product with float32 accumulation; ``contract`` names the
+    contracted dimension of ``a`` and of ``b``."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _causal_loops(body, carry, bounds):
+    """Run ``body(i, carry, masked=...)`` over the index ranges
+    ``bounds = [(lo, hi, masked), ...]`` in turn."""
+    for lo, hi, masked in bounds:
+        carry = jax.lax.fori_loop(
+            lo, hi, functools.partial(body, masked=masked), carry)
+    return carry
+
+
+def _k_bounds(iq, *, causal, block_q, block_k, t_kv):
+    """K-block ranges for q block ``iq``: whole blocks, then the blocks
+    the diagonal crosses; blocks past it are not visited."""
+    if not causal:
+        return [(0, t_kv // block_k, False)]
+    seen = jnp.minimum((iq + 1) * block_q + block_k - 1, t_kv) // block_k
+    whole = jnp.minimum((iq * block_q + 1) // block_k, seen)
+    return [(0, whole, False), (whole, seen, True)]
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k, packed):
+                block_q, block_k):
     # q_ref: [block_q, d]; k_ref/v_ref: [t_kv, d]; o_ref: [block_q, d]
-    # lse_ref: packed [1, 128] (one lane per row) or broadcast
-    # [block_q, 128] for odd block sizes
+    # lse_ref: [1, block_q], one lane per row
     iq = pl.program_id(1)
     t_kv = k_ref.shape[1]
     d = q_ref.shape[2]
-    nk = t_kv // block_k
 
-    q = q_ref[0].astype(jnp.float32) * scale
-
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o0 = jnp.zeros((block_q, d), jnp.float32)
+    q = q_ref[0]
+    # scaling q loses nothing for a power of two; float32 q was always
+    # scaled so and stays bit for bit.  Otherwise (bfloat16 at, say,
+    # 1/sqrt(80)) the float32 scores are scaled, as in the backward.
+    fold_scale = _is_pow2(scale) or q.dtype == jnp.float32
+    if fold_scale:
+        q = q * scale
 
     q_pos = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
-    def body(ik, carry):
+    def body(ik, carry, *, masked):
         m, l, o = carry
-        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        if causal:
+        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :]
+        s = _dot(q, k_blk, (1, 1))                       # [bq, bk]
+        if not fold_scale:
+            s = s * scale
+        if masked:
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)       # [bq, 1]
-        m_new = jnp.maximum(m, m_blk)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
-        p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        alpha = jnp.where(m > _NEG_INF / 2, alpha, 0.0)
+        alpha = jnp.exp(m - m_new)                       # [bq, 1]
+        if masked:
+            # a row that has seen nothing yet has m = m_new = NEG_INF and
+            # would read exp(0) = 1 off its masked entries
+            p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
+            alpha = jnp.where(m > _NEG_INF / 2, alpha, 0.0)
         l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o = o * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        o = o * alpha + _dot(p.astype(v_blk.dtype), v_blk, (1, 0))
         return m_new, l, o
 
-    if causal:
-        # K blocks fully past this q block contribute nothing; the loop
-        # bound itself is static-per-program via the grid index.
-        nk_eff = jnp.minimum(
-            (iq + 1) * block_q + block_k - 1, t_kv) // block_k
-    else:
-        nk_eff = nk
-    m, l, o = jax.lax.fori_loop(0, nk_eff, body, (m0, l0, o0))
+    m, l, o = _causal_loops(
+        body,
+        (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((block_q, 1), jnp.float32),
+         jnp.zeros((block_q, d), jnp.float32)),
+        _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
+                  t_kv=t_kv))
 
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[0] = (o / l_safe).astype(o_ref.dtype)
-    lse = m + jnp.log(l_safe)
-    if packed:
-        lse_ref[0, 0] = _col_to_row(lse)
-    else:
-        lse_ref[0] = jnp.broadcast_to(lse, (block_q, 128))
+    _store_row(lse_ref.at[0, 0], m + jnp.log(l_safe))
 
 
 def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
@@ -187,18 +247,10 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
     bh, t, d = q3.shape
     t_kv = k3.shape[1]
     nq = t // block_q
-    packed = block_q == _PACK
-
-    if packed:
-        lse_spec = _vmem_spec((1, 1, 1, _PACK), lambda b, i: (b, i, 0, 0))
-        lse_shape = _sds((bh, nq, 1, _PACK), jnp.float32, q3)
-    else:
-        lse_spec = _vmem_spec((1, block_q, 128), lambda b, i: (b, i, 0))
-        lse_shape = _sds((bh, t, 128), jnp.float32, q3)
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, packed=packed),
+                          block_q=block_q, block_k=block_k),
         grid=(bh, nq),
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -207,124 +259,119 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
         ],
         out_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            lse_spec,
+            _vmem_spec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
             _sds((bh, t, d), q3.dtype, q3),
-            lse_shape,
+            _sds((bh, nq, 1, block_q), jnp.float32, q3),
         ],
         interpret=interpret,
     )(q3, k3, v3)
-    return out, lse.reshape(bh, t) if packed else lse[:, :, 0]
+    return out, lse.reshape(bh, t)
 
 
 # --------------------------------------------------------------- backward
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, causal, block_q, block_k, packed):
+                   *, scale, causal, block_q, block_k):
     iq = pl.program_id(1)
     t_kv = k_ref.shape[1]
     d = q_ref.shape[2]
-    nk = t_kv // block_k
 
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    if packed:
-        lse = _row_to_col(lse_ref[0, 0])                    # [bq, 1]
-        delta = _row_to_col(delta_ref[0, 0])
-    else:
-        lse = lse_ref[0, :, 0:1]                            # [bq, 1]
-        delta = delta_ref[0, :, 0:1]
+    # a power-of-two scale rides on q (for the scores) and on the
+    # finished dq instead of on two [bq, bk] tiles a block pair
+    fold_scale = _is_pow2(scale)
+    q = q_ref[0] * scale if fold_scale else q_ref[0]
+    do = do_ref[0]
+    lse = _load_col(lse_ref.at[0, 0])                       # [bq, 1]
+    delta = _load_col(delta_ref.at[0, 0])
 
     q_pos = iq * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
 
-    def body(ik, dq):
-        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
+    def body(ik, dq, *, masked):
+        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :]
+        s = _dot(q, k_blk, (1, 1))
+        if not fold_scale:
+            s = s * scale
+        p = jnp.exp(s - lse)                              # [bq, bk]
+        if masked:
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        # fully-masked rows carry lse = m = NEG_INF; exp(s - lse)
-        # there would be exp(0) = 1 per entry — mirror the
-        # forward's guard so such rows contribute zero gradient
-        p = jnp.where(lse > _NEG_INF / 2,
-                      jnp.exp(s - lse), 0.0)              # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            # a fully-masked row carries lse = NEG_INF: mirror the
+            # forward's guard so it contributes zero gradient
+            p = jnp.where((q_pos >= k_pos) & (lse > _NEG_INF / 2), p, 0.0)
+        ds = p * (_dot(do, v_blk, (1, 1)) - delta)
+        if not fold_scale:
+            ds = ds * scale
+        return dq + _dot(ds.astype(k_blk.dtype), k_blk, (1, 0))
 
-    nk_eff = (jnp.minimum((iq + 1) * block_q + block_k - 1, t_kv)
-              // block_k) if causal else nk
-    dq = jax.lax.fori_loop(0, nk_eff, body,
-                           jnp.zeros((block_q, d), jnp.float32))
+    dq = _causal_loops(
+        body, jnp.zeros((block_q, d), jnp.float32),
+        _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
+                  t_kv=t_kv))
+    if fold_scale:
+        dq = dq * scale
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, block_k,
-                    packed):
+                    dk_ref, dv_ref, *, scale, causal, block_q, block_k):
+    # scores are held transposed, [block_k, block_q]: a q block's lse and
+    # delta broadcast down the sublanes from the lane rows they are
+    # stored as, and p.T @ dO, ds.T @ q are plain products
     ik = pl.program_id(1)
     t_q = q_ref.shape[1]
     d = k_ref.shape[2]
     nq = t_q // block_q
 
-    k_blk = k_ref[0].astype(jnp.float32)                    # [bk, d]
-    v_blk = v_ref[0].astype(jnp.float32)
+    v_blk = v_ref[0]                                        # [bk, d]
+    # a power-of-two scale rides on k (for the scores) and on the
+    # finished dk instead of on two [bk, bq] tiles a block pair
+    fold_scale = _is_pow2(scale)
+    k_blk = k_ref[0] * scale if fold_scale else k_ref[0]
 
     k_pos = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, (block_k, block_q), 0)
 
-    def body(iq, carry):
+    def body(iq, carry, *, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(iq * block_q, block_q), :].astype(jnp.float32)
-        if packed:
-            lse = _row_to_col(lse_ref[0, pl.ds(iq, 1), 0, :])
-            delta = _row_to_col(delta_ref[0, pl.ds(iq, 1), 0, :])
-        else:
-            lse = lse_ref[0, pl.ds(iq * block_q, block_q), 0:1]
-            delta = delta_ref[0, pl.ds(iq * block_q, block_q), 0:1]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
+        q = q_ref[0, pl.ds(iq * block_q, block_q), :]
+        do = do_ref[0, pl.ds(iq * block_q, block_q), :]
+        lse = lse_ref[0, pl.ds(iq, 1), 0, :]                # [1, bq]
+        delta = delta_ref[0, pl.ds(iq, 1), 0, :]
+        s = _dot(k_blk, q, (1, 1))                          # [bk, bq]
+        if not fold_scale:
+            s = s * scale
+        p = jnp.exp(s - lse)
+        if masked:
             q_pos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        # fully-masked rows carry lse = m = NEG_INF; exp(s - lse)
-        # there would be exp(0) = 1 per entry — mirror the
-        # forward's guard so such rows contribute zero gradient
-        p = jnp.where(lse > _NEG_INF / 2,
-                      jnp.exp(s - lse), 0.0)              # [bq, bk]
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bk]
-        ds = p * (dp - delta) * scale
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bk, d]
+                jnp.int32, (block_k, block_q), 1)
+            # a fully-masked row carries lse = NEG_INF: mirror the
+            # forward's guard so it contributes zero gradient
+            p = jnp.where((q_pos >= k_pos) & (lse > _NEG_INF / 2), p, 0.0)
+        dv = dv + _dot(p.astype(do.dtype), do, (1, 0))      # [bk, d]
+        ds = p * (_dot(v_blk, do, (1, 1)) - delta)          # [bk, bq]
+        if not fold_scale:
+            ds = ds * scale
+        dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))       # [bk, d]
         return dk, dv
 
     if causal:
-        # q blocks strictly before this k block see none of it
-        iq_start = (ik * block_k) // block_q
+        # q blocks before this k block see none of it, the blocks the
+        # diagonal crosses run masked, the rest see all of it
+        first = (ik * block_k) // block_q
+        whole = jnp.clip(((ik + 1) * block_k + block_q - 2) // block_q,
+                         first, nq)
+        bounds = [(first, whole, True), (whole, nq, False)]
     else:
-        iq_start = 0
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(iq_start, nq, body, (dk0, dv0))
+        bounds = [(0, nq, False)]
+    dk, dv = _causal_loops(
+        body, (jnp.zeros((block_k, d), jnp.float32),
+               jnp.zeros((block_k, d), jnp.float32)), bounds)
+    if fold_scale:
+        dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -344,25 +391,16 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
                     axis=-1)                                # [bh, t]
     if g_lse is not None:
         delta = delta - g_lse.astype(jnp.float32)
-    packed = block_q == _PACK
-    if packed:
-        # dense: one q-block's 128 row scalars per lane row (a reshape,
-        # i.e. free) — 128x less HBM than the broadcast fallback below
-        lse_b = lse.reshape(bh, nq, 1, _PACK)
-        delta_b = delta.reshape(bh, nq, 1, _PACK)
-        dq_lse_spec = _vmem_spec((1, 1, 1, _PACK),
-                                 lambda b, i: (b, i, 0, 0))
-        dkv_lse_spec = _vmem_spec((1, nq, 1, _PACK),
-                                  lambda b, i: (b, 0, 0, 0))
-    else:
-        lse_b = jnp.broadcast_to(lse[:, :, None], (bh, t, 128))
-        delta_b = jnp.broadcast_to(delta[:, :, None], (bh, t, 128))
-        dq_lse_spec = _vmem_spec((1, block_q, 128), lambda b, i: (b, i, 0))
-        dkv_lse_spec = _vmem_spec((1, t, 128), lambda b, i: (b, 0, 0))
+    # one q block's row scalars per lane row (a reshape, i.e. free)
+    lse_b = lse.reshape(bh, nq, 1, block_q)
+    delta_b = delta.reshape(bh, nq, 1, block_q)
+    dq_lse_spec = _vmem_spec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
+    dkv_lse_spec = _vmem_spec((1, nq, 1, block_q), lambda b, i: (b, 0, 0, 0))
+    kernel_args = dict(scale=scale, causal=causal, block_q=block_q,
+                       block_k=block_k)
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, packed=packed),
+        functools.partial(_bwd_dq_kernel, **kernel_args),
         grid=(bh, nq),
         in_specs=[
             _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -378,8 +416,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
     )(q3, k3, v3, g, lse_b, delta_b)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, packed=packed),
+        functools.partial(_bwd_dkv_kernel, **kernel_args),
         grid=(bh, nk),
         in_specs=[
             _vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
@@ -405,31 +442,45 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
 # ------------------------------------------------------------- public API
 
 def _pick_block(t, want):
-    """Largest divisor of t that is <= want (kernel blocks must tile T)."""
+    """Largest block <= want that tiles t (kernel blocks must tile T): a
+    multiple of the 128 lanes where one divides t, so that blocks stay
+    aligned to the tiling, else the largest divisor of t."""
     if want < 1:
         raise ValueError(f"block size must be >= 1, got {want}")
     b = min(want, t)
+    for aligned in range(b - b % _PACK, 0, -_PACK):
+        if t % aligned == 0:
+            return aligned
     while t % b != 0:
         b -= 1
     return b
 
 
+# A transformer calls this once a layer with the same shapes: under
+# ``jit`` the kernels are traced once and lowered once for all of them,
+# not once a call (GPT-2 medium's step: 72 kernel bodies down to 3).
+_STATIC = ("scale", "causal", "block_q", "block_k", "interpret")
+_fwd_once = jax.jit(_fwd, static_argnames=_STATIC)
+_bwd_once = jax.jit(_bwd, static_argnames=_STATIC)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    out, _ = _fwd(q3, k3, v3, scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, interpret=interpret)
+    out, _ = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
+                       block_q=block_q, block_k=block_k, interpret=interpret)
     return out
 
 
 def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd(q3, k3, v3, scale=scale, causal=causal,
-                    block_q=block_q, block_k=block_k, interpret=interpret)
+    out, lse = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
+                         block_q=block_q, block_k=block_k,
+                         interpret=interpret)
     return out, (q3, k3, v3, out, lse)
 
 
 def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
-    return _bwd(res, g, scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, interpret=interpret)
+    return _bwd_once(res, g, scale=scale, causal=causal, block_q=block_q,
+                     block_k=block_k, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -439,20 +490,22 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def _flash_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret):
     """Like ``_flash`` but also returns the logsumexp — the streaming-
     softmax state ring attention needs to combine per-block results."""
-    return _fwd(q3, k3, v3, scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, interpret=interpret)
+    return _fwd_once(q3, k3, v3, scale=scale, causal=causal,
+                     block_q=block_q, block_k=block_k, interpret=interpret)
 
 
 def _flash_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd(q3, k3, v3, scale=scale, causal=causal,
-                    block_q=block_q, block_k=block_k, interpret=interpret)
+    out, lse = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
+                         block_q=block_q, block_k=block_k,
+                         interpret=interpret)
     return (out, lse), (q3, k3, v3, out, lse)
 
 
 def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, res, g):
     g_out, g_lse = g
-    return _bwd(res, g_out, scale=scale, causal=causal, block_q=block_q,
-                block_k=block_k, interpret=interpret, g_lse=g_lse)
+    return _bwd_once(res, g_out, scale=scale, causal=causal,
+                     block_q=block_q, block_k=block_k, interpret=interpret,
+                     g_lse=g_lse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -484,14 +537,19 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
         scale = 1.0 / math.sqrt(d)
     if interpret is None:
         interpret = _default_interpret()
-    # HVD_FLASH_BLOCK_Q/K override the 128 x 128 default, which no
-    # sweep on a chip has confirmed yet (ROADMAP D4).  block_q=128 keeps
-    # the packed lse/delta layout; other values fall back to the
-    # broadcast layout.
+    # Blocks as large as the sequence allows, up to 512: a loop
+    # iteration is a chain (scores -> max -> exp -> sum -> product) whose
+    # latency the next iteration cannot hide, so a kernel pays per
+    # iteration, not per element, until its tiles are large.  Swept on a
+    # v5e through HVD_FLASH_BLOCK_Q/K (PERF.md, PR 25): forward + backward
+    # at [128,1024,64] bfloat16 causal take 5.97 ms at 128 x 128, 3.39 at
+    # 256 x 256, 2.74 at 512 x 512 and 2.78 at 1024 x 1024 (whose
+    # diagonal blocks are half masked work); 512 also compiles wherever
+    # 128 did (K and V whole in VMEM set that limit, not the tiles).
     if block_q is None:
-        block_q = _env_block("HVD_FLASH_BLOCK_Q", 128)
+        block_q = _env_block("HVD_FLASH_BLOCK_Q", _BLOCK)
     if block_k is None:
-        block_k = _env_block("HVD_FLASH_BLOCK_K", 128)
+        block_k = _env_block("HVD_FLASH_BLOCK_K", _BLOCK)
     block_q = _pick_block(t, block_q)
     block_k = _pick_block(t_kv, block_k)
 

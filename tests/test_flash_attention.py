@@ -276,3 +276,303 @@ def test_flash_block_env_overrides_validated(monkeypatch):
     from horovod_tpu.ops.pallas.flash_attention import _pick_block
     with _pytest.raises(ValueError, match="block size"):
         _pick_block(64, 0)
+
+
+# ------------------------------------------------- bfloat16: operand dtype
+# At heads of 64 with 128-blocks and T 384 a causal call has whole,
+# diagonal and skipped block pairs.  Errors are taken like chip_smoke.py's
+# ``rel_err``: the largest difference over the largest reference value.
+
+BF16_T, BF16_D = 384, 64
+
+
+def _bf16_case(seed=5):
+    rng = np.random.RandomState(seed)
+    mk = lambda: jnp.asarray(
+        rng.randn(1, BF16_T, 2, BF16_D).astype(np.float32)
+    ).astype(jnp.bfloat16)
+    return mk(), mk(), mk(), mk()  # q, k, v, dO
+
+
+def _rounded_dense(q, k, v, do, causal, rounded):
+    """Dense attention and its gradients under ``vdot(out, dO)``, written
+    out by hand.  ``rounded=True`` rounds where the kernel does: operands
+    of every product in the input dtype with float32 sums, ``p`` and
+    ``ds`` rounded to it at their products, everything else float32.
+    ``rounded=False`` is float32 throughout."""
+    dt = q.dtype if rounded else jnp.float32
+    cast = lambda x: x.astype(dt)
+    hi = jax.lax.Precision.HIGHEST
+    ein = lambda spec, a, b: jnp.einsum(
+        spec, cast(a), cast(b), precision=hi,
+        preferred_element_type=jnp.float32)
+    scale = q.shape[-1] ** -0.5
+    s = ein("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        msk = (jnp.arange(s.shape[-2])[:, None]
+               >= jnp.arange(s.shape[-1])[None, :])
+        s = jnp.where(msk[None, None], s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    e = jnp.exp(s - m)
+    l = jnp.sum(e, axis=-1, keepdims=True)
+    out = cast(jnp.swapaxes(ein("bhqk,bkhd->bhqd", e, v) / l, 1, 2))
+    p = jnp.exp(s - (m + jnp.log(l)))
+    do32, out32 = do.astype(jnp.float32), out.astype(jnp.float32)
+    delta = jnp.swapaxes(jnp.sum(do32 * out32, axis=-1), 1, 2)[..., None]
+    dv = ein("bhqk,bqhd->bkhd", p, do)
+    ds = p * (ein("bqhd,bkhd->bhqk", do, v) - delta) * scale
+    dq = ein("bhqk,bkhd->bqhd", ds, k)
+    dk = ein("bhqk,bqhd->bkhd", ds, q)
+    return {"forward": [out], "gradients": [dq, dk, dv]}
+
+
+def _rel_err(got, want):
+    got = np.asarray(got, dtype=np.float32)
+    want = np.asarray(want, dtype=np.float32)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("what", ["forward", "gradients"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_bf16_against_rounded_and_float32_reference(causal, what):
+    from chip_smoke import KERNEL_TOL
+
+    q, k, v, do = _bf16_case()
+
+    def weighed(q, k, v):
+        out = flash_attention(q, k, v, causal=causal, block_q=128,
+                              block_k=128)
+        return jnp.vdot(out.astype(jnp.float32),
+                        do.astype(jnp.float32)), out
+
+    (_, out), grads = jax.value_and_grad(
+        weighed, (0, 1, 2), has_aux=True)(q, k, v)
+    got = {"forward": [out], "gradients": list(grads)}[what]
+    assert all(g.dtype == jnp.bfloat16 for g in got)
+    rounded = _rounded_dense(q, k, v, do, causal, rounded=True)[what]
+    exact = _rounded_dense(q, k, v, do, causal, rounded=False)[what]
+    for g, r, e in zip(got, rounded, exact):
+        # against the reference that rounds where the kernel does: what
+        # is left is the order of the sums and the last bfloat16 bit
+        assert _rel_err(g, r) <= 1e-2
+        # against float32 throughout, at the chip smoke's tolerance
+        assert _rel_err(g, e) <= KERNEL_TOL["bfloat16"] == 3e-2
+
+
+# ----------------------------------- the mechanism engages: kernel jaxprs
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for item in value if isinstance(value, (list, tuple)) else [value]:
+            inner = getattr(item, "jaxpr", item)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _walk(jaxpr):
+    """Every equation under ``jaxpr``, loops' and calls' bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _flash_kernel_jaxprs(dtype, causal):
+    """``{kernel name: jaxpr}`` of the three kernels of one
+    forward-and-backward call at heads of 64."""
+    rng = np.random.RandomState(6)
+    q, k, v = (jnp.asarray(rng.randn(1, 256, 2, 64).astype(np.float32)
+                           ).astype(dtype) for _ in range(3))
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal).astype(jnp.float32)),
+        (0, 1, 2)))(q, k, v)
+    kernels = {}
+    for eqn in _walk(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            kernel = eqn.params["jaxpr"]
+            kernels[kernel.debug_info.func_name] = kernel
+    assert sorted(kernels) == ["_bwd_dkv_kernel", "_bwd_dq_kernel",
+                               "_fwd_kernel"], sorted(kernels)
+    return kernels
+
+
+def _operand_products(jaxpr):
+    """The ``dot_general`` equations on q, k, v, dO, p and ds: all but
+    the identity products that move a row of scalars into a column."""
+    return [eqn for eqn in _walk(jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and all(min(v.aval.shape) > 1 for v in eqn.invars)]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_flash_products_take_operands_in_the_input_dtype(dtype, causal):
+    """bfloat16 inputs reach the MXU as bfloat16 (with ``p`` and ``ds``
+    rounded to it) and float32 inputs as float32; every product
+    accumulates in float32 and none has a transposed left operand."""
+    per_body = {"_fwd_kernel": 2, "_bwd_dq_kernel": 3, "_bwd_dkv_kernel": 4}
+    bodies = 2 if causal else 1  # whole blocks; blocks on the diagonal
+    for name, jaxpr in _flash_kernel_jaxprs(dtype, causal).items():
+        products = _operand_products(jaxpr)
+        assert len(products) == per_body[name] * bodies, (name, products)
+        for eqn in products:
+            assert [v.aval.dtype for v in eqn.invars] == [dtype, dtype], eqn
+            assert eqn.outvars[0].aval.dtype == jnp.float32, eqn
+            (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+            assert tuple(lhs_contract) == (1,), (name, eqn)
+
+
+def test_flash_mask_work_only_on_the_diagonal():
+    """A causal kernel runs two loops: one over the blocks every row sees
+    whole, whose body has no iota, compare or select, and one over the
+    blocks the diagonal crosses.  Without ``causal`` there is one loop
+    and no mask work in it."""
+    mask_work = {"iota", "select_n", "ge", "gt"}
+
+    def loops(jaxpr):
+        """The primitives in each loop body (a ``fori_loop`` is a
+        ``while`` under traced bounds and a ``scan`` under static ones)."""
+        body_of = {"while": "body_jaxpr", "scan": "jaxpr"}
+        return [{e.primitive.name for e in _walk(
+                    eqn.params[body_of[eqn.primitive.name]].jaxpr)}
+                for eqn in jaxpr.eqns if eqn.primitive.name in body_of]
+
+    for name, jaxpr in _flash_kernel_jaxprs(jnp.bfloat16, True).items():
+        bodies = loops(jaxpr)
+        assert len(bodies) == 2, (name, len(bodies))
+        masked = [bool(body & mask_work) for body in bodies]
+        assert sorted(masked) == [False, True], (name, bodies)
+        assert all("dot_general" in body and "exp" in body
+                   for body in bodies), name
+    for name, jaxpr in _flash_kernel_jaxprs(jnp.bfloat16, False).items():
+        bodies = loops(jaxpr)
+        assert len(bodies) == 1 and not bodies[0] & mask_work, (name, bodies)
+
+
+# ------------------------------------------------------------- mask edges
+def _grads_match_reference(q, k, v, causal, rtol=1e-4, **blocks):
+    def loss(attn):
+        def f(q, k, v):
+            return jnp.sum(attn(q, k, v) ** 2)
+        return jax.value_and_grad(f, (0, 1, 2))(q, k, v)
+
+    want, want_g = loss(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal))
+    got, got_g = loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, **blocks))
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=rtol, atol=rtol)
+
+
+@pytest.mark.parametrize("t_q,t_kv", [(256, 128), (128, 256), (384, 256)])
+def test_flash_causal_cross_lengths(t_q, t_kv):
+    """Causal with ``t_q != t_kv`` (the mask is aligned at the start, as
+    ``reference_attention``'s): q blocks past the last K block see every
+    block whole, K blocks past the last q block see nothing."""
+    rng = np.random.RandomState(8)
+    q = jnp.asarray(rng.randn(1, t_q, 2, 32).astype(np.float32))
+    k = jnp.asarray(rng.randn(1, t_kv, 2, 32).astype(np.float32))
+    v = jnp.asarray(rng.randn(1, t_kv, 2, 32).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(reference_attention(q, k, v, causal=True)),
+        rtol=2e-5, atol=2e-5)
+    _grads_match_reference(q, k, v, True)
+
+
+@pytest.mark.parametrize("blocks", [
+    {},                                    # 192 -> one block of 192
+    {"block_q": 64, "block_k": 96},        # diagonal crosses unevenly
+    {"block_q": 96, "block_k": 32},
+], ids=["default", "q64k96", "q96k32"])
+def test_flash_causal_length_no_multiple_of_the_block(blocks):
+    q, k, v = _rand(b=1, t=192, h=2, d=32, seed=9)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True, **blocks)),
+        np.asarray(reference_attention(q, k, v, causal=True)),
+        rtol=2e-5, atol=2e-5)
+    _grads_match_reference(q, k, v, True, **blocks)
+
+
+@pytest.mark.parametrize("blocks", [
+    {"block_q": 128, "block_k": 128},
+    {"block_q": 256, "block_k": 128},      # two lane rows of lse a q block
+    {"block_q": 128, "block_k": 256},
+], ids=["q128k128", "q256k128", "q128k256"])
+def test_flash_causal_lse_gradients_across_block_shapes(blocks):
+    """``return_lse=True`` gradients, causal, against autodiff through the
+    dense masked logsumexp, with whole and diagonal blocks."""
+    q, k, v = _rand(b=1, t=512, h=1, d=32, seed=10)
+    scale = 1.0 / np.sqrt(32)
+
+    def loss_flash(q, k, v):
+        out, lse = flash_attention(q, k, v, causal=True, return_lse=True,
+                                   **blocks)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    def loss_dense(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        msk = jnp.arange(512)[:, None] >= jnp.arange(512)[None, :]
+        s = jnp.where(msk[None, None], s, -1e30)
+        lse = jax.scipy.special.logsumexp(s, axis=-1)
+        out = reference_attention(q, k, v, causal=True)
+        return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+
+    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+# ------------------------------------------------------ default block sizes
+@pytest.mark.parametrize("t,want,expected", [
+    (1024, 512, 512),   # the swept shape
+    (384, 512, 384),    # shorter than the default: one block
+    (1280, 512, 256),   # 512 does not divide; the largest aligned one does
+    (640, 512, 128),
+    (96, 512, 96),      # no multiple of 128 divides: largest divisor
+    (200, 128, 100),
+])
+def test_pick_block_prefers_lane_aligned_divisors(t, want, expected):
+    from horovod_tpu.ops.pallas.flash_attention import _pick_block
+    assert _pick_block(t, want) == expected
+
+
+def test_flash_default_blocks_are_the_swept_ones(monkeypatch):
+    """Without arguments or HVD_FLASH_BLOCK_Q/K a [*, 1024, *, 64] call
+    runs 512 x 512 blocks (two q blocks a batch-head in every kernel's
+    grid) and matches the dense reference through them."""
+    monkeypatch.delenv("HVD_FLASH_BLOCK_Q", raising=False)
+    monkeypatch.delenv("HVD_FLASH_BLOCK_K", raising=False)
+    q, k, v = _rand(b=1, t=1024, h=1, d=64, seed=12)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=True)),
+        (0, 1, 2)))(q, k, v)
+    grids = [eqn.params["grid_mapping"].grid for eqn in _walk(jaxpr.jaxpr)
+             if eqn.primitive.name == "pallas_call"]
+    assert grids == [(1, 2)] * 3, grids
+    _grads_match_reference(q, k, v, True)
+
+
+def test_flash_layers_of_one_shape_share_one_traced_kernel():
+    """A transformer calls the kernel once a layer with the same shapes;
+    the calls share one traced (and so one lowered) kernel body instead
+    of tracing it per layer, which is seconds of every job's set-up."""
+    q, k, v = _rand(b=1, t=128, h=2, d=32, seed=13)
+
+    def three_layers(q, k, v):
+        for _ in range(3):
+            q = flash_attention(q, k, v, causal=True)
+        return q
+
+    jaxpr = jax.make_jaxpr(three_layers)(q, k, v)
+    holders = [eqn.params["jaxpr"] for eqn in _walk(jaxpr.jaxpr)
+               if eqn.primitive.name == "jit" and any(
+                   e.primitive.name == "pallas_call"
+                   for e in eqn.params["jaxpr"].jaxpr.eqns)]
+    assert len(holders) == 3, len(holders)
+    assert len({id(h) for h in holders}) == 1
