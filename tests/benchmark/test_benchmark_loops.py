@@ -1,60 +1,26 @@
-"""Each loop end to end at a toy size on the CPU mesh, the last line's
+"""Each loop end to end at a toy size on the CPU mesh (the ``spmd`` loop
+on every cell of the manifest whose traffic names it), the last line's
 keys, the refusals of ``run.py``, and a fifth cell added with files
 alone."""
 
-import hashlib
 import json
 import os
-import statistics
 import subprocess
 import sys
 
 import jax
 import pytest
 
-from benchmark_toy import (BENCH, REPO, bench, dump_json,  # noqa: F401
-                           load_json, make_toy_root, toy_root)
-
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
-
-
-def check_result(result, cell, chips):
-    assert set(result) == RESULT_KEYS
-    assert result["correct"] is True and result["failed"] == 0
-    assert result["attempted"] > 0
-    assert set(result["metrics"]) == {m["name"] for m in cell.end_to_end}
-    for name, metric in result["metrics"].items():
-        assert set(metric) == {"value", "unit"} and metric["value"] > 0
-    assert set(result["device"]) == {"platform", "kind", "count",
-                                     "memory_peak_bytes"}
-    assert result["device"]["count"] == chips
+import benchmark_toy
+from benchmark_toy import (BENCH, REPO, RESULT_KEYS, bench,  # noqa: F401
+                           digest_tree, dump_json, load_json, make_toy_root,
+                           toy_root)
 
 
-@pytest.mark.parametrize("workload,chips", [
-    ("gpt2_medium-spmd-1chip", 1), ("gpt2_medium-spmd-dp4", 4),
-    ("resnet50_v15-spmd-1chip", 1)])
-def test_spmd_loop_runs_end_to_end(workload, chips, bench, toy_root):
-    cell = bench.load_cell(toy_root, workload)
-    lines = []
-    result = bench.run_cell(cell, jax.devices()[:chips], 0, 0.05, False,
-                            log=lines.append)
-    check_result(json.loads(json.dumps(result)), cell, chips)
-    earlier = json.loads(lines[-1])
-    assert earlier["failures"] == []
-    assert {"import_and_devices", "init", "trace_lower", "compile", "reference_check",
-            "warmup"} <= set(earlier["setup_split_s"])
-    # throughput is read from the median block of log_every steps
-    blocks = earlier["window"]["block_s"]
-    log_every = cell.traffic["log_every"]
-    assert len(blocks) == result["attempted"] // log_every
-    rate = next(v["value"] for k, v in result["metrics"].items()
-                if k != "setup_s")
-    per_block = (log_every * cell.job["per_chip_batch"]
-                 * cell.family.sample_units(cell.config, cell.job))
-    assert rate == pytest.approx(per_block / statistics.median(blocks))
-    if chips > 1:
-        assert earlier["notes"]["all_reduces"] > 0
-        assert earlier["notes"]["replica_spread"] == 0.0
+@pytest.mark.parametrize(
+    "workload", [cell for cell, _ in benchmark_toy.spmd_cells(REPO)])
+def test_spmd_loop_runs_end_to_end(workload, bench, toy_root):
+    benchmark_toy.spmd_loop_runs_end_to_end(bench, toy_root, workload)
 
 
 EAGER_DRIVER = """
@@ -122,18 +88,6 @@ def test_traced_run_without_a_device_plane_is_refused(bench, toy_root):
     with pytest.raises(bench.BenchmarkError, match="no operation"):
         bench.run_cell(cell, jax.devices()[:1], 0, 0.05, True,
                        log=lambda line: None)
-
-
-def digest_tree(root):
-    out = {}
-    for folder, _, files in os.walk(root):
-        for name in files:
-            if "__pycache__" not in folder:
-                path = os.path.join(folder, name)
-                with open(path, "rb") as f:
-                    out[os.path.relpath(path, root)] = hashlib.sha256(
-                        f.read()).hexdigest()
-    return out
 
 
 def test_a_fifth_cell_is_files_and_one_entry(bench, tmp_path):
