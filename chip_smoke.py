@@ -15,7 +15,8 @@ Default run, in order, one JSON line each:
 - ``device``        the platform is ``tpu``; versions and compile cache
 - ``kernels``       flash attention, LayerNorm and softmax-xent compiled
                     (``interpret=False``), forward and backward, at the
-                    language model's widths against their references
+                    language model's widths against their references;
+                    flash attention also at OLMoE's shape
 - ``train_lm``      the full-width language model, 5 ``adamw`` steps
 - ``train_resnet``  ResNet-50 bf16 batch 128, 3 SGD-momentum steps
 - ``eager``         allreduce / fused group / allgather / broadcast on
@@ -43,6 +44,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 # leg): widths and sequence are what the kernels are built for.
 LM = {"vocab": 32768, "layers": 8, "d_model": 1024, "heads": 8,
       "d_ff": 4096, "seq": 2048, "batch": 8}
+# The second shape the flash kernels run at in the benchmark: OLMoE's
+# attention (heads of 128, 4 sequences of 4096), after RoPE and QK-norm.
+OLMOE_ATTENTION = ("flash_attention_4x4096x16x128", (4, 4096, 16, 128))
 RESNET_BATCH = 128
 SEED = 0  # weights and data are random, made from this
 # Largest |kernel - reference| over largest |reference|, references
@@ -177,16 +181,23 @@ def phase_kernels():
             want = run(reference)
         return max(float(rel_err(g, w)) for g, w in zip(got, want))
 
+    def reference_by_sequence(q, k, v):
+        """One sequence at a time, its scores made again in the
+        backward pass: float32 ``[16, 4096, 4096]`` is 1 GiB."""
+        return jax.lax.map(jax.checkpoint(lambda s: reference_attention(
+            *(u[None] for u in s), causal=True)[0]), (q, k, v))
+
     errors = {}
     for dtype in (jnp.float32, jnp.bfloat16):
         name = jnp.dtype(dtype).name
-        q, k, v, do = (jax.random.normal(keys[i], (b, t, h, d), dtype)
-                       for i in range(4))
-        errors[f"flash_attention/{name}"] = compare(
-            lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                            interpret=False),
-            lambda q, k, v: reference_attention(q, k, v, causal=True),
-            (q, k, v), (0, 1, 2), do)
+        for label, shape in (("flash_attention", (b, t, h, d)),
+                             OLMOE_ATTENTION):
+            q, k, v, do = (jax.random.normal(keys[i], shape, dtype)
+                           for i in range(4))
+            errors[f"{label}/{name}"] = compare(
+                lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                interpret=False),
+                reference_by_sequence, (q, k, v), (0, 1, 2), do)
 
         x = jax.random.normal(keys[4], (b, t, LM["d_model"]), dtype)
         gamma = 1 + 0.1 * jax.random.normal(keys[5], (LM["d_model"],))

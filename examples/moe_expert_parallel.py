@@ -53,6 +53,7 @@ def main():
     def train_step(params, opt_state, tokens):
         def loss_fn(p):
             logits, aux = apply_with_aux(model, p, tokens)
+            aux = aux["load_balancing"]
             labels = jnp.roll(tokens, -1, axis=-1)
             xent = jnp.mean(
                 optax.softmax_cross_entropy_with_integer_labels(

@@ -56,7 +56,7 @@ def main():
         def loss_fn(p):
             logits, aux = apply_with_aux(model, p, tokens)
             # fused Pallas softmax-xent kernel on TPU
-            return lm_loss(logits, tokens) + 0.01 * aux
+            return lm_loss(logits, tokens) + 0.01 * aux["load_balancing"]
 
         loss, grads = jax.value_and_grad(loss_fn)(params)
         updates, opt_state = opt.update(grads, opt_state, params)

@@ -10,6 +10,7 @@ from horovod_tpu.models.vgg import VGG, VGG16, VGG19  # noqa: F401
 from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
+    BlockSpec,
     Transformer,
     TransformerConfig,
     apply_with_aux,

@@ -35,5 +35,7 @@ from horovod_tpu.parallel.pipeline import (  # noqa: F401
 from horovod_tpu.parallel.moe import (  # noqa: F401
     switch_moe,
     switch_route,
+    topk_moe,
+    topk_route,
     init_moe_params,
 )

@@ -11,8 +11,18 @@ combine einsums into ``all_to_all`` collectives over ICI.
 Top-1 (switch) routing with capacity factor + auxiliary load-balancing
 loss, per Switch Transformer; tokens overflowing an expert's capacity are
 passed through the residual (combine weight 0).
+
+:func:`topk_moe` is the second routing path, the one today's open sparse
+models use (OLMoE, Moonlight, Trinity): token-choice top-k over a softmax
+of all experts, **dropless** (every token-slot is computed whatever the
+imbalance: no capacity, no padding), gated SwiGLU experts, and a grouped
+matrix product over rows sorted by expert in place of the one-hot
+einsums.  Shapes are static and the group sizes are data, so nothing
+recompiles when the load shifts.  It is not expert-parallel yet: all
+experts are resident on the device that calls it.
 """
 
+import functools
 import math
 
 import jax
@@ -134,14 +144,19 @@ def switch_moe(x, params, *, capacity_factor=1.25, group_size=4096,
     return out.astype(x.dtype).reshape(orig_shape), aux
 
 
-def moe_param_shapes(d_model, d_ff, n_experts):
-    """The switch_moe parameter contract — single source of truth shared by
-    :func:`init_moe_params` and the flax ``MoeMlp`` module."""
-    return {
+def moe_param_shapes(d_model, d_ff, n_experts, gated=False):
+    """The parameter contract of :func:`switch_moe` and, with ``gated``
+    (one more ``[E, D, F]`` entry, the gate ``wg``), of :func:`topk_moe`
+    — single source of truth shared by :func:`init_moe_params` and the
+    flax MoE modules."""
+    shapes = {
         "router": (d_model, n_experts),
         "wi": (n_experts, d_model, d_ff),
         "wo": (n_experts, d_ff, d_model),
     }
+    if gated:
+        shapes["wg"] = (n_experts, d_model, d_ff)
+    return shapes
 
 
 def moe_kernel_init(rng, shape, dtype=jnp.float32):
@@ -151,8 +166,130 @@ def moe_kernel_init(rng, shape, dtype=jnp.float32):
     return (jax.random.normal(rng, shape) * scale).astype(dtype)
 
 
-def init_moe_params(rng, d_model, d_ff, n_experts, dtype=jnp.float32):
-    shapes = moe_param_shapes(d_model, d_ff, n_experts)
+def init_moe_params(rng, d_model, d_ff, n_experts, dtype=jnp.float32,
+                    gated=False):
+    shapes = moe_param_shapes(d_model, d_ff, n_experts, gated)
     keys = jax.random.split(rng, len(shapes))
     return {name: {"kernel": moe_kernel_init(k, shape, dtype)}
             for k, (name, shape) in zip(keys, shapes.items())}
+
+
+# ------------------------------------------------ dropless top-k routing
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
+    """Rows of ``x [N, D]`` in slot order: row ``i`` is token
+    ``order[i] // k``.  ``order`` is a permutation of the ``N * k``
+    token-slots and ``inverse`` its inverse, so the backward pass is a
+    gather by ``inverse`` and a sum over a token's ``k`` slots, not the
+    scatter-add that transposing the gather would give."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _dispatch_bwd(k, inverse, g):
+    return g[inverse].reshape(-1, k, g.shape[-1]).sum(1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unsort(y, order, inverse):
+    """``y[inverse]``: slot-ordered rows back in token order; backward
+    is the gather by ``order``."""
+    return y[inverse]
+
+
+_unsort.defvjp(lambda y, order, inverse: (y[inverse], order),
+               lambda order, g: (g[order], None, None))
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs [M, K]`` rows sorted by group, ``rhs [G, K, N]``,
+    ``group_sizes [G]`` summing to ``M`` -> ``[M, N]``: row ``i`` times
+    the matrix of the group it lies in.  ``jax.lax.ragged_dot``: the
+    TPU compiler lowers it, and both its gradients, to a Mosaic kernel
+    of its own (instructions ``%ragged-dot-*``) that multiplies only
+    the rows that exist; measured against megablox's ``gmm`` on the
+    v5e in PERF.md section 6 (PR 27)."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def topk_route(router_logits, k):
+    """Token-choice top-k routing from ``[N, E]`` float32 logits.
+
+    Returns ``(weights [N, k], experts [N, k], aux)``.  The weights are
+    the softmax probabilities of the chosen experts AS THEY ARE, not
+    renormalised over the k (OLMoE's ``norm_topk_prob: false``).
+    ``aux`` holds, over the N tokens,
+
+    - ``load_balancing``: ``E * sum_e f_e P_e`` with ``f_e`` the
+      token-slots routed to ``e`` over N and ``P_e`` the mean
+      probability of ``e`` (Hugging Face's ``load_balancing_loss_func``;
+      1 * k at a uniform router);
+    - ``router_z``: mean of ``logsumexp(logits)^2`` (ST-MoE);
+    - ``tokens_per_expert [E]`` int32, summing to ``N * k``.
+    """
+    n, e = router_logits.shape
+    probs = jax.nn.softmax(router_logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    tokens_per_expert = jnp.sum(
+        jax.nn.one_hot(experts, e, dtype=jnp.int32), axis=(0, 1))
+    f = tokens_per_expert.astype(jnp.float32) / n
+    aux = {
+        "load_balancing": e * jnp.sum(f * jnp.mean(probs, axis=0)),
+        "router_z": jnp.mean(jnp.square(
+            jax.nn.logsumexp(router_logits, axis=-1))),
+        "tokens_per_expert": tokens_per_expert,
+    }
+    return weights, experts, aux
+
+
+def topk_moe(x, params, *, k):
+    """Dropless top-``k`` MoE FFN with gated experts on ``x [..., D]``
+    (leading dims folded into N tokens); returns ``(out, aux)``.
+
+    params: ``router/kernel [D, E]``, ``wg/kernel`` and ``wi/kernel``
+    ``[E, D, F]`` (gate and up), ``wo/kernel [E, F, D]`` (create with
+    ``init_moe_params(..., gated=True)``).  Per token, with ``S`` the
+    ``k`` experts of largest router probability ``p``:
+
+        out = sum_{e in S} p_e * (silu(x wg_e) * (x wi_e)) wo_e
+
+    The router's product and softmax run in float32 whatever ``x`` is,
+    so that rounding does not flip a near-tie between the k-th and the
+    next expert.  The ``N * k`` token-slots are sorted by expert
+    (stable), their rows gathered, three grouped products run with
+    group sizes = tokens per expert, and the result is un-sorted by the
+    inverse permutation and summed over a token's slots: no capacity,
+    no token dropped, no scatter.  ``aux`` is :func:`topk_route`'s.
+    """
+    orig_shape = x.shape
+    d = orig_shape[-1]
+    xt = x.reshape(-1, d)
+    n = xt.shape[0]
+    dtype = x.dtype
+    with jax.named_scope("moe/route"):
+        logits = jnp.dot(
+            xt.astype(jnp.float32),
+            params["router"]["kernel"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        weights, experts, aux = topk_route(logits, k)
+    with jax.named_scope("moe/dispatch"):
+        order = jnp.argsort(experts.reshape(-1), stable=True)
+        inverse = jnp.argsort(order)
+        rows = _dispatch(xt, order, inverse, k)
+        group_sizes = aux["tokens_per_expert"]
+    with jax.named_scope("moe/experts"):
+        wg, wi, wo = (params[name]["kernel"].astype(dtype)
+                      for name in ("wg", "wi", "wo"))
+        hidden = (jax.nn.silu(grouped_matmul(rows, wg, group_sizes))
+                  * grouped_matmul(rows, wi, group_sizes))
+        y = grouped_matmul(hidden, wo, group_sizes)
+    with jax.named_scope("moe/combine"):
+        y = _unsort(y, order, inverse).reshape(n, k, d)
+        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
+    return out.astype(dtype).reshape(orig_shape), aux

@@ -47,7 +47,7 @@ def transformer_sharding_rules(tp_axis="tp", fsdp_axis=None):
         (r"mlp.*(up|fc1|wi|gate).*kernel", P(f, tp_axis)),
         (r"mlp.*(down|fc2|wo).*kernel", P(tp_axis, f)),
         # moe experts: [n_experts, d_in, d_out]
-        (r"moe.*(wi|up).*kernel", P("ep", f, tp_axis)),
+        (r"moe.*(wi|wg|up|gate).*kernel", P("ep", f, tp_axis)),
         (r"moe.*(wo|down).*kernel", P("ep", tp_axis, f)),
         (r"moe.*router.*kernel", P(f, None)),
         # embeddings / head: vocab-split; position table replicated

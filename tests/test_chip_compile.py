@@ -65,14 +65,24 @@ def _on(device, shape, dtype):
                                 sharding=SingleDeviceSharding(device))
 
 
+def _shaped(mesh, tree, spec):
+    """``tree``'s shapes placed over ``mesh`` as ``spec`` says."""
+    sharding = NamedSharding(mesh, spec)
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
 LM = chip_smoke.LM
 B, T, H, D = LM["batch"], LM["seq"], LM["heads"], \
     LM["d_model"] // LM["heads"]
 
 
+@pytest.mark.parametrize("shape", [(B, T, H, D),
+                                   chip_smoke.OLMOE_ATTENTION[1]],
+                         ids=["lm", "olmoe"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
-def test_flash_attention_compiles_for_v5e(v5e, dtype):
+def test_flash_attention_compiles_for_v5e(v5e, dtype, shape):
     from horovod_tpu.ops.pallas.flash_attention import flash_attention
 
     def fwd_bwd(q, k, v):
@@ -80,7 +90,7 @@ def test_flash_attention_compiles_for_v5e(v5e, dtype):
             q, k, v, causal=True, interpret=False).astype(jnp.float32)),
             (0, 1, 2))(q, k, v)
 
-    qkv = [_on(v5e[0], (B, T, H, D), dtype)] * 3
+    qkv = [_on(v5e[0], shape, dtype)] * 3
     text = _compile(fwd_bwd, *qkv).as_text()
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
 
@@ -127,17 +137,13 @@ def test_lm_step_compiles_for_v5e(v5e, chips):
     model = chip_smoke.lm_model()
     opt = hvd.DistributedOptimizer(optax.adamw(1e-4))
 
-    def shaped(tree, spec):
-        sharding = NamedSharding(mesh, spec)
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=sharding), tree)
-
     tokens = jax.ShapeDtypeStruct((B, T), jnp.int32)
     params = jax.eval_shape(
         model.init, jax.random.PRNGKey(0), tokens)["params"]
     compiled = chip_smoke.lm_step(model, opt, mesh).lower(
-        shaped(params, P()), shaped(jax.eval_shape(opt.init, params), P()),
-        shaped(tokens, P("hvd"))).compile()
+        _shaped(mesh, params, P()),
+        _shaped(mesh, jax.eval_shape(opt.init, params), P()),
+        _shaped(mesh, tokens, P("hvd"))).compile()
 
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -182,3 +188,70 @@ def test_sequence_parallel_attention_compiles_for_v5e(v5e, kind):
     assert "tpu_custom_call" in text
     moves = "all-to-all" if kind == "ulysses" else "collective-permute"
     assert moves in text
+
+
+def test_topk_moe_compiles_for_v5e_as_grouped_product_kernels(v5e):
+    """The dropless expert layer at OLMoE's widths and the benchmark
+    cell's 16,384 tokens, forward and backward: the compiler lowers
+    ``ragged_dot`` and both its gradients to kernels of its own (nine
+    ``ragged-dot`` custom calls), not to a dense product over all 64
+    experts, and the backward pass holds no scatter of rows."""
+    from horovod_tpu.parallel.moe import moe_param_shapes, topk_moe
+
+    def fwd_bwd(x, params):
+        return jax.grad(lambda x, p: jnp.sum(
+            topk_moe(x, p, k=8)[0].astype(jnp.float32)), (0, 1))(x, params)
+
+    params = {name: {"kernel": _on(v5e[0], shape, jnp.float32)}
+              for name, shape in moe_param_shapes(
+                  2048, 1024, 64, gated=True).items()}
+    text = _compile(fwd_bwd, _on(v5e[0], (16384, 2048), jnp.bfloat16),
+                    params).as_text()
+    products = [line for line in text.splitlines()
+                if " custom-call(" in line and "%ragged-dot-none" in line
+                and "tpu_custom_call" in line]
+    assert len(products) == 9
+    assert "bf16[131072,2048]" in text
+    assert not [line for line in text.splitlines()
+                if " scatter(" in line and "[131072,2048]" in line]
+
+
+def test_olmoe_cell_step_compiles_for_v5e(v5e):
+    """The benchmark's ``olmoe_1b_7b-spmd-1chip`` as ``benchmark/run.py``
+    builds it (the ``spmd`` loop's own step over the family's loss) at
+    published widths, depth 1, 4 sequences of 4096: fits one chip."""
+    import sys
+
+    from horovod_tpu.parallel import make_mesh
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(repo, "tests", "benchmark"))
+    try:
+        from benchmark_toy import load_by_path
+    finally:
+        sys.path.pop(0)
+    bench = load_by_path(os.path.join(repo, "benchmark", "run.py"),
+                         "hvd_benchmark_run_chip_compile")
+    cell = bench.load_cell(repo, "olmoe_1b_7b-spmd-1chip")
+    mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
+
+    import optax
+
+    opt, step = cell.loop.make_step(
+        cell, optax.adamw(**cell.job["optimizer"]["args"]), mesh)
+    params, extra = jax.eval_shape(
+        lambda key: cell.family.init(cell.config, cell.job, key),
+        jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct(
+        (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
+    compiled = step.lower(
+        _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
+        _shaped(mesh, jax.eval_shape(opt.init, params), P()),
+        _shaped(mesh, tokens, P("hvd"))).compile()
+    text = compiled.as_text()
+    # nine grouped products, three flash kernels, softmax-xent's two
+    assert text.count("%ragged-dot-none") >= 9
+    assert text.count("tpu_custom_call") >= 14
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
