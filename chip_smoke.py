@@ -47,6 +47,9 @@ LM = {"vocab": 32768, "layers": 8, "d_model": 1024, "heads": 8,
 # The second shape the flash kernels run at in the benchmark: OLMoE's
 # attention (heads of 128, 4 sequences of 4096), after RoPE and QK-norm.
 OLMOE_ATTENTION = ("flash_attention_4x4096x16x128", (4, 4096, 16, 128))
+# The second shape softmax-xent runs at in the benchmark: OLMoE's logits
+# (4 sequences of 4096, vocabulary 50304 = 128 x 393).
+OLMOE_LOGITS = ("softmax_xent_4x4096x50304", (4, 4096, 50304))
 RESNET_BATCH = 128
 SEED = 0  # weights and data are random, made from this
 # Largest |kernel - reference| over largest |reference|, references
@@ -207,12 +210,15 @@ def phase_kernels():
             layer_norm_reference, (x, gamma, beta), (0, 1, 2),
             jax.random.normal(keys[7], x.shape, dtype))
 
-        logits = 5 * jax.random.normal(keys[8], (b, t, LM["vocab"]), dtype)
-        labels = jax.random.randint(keys[9], (b, t), 0, LM["vocab"])
-        errors[f"softmax_xent/{name}"] = compare(
-            lambda lg: softmax_xent(lg, labels, False),
-            lambda lg: softmax_xent_reference(lg, labels),
-            (logits,), (0,), jnp.full((b, t), 1.0 / (b * t)))
+        for label, shape in (("softmax_xent", (b, t, LM["vocab"])),
+                             OLMOE_LOGITS):
+            logits = 5 * jax.random.normal(keys[8], shape, dtype)
+            labels = jax.random.randint(keys[9], shape[:-1], 0, shape[-1])
+            errors[f"{label}/{name}"] = compare(
+                lambda lg: softmax_xent(lg, labels, False),
+                lambda lg: softmax_xent_reference(lg, labels),
+                (logits,), (0,),
+                jnp.full(shape[:-1], 1.0 / math.prod(shape[:-1])))
 
     bad = {k: e for k, e in errors.items()
            if not e <= KERNEL_TOL[k.split("/")[1]]}

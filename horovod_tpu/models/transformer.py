@@ -275,8 +275,9 @@ def lm_loss(logits, tokens):
     """Mean next-token cross-entropy — the LM training loss.
 
     On TPU this is the fused Pallas kernel
-    (``ops/pallas/softmax_xent.py``: vocab streamed in VMEM chunks, no
-    materialized ``[rows, vocab]`` log-softmax); the XLA/optax lowering
+    (``ops/pallas/softmax_xent.py``: no materialized ``[rows, vocab]``
+    log-softmax; the logits walked in tiles sized by what fits VMEM,
+    whatever the vocabulary divides by); the XLA/optax lowering
     elsewhere."""
     labels = jnp.roll(tokens, -1, axis=-1)
     if jax.default_backend() == "tpu":

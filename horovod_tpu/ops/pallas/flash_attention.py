@@ -77,9 +77,10 @@ def _flatten_rows(x, fill=0.0, pad_multiple=8):
 
 
 def _pick_block_n(n, d, slabs=1):
-    """Row-block size for the row-blocked kernels (layer_norm,
-    softmax_xent): keep the kernel's [block_n, d] fp32 slabs well under
-    VMEM; ``slabs`` counts how many the kernel holds at once."""
+    """Row-block size for ``layer_norm``, whose blocks are whole rows:
+    keep the kernel's [block_n, d] fp32 slabs well under VMEM; ``slabs``
+    counts how many the kernel holds at once.  (``softmax_xent`` tiles
+    the columns too and asks in its own file.)"""
     budget = max((4 << 20) // (d * 4 * slabs), 8)
     for cand in (256, 128, 64, 32, 16, 8):
         if cand <= budget and n % cand == 0:
