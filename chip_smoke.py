@@ -1,47 +1,49 @@
-"""Quickest proof that horovod_tpu starts on the attached TPU.
+"""Quickest proof that horovod_tpu starts on the attached TPU, and the
+checks of the device path that no cell of the benchmark makes.
 
-    python chip_smoke.py            # one chip: every phase below
+    python chip_smoke.py            # one chip: device, kernels, eager
     python chip_smoke.py --chips 4  # one four-chip host: cross-chip only
 
 One process, the only one that touches JAX.  It drives the public
 surface the way a user script does — ``hvd.init()``, ``make_mesh``,
 ``hvd.DistributedOptimizer`` inside ``shard_map``, ``Transformer`` /
-``lm_loss``, ``ResNet50``, ``run_parallel`` + the eager collectives — and
-fails (non-zero exit, no ``"ok"`` line) at the first phase that does not
+``lm_loss``, ``run_parallel`` + the eager collectives — and fails
+(non-zero exit, no ``"ok"`` line) at the first phase that does not
 hold.  There is no CPU branch: without a TPU it refuses to run.
 
 Default run, in order, one JSON line each:
 
-- ``device``        the platform is ``tpu``; versions and compile cache
-- ``kernels``       flash attention, LayerNorm and softmax-xent compiled
-                    (``interpret=False``), forward and backward, at the
-                    language model's widths against their references;
-                    flash attention also at OLMoE's shape
-- ``train_lm``      the full-width language model, 5 ``adamw`` steps
-- ``train_resnet``  ResNet-50 bf16 batch 128, 3 SGD-momentum steps
-- ``eager``         allreduce / fused group / allgather / broadcast on
-                    device arrays through the negotiated plane
+- ``device``   the platform is ``tpu``; versions and compile cache
+- ``kernels``  flash attention, LayerNorm and softmax-xent compiled
+               (``interpret=False``), forward and backward, in both
+               dtypes against their references, at the language model's
+               widths; flash attention and softmax-xent also at OLMoE's
+               shapes
+- ``eager``    allreduce / fused group / allgather / broadcast on
+               device arrays through the negotiated plane
 
 ``--chips 4`` runs ``device``, then ``dp_lm`` (the language-model step
-data-parallel over four chips against the same batch on one chip) and
-``eager`` with four device-ranks, and nothing else.
+data-parallel over four chips against the same batch on one chip: a
+sum for a mean, which ``adamw`` hides from the benchmark's ``correct``)
+and ``eager`` with four device-ranks, and nothing else.
 
-Times, compile seconds and peak memory on the phase lines are
-information for whoever reads the log, not benchmark metrics.
+Whole training steps are the benchmark's (``python3 benchmark/run.py
+--workload <cell>``, ``BENCHMARK.json``): ``gpt2_medium-spmd-1chip``
+and ``resnet50_v15-spmd-1chip`` run the language-model and ResNet-50
+steps through the same ``DistributedOptimizer`` + ``shard_map``, hold
+them to a float32 reference and to a window in which nothing compiles.
 """
 
 import argparse
-import contextlib
 import json
 import math
 import os
 import sys
-import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# The repository's full-width language model (bench.py's transformer
-# leg): widths and sequence are what the kernels are built for.
+# The language model of ``dp_lm`` and of ``tests/test_chip_compile.py``:
+# widths and sequence are what the kernels are built for.
 LM = {"vocab": 32768, "layers": 8, "d_model": 1024, "heads": 8,
       "d_ff": 4096, "seq": 2048, "batch": 8}
 # The second shape the flash kernels run at in the benchmark: OLMoE's
@@ -50,7 +52,6 @@ OLMOE_ATTENTION = ("flash_attention_4x4096x16x128", (4, 4096, 16, 128))
 # The second shape softmax-xent runs at in the benchmark: OLMoE's logits
 # (4 sequences of 4096, vocabulary 50304 = 128 x 393).
 OLMOE_LOGITS = ("softmax_xent_4x4096x50304", (4, 4096, 50304))
-RESNET_BATCH = 128
 SEED = 0  # weights and data are random, made from this
 # Largest |kernel - reference| over largest |reference|, references
 # traced at "highest" matmul precision.  Measured on v5e (CHANGES.md,
@@ -81,40 +82,6 @@ def emit(phase, **fields):
 def require(cond, message):
     if not cond:
         raise SmokeFailure(message)
-
-
-@contextlib.contextmanager
-def count_compiles():
-    """Counts backend compilations inside the block (``jax.monitoring``
-    reports one duration event per compiled program)."""
-    from jax import monitoring
-
-    seen = []
-
-    def listener(event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            seen.append(duration)
-
-    monitoring.register_event_duration_secs_listener(listener)
-    try:
-        yield seen
-    finally:
-        monitoring.unregister_event_duration_listener(listener)
-
-
-def memory(device, compiled=None):
-    """The allocator's peak so far and, for a compiled step, XLA's own
-    account of it.  On v5e the allocator's peak leaves out a program's
-    temporaries (PERF.md, PR 21), so the second is the one to size by."""
-    out = {"peak_bytes_in_use": (device.memory_stats() or {}).get(
-        "peak_bytes_in_use")}
-    if compiled is not None:
-        analysis = compiled.memory_analysis()
-        out.update(argument_bytes=analysis.argument_size_in_bytes,
-                   temp_bytes=analysis.temp_size_in_bytes,
-                   output_bytes=analysis.output_size_in_bytes,
-                   alias_bytes=analysis.alias_size_in_bytes)
-    return out
 
 
 # --------------------------------------------------------------- device
@@ -285,132 +252,6 @@ def lm_inputs(model, mesh):
     return params, jax.device_put(tokens, NamedSharding(mesh, P("hvd")))
 
 
-def phase_train_lm(devices):
-    import jax
-    import optax
-
-    import horovod_tpu as hvd
-    from horovod_tpu.parallel import make_mesh
-
-    mesh = make_mesh({"hvd": 1}, devices=devices[:1])
-    model = lm_model()
-    opt = hvd.DistributedOptimizer(optax.adamw(1e-4))
-    params, tokens = lm_inputs(model, mesh)
-    opt_state = opt.init(params)
-
-    start = time.perf_counter()
-    step = lm_step(model, opt, mesh).lower(
-        params, opt_state, tokens).compile()
-    compile_s = time.perf_counter() - start
-    # the Pallas branch of models/transformer.py, not the references
-    n_kernels = step.as_text().count("tpu_custom_call")
-    require(n_kernels > 0, "compiled step has no tpu_custom_call: the "
-                           "model took its reference branch")
-
-    losses, ms_ready, ms_get = [], [], []
-    with count_compiles() as compiles:
-        for i in range(5):
-            if i == 1:
-                del compiles[:]  # steps 2..5 must compile nothing
-            start = time.perf_counter()
-            params, opt_state, loss = step(params, opt_state, tokens)
-            jax.block_until_ready(loss)
-            ms_ready.append((time.perf_counter() - start) * 1e3)
-            losses.append(float(jax.device_get(loss)))
-            ms_get.append((time.perf_counter() - start) * 1e3)
-    require(all(math.isfinite(x) for x in losses),
-            f"loss not finite: {losses}")
-    require(abs(losses[0] - math.log(LM["vocab"])) < 1.0,
-            f"first loss {losses[0]} is not near ln(vocab) = "
-            f"{math.log(LM['vocab']):.2f} for random weights")
-    require(losses[-1] < losses[0], f"loss did not fall: {losses}")
-    require(not compiles, f"{len(compiles)} compiles after the first step")
-    emit("train_lm", config=LM, tpu_custom_call=n_kernels,
-         losses=[round(x, 4) for x in losses], compile_s=round(compile_s, 2),
-         compiles_after_first_step=len(compiles),
-         step_ms_block_until_ready=[round(x, 2) for x in ms_ready[1:]],
-         step_ms_device_get=[round(x, 2) for x in ms_get[1:]],
-         memory=memory(devices[0], step))
-
-
-# --------------------------------------------------------------- resnet
-def phase_train_resnet(devices):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu.models import ResNet50
-    from horovod_tpu.parallel import make_mesh
-    from horovod_tpu.parallel._compat import shard_map
-
-    mesh = make_mesh({"hvd": 1}, devices=devices[:1])
-    model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)
-    # placed as the step returns them, or the second call compiles anew
-    variables = jax.jit(
-        lambda r, x: model.init(r, x, train=True),
-        out_shardings=NamedSharding(mesh, P()))(
-        jax.random.PRNGKey(SEED),
-        jnp.zeros((1, 224, 224, 3), jnp.float32))
-    params, stats = variables["params"], variables["batch_stats"]
-    stats_before = jax.device_get(stats)
-    opt = hvd.DistributedOptimizer(optax.sgd(0.1, momentum=0.9))
-    opt_state = opt.init(params)
-
-    def per_shard(params, stats, opt_state, x, y):
-        def loss_fn(p):
-            logits, updates = model.apply(
-                {"params": p, "batch_stats": stats}, x, train=True,
-                mutable=["batch_stats"])
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits, y).mean()
-            return loss, updates["batch_stats"]
-
-        (loss, stats), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        stats = jax.tree.map(lambda s: jax.lax.pmean(s, "hvd"), stats)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return (optax.apply_updates(params, updates), stats, opt_state,
-                jax.lax.pmean(loss, "hvd"))
-
-    step = jax.jit(shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(), P(), P(), P("hvd"), P("hvd")),
-        out_specs=(P(), P(), P(), P())), donate_argnums=(0, 1, 2))
-    rng = np.random.RandomState(SEED)
-    sharded = NamedSharding(mesh, P("hvd"))
-    x = jax.device_put(rng.randn(RESNET_BATCH, 224, 224, 3).astype(
-        np.float32), sharded)
-    y = jax.device_put(rng.randint(0, 1000, (RESNET_BATCH,)), sharded)
-
-    losses, ms = [], []
-    with count_compiles() as compiles:
-        for i in range(3):
-            if i == 1:
-                del compiles[:]
-            start = time.perf_counter()
-            params, stats, opt_state, loss = step(
-                params, stats, opt_state, x, y)
-            losses.append(float(jax.device_get(loss)))
-            ms.append((time.perf_counter() - start) * 1e3)
-    require(all(math.isfinite(v) for v in losses),
-            f"loss not finite: {losses}")
-    require(not compiles, f"{len(compiles)} compiles after the first step")
-    moved = [not np.allclose(a, b) for a, b in zip(
-        jax.tree.leaves(stats_before), jax.tree.leaves(
-            jax.device_get(stats)))]
-    require(all(moved), f"{moved.count(False)} of {len(moved)} batch "
-                        f"statistics never updated")
-    emit("train_resnet", batch=RESNET_BATCH,
-         losses=[round(v, 4) for v in losses],
-         first_step_with_compile_s=round(ms[0] / 1e3, 2),
-         step_ms_device_get=[round(v, 2) for v in ms[1:]],
-         compiles_after_first_step=len(compiles),
-         batch_stats_updated=len(moved), memory=memory(devices[0]))
-
-
 # ---------------------------------------------------------------- eager
 def phase_eager(devices):
     """The negotiated plane: every device is a rank on its own thread,
@@ -562,8 +403,6 @@ def main(argv=None):
         try:
             if args.chips == 1:
                 phase_kernels()
-                phase_train_lm(devices)
-                phase_train_resnet(devices)
             else:
                 phase_dp_lm(devices)
             phase_eager(devices)

@@ -1,4 +1,4 @@
-"""ImageNet ResNet-50 training — the BASELINE.md flagship (reference:
+"""ImageNet ResNet-50 training — the upstream's flagship model (reference:
 ``examples/pytorch_imagenet_resnet50.py``): real-data pipeline with
 rank-sharded loading, bf16 SPMD training step over the ``hvd`` mesh,
 linear-scaled LR with warmup + staircase decay, top-1/top-5 validation

@@ -1206,9 +1206,9 @@ class RingPlane:
                        timeout=None):
         """The seed-era exact ring, verbatim: float64/int64 accumulator
         bytes on the wire, strictly serial whole-chunk blocking steps on
-        the control connection.  Kept as the measured baseline for the
-        pipelined plane (bench leg ``allreduce_gbs_ring_pipelined``) and
-        as the oracle for the parity matrix — NOT used in production."""
+        the control connection.  Kept as the oracle for the pipelined
+        plane's parity matrix (``tests/test_tcp_matrix.py``) — NOT used
+        in production."""
         participants = sorted(participants)
         p = len(participants)
         idx = participants.index(self.rank)

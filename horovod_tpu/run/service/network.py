@@ -30,7 +30,7 @@ from horovod_tpu.run.service import secret
 from horovod_tpu.utils import env as env_util
 
 # Largest frame accepted before authentication.  Generous: the tcp star
-# data plane ships whole tensors (the bench sweep goes to 256 MB).
+# data plane ships whole tensors (hundreds of MB for a fused bucket).
 MAX_FRAME_BYTES = 1 << 30
 
 # Bulk (raw-bytes) frame: the high bit of the length word flags a frame
@@ -197,7 +197,7 @@ def _valid_seq(value):
             and 0 <= value < (1 << 62))
 
 
-# process-wide session telemetry (soak gates + bench read these)
+# process-wide session telemetry (the soak gates read these)
 _session_stats_lock = threading.Lock()
 _session_stats = {"reconnects_healed": 0, "reconnects_failed": 0,
                   "frames_replayed": 0}

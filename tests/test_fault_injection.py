@@ -16,6 +16,7 @@ import time
 
 import pytest
 
+import ring_rig
 from conftest import spawn_tcp_ranks
 from horovod_tpu.common import faults
 from horovod_tpu.common.handles import HvdAbortedError
@@ -638,19 +639,12 @@ def test_injected_drop_inside_subgroup_promotes_stall():
 
 
 # ----------------------------------------- pipelined stripe data plane ------
-def _stripe_planes(p=2, segment_bytes=1024, stripes=2):
-    """Loopback ring rig — one definition in ``bench._ring_harness``."""
-    import bench
-
-    return bench._ring_harness(p, segment_bytes, stripes)
-
-
 def test_abort_wakes_blocked_stripe_recv_mid_pipeline():
     """A recv blocked on the MISSING segments of a partially-delivered
     chunk (some stripes delivered, one wedged) must wake with the typed
     error when the abort lands — stripe sockets are covered by the same
     mailbox condition the abort signals."""
-    services, planes = _stripe_planes(p=2, segment_bytes=1024, stripes=2)
+    services, planes = ring_rig.ring_harness(2, 1024, 2)
     try:
         # rank 0 delivers only the FIRST segment of a 3-segment chunk
         # (simulating a wedged stripe): enqueue segment 0 directly
@@ -690,7 +684,7 @@ def test_purge_drops_stale_segments_mid_pipeline_and_is_ring_indexed():
     segments (O(chunks of the ring) via the ring-id index), refuses its
     late-arriving stripe segments, and leaves other rounds' chunks
     untouched."""
-    services, planes = _stripe_planes(p=2, segment_bytes=1024, stripes=2)
+    services, planes = ring_rig.ring_harness(2, 1024, 2)
     try:
         svc = services[1]
         # segments of two interleaved rounds, delivered over stripes

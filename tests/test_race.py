@@ -173,7 +173,9 @@ def _nonbaselined(report_glob):
 
 def _run_inline_under_shim(body, report_prefix, tmp_path):
     env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    # the fixture scripts import tests/ring_rig.py
+    env["PYTHONPATH"] = os.pathsep.join(
+        [REPO, os.path.join(REPO, "tests"), env.get("PYTHONPATH", "")])
     env.update({
         "JAX_PLATFORMS": "cpu",
         "HVD_TPU_RACE": "1",
@@ -191,37 +193,26 @@ RING_HARNESS = r"""
 import numpy as np
 import threading
 import horovod_tpu  # installs the shim
-import bench
+import ring_rig
 
-services, planes = bench._ring_harness(2, 1024, 2)
-def run_all(fn):
-    errs = []
-    def run(r):
-        try:
-            fn(r)
-        except BaseException as e:
-            errs.append(e)
-    ts = [threading.Thread(target=run, args=(r,)) for r in range(2)]
-    for t in ts: t.start()
-    for t in ts: t.join()
-    assert not errs, errs
+services, planes = ring_rig.ring_harness(2, 1024, 2)
 
 arrs = [np.arange(4000, dtype=np.float32) * (r + 1) for r in range(2)]
 out = [None, None]
 def ar(r):
     out[r] = planes[r].allreduce(1, arrs[r], [0, 1],
                                  op_average=False, world_size=2)
-run_all(ar)
+ring_rig.run_all(planes, ar)
 assert np.array_equal(out[0], out[1])
 def ar8(r):
     out[r] = planes[r].allreduce(2, arrs[r], [0, 1], op_average=False,
                                  world_size=2, compression="int8")
-run_all(ar8)
+ring_rig.run_all(planes, ar8)
 def bc(r):
     out[r] = planes[r].broadcast(3, arrs[0] if r == 0 else None,
                                  [0, 1], 0, shape=arrs[0].shape,
                                  dtype="float32")
-run_all(bc)
+ring_rig.run_all(planes, bc)
 # abort waking a blocked stripe recv, then teardown
 caught = []
 def blocked():
@@ -573,22 +564,10 @@ def test_adaptive_coordinator_path_clean_under_shim(tmp_path):
 
 HIER_HARNESS = r"""
 import numpy as np
-import threading
 import horovod_tpu  # installs the shim
-import bench
+import ring_rig
 
-services, planes = bench._ring_harness(4, 4096, 2)
-def run_all(fn):
-    errs = []
-    def run(r):
-        try:
-            fn(r)
-        except BaseException as e:
-            errs.append(e)
-    ts = [threading.Thread(target=run, args=(r,)) for r in range(4)]
-    for t in ts: t.start()
-    for t in ts: t.join()
-    assert not errs, errs
+services, planes = ring_rig.ring_harness(4, 4096, 2)
 
 arrs = [np.arange(5000, dtype=np.float32) * (r + 1) for r in range(4)]
 groups = [[0, 1], [2, 3]]
@@ -597,18 +576,18 @@ def hier(r):
     out[r] = planes[r].allreduce_hierarchical(
         1, arrs[r], [0, 1, 2, 3], groups, op_average=False,
         world_size=4)
-run_all(hier)
+ring_rig.run_all(planes, hier)
 assert all(np.array_equal(o, out[0]) for o in out[1:])
 def hier8(r):
     out[r] = planes[r].allreduce_hierarchical(
         2, arrs[r], [0, 1, 2, 3], groups, op_average=False,
         world_size=4, compression="int8")
-run_all(hier8)
+ring_rig.run_all(planes, hier8)
 assert all(np.array_equal(o, out[0]) for o in out[1:])
 def rhd(r):
     out[r] = planes[r].allreduce_rhd(3, arrs[r], [0, 1, 2, 3],
                                      op_average=False, world_size=4)
-run_all(rhd)
+ring_rig.run_all(planes, rhd)
 assert all(np.array_equal(o, out[0]) for o in out[1:])
 for p in planes: p.close()
 for s in services: s.shutdown()

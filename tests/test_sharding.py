@@ -65,6 +65,27 @@ def test_zero_shard_layout_matches_array_split(n_params, world):
     assert list(counts) == list(reduce_scatter_split_sizes(n_params, world))
 
 
+@pytest.mark.parametrize("world", [1, 2, 4, 8])
+def test_zero_optimizer_state_is_one_over_n(world):
+    """ZeRO acceptance (docs/sharding.md): the largest rank's adam state
+    for its shard of a flat parameter vector is ~1/N of the replicated
+    state (``np.array_split`` gives the first ranks one extra element)."""
+    n_params = 1 << 20
+    params = jnp.zeros((n_params,), jnp.float32)
+    opt = optax.adam(1e-3)
+
+    def nbytes(state):
+        return sum(np.asarray(leaf).nbytes for leaf in jax.tree.leaves(state))
+
+    largest = 0
+    for rank in range(world):
+        _, off, cnt = zero_shard_layout(n_params, world, rank)
+        largest = max(largest, nbytes(opt.init(params[off:off + cnt])))
+    ratio = largest / nbytes(opt.init(params))
+    # mu+nu shard exactly; the count scalar is O(1) — allow 2% over 1/N
+    assert 1.0 / world * 0.9 <= ratio <= 1.0 / world + 0.02, (world, ratio)
+
+
 def test_shard_chunk_size_is_ceil_div():
     assert shard_chunk_size(8, 4) == 2
     assert shard_chunk_size(9, 4) == 3

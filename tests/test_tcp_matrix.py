@@ -19,6 +19,7 @@ import threading
 import numpy as np
 import pytest
 
+import ring_rig
 from conftest import spawn_tcp_ranks
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -435,14 +436,12 @@ def test_tcp_reduce_scatter_both_planes_4proc():
 # ===================================================================
 class _PipelinedHarness:
     """One PeerService mailbox + RingPlane per rank with bulk stripes
-    (the transport rig is ``bench._ring_harness`` — one definition for
-    the bench sweep, this matrix, and the fault tests)."""
+    (the transport rig is ``ring_rig.ring_harness`` — one definition
+    for this matrix, the race fixtures and the fault tests)."""
 
     def __init__(self, p, segment_bytes, stripes):
-        import bench
-
         self.p = p
-        self.services, self.planes = bench._ring_harness(
+        self.services, self.planes = ring_rig.ring_harness(
             p, segment_bytes, stripes)
         self._ring_id = 0
 
