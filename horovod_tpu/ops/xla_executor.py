@@ -1,9 +1,8 @@
 """XLA collective executor — the TPU data plane.
 
 This is the TPU-native replacement for the reference's collective backends
-(``horovod/common/ops/{nccl,mpi,gloo}_operations.cc``): fused groups built by
-the controller are staged into a stacked, mesh-sharded ``jax.Array`` (the
-fusion buffer) and executed by ONE compiled XLA program per steady-state
+(``horovod/common/ops/{nccl,mpi,gloo}_operations.cc``): a response the
+controller built is executed by ONE compiled XLA program per steady-state
 signature — ``lax.psum`` / ``lax.all_gather`` over the ``hvd`` mesh axis rides
 ICI within a slice and DCN across slices.
 
@@ -15,9 +14,21 @@ Design notes (vs the reference):
   signature (op, dtype, shapes, scale factors), so a training loop's recurring
   gradient buckets hit the XLA executable cache after the first step — the
   ResponseCache idea (``response_cache.cc``) mapped onto the compilation model.
-- Fusion-buffer "memcpy in/out" (``collective_operations.cc:44``) becomes a
-  per-rank jitted concat/split running on that rank's device; XLA fuses the
-  reshape/cast/scale into the collective program.
+- **An allreduce response is one program launch.**  The reference copies
+  every tensor into the fusion buffer before the collective and out of it
+  after (``MemcpyInFusionBuffer`` / ``MemcpyOutFusionBuffer``,
+  ``collective_operations.cc:44``).  Here no buffer is built outside the
+  program: each entry's per-rank tensors become one mesh-sharded
+  ``jax.Array`` whose shard on a rank's device IS that rank's tensor (an
+  assembly of handles, no device work), and the cached program takes those
+  arrays as its arguments and ravels, concatenates, scales, casts, reduces
+  and splits inside itself.  The flat buffer exists only as a value of the
+  program, where XLA fuses the copies into the collective's neighbours; a
+  launch costs the host far more than the bytes cost the chip, and a
+  program per copy made a 100 us collective wait for 765 us of host work
+  (PERF.md, PR 30).  The other collectives still stage a per-rank buffer
+  with a small jitted program first (``_fuse_in``, the pads of allgather
+  and alltoall) and assemble it with ``_stack``.
 - GPU ready-events + finalizer threads (``gpu_operations.h:92``) are
   unnecessary: JAX's async dispatch returns immediately and consumers block
   only when they touch the result.
@@ -72,6 +83,7 @@ class XlaExecutor:
         # share the topology.
         self.mesh, self.axis = self._build_mesh(self.devices)
         self._sharded = NamedSharding(self.mesh, P(self.axis))
+        self._replicated = NamedSharding(self.mesh, P())
         # Multi-process (global-mesh) support: this process only produces
         # and consumes the shards that live on its own devices; the
         # compiled program spans the full mesh (reference analog: each
@@ -83,6 +95,7 @@ class XlaExecutor:
         self.multiprocess = len(self.local_ranks) != self.num_ranks
         # caches are touched only from the coordinator thread
         self._fuse_in_cache = {}
+        self._zeros_cache = {}
         self._allreduce_cache = {}
         self._allgather_cache = {}
         self._alltoall_cache = {}
@@ -194,7 +207,10 @@ class XlaExecutor:
     # ------------------------------------------------------- fusion buffer in
     def _fuse_in(self, tensors, sizes, dtype):
         """Concat one rank's tensors into a flat [1, total] buffer on its
-        device (reference: MemcpyInFusionBuffer)."""
+        device (reference: MemcpyInFusionBuffer).  A device program of its
+        own: only ``reduce_scatter``, ``broadcast`` and ``adasum`` still
+        stage their one tensor this way; an allreduce flattens inside its
+        collective program (``allreduce_fused``)."""
         with trace.span("hvd.exec.fuse_in"):
             key = (tuple(sizes), np.dtype(dtype).name)
             fn = self._fuse_in_cache.get(key)
@@ -208,9 +224,50 @@ class XlaExecutor:
 
     def _zeros_buf(self, total, dtype, rank):
         """Zero stand-in buffer for a joined rank (reference:
-        tensor_queue.cc GetTensorEntriesFromResponse joined path)."""
+        tensor_queue.cc GetTensorEntriesFromResponse joined path).  Made
+        anew each time: the programs that take it donate it."""
         return jax.device_put(np.zeros((1, total), dtype=dtype),
                               self.devices[rank])
+
+    def _zeros(self, shape, dtype, rank):
+        """A joined rank's stand-in for one absent allreduce entry: zeros
+        of the entry's shape on that rank's device, made once (nothing
+        donates an allreduce's arguments, so the array is shared)."""
+        key = (shape, np.dtype(dtype).name, rank)
+        zeros = self._zeros_cache.get(key)
+        if zeros is None:
+            zeros = self._zeros_cache[key] = jax.device_put(
+                np.zeros(shape, dtype=dtype), self.devices[rank])
+        return zeros
+
+    def _rank_sharded(self, shape, dtype, tensors):
+        """One mesh-sharded array whose shard on a local rank's device IS
+        that rank's tensor: global shape ``(N * s0, *rest)`` split on axis
+        0 over the rank axis.  An assembly of handles: no device program,
+        no copy.  ``tensors`` maps rank -> committed array (None or absent
+        for a joined rank: zeros).
+
+        A zero-dimensional tensor has no axis to carry the ranks, and
+        declaring N different scalars one "replicated" array would license
+        XLA to turn the all-reduce into a multiplication.  So each rank's
+        scalar is given shape ``(1,)`` first, by one small program a rank:
+        the only allreduce response with more than one launch."""
+        shard_shape = shape or (1,)
+        shards = []
+        for rank in self.local_ranks:
+            t = tensors.get(rank)
+            if t is None:
+                t = self._zeros(shard_shape, dtype, rank)
+            else:
+                # a no-op for what ops/eager.py committed; XLA would place
+                # a stray tensor's results with the stray tensor
+                t = self.commit(t, rank)
+                if not shape:
+                    t = t.reshape(1)
+            shards.append(t)
+        return jax.make_array_from_single_device_arrays(
+            (self.num_ranks * shard_shape[0],) + shard_shape[1:],
+            self._sharded, shards)
 
     # -------------------------------------------------------------- allreduce
     def _effective_compression(self, compression, dtype, total):
@@ -236,38 +293,35 @@ class XlaExecutor:
 
     def allreduce_fused(self, entries, op, prescale_factor, postscale_factor,
                         compression="none"):
-        """Execute a fused allreduce group.
+        """Execute a fused allreduce group as ONE launch of ONE cached
+        program, for one tensor and for a bucket, on one rank and on many.
 
         ``entries`` is a list of group entries with ``.shape``, ``.dtype``,
         ``.tensors`` (rank -> committed array, or None for joined ranks) and
         ``.handles`` (rank -> Handle).  All entries share one dtype (and
         one ``compression`` — the bucket key separates them).
+
+        The program's arguments are the entries' own tensors, one
+        mesh-sharded array an entry (``_rank_sharded``); flattening,
+        concatenation, scaling, casts, the collective and the split back
+        into shapes happen inside it (``_build_allreduce``).  Nothing is
+        copied or reshaped outside the program, so the host pays for one
+        launch a response, and nothing is donated, because the arguments
+        are the caller's arrays.  A joined rank contributes cached zeros
+        for each entry it is absent from, and only for those.  An empty
+        tensor is no argument at all: the program makes its empty result
+        itself, so a response of nothing but empty tensors is still one
+        launch.  With one rank the response still launches its program.
         """
         shapes = tuple(tuple(e.shape) for e in entries)
-        sizes = [_prod(s) for s in shapes]
-        total = sum(sizes)
+        total = sum(_prod(s) for s in shapes)
         dtype = entries[0].dtype
         comp = self._effective_compression(compression, dtype, total)
 
-        bufs = []
-        for rank in self.local_ranks:
-            tensors = [e.tensors.get(rank) for e in entries]
-            if all(t is None for t in tensors):
-                bufs.append(self._zeros_buf(total, dtype, rank))
-            elif any(t is None for t in tensors):
-                # mixed bucket (the rank joined between two entries'
-                # submissions): zero ONLY the absent entries — zeroing
-                # the whole buffer would silently drop this rank's real
-                # contributions to the present ones
-                filled = [t if t is not None
-                          else jax.device_put(
-                              np.zeros(shapes[i], dtype),
-                              self.devices[rank])
-                          for i, t in enumerate(tensors)]
-                bufs.append(self._fuse_in(filled, sizes, dtype))
-            else:
-                bufs.append(self._fuse_in(tensors, sizes, dtype))
-        garr = self._stack(bufs, (1, total), dtype)
+        with trace.span("hvd.exec.assemble"):
+            garrs = [self._rank_sharded(shape, dtype, entry.tensors)
+                     for shape, entry in zip(shapes, entries)
+                     if _prod(shape)]
 
         with trace.span("hvd.exec.lookup"):
             hierarchical = bool(self.hierarchical_allreduce
@@ -277,57 +331,128 @@ class XlaExecutor:
                    hierarchical, comp)
             fn = self._allreduce_cache.get(key)
             if fn is None:  # a miss: the build lands in this span
-                args = (shapes, sizes, total, dtype, op, prescale_factor,
-                        postscale_factor, hierarchical)
-                fn = (self._build_int8_allreduce(*args) if comp == "int8"
-                      else self._build_allreduce(*args, comp))
+                fn = self._build_allreduce(
+                    shapes, dtype, op, prescale_factor, postscale_factor,
+                    hierarchical, comp)
                 self._allreduce_cache[key] = fn
 
         with trace.span("hvd.exec.launch"):
-            outs = fn(garr)
+            outs = fn(*garrs)
         with trace.span("hvd.exec.complete"):
             for entry, out in zip(entries, outs):
                 for rank, handle in entry.handles.items():
                     handle.set_result(self._shard_for(out, rank))
 
-    def _build_allreduce(self, shapes, sizes, total, dtype, op,
-                         prescale_factor, postscale_factor, hierarchical,
-                         comp):
-        """Compile the fused allreduce of one signature: exact, or with
-        the collective run in a narrower dtype (``comp`` bf16 / fp16)."""
+    def _build_allreduce(self, shapes, dtype, op, prescale_factor,
+                         postscale_factor, hierarchical, comp):
+        """Compile the fused allreduce of one signature: exact, with the
+        collective run in a narrower dtype (``comp`` bf16 / fp16), or
+        block-scaled int8 (``_int8_body``).
+
+        The program takes one argument for every entry that has elements
+        (``[N * s0, *rest]`` sharded over the ranks, so a shard is the
+        rank's tensor as it was handed in) and returns one replicated
+        result an entry.  Per shard it ravels and concatenates the tensors
+        into the flat buffer, prescales, casts to the wire dtype and
+        reduces; the reduced buffer is then cast back, averaged,
+        postscaled, sliced and reshaped.  The buffer is a value inside the
+        program and never an array of its own."""
         num_ranks = self.num_ranks
-        axis = self.axis
-        # Cast compression (bf16/fp16): the collective itself runs in
-        # the narrow dtype — XLA fuses the casts into the program and
-        # every leg (ICI and DCN) moves half the bytes (reference:
-        # fp16 compression, horovod/torch/compression.py:45).
-        wire_dt = {"bf16": jnp.bfloat16,
-                   "fp16": jnp.float16}.get(comp)
+        sizes = [_prod(s) for s in shapes]
+        live = sum(1 for size in sizes if size)  # the arguments
+        total = sum(sizes)
+        mesh = self.hier_mesh if hierarchical else self.mesh
+        in_spec = P(("cross", "local")) if hierarchical else P(self.axis)
         # Integer tensors: the reduction stays exact in the integer
         # dtype and ALL scaling (pre x post x 1/n, which commutes
         # with the sum) happens once in float32 with a cast back —
         # casting a fractional factor to an int dtype would truncate
         # it to 0 and silently zero every result, and int/int true
-        # division would silently change the output dtype.
+        # division would silently change the output dtype.  (NumPy does
+        # not count bfloat16 among its floating types, so it is scaled
+        # this way too: once, in float32.)
         int_dtype = not np.issubdtype(np.dtype(dtype), np.floating)
 
-        def flat_body(shard):  # shard: [1, total] on one rank
-            x = shard
-            if prescale_factor != 1.0 and not int_dtype:
-                x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
-            if wire_dt is not None:
-                x = x.astype(wire_dt)
-            return jax.lax.psum(x, axis)
+        if comp == "int8":
+            reduce_flat = self._int8_body(total, prescale_factor,
+                                          hierarchical)
+        else:
+            reduce_flat = self._cast_body(
+                total, 1.0 if int_dtype else prescale_factor,
+                {"bf16": jnp.bfloat16, "fp16": jnp.float16}.get(comp),
+                hierarchical)
 
-        def hier_body(shard):
+        def body(*shards):  # a rank's own tensors, as handed in
+            return reduce_flat(
+                jnp.concatenate([s.reshape(-1) for s in shards]))
+
+        def scaled(flat):  # the reduced buffer -> the entries' dtype
+            if comp == "int8":  # fp32 accumulate
+                if op == ReduceOp.AVERAGE:
+                    flat = flat / num_ranks
+                if postscale_factor != 1.0:
+                    flat = flat * postscale_factor
+                return flat.astype(dtype)
+            flat = flat.astype(dtype)  # back from the wire dtype
+            if not int_dtype:
+                if op == ReduceOp.AVERAGE:
+                    flat = flat / jnp.asarray(num_ranks, dtype=flat.dtype)
+                if postscale_factor != 1.0:
+                    flat = flat * jnp.asarray(postscale_factor,
+                                              dtype=flat.dtype)
+                return flat
+            factor = prescale_factor * postscale_factor
+            if op == ReduceOp.AVERAGE:
+                factor /= num_ranks
+            if factor != 1.0:
+                # float64 when x64 is on; otherwise f32 caps exactness at
+                # 2**24 — large int sums can lose low bits (the tcp plane
+                # scales in f64)
+                sdt = (jnp.float64 if jax.config.jax_enable_x64
+                       else jnp.float32)
+                flat = (flat.astype(sdt) * factor).astype(flat.dtype)
+            return flat
+
+        def fused(*gs):
+            if not live:  # nothing but empty tensors
+                return tuple(jnp.zeros(shape, dtype) for shape in shapes)
+            flat = scaled(_shard_map_gathered(
+                body, mesh, (in_spec,) * live, P())(*gs))
+            outs = []
+            offset = 0
+            for size, shape in zip(sizes, shapes):
+                outs.append(
+                    jax.lax.slice(flat, (offset,),
+                                  (offset + size,)).reshape(shape))
+                offset += size
+            return tuple(outs)
+
+        # replicated over the rank mesh whatever the body was: a program
+        # of constants alone would leave its results on the default device
+        return jax.jit(fused, out_shardings=self._replicated)
+
+    def _cast_body(self, total, prescale_factor, wire_dt, hierarchical):
+        """Per-shard reduction of the flat ``[total]`` buffer, exact or
+        with the collective in a narrower dtype."""
+        axis = self.axis
+
+        def on_the_wire(x):
+            if prescale_factor != 1.0:
+                x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
+            # Cast compression (bf16/fp16): the collective itself runs in
+            # the narrow dtype — XLA fuses the casts into the program and
+            # every leg (ICI and DCN) moves half the bytes (reference:
+            # fp16 compression, horovod/torch/compression.py:45).
+            return x if wire_dt is None else x.astype(wire_dt)
+
+        def flat_body(x):
+            return jax.lax.psum(on_the_wire(x), axis)
+
+        def hier_body(x):
             # reduce-scatter on ICI -> cross allreduce on DCN ->
             # allgather on ICI (reference: nccl_operations.cc:162-289:
             # ncclReduceScatter -> MPI allreduce -> ncclAllgather).
-            x = shard.reshape(-1)
-            if prescale_factor != 1.0 and not int_dtype:
-                x = x * jnp.asarray(prescale_factor, dtype=x.dtype)
-            if wire_dt is not None:
-                x = x.astype(wire_dt)
+            x = on_the_wire(x)
             local = self.hier_mesh.shape["local"]
             align = local * FUSION_ALIGN_ELEMS
             padded = -(-total // align) * align
@@ -337,99 +462,38 @@ class XlaExecutor:
                                          tiled=True)
             chunk = jax.lax.psum(chunk, "cross")
             full = jax.lax.all_gather(chunk, "local", tiled=True)
-            return full[:total][None]
+            return full[:total]
 
-        def fused(g):
-            if hierarchical:
-                red = _shard_map_gathered(
-                    hier_body, self.hier_mesh,
-                    P(("cross", "local")), P())(g)
-            else:
-                red = _shard_map(flat_body, mesh=self.mesh,
-                                 in_specs=P(axis), out_specs=P())(g)
-            flat = red.reshape(-1)
-            if wire_dt is not None:
-                flat = flat.astype(dtype)
-            if int_dtype:
-                factor = prescale_factor * postscale_factor
-                if op == ReduceOp.AVERAGE:
-                    factor /= num_ranks
-                if factor != 1.0:
-                    # float64 when x64 is on; otherwise f32 caps
-                    # exactness at 2**24 — large int sums can lose
-                    # low bits (the tcp plane scales in f64)
-                    sdt = (jnp.float64 if jax.config.jax_enable_x64
-                           else jnp.float32)
-                    flat = (flat.astype(sdt)
-                            * factor).astype(flat.dtype)
-            else:
-                if op == ReduceOp.AVERAGE:
-                    flat = flat / jnp.asarray(num_ranks,
-                                              dtype=flat.dtype)
-                if postscale_factor != 1.0:
-                    flat = flat * jnp.asarray(postscale_factor,
-                                              dtype=flat.dtype)
-            outs = []
-            offset = 0
-            for size, shape in zip(sizes, shapes):
-                outs.append(
-                    jax.lax.slice(flat, (offset,),
-                                  (offset + size,)).reshape(shape))
-                offset += size
-            return tuple(outs)
+        return hier_body if hierarchical else flat_body
 
-        return jax.jit(fused, donate_argnums=0)
-
-    def _build_int8_allreduce(self, shapes, sizes, total, dtype, op,
-                              prescale_factor, postscale_factor,
-                              hierarchical):
-        """Compile the block-scaled int8 fused allreduce (EQuARX,
-        arXiv:2506.17615): quantize inside the jitted program, exchange
-        int8 + fp32 block scales via ``all_to_all`` (the reduce-scatter
-        leg), accumulate in fp32, requantize the reduced chunk before the
-        allgather leg, dequantize on unpack.  Each element passes through
-        exactly two quantizations regardless of rank count.  On the
-        hierarchical mesh the quantized legs run over the fast "local"
-        axis and the owned chunk crosses DCN once in fp32 (already
+    def _int8_body(self, total, prescale_factor, hierarchical):
+        """Per-shard block-scaled int8 reduction of the flat buffer
+        (EQuARX, arXiv:2506.17615): quantize inside the jitted program,
+        exchange int8 + fp32 block scales via ``all_to_all`` (the
+        reduce-scatter leg), accumulate in fp32, requantize the reduced
+        chunk before the allgather leg, dequantize on unpack.  Each element
+        passes through exactly two quantizations regardless of rank count.
+        On the hierarchical mesh the quantized legs run over the fast
+        "local" axis and the owned chunk crosses DCN once in fp32 (already
         1/local_size of the payload)."""
-        num_ranks = self.num_ranks
-        hier = bool(hierarchical and self.hier_mesh is not None)
-        mesh = self.hier_mesh if hier else self.mesh
-        axis = "local" if hier else self.axis
-        n_split = mesh.shape["local"] if hier else num_ranks
+        axis = "local" if hierarchical else self.axis
+        n_split = (self.hier_mesh.shape["local"] if hierarchical
+                   else self.num_ranks)
         chunk = -(-total // (n_split * INT8_BLOCK)) * INT8_BLOCK
         padded = chunk * n_split
-        in_spec = P(("cross", "local")) if hier else P(self.axis)
 
-        def body(shard):  # [1, total] on one rank
-            x = shard.reshape(-1).astype(jnp.float32)
+        def body(x):
+            x = x.astype(jnp.float32)
             if prescale_factor != 1.0:
                 x = x * prescale_factor
             x = jnp.pad(x, (0, padded - total))
             red = quantized_reduce_scatter(x.reshape(n_split, chunk), axis)
-            if hier:
+            if hierarchical:
                 red = jax.lax.psum(red, "cross")
             full = quantized_all_gather(red, axis)
-            return full[:total][None]
+            return full[:total]
 
-        def fused(g):
-            red = _shard_map_gathered(body, mesh, in_spec, P())(g)
-            flat = red.reshape(-1)  # fp32 accumulate
-            if op == ReduceOp.AVERAGE:
-                flat = flat / num_ranks
-            if postscale_factor != 1.0:
-                flat = flat * postscale_factor
-            flat = flat.astype(dtype)
-            outs = []
-            offset = 0
-            for size, shape in zip(sizes, shapes):
-                outs.append(
-                    jax.lax.slice(flat, (offset,),
-                                  (offset + size,)).reshape(shape))
-                offset += size
-            return tuple(outs)
-
-        return jax.jit(fused, donate_argnums=0)
+        return body
 
     # -------------------------------------------------------------- allgather
     def allgather(self, entry):

@@ -11,8 +11,16 @@ Two instruments, both always on (docs/observability.md):
 
       caller      hvd.submit  hvd.wait
       dispatcher  hvd.wait_batch  hvd.decode  hvd.mark_done
-                  hvd.execute > hvd.exec.{fuse_in, stack, lookup,
-                                          launch, complete}
+                  hvd.execute > hvd.exec.{assemble, lookup, launch,
+                                          complete}
+
+  An allreduce response is those four under its ``hvd.execute``:
+  ``assemble`` hands the ranks' own tensors to the collective program
+  without a device program, ``launch`` is the response's one launch.
+  The other collectives stage a buffer first: ``hvd.exec.fuse_in``
+  (reduce_scatter, broadcast, adasum: a small jitted program a rank) and
+  ``hvd.exec.stack`` (those and allgather, alltoall) in ``assemble``'s
+  place.
 
 - **A request log.**  One tuple per finished request, in
   ``time.perf_counter_ns()``:

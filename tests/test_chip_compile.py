@@ -260,3 +260,36 @@ def test_olmoe_cell_step_compiles_for_v5e(v5e):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("chips,compression,hierarchical", [
+    (1, "none", False), (4, "none", False), (4, "bf16", False),
+    (4, "int8", False), (4, "none", True)],
+    ids=["1-exact", "4-exact", "4-bf16", "4-int8", "4-hierarchical"])
+def test_eager_allreduce_response_compiles_for_v5e(v5e, chips, compression,
+                                                   hierarchical):
+    """The eager plane's one program a response
+    (``ops/xla_executor.py:_build_allreduce``), for a bucket of ResNet-50
+    gradients handed in as they are: a convolution's weights, a
+    batch-norm scale, the classifier's bias and a scalar."""
+    import numpy as np
+
+    from horovod_tpu.common.ops_enum import ReduceOp
+    from horovod_tpu.ops.xla_executor import XlaExecutor
+
+    executor = XlaExecutor(v5e[:chips],
+                           hier_local_size=2 if hierarchical else None)
+    assert (executor.hier_mesh is not None) == hierarchical
+    shapes = ((3, 3, 512, 512), (512,), (1000,), ())
+    program = executor._build_allreduce(
+        shapes, np.dtype(np.float32), ReduceOp.AVERAGE, 1.0, 1.0,
+        hierarchical, compression)
+    args = [jax.ShapeDtypeStruct(
+        (chips * (shape or (1,))[0],) + shape[1:], jnp.float32,
+        sharding=executor._sharded) for shape in shapes]
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    assert ("all-reduce" in text or "all-to-all" in text) == (chips > 1)
+    outs = compiled.output_shardings
+    assert len(outs) == len(shapes)
+    assert all(out.is_fully_replicated for out in outs)

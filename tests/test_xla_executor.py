@@ -1,13 +1,24 @@
 """XLA executor internals: one-executable steady state (the ResponseCache
 idea mapped onto XLA's compilation model — ``xla_executor.py`` module
-doc), compiled alltoall (VERDICT r1 item 5), and fusion-bucket numerics
-at alignment edges (reference: 64-elem alignment,
-``controller.cc:358-376``)."""
+doc), an allreduce response as ONE launch of that executable, compiled
+alltoall (VERDICT r1 item 5), and fusion-bucket numerics at alignment
+edges (reference: 64-elem alignment, ``controller.cc:358-376``)."""
 
+import collections
+import glob
+import os
+
+import jax
+import ml_dtypes
 import numpy as np
 import jax.numpy as jnp
+import pytest
 
 from horovod_tpu.common import basics
+from horovod_tpu.common.handles import Handle
+from horovod_tpu.common.ops_enum import ReduceOp
+from horovod_tpu.ops.python_controller import GroupEntry
+from horovod_tpu.ops.xla_executor import XlaExecutor
 
 N = 8
 
@@ -190,3 +201,250 @@ def test_int_allreduce_fractional_scale_and_average(hvd):
         assert avg_dtype == jnp.int32, avg_dtype
         np.testing.assert_allclose(
             avg, np.full((3,), int(sum(range(N)) / N)))
+
+
+# ------------------------------------ one response = one program launch
+# A bucket of mixed ranks-of-shape at odd sizes, with a scalar and an
+# empty tensor in it, and each of them alone.
+BUCKET = ((7, 3), (), (129,), (0, 4), (2, 3, 5))
+RESPONSES = {"scalar": ((),), "empty": ((0, 4),), "odd": ((1023,),),
+             "bucket": BUCKET}
+DTYPES = {"float32": np.float32, "bfloat16": ml_dtypes.bfloat16,
+          "int32": np.int32}
+
+
+def _executor_of(ranks, hierarchical=False):
+    executor = XlaExecutor(jax.devices()[:ranks],
+                           hier_local_size=2 if hierarchical else None)
+    executor.hierarchical_allreduce = hierarchical
+    assert (executor.hier_mesh is not None) == hierarchical
+    return executor
+
+
+def _response(executor, shapes, data, absent=()):
+    """The entries of one response: ``data[i][rank]`` is rank's array
+    for entry ``i``, committed to its device; ``absent`` holds the
+    ``(i, rank)`` a joined rank never handed in."""
+    entries = []
+    for i, shape in enumerate(shapes):
+        tensors = {rank: None if (i, rank) in absent
+                   else executor.commit(array, rank)
+                   for rank, array in enumerate(data[i])}
+        handles = {rank: Handle(f"t{i}") for rank, t in tensors.items()
+                   if t is not None}
+        entries.append(GroupEntry(
+            name=f"t{i}", shape=shape, dtype=np.dtype(data[i][0].dtype),
+            tensors=tensors, handles=handles))
+    return entries
+
+
+def _small_integers(shapes, dtype, ranks, seed=0):
+    """Values whose sums, halves and quarters every dtype here holds
+    exactly, so the reference is exact in whatever order a backend
+    adds."""
+    rng = np.random.RandomState(seed)
+    return [[np.asarray(rng.randint(-8, 9, size=shape)).astype(dtype)
+             for _ in range(ranks)] for shape in shapes]
+
+
+def _reference(per_rank, op, prescale, postscale):
+    """NumPy's allreduce of one entry, scaled where the executor scales:
+    float32 in its own dtype, integers and bfloat16 once in float32."""
+    dtype, ranks = per_rank[0].dtype, len(per_rank)
+    if dtype == np.float32:
+        total = sum(x * dtype.type(prescale) for x in per_rank)
+        if op == ReduceOp.AVERAGE:
+            total = total / dtype.type(ranks)
+        return total * dtype.type(postscale)
+    total = sum(x.astype(np.float32) for x in per_rank)
+    factor = prescale * postscale
+    if op == ReduceOp.AVERAGE:
+        factor /= ranks
+    return (total * np.float32(factor)).astype(dtype)
+
+
+def _results(executor, entries):
+    """Every handle's result as NumPy, after checking that it has the
+    entry's shape and dtype and lives on its rank's device."""
+    out = []
+    for entry in entries:
+        per_rank = {}
+        for rank, handle in entry.handles.items():
+            result = handle.wait(0)
+            assert result.devices() == {executor.devices[rank]}
+            assert result.shape == entry.shape
+            assert result.dtype == entry.dtype
+            per_rank[rank] = np.asarray(result)
+        out.append(per_rank)
+    return out
+
+
+def _profiled_events(trace_dir, work):
+    """Run ``work()`` under the profiler: how often each event of the
+    host's lines occurred (the CPU backend's program executions and
+    compilations are events there)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(trace_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    return collections.Counter(
+        event.name
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        for line in plane.lines for event in line.events)
+
+
+@pytest.mark.parametrize("tensors", [1, 5])
+@pytest.mark.parametrize("ranks", [1, 2, 4])
+def test_a_response_is_one_execution_of_one_cached_program(
+        ranks, tensors, tmp_path):
+    """The second step's response runs ONE device program (the cached
+    collective, which flattens and splits inside itself) and compiles
+    nothing; with one rank it still runs it."""
+    executor = _executor_of(ranks)
+    shapes = ((7, 3), (129,), (2, 3, 5), (64,), (1, 1))[:tensors]
+    data = [_small_integers(shapes, np.float32, ranks, seed)
+            for seed in (0, 1)]
+    steps = [_response(executor, shapes, step) for step in data]
+
+    def respond(entries):
+        executor.allreduce_fused(entries, op=ReduceOp.AVERAGE,
+                                 prescale_factor=1.0, postscale_factor=1.0)
+        for entry in entries:
+            for handle in entry.handles.values():
+                handle.wait(0).block_until_ready()
+
+    respond(steps[0])  # compiles
+    events = _profiled_events(tmp_path, lambda: respond(steps[1]))
+    assert events["PjRtCpuExecutable::Execute"] == 1, events
+    assert events["PjRtCpuClient::Compile"] == 0, events
+    assert events["hvd.exec.launch"] == 1
+    assert not events["hvd.exec.fuse_in"] and not events["hvd.exec.stack"]
+    assert len(executor._allreduce_cache) == 1
+    assert not executor._fuse_in_cache
+    for per_rank, results in zip(data[1], _results(executor, steps[1])):
+        expected = _reference(per_rank, ReduceOp.AVERAGE, 1.0, 1.0)
+        for result in results.values():
+            np.testing.assert_array_equal(result, expected)
+
+
+@pytest.mark.parametrize("scales", [(1.0, 1.0), (0.5, 3.0)],
+                         ids=["unscaled", "pre0.5post3"])
+@pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVERAGE],
+                         ids=["sum", "average"])
+@pytest.mark.parametrize("response", list(RESPONSES))
+@pytest.mark.parametrize("ranks", [1, 2, 4])
+@pytest.mark.parametrize("dtype", list(DTYPES))
+def test_allreduce_fused_equals_numpy(dtype, ranks, response, op, scales):
+    """The exact paths against NumPy, value for value."""
+    shapes = RESPONSES[response]
+    executor = _executor_of(ranks)
+    data = _small_integers(shapes, DTYPES[dtype], ranks)
+    entries = _response(executor, shapes, data)
+    executor.allreduce_fused(entries, op=op, prescale_factor=scales[0],
+                             postscale_factor=scales[1])
+    for per_rank, results in zip(data, _results(executor, entries)):
+        expected = _reference(per_rank, op, *scales)
+        assert len(results) == ranks
+        for result in results.values():
+            np.testing.assert_array_equal(result, expected)
+
+
+@pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVERAGE],
+                         ids=["sum", "average"])
+@pytest.mark.parametrize("dtype", list(DTYPES))
+def test_hierarchical_allreduce_fused_equals_numpy(dtype, op):
+    """Reduce-scatter, cross allreduce and all-gather over (2, 2): the
+    same values as the flat path."""
+    executor = _executor_of(4, hierarchical=True)
+    data = _small_integers(BUCKET, DTYPES[dtype], 4)
+    entries = _response(executor, BUCKET, data)
+    executor.allreduce_fused(entries, op=op, prescale_factor=0.5,
+                             postscale_factor=3.0)
+    for per_rank, results in zip(data, _results(executor, entries)):
+        expected = _reference(per_rank, op, 0.5, 3.0)
+        for result in results.values():
+            np.testing.assert_array_equal(result, expected)
+
+
+@pytest.mark.parametrize("op", [ReduceOp.SUM, ReduceOp.AVERAGE],
+                         ids=["sum", "average"])
+@pytest.mark.parametrize("ranks,hierarchical",
+                         [(2, False), (4, False), (4, True)],
+                         ids=["2flat", "4flat", "4hier"])
+@pytest.mark.parametrize("compression", ["bf16", "int8"])
+def test_compressed_allreduce_fused_is_close_to_numpy(
+        compression, ranks, hierarchical, op):
+    """A bucket on the narrow wire: within the wire format's error of
+    the float32 sum, over the whole flat buffer and entry by entry."""
+    shapes = ((37, 31), (), (2049,), (0, 4), (5, 3, 7))
+    rng = np.random.RandomState(ranks)
+    data = [[np.asarray(rng.randn(*shape)).astype(np.float32)
+             for _ in range(ranks)] for shape in shapes]
+    executor = _executor_of(ranks, hierarchical)
+    entries = _response(executor, shapes, data)
+    executor.allreduce_fused(entries, op=op, prescale_factor=0.5,
+                             postscale_factor=3.0, compression=compression)
+    (key,) = executor._allreduce_cache
+    assert key[-1] == compression
+    expected = [_reference(per_rank, op, 0.5, 3.0) for per_rank in data]
+    scale = max(np.abs(e).max() for e in expected if e.size)
+    for want, results in zip(expected, _results(executor, entries)):
+        for result in results.values():
+            assert np.abs(result - want).max(initial=0) <= 2e-2 * scale
+
+
+@pytest.mark.parametrize("whole_rank", [False, True],
+                         ids=["some-entries", "every-entry"])
+@pytest.mark.parametrize("ranks,hierarchical",
+                         [(2, False), (4, False), (4, True)],
+                         ids=["2flat", "4flat", "4hier"])
+def test_a_joined_rank_adds_zeros_for_what_it_did_not_hand_in(
+        ranks, hierarchical, whole_rank):
+    """The last rank joined: before the response (absent from every
+    entry) or between two submissions (absent from every other one).
+    Its zeros are made once and shared by the following steps."""
+    executor = _executor_of(ranks, hierarchical)
+    data = _small_integers(BUCKET, np.float32, ranks)
+    joined = ranks - 1
+    absent = {(i, joined) for i in range(len(BUCKET))
+              if whole_rank or i % 2 == 0}
+    for _ in range(2):
+        entries = _response(executor, BUCKET, data, absent)
+        executor.allreduce_fused(entries, op=ReduceOp.SUM,
+                                 prescale_factor=1.0, postscale_factor=1.0)
+        for i, results in enumerate(_results(executor, entries)):
+            expected = sum(x for rank, x in enumerate(data[i])
+                           if (i, rank) not in absent)
+            assert sorted(results) == [
+                rank for rank in range(ranks) if (i, rank) not in absent]
+            for result in results.values():
+                np.testing.assert_array_equal(result, expected)
+    # zeros for the entries that have elements, and no others
+    assert len(executor._zeros_cache) == sum(
+        1 for i, _ in absent if np.prod(BUCKET[i]))
+
+
+def test_one_rank_average_is_the_input_bit_for_bit_on_its_device():
+    """What the benchmark's eager cell holds the plane to: alone, an
+    ``Average`` hands back the bits it was given, in a new array on the
+    same device, and still through the program."""
+    executor = _executor_of(1)
+    rng = np.random.RandomState(7)
+    shapes = ((64, 3, 3, 64), (1000,), ())
+    data = [[np.asarray(rng.randn(*shape) * 1e-3).astype(np.float32)]
+            for shape in shapes]
+    for shape, arrays in zip(shapes, data):
+        (entry,) = _response(executor, (shape,), [arrays])
+        executor.allreduce_fused([entry], op=ReduceOp.AVERAGE,
+                                 prescale_factor=1.0, postscale_factor=1.0)
+        result = entry.handles[0].wait(0)
+        assert result is not entry.tensors[0]
+        assert result.devices() == {executor.devices[0]}
+        assert np.asarray(result).tobytes() == arrays[0].tobytes()
+    assert len(executor._allreduce_cache) == len(shapes)
