@@ -11,6 +11,9 @@ from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     BlockSpec,
+    LatentAttention,
+    NextTokenModule,
+    TopkExperts,
     Transformer,
     TransformerConfig,
     apply_with_aux,

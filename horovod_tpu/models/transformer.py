@@ -17,16 +17,20 @@ what exercises the framework's TPU-first parallel subsystems together:
   ``horovod_tpu.parallel.pipeline.pipeline_apply`` unchanged
 
 The block is described by data: a :class:`BlockSpec` on the
-configuration says which norm, position scheme and feed-forward a block
-has.  The default is the GPT-2 block (LayerNorm, learned positions,
-GELU); ``BlockSpec(norm="rms", positions="rope", qk_norm=True,
-ffn="moe_topk")`` is OLMoE's.
+configuration says which norm, position scheme, attention and
+feed-forward a block has.  The default is the GPT-2 block (LayerNorm,
+learned positions, GELU); ``BlockSpec(norm="rms", positions="rope",
+qk_norm=True, ffn="moe_topk")`` is OLMoE's; a block may also have
+``attention=LatentAttention(...)``, ``positions="rope_pairs"`` and
+``ffn=TopkExperts(scoring="sigmoid", ...)``, behind
+``TransformerConfig.leading_dense`` dense SwiGLU layers and with a
+:class:`NextTokenModule` beside the model.
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -36,8 +40,40 @@ from horovod_tpu.parallel.ring_attention import reference_attention
 
 
 NORMS = ("layer", "rms")
-POSITIONS = ("learned", "rope")
-FFNS = ("gelu", "moe_switch", "moe_topk")
+POSITIONS = ("learned", "rope", "rope_pairs")
+ATTENTIONS = ("full",)
+FFNS = ("gelu", "swiglu", "moe_switch", "moe_topk")
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434): the
+    queries come through a normed latent of ``q_rank``, keys and values
+    through one of ``kv_rank`` beside ONE rotated key of ``rope_dim``
+    that all heads share.  A head's query and key are ``[nope_dim |
+    rope_dim]`` wide, its value ``v_dim``."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class TopkExperts:
+    """A top-k expert layer (:func:`~horovod_tpu.parallel.moe.topk_moe`)
+    told more than ``"moe_topk"`` says.  ``scoring``, ``renormalize``
+    and ``scale`` are :func:`~horovod_tpu.parallel.moe.topk_route`'s
+    (its ``bias`` is the row of ``router_bias`` the caller hands the
+    model); ``shared``: that many SwiGLU experts of the same width
+    every token goes through, beside the routed ones; ``held``:
+    ``(first, count)``, the routed experts this device holds of the
+    router's ``n_experts``."""
+    scoring: str = "softmax"
+    renormalize: bool = False
+    scale: float = 1.0
+    shared: int = 0
+    held: Optional[Tuple[int, int]] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -45,21 +81,30 @@ class BlockSpec:
     """What a block is made of.  ``norm``: ``"layer"`` (LayerNorm with
     a bias, the fused kernel on TPU) or ``"rms"`` (RMSNorm, scale
     only).  ``positions``: ``"learned"`` (a table added to the
-    embedding) or ``"rope"`` (rotary, applied to q and k before the
-    attention function; no table).  ``qk_norm``: a norm of the block's
-    kind over the whole q and k projections, before the split into
-    heads.  ``ffn``: ``"gelu"`` (dense up-GELU-down), ``"moe_switch"``
-    (:func:`~horovod_tpu.parallel.moe.switch_moe`) or ``"moe_topk"``
-    (:func:`~horovod_tpu.parallel.moe.topk_moe`)."""
+    embedding), ``"rope"`` (rotary in the rotate-half pairing, applied
+    to q and k before the attention function; no table) or
+    ``"rope_pairs"`` (rotary over the pairs ``(2i, 2i + 1)``).
+    ``qk_norm``: a norm of the block's kind over the whole q and k
+    projections, before the split into heads.  ``attention``:
+    ``"full"`` (one fused q, k, v projection, heads of one width) or a
+    :class:`LatentAttention`.  ``ffn``: ``"gelu"`` (dense
+    up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x gate) * (x up)``
+    down), ``"moe_switch"``
+    (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
+    (:func:`~horovod_tpu.parallel.moe.topk_moe` as OLMoE has it) or a
+    :class:`TopkExperts`."""
     norm: str = "layer"
     positions: str = "learned"
     qk_norm: bool = False
-    ffn: str = "gelu"
+    ffn: Union[str, TopkExperts] = "gelu"
+    attention: Union[str, LatentAttention] = "full"
 
     def __post_init__(self):
-        for value, known in ((self.norm, NORMS),
-                             (self.positions, POSITIONS), (self.ffn, FFNS)):
-            if value not in known:
+        for value, known, cls in (
+                (self.norm, NORMS, ()), (self.positions, POSITIONS, ()),
+                (self.ffn, FFNS, TopkExperts),
+                (self.attention, ATTENTIONS, LatentAttention)):
+            if value not in known and not isinstance(value, cls):
                 raise ValueError(f"BlockSpec: {value!r} is none of {known}")
 
 
@@ -78,6 +123,10 @@ class TransformerConfig:
     # every k-th block uses a switch-MoE FFN whatever ``block.ffn``
     # says (0 = every block as ``block`` has it)
     moe_every: int = 0
+    # the first blocks whose feed-forward is a dense SwiGLU of width
+    # ``d_ff`` whatever ``block.ffn`` says (dense layers before expert
+    # layers)
+    leading_dense: int = 0
     n_experts: int = 8
     # sizes only some blocks read: a head's width (None: d_model /
     # n_heads), experts a token and an expert's width (None: d_ff) for
@@ -93,6 +142,14 @@ class TransformerConfig:
     # activations, not weights, bound the batch size
     remat: bool = False
 
+    def ffn_of(self, layer):
+        """The feed-forward of block ``layer``: the per-layer pattern."""
+        if layer < self.leading_dense:
+            return "swiglu"
+        if self.moe_every and (layer + 1) % self.moe_every == 0:
+            return "moe_switch"
+        return self.block.ffn
+
 
 def default_attention():
     """The hot-path kernel: Pallas flash attention on TPU (O(T) memory,
@@ -105,18 +162,24 @@ def default_attention():
     return reference_attention
 
 
-def rope(x, theta=10000.0):
-    """Rotary position embedding of ``x [..., T, H, D]`` in the
-    rotate-half form: ``x * cos + rotate_half(x) * sin`` with
-    ``rotate_half([x1, x2]) = [-x2, x1]`` on the two halves of D and
-    ``angle(t, i) = t * theta^(-2i / D)`` for i < D / 2, the same angles
-    for both halves.  Computed in float32, returned in ``x.dtype``."""
+def rope(x, theta=10000.0, pairs=False):
+    """Rotary position embedding of ``x [..., T, H, D]``: each pair of
+    columns ``(x1, x2)`` is turned by ``angle(t, i) = t * theta^(-2i /
+    D)``, i < D / 2, to ``(x1 cos - x2 sin, x2 cos + x1 sin)``.  The
+    i-th pair is columns ``(i, i + D / 2)`` (the rotate-half form: ``x *
+    cos + rotate_half(x) * sin``) or, with ``pairs``, the neighbours
+    ``(2i, 2i + 1)``.  Computed in float32, returned in ``x.dtype``."""
     t, d = x.shape[-3], x.shape[-1]
     half = d // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / d)
     angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)        # [T, 1, D / 2]
     x32 = x.astype(jnp.float32)
+    if pairs:
+        x32 = x32.reshape(x.shape[:-1] + (half, 2))
+        x1, x2 = x32[..., 0], x32[..., 1]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
@@ -143,29 +206,76 @@ def make_norm(cfg, name):
     return cls(eps=cfg.norm_eps, name=name)
 
 
+def full_qkv(cfg, x):
+    """q, k, v ``[..., T, H, D]`` of one fused projection (submodules of
+    the :class:`Attention` that calls it)."""
+    h = cfg.n_heads
+    d = cfg.head_dim or cfg.d_model // cfg.n_heads
+    qkv = nn.DenseGeneral((3, h, d), use_bias=False, dtype=cfg.dtype,
+                          name="qkv")(x)
+    q, k, v = (qkv[..., i, :, :] for i in range(3))
+    if cfg.block.qk_norm:
+        with jax.named_scope("attn/qk_norm"):
+            # over the whole projection, not per head
+            q, k = (make_norm(cfg, name)(
+                u.reshape(u.shape[:-2] + (h * d,))).reshape(u.shape)
+                for u, name in ((q, "q_norm"), (k, "k_norm")))
+    if cfg.block.positions != "learned":
+        with jax.named_scope("attn/rope"):
+            q, k = (rope(u, cfg.rope_theta,
+                         pairs=cfg.block.positions == "rope_pairs")
+                    for u in (q, k))
+    return q, k, v
+
+
+def latent_qkv(cfg, x):
+    """q, k ``[..., T, H, nope_dim + rope_dim]`` and v ``[..., T, H,
+    v_dim]`` of latent attention (submodules of the :class:`Attention`
+    that calls it):
+
+        c_q = norm(x W_qa);  q = c_q W_qb = [q_nope | q_rope] a head
+        x W_kva = [c_kv | k_rope];  norm(c_kv) W_kvb = [k_nope | v] a head
+        q = [q_nope | rope(q_rope)];  k = [k_nope | rope(k_rope)]
+
+    with the one ``k_rope`` turned once and shared by every head."""
+    spec, h = cfg.block.attention, cfg.n_heads
+    pairs = cfg.block.positions == "rope_pairs"
+
+    def dense(features, name):
+        return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype,
+                               name=name)
+
+    with jax.named_scope("attn/latent"):
+        c_q = make_norm(cfg, "q_a_norm")(dense(spec.q_rank, "q_a")(x))
+        q = dense((h, spec.nope_dim + spec.rope_dim), "q_b")(c_q)
+        kv_a = dense(spec.kv_rank + spec.rope_dim, "kv_a")(x)
+        c_kv = make_norm(cfg, "kv_a_norm")(kv_a[..., :spec.kv_rank])
+        kv = dense((h, spec.nope_dim + spec.v_dim), "kv_b")(c_kv)
+        k_rope = rope(kv_a[..., None, spec.kv_rank:], cfg.rope_theta, pairs)
+        q = jnp.concatenate(
+            [q[..., :spec.nope_dim],
+             rope(q[..., spec.nope_dim:], cfg.rope_theta, pairs)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :spec.nope_dim],
+             jnp.broadcast_to(k_rope, kv.shape[:-1] + (spec.rope_dim,))],
+            axis=-1)
+    return q, k, kv[..., spec.nope_dim:]
+
+
 class Attention(nn.Module):
+    """Causal self-attention of the kind ``cfg.block.attention`` names;
+    the attention function is handed heads of the scores' width for q
+    and k and of the values' width for v."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = cfg.n_heads
-        d = cfg.head_dim or cfg.d_model // cfg.n_heads
-        qkv = nn.DenseGeneral((3, h, d), use_bias=False, dtype=cfg.dtype,
-                              name="qkv")(x)
-        q, k, v = (qkv[..., i, :, :] for i in range(3))
-        if cfg.block.qk_norm:
-            with jax.named_scope("attn/qk_norm"):
-                # over the whole projection, not per head
-                q, k = (make_norm(cfg, name)(
-                    u.reshape(u.shape[:-2] + (h * d,))).reshape(u.shape)
-                    for u, name in ((q, "q_norm"), (k, "k_norm")))
-        if cfg.block.positions == "rope":
-            with jax.named_scope("attn/rope"):
-                q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+        latent = isinstance(cfg.block.attention, LatentAttention)
+        q, k, v = (latent_qkv if latent else full_qkv)(cfg, x)
         attn = cfg.attn_fn or default_attention()
         o = attn(q, k, v, causal=True)
-        o = o.reshape(o.shape[:-2] + (h * d,))
+        o = o.reshape(o.shape[:-2] + (-1,))
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="out")(o)
 
@@ -181,6 +291,24 @@ class Mlp(nn.Module):
         x = nn.gelu(x)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="down")(x)
+
+
+class SwigluMlp(nn.Module):
+    """``(silu(x gate) * (x up)) down``, no biases."""
+    cfg: TransformerConfig
+    width: Optional[int] = None  # None: cfg.d_ff
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        width = self.width or cfg.d_ff
+        hidden = nn.silu(dense(width, "gate")(x)) * dense(width, "up")(x)
+        return dense(cfg.d_model, "down")(hidden)
 
 
 class MoeMlp(nn.Module):
@@ -203,24 +331,38 @@ class MoeMlp(nn.Module):
 
 class TopkMoeMlp(nn.Module):
     """The dropless top-k expert layer (``parallel/moe.py:topk_moe``):
-    ``cfg.n_experts`` gated experts of width ``cfg.d_expert``,
-    ``cfg.experts_per_token`` a token.  Sows its load-balancing loss
+    a router over ``cfg.n_experts`` gated experts of width
+    ``cfg.d_expert``, ``cfg.experts_per_token`` a token, as ``spec``
+    (a :class:`TopkExperts`; the default is ``"moe_topk"``) says: the
+    weights of the ``spec.held`` experts alone, ``spec.shared`` experts
+    every token goes through, the choice made through ``router_bias
+    [E]`` where one is given.  Sows its load-balancing loss
     (``moe_aux_loss``), its router z-loss (``moe_z_loss``) and the
     counter ``moe_tokens_per_expert`` for :func:`apply_with_aux`."""
     cfg: TransformerConfig
+    spec: TopkExperts = TopkExperts()
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_bias=None):
         from horovod_tpu.parallel.moe import (
             moe_kernel_init, moe_param_shapes, topk_moe)
 
-        cfg = self.cfg
-        shapes = moe_param_shapes(cfg.d_model, cfg.d_expert or cfg.d_ff,
-                                  cfg.n_experts, gated=True)
+        cfg, spec = self.cfg, self.spec
+        width = cfg.d_expert or cfg.d_ff
+        held = spec.held[1] if spec.held else cfg.n_experts
+        shapes = moe_param_shapes(cfg.d_model, width, held, gated=True)
+        shapes["router"] = (cfg.d_model, cfg.n_experts)
         params = {name: {"kernel": self.param(
             f"{name}_kernel", moe_kernel_init, shape)}
             for name, shape in shapes.items()}
-        out, aux = topk_moe(x, params, k=cfg.experts_per_token)
+        out, aux = topk_moe(
+            x, params, k=cfg.experts_per_token, held=spec.held,
+            scoring=spec.scoring, bias=router_bias,
+            renormalize=spec.renormalize, scale=spec.scale)
+        if spec.shared:
+            with jax.named_scope("moe/shared"):
+                out = out + SwigluMlp(cfg, width=spec.shared * width,
+                                      name="shared")(x)
         self.sow("intermediates", "moe_aux_loss", aux["load_balancing"])
         self.sow("intermediates", "moe_z_loss", aux["router_z"])
         self.sow("intermediates", "moe_tokens_per_expert",
@@ -252,23 +394,29 @@ class FusedLayerNorm(nn.Module):
 
 # a feed-forward by its name in BlockSpec: the module and the name its
 # parameters live under (the sharding rules read "mlp" and "moe")
-FEED_FORWARDS = {"gelu": (Mlp, "mlp"), "moe_switch": (MoeMlp, "moe"),
+FEED_FORWARDS = {"gelu": (Mlp, "mlp"), "swiglu": (SwigluMlp, "mlp"),
+                 "moe_switch": (MoeMlp, "moe"),
                  "moe_topk": (TopkMoeMlp, "moe")}
 
 
 class Block(nn.Module):
     cfg: TransformerConfig
     # this block's feed-forward where it is not ``cfg.block.ffn``
-    ffn: Optional[str] = None
+    ffn: Union[None, str, TopkExperts] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_bias=None):
+        """``router_bias [E]``: the balancing bias of this block's
+        router, for a :class:`TopkExperts`."""
         cfg = self.cfg
         y = make_norm(cfg, "ln1")(x)
         x = x + Attention(cfg, name="attn")(y.astype(cfg.dtype))
-        y = make_norm(cfg, "ln2")(x)
-        module, name = FEED_FORWARDS[self.ffn or cfg.block.ffn]
-        return x + module(cfg, name=name)(y.astype(cfg.dtype))
+        y = make_norm(cfg, "ln2")(x).astype(cfg.dtype)
+        ffn = self.ffn or cfg.block.ffn
+        if isinstance(ffn, TopkExperts):
+            return x + TopkMoeMlp(cfg, ffn, name="moe")(y, router_bias)
+        module, name = FEED_FORWARDS[ffn]
+        return x + module(cfg, name=name)(y)
 
 
 def lm_loss(logits, tokens):
@@ -287,7 +435,23 @@ def lm_loss(logits, tokens):
     return jnp.mean(softmax_xent_reference(logits, labels))
 
 
-def apply_with_aux(model, params, tokens):
+def _sown(state):
+    """What the MoE blocks of one ``apply`` sowed, by name, each list in
+    the order of the blocks (``block_2`` before ``block_10``)."""
+    found = {"moe_aux_loss": [], "moe_z_loss": [],
+             "moe_tokens_per_expert": []}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(
+            state.get("intermediates", {}))[0]:
+        keys = [k.key for k in path if hasattr(k, "key")]
+        block = keys[0].rpartition("_")[2]
+        for name in found.keys() & set(keys):
+            found[name].append((int(block) if block.isdigit() else 0, leaf))
+    return {name: [leaf for _, leaf in sorted(leaves, key=lambda e: e[0])]
+            for name, leaves in found.items()}
+
+
+def apply_with_aux(model, params, tokens, *, router_bias=None,
+                   next_token=None):
     """Forward pass returning ``(logits, aux)``.
 
     MoE blocks ``sow`` their auxiliary losses and counters into the
@@ -298,34 +462,54 @@ def apply_with_aux(model, params, tokens):
     gradient.  ``aux`` holds, summed over the MoE blocks,
     ``load_balancing`` and ``router_z`` (0 where no block has one),
     beside ``moe_layers`` (how many blocks were summed, for a mean) and
-    the counter ``tokens_per_expert [layers, E]`` of the top-k blocks
-    (``None`` without one).
+    the counter ``tokens_per_expert [layers, E]`` of the top-k blocks in
+    the order of the layers (``None`` without one).
+
+    ``router_bias [layers, E]``: the balancing biases of the
+    :class:`TopkExperts` blocks, a row a block in the counter's order; the caller moves them after the step
+    (:func:`~horovod_tpu.parallel.moe.balance_bias` of the bias and the
+    counter) and carries them beside the parameters.
+
+    ``next_token``: a :class:`NextTokenModule`, applied to the model's
+    last hidden state with ``params["next_token"]``, the model's
+    embedding and its head; its logits (for token ``i + 2`` at position
+    ``i``) come back as ``aux["next_token_logits"]``, its expert layer
+    is the last row of the counter and reads the last row of
+    ``router_bias``.
     """
-    logits, state = model.apply({"params": params}, tokens,
-                                mutable=["intermediates"])
-    sown = {"moe_aux_loss": [], "moe_z_loss": [],
-            "moe_tokens_per_expert": []}
-    for path, leaf in jax.tree_util.tree_flatten_with_path(
-            state.get("intermediates", {}))[0]:
-        for k in path:
-            if getattr(k, "key", None) in sown:
-                sown[k.key].append(leaf)
+    out, state = model.apply(
+        {"params": params}, tokens, router_bias=router_bias,
+        return_hidden=next_token is not None, mutable=["intermediates"])
+    sown = _sown(state)
+    aux = {}
+    if next_token is not None:
+        out, hidden = out
+        with jax.named_scope("mtp"):
+            aux["next_token_logits"], state = next_token.apply(
+                {"params": params["next_token"]}, hidden, tokens,
+                params["embed"]["embedding"], params["lm_head"]["kernel"],
+                None if router_bias is None else router_bias[-1],
+                mutable=["intermediates"])
+        for name, leaves in _sown(state).items():
+            sown[name] += leaves
     counts = sown["moe_tokens_per_expert"]
-    return logits, {
-        "load_balancing": sum(sown["moe_aux_loss"],
-                              jnp.zeros((), jnp.float32)),
-        "router_z": sum(sown["moe_z_loss"], jnp.zeros((), jnp.float32)),
-        "moe_layers": len(sown["moe_aux_loss"]),
-        "tokens_per_expert": jnp.stack(counts) if counts else None,
-    }
+    aux.update(
+        load_balancing=sum(sown["moe_aux_loss"], jnp.zeros((), jnp.float32)),
+        router_z=sum(sown["moe_z_loss"], jnp.zeros((), jnp.float32)),
+        moe_layers=len(sown["moe_aux_loss"]),
+        tokens_per_expert=jnp.stack(counts) if counts else None)
+    return out, aux
 
 
 class Transformer(nn.Module):
-    """Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (causal LM)."""
+    """Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (causal LM).
+    ``router_bias [layers, E]``: see :func:`apply_with_aux`.  With
+    ``return_hidden`` also the last block's output before the final
+    norm: ``(logits, hidden)``."""
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, router_bias=None, return_hidden=False):
         cfg = self.cfg
         x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
                      name="embed")(tokens)
@@ -334,10 +518,43 @@ class Transformer(nn.Module):
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
         block_cls = nn.remat(Block) if cfg.remat else Block
+        rows = 0  # blocks so far that read a row of router_bias
         for i in range(cfg.n_layers):
-            switch = cfg.moe_every and (i + 1) % cfg.moe_every == 0
-            x = block_cls(cfg, ffn="moe_switch" if switch else None,
-                          name=f"block_{i}")(x)
+            ffn = cfg.ffn_of(i)
+            bias = None
+            if router_bias is not None and isinstance(ffn, TopkExperts):
+                bias, rows = router_bias[rows], rows + 1
+            x = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
+        hidden = x
         x = make_norm(cfg, "ln_f")(x)
-        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                        name="lm_head")(x.astype(cfg.dtype))
+        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                          name="lm_head")(x.astype(cfg.dtype))
+        return (logits, hidden) if return_hidden else logits
+
+
+class NextTokenModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+    section 2.2), beside a :class:`Transformer` whose embedding and head
+    it shares: at position ``i``
+
+        h'_i = W_eh [norm_e(Emb(t_{i+1})) ; norm_h(z_i)]
+
+    with ``z`` the model's last hidden state before its final norm, then
+    one block of the model's kind, a final norm of its own and the
+    model's head: logits for token ``i + 2``.  ``t_{i+1}`` is the roll
+    of the tokens, as :func:`lm_loss` has its labels."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, hidden, tokens, embedding, head, router_bias=None):
+        cfg = self.cfg
+        following = embedding[jnp.roll(tokens, -1, axis=-1)]
+        x = jnp.concatenate(
+            [make_norm(cfg, "enorm")(following.astype(cfg.dtype)),
+             make_norm(cfg, "hnorm")(hidden)], axis=-1)
+        x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                     name="eh_proj")(x)
+        block_cls = nn.remat(Block) if cfg.remat else Block
+        x = block_cls(cfg, name="block")(x, router_bias)
+        x = make_norm(cfg, "ln_f")(x).astype(cfg.dtype)
+        return jnp.dot(x, head.astype(cfg.dtype))

@@ -29,7 +29,11 @@ framework ships one).  Design per the TPU architecture:
   transposed left operand.
 
 Layout: public API takes ``[B, T, H, D]`` (framework convention);
-kernels run on ``[B*H, T, D]``.
+kernels run on ``[B*H, T, D]``.  q and k share one width (``d_qk``, the
+contraction of the scores), v and the output another (``d_v``): latent
+attention has heads of 192 for the scores and of 128 for the values.
+Either may be any width the array itself has (a block's last dimension
+is the array's), so 192 goes in as it is and is not padded to 256.
 """
 
 import functools
@@ -190,11 +194,11 @@ def _k_bounds(iq, *, causal, block_q, block_k, t_kv):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 block_q, block_k):
-    # q_ref: [block_q, d]; k_ref/v_ref: [t_kv, d]; o_ref: [block_q, d]
-    # lse_ref: [1, block_q], one lane per row
+    # q_ref: [block_q, d_qk]; k_ref: [t_kv, d_qk]; v_ref: [t_kv, d_v];
+    # o_ref: [block_q, d_v]; lse_ref: [1, block_q], one lane per row
     iq = pl.program_id(1)
     t_kv = k_ref.shape[1]
-    d = q_ref.shape[2]
+    d_v = v_ref.shape[2]
 
     q = q_ref[0]
     # scaling q loses nothing for a power of two; float32 q was always
@@ -234,7 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         body,
         (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
          jnp.zeros((block_q, 1), jnp.float32),
-         jnp.zeros((block_q, d), jnp.float32)),
+         jnp.zeros((block_q, d_v), jnp.float32)),
         _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
                   t_kv=t_kv))
 
@@ -244,9 +248,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
 
 def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
-    """Returns ``(out [bh, t, d], lse [bh, t])``."""
-    bh, t, d = q3.shape
-    t_kv = k3.shape[1]
+    """Returns ``(out [bh, t, d_v], lse [bh, t])``."""
+    bh, t, d_qk = q3.shape
+    t_kv, d_v = v3.shape[1:]
     nq = t // block_q
 
     out, lse = pl.pallas_call(
@@ -254,16 +258,16 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
                           block_q=block_q, block_k=block_k),
         grid=(bh, nq),
         in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, t_kv, d), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, t_kv, d_qk), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, t_kv, d_v), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             _vmem_spec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
-            _sds((bh, t, d), q3.dtype, q3),
+            _sds((bh, t, d_v), q3.dtype, q3),
             _sds((bh, nq, 1, block_q), jnp.float32, q3),
         ],
         interpret=interpret,
@@ -324,7 +328,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # stored as, and p.T @ dO, ds.T @ q are plain products
     ik = pl.program_id(1)
     t_q = q_ref.shape[1]
-    d = k_ref.shape[2]
     nq = t_q // block_q
 
     v_blk = v_ref[0]                                        # [bk, d]
@@ -369,8 +372,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         bounds = [(0, nq, False)]
     dk, dv = _causal_loops(
-        body, (jnp.zeros((block_k, d), jnp.float32),
-               jnp.zeros((block_k, d), jnp.float32)), bounds)
+        body, (jnp.zeros(k_blk.shape, jnp.float32),
+               jnp.zeros(v_blk.shape, jnp.float32)), bounds)
     if fold_scale:
         dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -380,8 +383,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
          g_lse=None):
     q3, k3, v3, out, lse = res
-    bh, t, d = q3.shape
-    t_kv = k3.shape[1]
+    bh, t, d_qk = q3.shape
+    t_kv, d_v = v3.shape[1:]
     nq = t // block_q
     nk = t_kv // block_k
 
@@ -404,15 +407,15 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
         functools.partial(_bwd_dq_kernel, **kernel_args),
         grid=(bh, nq),
         in_specs=[
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, t_kv, d), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, t_kv, d_qk), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, t_kv, d_v), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, block_q, d_v), lambda b, i: (b, i, 0)),
             dq_lse_spec,
             dq_lse_spec,
         ],
-        out_specs=_vmem_spec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=_sds((bh, t, d), q3.dtype, q3),
+        out_specs=_vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
+        out_shape=_sds((bh, t, d_qk), q3.dtype, q3),
         interpret=interpret,
     )(q3, k3, v3, g, lse_b, delta_b)
 
@@ -420,20 +423,20 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
         functools.partial(_bwd_dkv_kernel, **kernel_args),
         grid=(bh, nk),
         in_specs=[
-            _vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t, d), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, t, d_qk), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, t, d_v), lambda b, i: (b, 0, 0)),
             dkv_lse_spec,
             dkv_lse_spec,
         ],
         out_specs=[
-            _vmem_spec((1, block_k, d), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, block_k, d), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0)),
+            _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            _sds((bh, t_kv, d), k3.dtype, k3),
-            _sds((bh, t_kv, d), v3.dtype, v3),
+            _sds((bh, t_kv, d_qk), k3.dtype, k3),
+            _sds((bh, t_kv, d_v), v3.dtype, v3),
         ],
         interpret=interpret,
     )(q3, k3, v3, g, lse_b, delta_b)
@@ -521,7 +524,9 @@ def _env_block(name, default):
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, return_lse=False):
-    """Flash multi-head attention, ``[B, T, H, D] -> [B, T, H, D]``.
+    """Flash multi-head attention: q ``[B, T, H, d_qk]``, k ``[B, T_kv,
+    H, d_qk]``, v ``[B, T_kv, H, d_v]`` -> ``[B, T, H, d_v]``; ``scale``
+    defaults to ``1 / sqrt(d_qk)``.
 
     Differentiable (custom VJP with Pallas backward kernels).  On
     non-TPU backends runs in Pallas interpret mode (tests);
@@ -532,10 +537,10 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     (differentiable), which lets callers combine partial attention
     results streaming-softmax style (ring attention's per-block use).
     """
-    b, t, h, d = q.shape
-    t_kv = k.shape[1]
+    b, t, h, d_qk = q.shape
+    t_kv, d_v = k.shape[1], v.shape[3]
     if scale is None:
-        scale = 1.0 / math.sqrt(d)
+        scale = 1.0 / math.sqrt(d_qk)
     if interpret is None:
         interpret = _default_interpret()
     # Blocks as large as the sequence allows, up to 512: a loop
@@ -561,9 +566,9 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     if return_lse:
         out3, lse3 = _flash_lse(to3(q), to3(k), to3(v), scale, causal,
                                 block_q, block_k, interpret)
-        out = out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+        out = out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
         return out, lse3.reshape(b, h, t)
 
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
                   interpret)
-    return out3.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)

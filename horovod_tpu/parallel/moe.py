@@ -18,8 +18,14 @@ of all experts, **dropless** (every token-slot is computed whatever the
 imbalance: no capacity, no padding), gated SwiGLU experts, and a grouped
 matrix product over rows sorted by expert in place of the one-hot
 einsums.  Shapes are static and the group sizes are data, so nothing
-recompiles when the load shifts.  It is not expert-parallel yet: all
-experts are resident on the device that calls it.
+recompiles when the load shifts.  The router may score by softmax or by
+sigmoid, choose through a balancing bias that never enters a weight
+(:func:`balance_bias` moves it), renormalise the k weights and scale
+them.  With ``held=(first, count)`` the layer routes over all experts
+and computes the part of the result that the ``count`` experts it holds
+give: one chip's share under expert parallelism, still dropless.  The
+exchange of rows between chips is not here yet: every token the layer
+is given is one of this chip's.
 """
 
 import functools
@@ -175,36 +181,51 @@ def init_moe_params(rng, d_model, d_ff, n_experts, dtype=jnp.float32,
 
 
 # ------------------------------------------------ dropless top-k routing
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inverse, k):
+def _rows_of_slots(y, inverse, partial):
+    """Row ``inverse[s]`` of ``y`` for every token-slot ``s``.  Where
+    ``y`` holds every slot's row ``inverse`` is a permutation and this
+    is a plain gather.  Where it holds a device's share (``partial``) a
+    slot whose row is not among the computed ones points past ``y`` and
+    reads zeros."""
+    if partial:
+        return y.at[inverse].get(mode="fill", fill_value=0)
+    return y[inverse]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(x, order, inverse, k, partial=False):
     """Rows of ``x [N, D]`` in slot order: row ``i`` is token
-    ``order[i] // k``.  ``order`` is a permutation of the ``N * k``
-    token-slots and ``inverse`` its inverse, so the backward pass is a
-    gather by ``inverse`` and a sum over a token's ``k`` slots, not the
-    scatter-add that transposing the gather would give."""
+    ``order[i] // k``.  ``order`` holds the first M token-slots of a
+    permutation of all ``N * k`` and ``inverse`` says where a slot's
+    row is, so the backward pass is a gather by ``inverse`` and a sum
+    over a token's ``k`` slots, not the scatter-add that transposing
+    the gather would give."""
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, k):
+def _dispatch_fwd(x, order, inverse, k, partial):
     return x[order // k], inverse
 
 
-def _dispatch_bwd(k, inverse, g):
-    return g[inverse].reshape(-1, k, g.shape[-1]).sum(1), None, None
+def _dispatch_bwd(k, partial, inverse, g):
+    rows = _rows_of_slots(g, inverse, partial)
+    return rows.reshape(-1, k, g.shape[-1]).sum(1), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _unsort(y, order, inverse):
-    """``y[inverse]``: slot-ordered rows back in token order; backward
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _unsort(y, order, inverse, partial=False):
+    """Slot-ordered rows back in token order, ``[N * k, D]``; backward
     is the gather by ``order``."""
-    return y[inverse]
+    return _rows_of_slots(y, inverse, partial)
 
 
-_unsort.defvjp(lambda y, order, inverse: (y[inverse], order),
-               lambda order, g: (g[order], None, None))
+_unsort.defvjp(
+    lambda y, order, inverse, partial: (
+        _rows_of_slots(y, inverse, partial), order),
+    lambda partial, order, g: (g[order], None, None))
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -218,24 +239,42 @@ def grouped_matmul(lhs, rhs, group_sizes):
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
-def topk_route(router_logits, k):
+SCORINGS = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+            "sigmoid": jax.nn.sigmoid}
+
+
+def topk_route(router_logits, k, *, scoring="softmax", bias=None,
+               renormalize=False, scale=1.0):
     """Token-choice top-k routing from ``[N, E]`` float32 logits.
 
-    Returns ``(weights [N, k], experts [N, k], aux)``.  The weights are
-    the softmax probabilities of the chosen experts AS THEY ARE, not
-    renormalised over the k (OLMoE's ``norm_topk_prob: false``).
-    ``aux`` holds, over the N tokens,
+    Returns ``(weights [N, k], experts [N, k], aux)``.  An expert's
+    score is the ``scoring`` (``"softmax"`` over the experts or
+    ``"sigmoid"`` of its own logit) of its logit.  Chosen are the k
+    largest of ``score + bias``: ``bias [E]`` balances the load
+    (:func:`balance_bias`), decides the choice alone and never enters a
+    weight.  The weights are the scores of the chosen experts, divided
+    by their sum over the k where ``renormalize``, times ``scale``.
+    The defaults are OLMoE's: the softmax probabilities AS THEY ARE
+    (``norm_topk_prob: false``).  ``aux`` holds, over the N tokens,
 
     - ``load_balancing``: ``E * sum_e f_e P_e`` with ``f_e`` the
-      token-slots routed to ``e`` over N and ``P_e`` the mean
-      probability of ``e`` (Hugging Face's ``load_balancing_loss_func``;
-      1 * k at a uniform router);
+      token-slots routed to ``e`` over N and ``P_e`` the mean score of
+      ``e`` (Hugging Face's ``load_balancing_loss_func``; 1 * k at a
+      uniform softmax router);
     - ``router_z``: mean of ``logsumexp(logits)^2`` (ST-MoE);
     - ``tokens_per_expert [E]`` int32, summing to ``N * k``.
     """
     n, e = router_logits.shape
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    probs = SCORINGS[scoring](router_logits)
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(probs + bias, k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     tokens_per_expert = jnp.sum(
         jax.nn.one_hot(experts, e, dtype=jnp.int32), axis=(0, 1))
     f = tokens_per_expert.astype(jnp.float32) / n
@@ -248,24 +287,45 @@ def topk_route(router_logits, k):
     return weights, experts, aux
 
 
-def topk_moe(x, params, *, k):
+def balance_bias(bias, tokens_per_expert, rate):
+    """The selection bias after a step (DeepSeek-V3, arXiv:2412.19437
+    section 2.1.2): ``b_e += rate * sign(mean(c) - c_e)`` with ``c`` the
+    step's token-slots per expert, over the last axis: an overloaded
+    expert's bias falls, an underloaded one's rises.  No gradient: the
+    bias is state beside the parameters."""
+    c = tokens_per_expert.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
+
+
+def topk_moe(x, params, *, k, held=None, **route):
     """Dropless top-``k`` MoE FFN with gated experts on ``x [..., D]``
     (leading dims folded into N tokens); returns ``(out, aux)``.
 
     params: ``router/kernel [D, E]``, ``wg/kernel`` and ``wi/kernel``
     ``[E, D, F]`` (gate and up), ``wo/kernel [E, F, D]`` (create with
     ``init_moe_params(..., gated=True)``).  Per token, with ``S`` the
-    ``k`` experts of largest router probability ``p``:
+    ``k`` experts :func:`topk_route` chooses and ``w`` their weights
+    (``route``: its keyword arguments; by default the softmax
+    probabilities as they are):
 
-        out = sum_{e in S} p_e * (silu(x wg_e) * (x wi_e)) wo_e
+        out = sum_{e in S} w_e * (silu(x wg_e) * (x wi_e)) wo_e
 
-    The router's product and softmax run in float32 whatever ``x`` is,
+    The router's product and scores run in float32 whatever ``x`` is,
     so that rounding does not flip a near-tie between the k-th and the
     next expert.  The ``N * k`` token-slots are sorted by expert
     (stable), their rows gathered, three grouped products run with
     group sizes = tokens per expert, and the result is un-sorted by the
     inverse permutation and summed over a token's slots: no capacity,
     no token dropped, no scatter.  ``aux`` is :func:`topk_route`'s.
+
+    ``held=(first, count)``: this device holds the experts ``first ...
+    first + count - 1`` of the router's ``E`` (``wg``, ``wi``, ``wo``
+    are ``[count, ...]``).  Routing, weights and ``tokens_per_expert
+    [E]`` are over all ``E``; the sum above runs over the chosen AND
+    held experts only: what the other devices' experts would add is
+    theirs to add.  Dropless whatever the router does: a token has at
+    most ``min(k, count)`` held slots, so the held slots, sorted to the
+    front, always fit the ``N * min(k, count)`` rows the buffer has.
     """
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -277,12 +337,26 @@ def topk_moe(x, params, *, k):
             xt.astype(jnp.float32),
             params["router"]["kernel"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
-        weights, experts, aux = topk_route(logits, k)
+        weights, experts, aux = topk_route(logits, k, **route)
     with jax.named_scope("moe/dispatch"):
-        order = jnp.argsort(experts.reshape(-1), stable=True)
-        inverse = jnp.argsort(order)
-        rows = _dispatch(xt, order, inverse, k)
         group_sizes = aux["tokens_per_expert"]
+        keys = experts.reshape(-1)
+        if held is not None:
+            first, count = held
+            # held slots first, by expert; the others behind them
+            keys = jnp.where((keys >= first) & (keys < first + count),
+                             keys - first, count)
+            group_sizes = group_sizes[first:first + count]
+        order = jnp.argsort(keys, stable=True)
+        inverse = jnp.argsort(order)
+        if held is not None:
+            order = order[:n * min(k, count)]
+            # a grouped product leaves the rows outside its groups
+            # undefined, forward and backward: a slot that is not held
+            # reads zeros wherever its row would be read
+            inverse = jnp.where(inverse < jnp.sum(group_sizes), inverse,
+                                order.shape[0])
+        rows = _dispatch(xt, order, inverse, k, held is not None)
     with jax.named_scope("moe/experts"):
         wg, wi, wo = (params[name]["kernel"].astype(dtype)
                       for name in ("wg", "wi", "wo"))
@@ -290,6 +364,6 @@ def topk_moe(x, params, *, k):
                   * grouped_matmul(rows, wi, group_sizes))
         y = grouped_matmul(hidden, wo, group_sizes)
     with jax.named_scope("moe/combine"):
-        y = _unsort(y, order, inverse).reshape(n, k, d)
+        y = _unsort(y, order, inverse, held is not None).reshape(n, k, d)
         out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
     return out.astype(dtype).reshape(orig_shape), aux

@@ -95,6 +95,74 @@ def test_flash_attention_compiles_for_v5e(v5e, dtype, shape):
     assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
 
 
+def test_flash_attention_at_two_widths_compiles_for_v5e(v5e):
+    """Heads of 192 for the scores and 128 for the values at the
+    latent-attention cell's shape: 192 is no multiple of the 128 lanes
+    and goes in as it is (a block's last dimension is the array's)."""
+    from horovod_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    qk = _on(v5e[0], (4, 4096, 32, 192), jnp.bfloat16)
+    text = _compile(fwd_bwd, qk, qk,
+                    _on(v5e[0], (4, 4096, 32, 128), jnp.bfloat16)).as_text()
+    assert text.count("tpu_custom_call") >= 3  # fwd, dq, dk/dv
+    assert "bf16[128,4096,192]" in text and "bf16[128,4096,128]" in text
+
+
+def test_latent_expert_block_and_module_compile_for_v5e(v5e):
+    """The block the latent-attention cell is made of, at a small size
+    (its head widths, fewer heads, narrower layers): a dense layer, an
+    expert layer that holds 4 of 16 experts beside a shared one, the
+    multi-token-prediction module, recomputed in the backward pass.
+    The flash kernels take 192 / 128 and the held experts lower to
+    grouped-product kernels."""
+    from horovod_tpu.models import (BlockSpec, LatentAttention,
+                                    NextTokenModule, TopkExperts,
+                                    Transformer, TransformerConfig,
+                                    apply_with_aux, lm_loss)
+
+    cfg = TransformerConfig(
+        vocab_size=2048, n_layers=2, d_model=512, n_heads=4, d_ff=1024,
+        d_expert=256, n_experts=16, experts_per_token=4, max_len=1024,
+        rope_theta=32e6, leading_dense=1, remat=True,
+        block=BlockSpec(
+            norm="rms", positions="rope_pairs",
+            attention=LatentAttention(q_rank=384, kv_rank=128, nope_dim=128,
+                                      rope_dim=64, v_dim=128),
+            ffn=TopkExperts(scoring="sigmoid", renormalize=True, scale=2.5,
+                            shared=1, held=(4, 4))))
+    model, module = Transformer(cfg), NextTokenModule(cfg)
+    tokens = jax.ShapeDtypeStruct((2, 1024), jnp.int32)
+
+    def init(key):
+        params = model.init(key, jnp.zeros((1, 1024), jnp.int32))["params"]
+        params["next_token"] = module.init(
+            key, jnp.zeros((1, 1024, 512), cfg.dtype),
+            jnp.zeros((1, 1024), jnp.int32), params["embed"]["embedding"],
+            params["lm_head"]["kernel"])["params"]
+        return params
+
+    def loss(params, bias, tokens):
+        logits, aux = apply_with_aux(model, params, tokens,
+                                     router_bias=bias, next_token=module)
+        return lm_loss(logits, tokens) + 0.3 * lm_loss(
+            aux["next_token_logits"], jnp.roll(tokens, -1, axis=-1))
+
+    params = jax.eval_shape(init, jax.random.PRNGKey(0))
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: _on(v5e[0], a.shape, a.dtype), tree)
+    text = _compile(jax.value_and_grad(loss), on_chip(params),
+                    _on(v5e[0], (2, 16), jnp.float32),
+                    on_chip(tokens)).as_text()
+    assert "bf16[8,1024,192]" in text
+    assert text.count("%ragged-dot-none") >= 9
+    assert "bf16[8192,512]" in text  # the buffer: N * min(k, count) rows
+
+
 def test_layer_norm_compiles_for_v5e(v5e):
     from horovod_tpu.ops.pallas.layer_norm import layer_norm
 
@@ -221,10 +289,18 @@ def test_topk_moe_compiles_for_v5e_as_grouped_product_kernels(v5e):
                 if " scatter(" in line and "[131072,2048]" in line]
 
 
-def test_olmoe_cell_step_compiles_for_v5e(v5e):
-    """The benchmark's ``olmoe_1b_7b-spmd-1chip`` as ``benchmark/run.py``
-    builds it (the ``spmd`` loop's own step over the family's loss) at
-    published widths, depth 1, 4 sequences of 4096: fits one chip."""
+@pytest.mark.parametrize("workload,grouped_products,kernels", [
+    # nine grouped products, three flash kernels, softmax-xent's two
+    ("olmoe_1b_7b-spmd-1chip", 9, 14),
+    # five expert layers' grouped products, forward, recomputed and
+    # backward; six blocks' flash kernels; softmax-xent twice
+    ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * 9 + 6 * 3 + 4)],
+    ids=["olmoe_1b_7b", "joyai_llm_flash"])
+def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
+                                           kernels):
+    """A sparse cell as ``benchmark/run.py`` builds it (the ``spmd``
+    loop's own step over the family's loss) at published widths and the
+    cell's batch of 4 sequences of 4096: fits one chip."""
     import sys
 
     from horovod_tpu.parallel import make_mesh
@@ -237,7 +313,7 @@ def test_olmoe_cell_step_compiles_for_v5e(v5e):
         sys.path.pop(0)
     bench = load_by_path(os.path.join(repo, "benchmark", "run.py"),
                          "hvd_benchmark_run_chip_compile")
-    cell = bench.load_cell(repo, "olmoe_1b_7b-spmd-1chip")
+    cell = bench.load_cell(repo, workload)
     mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
 
     import optax
@@ -254,9 +330,8 @@ def test_olmoe_cell_step_compiles_for_v5e(v5e):
         _shaped(mesh, jax.eval_shape(opt.init, params), P()),
         _shaped(mesh, tokens, P("hvd"))).compile()
     text = compiled.as_text()
-    # nine grouped products, three flash kernels, softmax-xent's two
-    assert text.count("%ragged-dot-none") >= 9
-    assert text.count("tpu_custom_call") >= 14
+    assert text.count("%ragged-dot-none") >= grouped_products
+    assert text.count("tpu_custom_call") >= kernels
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES

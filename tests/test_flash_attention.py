@@ -576,3 +576,66 @@ def test_flash_layers_of_one_shape_share_one_traced_kernel():
                    for e in eqn.params["jaxpr"].jaxpr.eqns)]
     assert len(holders) == 3, len(holders)
     assert len({id(h) for h in holders}) == 1
+
+
+# ------------------------------------------- two widths: d_qk and d_v
+def _rand_two_widths(d_qk, d_v, b=1, t=128, h=2, seed=3):
+    rng = np.random.RandomState(seed)
+    mk = lambda d: jnp.asarray(rng.randn(b, t, h, d).astype(np.float32))
+    return mk(d_qk), mk(d_qk), mk(d_v)
+
+
+@pytest.mark.parametrize("d_qk,d_v", [(192, 128), (64, 64), (128, 128),
+                                      (24, 16)])
+def test_flash_two_widths_forward_and_all_three_gradients(d_qk, d_v):
+    """q and k of the scores' width, v and the output of the values':
+    latent attention's 192 / 128 beside the widths the other
+    configurations run; the default scale is ``1 / sqrt(d_qk)``."""
+    q, k, v = _rand_two_widths(d_qk, d_v)
+    ct = jnp.asarray(np.random.RandomState(4).randn(
+        *v.shape).astype(np.float32))
+    got = flash_attention(q, k, v, causal=True, block_q=64, block_k=32)
+    want = reference_attention(q, k, v, causal=True)
+    assert got.shape == want.shape == q.shape[:-1] + (d_v,)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    explicit = flash_attention(q, k, v, causal=True, block_q=64,
+                               block_k=32, scale=1 / np.sqrt(d_qk))
+    np.testing.assert_array_equal(got, explicit)
+
+    def loss(attend, **blocks):
+        return lambda q, k, v: jnp.vdot(
+            attend(q, k, v, causal=True, **blocks), ct)
+
+    gf = jax.grad(loss(flash_attention, block_q=64, block_k=32),
+                  (0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(reference_attention), (0, 1, 2))(q, k, v)
+    for got, want, like in zip(gf, gr, (q, k, v)):
+        assert got.shape == like.shape
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_two_widths_lse_and_its_gradient():
+    """``return_lse`` at 192 / 128: the logsumexp of the scaled scores,
+    differentiable, as ring attention combines partial results."""
+    q, k, v = _rand_two_widths(192, 128, t=64)
+
+    def dense(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(192)
+        s = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), s, -jnp.inf)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v),
+                jax.nn.logsumexp(s, -1))
+
+    def weighed(attend):
+        def loss(q, k, v):
+            out, lse = attend(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(jnp.sin(lse))
+        return loss
+
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                            return_lse=True, block_q=32,
+                                            block_k=32)
+    for got, want in zip(flash(q, k, v), dense(q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    for got, want in zip(jax.grad(weighed(flash), (0, 1, 2))(q, k, v),
+                         jax.grad(weighed(dense), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)

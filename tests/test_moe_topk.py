@@ -182,3 +182,181 @@ def test_a_shifted_load_compiles_nothing_new():
     fn(x, params)
     fn(jnp.abs(x), skewed(params, (0, 5)))
     assert fn._cache_size() == 1
+
+
+# ------------------------------- scoring, bias, renormalisation, scale
+def test_default_route_is_olmoes_bit_for_bit():
+    """(e) softmax, the probabilities as they are, no bias, no scale:
+    what ``topk_route`` was before it took arguments."""
+    logits = jax.random.normal(jax.random.PRNGKey(8), (N, E))
+    weights, experts, aux = jax.jit(lambda lg: topk_route(lg, 3))(logits)
+    want_w, want_e = jax.jit(
+        lambda lg: jax.lax.top_k(jax.nn.softmax(lg, axis=-1), 3))(logits)
+    np.testing.assert_array_equal(weights, want_w)
+    np.testing.assert_array_equal(experts, want_e)
+    explicit = jax.jit(lambda lg: topk_route(
+        lg, 3, scoring="softmax", bias=None, renormalize=False,
+        scale=1.0))(logits)
+    np.testing.assert_array_equal(explicit[0], weights)
+    np.testing.assert_array_equal(explicit[2]["load_balancing"],
+                                  aux["load_balancing"])
+
+
+def route_loop(logits, k, bias, scale):
+    """One token at a time: sigmoid scores, the k largest of score +
+    bias, weights = scale * score / sum of the chosen scores."""
+    weights, experts = [], []
+    for row in np.asarray(logits, np.float64):
+        s = 1 / (1 + np.exp(-row))
+        chosen = np.argsort(-(s + np.asarray(bias)), kind="stable")[:k]
+        experts.append(chosen)
+        weights.append(scale * s[chosen] / s[chosen].sum())
+    return np.stack(weights), np.stack(experts)
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+def test_sigmoid_biased_renormalised_scaled_route_matches_the_loop(k):
+    logits = jax.random.normal(jax.random.PRNGKey(9), (N, E))
+    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(10), (E,))
+    weights, experts, aux = topk_route(
+        logits, k, scoring="sigmoid", bias=bias, renormalize=True,
+        scale=2.5)
+    want_w, want_e = route_loop(logits, k, bias, 2.5)
+    np.testing.assert_array_equal(experts, want_e)
+    np.testing.assert_allclose(weights, want_w, rtol=1e-5)
+    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-5)
+    assert int(aux["tokens_per_expert"].sum()) == N * k
+
+
+def test_the_bias_decides_the_choice_and_enters_no_weight():
+    """(f) a large bias on one expert sends every token to it; its
+    weight is still its score, renormalised with the others', and the
+    same experts chosen without the bias weigh the same."""
+    logits = jax.random.normal(jax.random.PRNGKey(11), (N, E))
+    s = jax.nn.sigmoid(logits)
+    bias = jnp.zeros((E,)).at[4].set(10.0)
+    weights, experts, aux = topk_route(logits, 2, scoring="sigmoid",
+                                       bias=bias, renormalize=True)
+    assert np.all(np.asarray(experts[:, 0]) == 4)
+    assert int(aux["tokens_per_expert"][4]) == N
+    chosen = jnp.take_along_axis(s, experts, -1)
+    np.testing.assert_allclose(weights, chosen / chosen.sum(-1,
+                                                            keepdims=True),
+                               rtol=1e-6)
+    # a bias that changes no choice changes nothing
+    flat = topk_route(logits, 2, scoring="sigmoid", renormalize=True)
+    same = topk_route(logits, 2, scoring="sigmoid", renormalize=True,
+                      bias=jnp.full((E,), 0.25))
+    np.testing.assert_array_equal(same[1], flat[1])
+    np.testing.assert_array_equal(same[0], flat[0])
+    # and it gets no gradient through the weights
+    grad = jax.grad(lambda b: jnp.sum(topk_route(
+        logits, 2, scoring="sigmoid", bias=b, renormalize=True)[0] ** 2))(
+            bias)
+    assert not np.any(np.asarray(grad))
+
+
+def test_balance_bias_moves_by_the_rule():
+    """(f) ``b_e += rate * sign(mean(c) - c_e)``: down where loaded
+    above the mean, up below it, still at it; a row a layer."""
+    from horovod_tpu.parallel.moe import balance_bias
+
+    counts = jnp.asarray([[8, 0, 4, 4], [1, 1, 1, 13]], jnp.int32)
+    bias = jnp.asarray([[0.0, 0.0, 0.5, -0.5], [0.1, 0.1, 0.1, 0.1]])
+    moved = balance_bias(bias, counts, 0.001)
+    np.testing.assert_allclose(
+        moved, [[-0.001, 0.001, 0.5, -0.5], [0.101, 0.101, 0.101, 0.099]],
+        rtol=1e-6)
+
+
+# --------------------------------------------------- the experts held
+def share(params, first, count):
+    """The weights one device holds: all of the router, ``count``
+    experts from ``first``."""
+    return {"router": params["router"], **{
+        name: {"kernel": params[name]["kernel"][first:first + count]}
+        for name in ("wg", "wi", "wo")}}
+
+
+ROUTES = {"olmoe": {}, "sigmoid": dict(scoring="sigmoid", renormalize=True,
+                                       scale=2.5)}
+
+
+@pytest.mark.parametrize("route", sorted(ROUTES))
+@pytest.mark.parametrize("k,count", [(2, 3), (2, 2), (3, 2), (4, 1), (1, 6)])
+def test_the_shares_add_up_to_the_whole_layer(k, count, route):
+    """(c) ``E / count`` devices, each routing over all E and computing
+    its own experts' part: the parts sum to the layer that holds every
+    expert, output and every gradient leaf, also where a device holds
+    fewer experts than a token has slots (``count < k``: the buffer is
+    ``N * count`` rows)."""
+    x, params, ct = inputs(12)
+    kwargs = ROUTES[route]
+
+    def whole(x, params):
+        return jnp.vdot(topk_moe(x, params, k=k, **kwargs)[0], ct)
+
+    def shares(x, params):
+        return sum(jnp.vdot(topk_moe(
+            x, share(params, first, count), k=k, held=(first, count),
+            **kwargs)[0], ct) for first in range(0, E, count))
+
+    full, aux = topk_moe(x, params, k=k, **kwargs)
+    parts = [topk_moe(x, share(params, first, count), k=k,
+                      held=(first, count), **kwargs)
+             for first in range(0, E, count)]
+    np.testing.assert_allclose(sum(out for out, _ in parts), full,
+                               rtol=1e-5, atol=1e-6)
+    for _, part_aux in parts:
+        # routing is over all E on every device
+        assert part_aux["tokens_per_expert"].shape == (E,)
+        np.testing.assert_array_equal(part_aux["tokens_per_expert"],
+                                      aux["tokens_per_expert"])
+    got = jax.grad(shares, (0, 1))(x, params)
+    want = jax.grad(whole, (0, 1))(x, params)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("held,k", [((2, 2), 2), ((0, 3), 2), ((3, 1), 1)])
+def test_every_slot_on_held_experts_and_nothing_is_dropped(held, k):
+    """(d) the router rigged so that every token's k slots land on
+    experts this device holds: the buffer's bound ``N * min(k, count)``
+    is met exactly, and the result is the per-token loop's over all
+    experts (the others get no token)."""
+    first, count = held
+    favourites = tuple(range(first, first + k))
+    x, params, ct = inputs(13)
+    x, params = jnp.abs(x), skewed(params, favourites)
+    out, aux = topk_moe(x, share(params, first, count), k=k, held=held)
+    want, _, _, counts = per_token_loop(x, params, k)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(aux["tokens_per_expert"], counts)
+    assert int(aux["tokens_per_expert"][first:first + count].sum()) == N * k
+
+    def system_held(x, params):
+        return jnp.vdot(topk_moe(x, share(params, first, count), k=k,
+                                 held=held)[0], ct)
+
+    def loop_all(x, params):
+        return jnp.vdot(per_token_loop(x, params, k)[0], ct)
+
+    got = jax.grad(system_held, (0, 1))(x, params)
+    want = jax.grad(loop_all, (0, 1))(x, params)
+    for name in ("wg", "wi", "wo"):
+        np.testing.assert_allclose(
+            got[1][name]["kernel"], want[1][name]["kernel"], rtol=1e-4,
+            atol=1e-5)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-5)
+
+
+def test_no_slot_on_a_held_expert_gives_zeros_and_no_nan():
+    """The other extreme: nothing routed here.  The grouped products
+    have no row, and what they leave undefined is zeroed."""
+    x, params, ct = inputs(14)
+    x, params = jnp.abs(x), skewed(params, (0, 1))
+    fn = lambda x, p: topk_moe(x, share(p, 3, 3), k=2, held=(3, 3))[0]
+    assert not np.any(np.asarray(fn(x, params)))
+    grads = jax.grad(lambda x, p: jnp.vdot(fn(x, p), ct), (0, 1))(x, params)
+    for leaf in jax.tree.leaves(grads):
+        assert not np.any(np.asarray(leaf))

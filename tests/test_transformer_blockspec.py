@@ -8,8 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import (BlockSpec, Transformer, TransformerConfig,
-                                apply_with_aux)
+from horovod_tpu.models import (BlockSpec, LatentAttention, NextTokenModule,
+                                TopkExperts, Transformer, TransformerConfig,
+                                apply_with_aux, lm_loss)
 from horovod_tpu.models.transformer import Attention, RMSNorm, rope
 
 OLMOE = BlockSpec(norm="rms", positions="rope", qk_norm=True,
@@ -65,7 +66,8 @@ def test_moe_every_still_makes_every_kth_block_a_switch_layer():
 
 
 @pytest.mark.parametrize("field,value", [
-    ("norm", "batch"), ("positions", "alibi"), ("ffn", "swiglu")])
+    ("norm", "batch"), ("positions", "alibi"), ("ffn", "relu"),
+    ("attention", "linear")])
 def test_an_unknown_part_is_refused_by_name(field, value):
     with pytest.raises(ValueError, match=value):
         BlockSpec(**{field: value})
@@ -226,6 +228,207 @@ def test_olmoe_spec_trains_in_bfloat16_under_remat():
         logits, aux = apply_with_aux(model, p, TOKENS)
         return (lm_loss(logits, TOKENS) + 0.01 * aux["load_balancing"]
                 + 0.001 * aux["router_z"])
+
+    value, grads = jax.jit(jax.value_and_grad(loss))(params)
+    assert np.isfinite(float(value))
+    assert all(np.all(np.isfinite(np.asarray(g, np.float32)))
+               for g in jax.tree.leaves(grads))
+
+
+# ------------- latent attention, layer kinds, held and shared experts
+LATENT = LatentAttention(q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4,
+                         v_dim=6)
+EXPERTS = TopkExperts(scoring="sigmoid", renormalize=True, scale=2.5,
+                      shared=1, held=(4, 4))
+SPARSE = dict(**SIZES, n_experts=16, experts_per_token=4, d_expert=12,
+              leading_dense=1, rope_theta=32e6,
+              block=BlockSpec(norm="rms", positions="rope_pairs",
+                              attention=LATENT, ffn=EXPERTS))
+
+
+def test_latent_dense_and_expert_layers_tree():
+    """Written out: five projections and two inner norms of latent
+    attention (heads of 8 + 4 for the scores, 6 for the values); block
+    0 a dense SwiGLU of width ``d_ff``, block 1 a router over all 16
+    outputs, the 4 experts held and a shared one of the experts' width."""
+    attn = {"attn/q_a/kernel": (32, 24), "attn/q_a_norm/scale": (24,),
+            "attn/q_b/kernel": (24, 4, 12), "attn/kv_a/kernel": (32, 20),
+            "attn/kv_a_norm/scale": (16,), "attn/kv_b/kernel": (16, 4, 14),
+            "attn/out/kernel": (24, 32), "ln1/scale": (32,),
+            "ln2/scale": (32,)}
+    dense = {"mlp/gate/kernel": (32, 64), "mlp/up/kernel": (32, 64),
+             "mlp/down/kernel": (64, 32)}
+    sparse = {"moe/router_kernel": (32, 16), "moe/wg_kernel": (4, 32, 12),
+              "moe/wi_kernel": (4, 32, 12), "moe/wo_kernel": (4, 12, 32),
+              "moe/shared/gate/kernel": (32, 12),
+              "moe/shared/up/kernel": (32, 12),
+              "moe/shared/down/kernel": (12, 32)}
+    want = {"embed/embedding": (50, 32), "ln_f/scale": (32,),
+            "lm_head/kernel": (32, 50)}
+    want.update({f"block_0/{k}": v for k, v in {**attn, **dense}.items()})
+    want.update({f"block_1/{k}": v for k, v in {**attn, **sparse}.items()})
+    cfg = TransformerConfig(**SPARSE)
+    assert [cfg.ffn_of(i) for i in range(2)] == ["swiglu", EXPERTS]
+    assert shapes(cfg) == want
+
+
+def test_plain_topk_spec_is_the_default_topk_experts():
+    """``"moe_topk"`` and ``TopkExperts()`` are one layer."""
+    sizes = dict(**SIZES, n_experts=8, experts_per_token=2, d_expert=24)
+    named = TransformerConfig(**sizes, block=OLMOE)
+    spelled = TransformerConfig(**sizes, block=BlockSpec(
+        norm="rms", positions="rope", qk_norm=True, ffn=TopkExperts()))
+    assert shapes(named) == shapes(spelled)
+    params = Transformer(named).init(jax.random.PRNGKey(0), TOKENS)["params"]
+    np.testing.assert_array_equal(
+        Transformer(named).apply({"params": params}, TOKENS),
+        Transformer(spelled).apply({"params": params}, TOKENS))
+
+
+def pair_rotation(x, theta):
+    """The neighbours ``(x[2i], x[2i + 1])`` as one complex number
+    turned by ``t * theta^(-2i / D)``."""
+    t, d = x.shape[-3], x.shape[-1]
+    x = np.asarray(x, np.float64)
+    z = x[..., 0::2] + 1j * x[..., 1::2]
+    z = z * np.exp(1j * np.arange(t)[:, None, None]
+                   * theta ** (-2 * np.arange(d // 2) / d))
+    return np.stack([z.real, z.imag], -1).reshape(x.shape)
+
+
+def test_rope_pairs_is_the_complex_rotation_of_neighbours():
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 12, 3, 16))
+    np.testing.assert_allclose(rope(x, 32e6, pairs=True),
+                               pair_rotation(x, 32e6), rtol=1e-5, atol=1e-5)
+    # the two pairings give the same scores once q and k are both
+    # de-interleaved: what Hugging Face's ``rope_interleave`` does
+    q, k = x[:1], x[1:]
+    flat = lambda u: jnp.concatenate([u[..., 0::2], u[..., 1::2]], -1)
+    np.testing.assert_allclose(
+        jnp.einsum("bqhd,bkhd->bhqk", rope(q, 500.0, pairs=True),
+                   rope(k, 500.0, pairs=True)),
+        jnp.einsum("bqhd,bkhd->bhqk", rope(flat(q), 500.0),
+                   rope(flat(k), 500.0)), rtol=1e-4, atol=1e-5)
+
+
+def test_latent_attention_hands_the_kernel_two_widths_and_one_shared_key():
+    seen = {}
+
+    def attend(q, k, v, causal):
+        seen.update(q=q, k=k, v=v)
+        return v
+
+    cfg = TransformerConfig(**{**SPARSE, "attn_fn": attend})
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 16, 32))
+    attn = Attention(cfg)
+    params = attn.init(jax.random.PRNGKey(8), x)["params"]
+    out = attn.apply({"params": params}, x)
+    assert out.shape == x.shape
+    assert seen["q"].shape == seen["k"].shape == (2, 16, 4, 12)
+    assert seen["v"].shape == (2, 16, 4, 6)
+    # the rotated key is one for all heads, and is the pair rotation of
+    # the last rope_dim columns of the kv_a projection
+    k_rope = seen["k"][..., 8:]
+    np.testing.assert_array_equal(k_rope, jnp.broadcast_to(
+        k_rope[:, :, :1], k_rope.shape))
+    down = x @ params["kv_a"]["kernel"]
+    np.testing.assert_allclose(
+        k_rope[:, :, 0], pair_rotation(down[:, :, None, 16:], 32e6)[:, :, 0],
+        rtol=1e-4, atol=1e-5)
+    # q's first nope_dim columns are not turned, the rest are
+    c_q = RMSNorm().apply({"params": params["q_a_norm"]},
+                          x @ params["q_a"]["kernel"])
+    q = jnp.einsum("btr,rhk->bthk", c_q, params["q_b"]["kernel"])
+    np.testing.assert_allclose(seen["q"][..., :8], q[..., :8], rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(seen["q"][..., 8:],
+                               pair_rotation(q[..., 8:], 32e6), rtol=1e-4,
+                               atol=1e-5)
+
+
+def model_and_module(**changes):
+    cfg = TransformerConfig(**{**SPARSE, "n_layers": 3, **changes})
+    model, module = Transformer(cfg), NextTokenModule(cfg)
+    params = model.init(jax.random.PRNGKey(9), TOKENS)["params"]
+    params["next_token"] = module.init(
+        jax.random.PRNGKey(10), jnp.zeros((2, 16, 32)), TOKENS,
+        params["embed"]["embedding"], params["lm_head"]["kernel"])["params"]
+    return model, module, params
+
+
+def test_the_counter_reaches_apply_with_aux_for_every_expert_layer():
+    """Two expert layers and the module's, in that order, each over
+    all 16 outputs; a bias row a layer decides the choice there and
+    nowhere else."""
+    model, module, params = model_and_module()
+    bias = jnp.zeros((3, 16))
+    logits, aux = apply_with_aux(model, params, TOKENS, router_bias=bias,
+                                 next_token=module)
+    assert logits.shape == aux["next_token_logits"].shape == (2, 16, 50)
+    assert aux["moe_layers"] == 3
+    assert aux["tokens_per_expert"].shape == (3, 16)
+    np.testing.assert_array_equal(aux["tokens_per_expert"].sum(-1),
+                                  [2 * 16 * 4] * 3)
+    np.testing.assert_allclose(
+        logits, model.apply({"params": params}, TOKENS), rtol=1e-6)
+    for row in range(3):
+        pushed = apply_with_aux(
+            model, params, TOKENS, next_token=module,
+            router_bias=bias.at[row, 7].set(10.0))[1]["tokens_per_expert"]
+        assert int(pushed[row, 7]) == 2 * 16
+        if row == 0:  # later layers see another input; the first does not
+            np.testing.assert_array_equal(
+                np.delete(pushed[0], 7).sum(), 2 * 16 * 3)
+        else:
+            np.testing.assert_array_equal(pushed[:row],
+                                          aux["tokens_per_expert"][:row])
+
+
+def test_the_module_predicts_the_token_after_next_from_shared_weights():
+    """``h' = W_eh [norm(Emb(t_{i+1})) ; norm(z_i)]`` through one block
+    and the model's own head; the embedding and the head get gradients
+    from both losses."""
+    model, module, params = model_and_module()
+
+    def losses(p):
+        logits, aux = apply_with_aux(model, p, TOKENS, next_token=module)
+        return (lm_loss(logits, TOKENS),
+                lm_loss(aux["next_token_logits"], jnp.roll(TOKENS, -1, -1)))
+
+    main, second = (jax.grad(lambda p, i=i: losses(p)[i])(params)
+                    for i in range(2))
+    assert not np.any(np.asarray(main["next_token"]["eh_proj"]["kernel"]))
+    for grads in (main, second):
+        for leaf in (grads["embed"]["embedding"],
+                     grads["lm_head"]["kernel"],
+                     grads["block_0"]["mlp"]["gate"]["kernel"]):
+            assert np.any(np.asarray(leaf))
+    # by hand, from the model's hidden state
+    _, hidden = model.apply({"params": params}, TOKENS, return_hidden=True)
+    m = params["next_token"]
+    norm = lambda u, w: RMSNorm().apply({"params": w}, u)
+    following = params["embed"]["embedding"][jnp.roll(TOKENS, -1, -1)]
+    x = jnp.concatenate([norm(following, m["enorm"]),
+                         norm(hidden, m["hnorm"])], -1) @ m["eh_proj"][
+                             "kernel"]
+    from horovod_tpu.models.transformer import Block
+
+    x = Block(module.cfg).apply({"params": m["block"]}, x)
+    want = norm(x, m["ln_f"]) @ params["lm_head"]["kernel"]
+    got = apply_with_aux(model, params, TOKENS,
+                         next_token=module)[1]["next_token_logits"]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_sparse_spec_trains_in_bfloat16_under_remat():
+    model, module, params = model_and_module(dtype=jnp.bfloat16, remat=True)
+
+    def loss(p):
+        logits, aux = apply_with_aux(
+            model, p, TOKENS, router_bias=jnp.zeros((3, 16)),
+            next_token=module)
+        return lm_loss(logits, TOKENS) + 0.3 * lm_loss(
+            aux["next_token_logits"], jnp.roll(TOKENS, -1, -1))
 
     value, grads = jax.jit(jax.value_and_grad(loss))(params)
     assert np.isfinite(float(value))
