@@ -23,9 +23,10 @@ sigmoid, choose through a balancing bias that never enters a weight
 (:func:`balance_bias` moves it), renormalise the k weights and scale
 them.  With ``held=(first, count)`` the layer routes over all experts
 and computes the part of the result that the ``count`` experts it holds
-give: one chip's share under expert parallelism, still dropless.  The
-exchange of rows between chips is not here yet: every token the layer
-is given is one of this chip's.
+give: one chip's share under expert parallelism, still dropless: the
+buffer has the rows of the proven bound and its passes run over the
+rows that exist.  The exchange of rows between chips is not here yet:
+every token the layer is given is one of this chip's.
 """
 
 import functools
@@ -181,51 +182,213 @@ def init_moe_params(rng, d_model, d_ff, n_experts, dtype=jnp.float32,
 
 
 # ------------------------------------------------ dropless top-k routing
-def _rows_of_slots(y, inverse, partial):
-    """Row ``inverse[s]`` of ``y`` for every token-slot ``s``.  Where
-    ``y`` holds every slot's row ``inverse`` is a permutation and this
-    is a plain gather.  Where it holds a device's share (``partial``) a
-    slot whose row is not among the computed ones points past ``y`` and
-    reads zeros."""
-    if partial:
-        return y.at[inverse].get(mode="fill", fill_value=0)
-    return y[inverse]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _dispatch(x, order, inverse, k, partial=False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k):
     """Rows of ``x [N, D]`` in slot order: row ``i`` is token
-    ``order[i] // k``.  ``order`` holds the first M token-slots of a
-    permutation of all ``N * k`` and ``inverse`` says where a slot's
-    row is, so the backward pass is a gather by ``inverse`` and a sum
-    over a token's ``k`` slots, not the scatter-add that transposing
-    the gather would give."""
+    ``order[i] // k``.  ``order`` is a permutation of the ``N * k``
+    token-slots and ``inverse`` says where a slot's row is, so the
+    backward pass is a gather by ``inverse`` and a sum over a token's
+    ``k`` slots, not the scatter-add that transposing the gather would
+    give."""
     return x[order // k]
 
 
-def _dispatch_fwd(x, order, inverse, k, partial):
+def _dispatch_fwd(x, order, inverse, k):
     return x[order // k], inverse
 
 
-def _dispatch_bwd(k, partial, inverse, g):
-    rows = _rows_of_slots(g, inverse, partial)
-    return rows.reshape(-1, k, g.shape[-1]).sum(1), None, None
+def _dispatch_bwd(k, inverse, g):
+    return g[inverse].reshape(-1, k, g.shape[-1]).sum(1), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _unsort(y, order, inverse, partial=False):
+@jax.custom_vjp
+def _unsort(y, order, inverse):
     """Slot-ordered rows back in token order, ``[N * k, D]``; backward
     is the gather by ``order``."""
-    return _rows_of_slots(y, inverse, partial)
+    return y[inverse]
 
 
-_unsort.defvjp(
-    lambda y, order, inverse, partial: (
-        _rows_of_slots(y, inverse, partial), order),
-    lambda partial, order, g: (g[order], None, None))
+_unsort.defvjp(lambda y, order, inverse: (y[inverse], order),
+               lambda order, g: (g[order], None, None))
+
+
+# The experts held (``topk_moe(held=)``): the buffer has the rows of the
+# proven bound, ``H`` of them exist, and ``H`` is a value of the step.
+# Every pass over the buffer is a loop over chunks of rows with the trip
+# count ``ceil(H / chunk)``, so it costs what the rows that exist cost.
+# The loops sit inside hand-written ``custom_vjp`` rules: nothing
+# differentiates through them.  A chunk is this many bytes of rows (swept
+# on the v5e, PERF.md section 6, PR 32).
+_CHUNK_BYTES = 4 * 2 ** 20
+
+
+def _chunks(m, d, dtype, h):
+    """``(rows a chunk, chunks that hold the first h of m rows)``."""
+    chunk = max(1, min(m, _CHUNK_BYTES // (d * jnp.dtype(dtype).itemsize)))
+    return chunk, (h + chunk - 1) // chunk
+
+
+def _chunk_at(c, chunk, m, h):
+    """Where chunk ``c`` starts, held inside the buffer (the last one
+    of a buffer that ``chunk`` does not divide overlaps the one before
+    it), and which of its rows are its own and exist."""
+    start = jnp.minimum(c * chunk, m - chunk)
+    at = start + jnp.arange(chunk)
+    return start, (at >= c * chunk) & (at < h)
+
+
+def _unwritten(after, shape, dtype):
+    """A buffer that nobody has written, there once ``after`` is: on
+    the TPU the result of a kernel that writes nothing (zeros cost a
+    pass over the whole bound, 0.82 ms for 131,072 rows of 2,048).
+    ``jax.lax.empty`` takes no operand, and the TPU compiler then puts
+    every such buffer of a step at the step's start: 20 x 512 MiB in the
+    JoyAI cell, which no longer fits the chip."""
+    if jax.default_backend() != "tpu":
+        return jnp.zeros(shape, dtype)
+    from jax.experimental import pallas as pl
+
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        lambda after_ref, out_ref: None,
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        in_specs=[anywhere], out_specs=anywhere, name="unwritten")(after)
+
+
+def _rows_of_tokens(x, order, k, h):
+    """``[M, D]`` whose row ``i < h`` is ``x[order[i] // k]``; the rows
+    from ``h`` on (no grouped product reads them) are what the buffer
+    was made with."""
+    m, d = order.shape[0], x.shape[-1]
+    chunk, trips = _chunks(m, d, x.dtype, h)
+
+    def body(c, rows):
+        start, _ = _chunk_at(c, chunk, m, h)
+        slots = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        return jax.lax.dynamic_update_slice(rows, x[slots // k], (start, 0))
+
+    return jax.lax.fori_loop(
+        0, trips, body, _unwritten(order, (m, d), x.dtype))
+
+
+def _tokens_of_rows(rows, order, k, h, n, weights=None):
+    """``[n, D]``: token ``t`` is the sum of the rows ``i < h`` with
+    ``order[i] // k == t``, each times ``weights.reshape(-1)[order[i]]``
+    where weights ``[n, k]`` are given; summed in float32, cast once.
+
+    Gathers alone (a scatter-add of rows costs the v5e eight times a
+    gather's time a row, PERF.md section 6, PR 32).  The held slots are
+    sorted once more, by token, so that a token's rows (at most ``M /
+    n``) lie side by side; a chunk at a time they are gathered in that
+    order and each is given the sum of the rows behind it that are its
+    token's, so the first row of a token holds the token's sum, and a
+    token takes its first row's."""
+    m, d = rows.shape
+    most = m // n
+    chunk, trips = _chunks(m, d, rows.dtype, h)
+    at = jnp.arange(m)
+    slots, src = jax.lax.sort(
+        (jnp.where(at < h, order, n * k), at), num_keys=1)
+    # a chunk reads the token before its first row and ``most - 1`` rows
+    # behind its last: no token there
+    tokens = jnp.pad(slots // k, (1, most - 1), constant_values=n)
+    slots, src = (jnp.pad(a, (0, most - 1)) for a in (slots, src))
+    span = chunk + most - 1
+
+    def body(c, carry):
+        sums, first_of = carry
+        start, live = _chunk_at(c, chunk, m, h)
+        token = jax.lax.dynamic_slice(tokens, (start,), (span + 1,))
+        before, token = token[:chunk], token[1:]
+        part = rows[jax.lax.dynamic_slice(src, (start,), (span,))].astype(
+            jnp.float32)
+        if weights is not None:
+            part = part * weights.reshape(-1)[
+                jax.lax.dynamic_slice(slots, (start,), (span,))][:, None]
+        total = part[:chunk]
+        for j in range(1, most):
+            total += jnp.where(
+                (token[j:j + chunk] == token[:chunk])[:, None],
+                part[j:j + chunk], 0)
+        sums = jax.lax.dynamic_update_slice(
+            sums, total.astype(rows.dtype), (start, 0))
+        first = live & (token[:chunk] != before)
+        first_of = first_of.at[jnp.where(first, token[:chunk], n)].set(
+            start + jnp.arange(chunk), mode="drop")
+        return sums, first_of
+
+    sums, first_of = jax.lax.fori_loop(0, trips, body, (
+        _unwritten(rows, (m, d), rows.dtype), jnp.full((n,), m, jnp.int32)))
+    return jnp.where((first_of < m)[:, None],
+                     sums[jnp.minimum(first_of, m - 1)], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_held(x, order, h, k):
+    """:func:`_dispatch` where ``order`` holds the held slots first,
+    ``h`` of them: the rows that exist, forward and backward."""
+    return _rows_of_tokens(x, order, k, h)
+
+
+def _dispatch_held_fwd(x, order, h, k):
+    # of x the backward pass needs the number of tokens alone
+    return _dispatch_held(x, order, h, k), (order, h, x[:, :0])
+
+
+def _dispatch_held_bwd(k, res, g):
+    order, h, x = res
+    return _tokens_of_rows(g, order, k, h, x.shape[0]), None, None
+
+
+_dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@jax.custom_vjp
+def _combine_held(y, weights, order, h):
+    """``out[t] = sum_j weights[t, j] * y[row of slot (t, j)]`` over the
+    held slots, accumulated in float32 and cast once: the un-sort and
+    the weighted sum in one pass over the ``h`` rows that exist, so
+    ``[N k, D]`` is never made."""
+    n, k = weights.shape
+    return _tokens_of_rows(y, order, k, h, n, weights)
+
+
+def _combine_held_fwd(y, weights, order, h):
+    return _combine_held(y, weights, order, h), (y, weights, order, h)
+
+
+def _combine_held_bwd(res, g):
+    """``g_y[i] = w_i * g[token of i]`` and ``g_w`` of slot ``order[i]``
+    ``= <g[token of i], y[i]>`` for ``i < h``, from one gather of
+    ``g``'s rows a chunk.  ``g_y`` is written over ``y``, a chunk's
+    rows once they are read: no second buffer of the bound."""
+    y, weights, order, h = res
+    (n, k), (m, d) = weights.shape, y.shape
+    chunk, trips = _chunks(m, d, y.dtype, h)
+
+    def body(c, carry):
+        g_y, g_w = carry
+        start, live = _chunk_at(c, chunk, m, h)
+        slots = jax.lax.dynamic_slice(order, (start,), (chunk,))
+        g_rows = g[slots // k].astype(jnp.float32)
+        y_rows = jax.lax.dynamic_slice(
+            g_y, (start, 0), (chunk, d)).astype(jnp.float32)
+        w_rows = weights.reshape(-1)[slots][:, None]
+        g_w = g_w.at[jnp.where(live, slots, n * k)].set(
+            jnp.sum(g_rows * y_rows, axis=-1), mode="drop")
+        g_y = jax.lax.dynamic_update_slice(
+            g_y, (w_rows * g_rows).astype(y.dtype), (start, 0))
+        return g_y, g_w
+
+    g_y, g_w = jax.lax.fori_loop(
+        0, trips, body, (y, jnp.zeros((n * k,), jnp.float32)))
+    return g_y, g_w.reshape(n, k).astype(weights.dtype), None, None
+
+
+_combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -316,7 +479,9 @@ def topk_moe(x, params, *, k, held=None, **route):
     (stable), their rows gathered, three grouped products run with
     group sizes = tokens per expert, and the result is un-sorted by the
     inverse permutation and summed over a token's slots: no capacity,
-    no token dropped, no scatter.  ``aux`` is :func:`topk_route`'s.
+    no token dropped, no scatter of rows.  ``aux`` is
+    :func:`topk_route`'s and ``held_rows`` (int32): the rows of the
+    buffer that exist.
 
     ``held=(first, count)``: this device holds the experts ``first ...
     first + count - 1`` of the router's ``E`` (``wg``, ``wi``, ``wo``
@@ -326,6 +491,10 @@ def topk_moe(x, params, *, k, held=None, **route):
     theirs to add.  Dropless whatever the router does: a token has at
     most ``min(k, count)`` held slots, so the held slots, sorted to the
     front, always fit the ``N * min(k, count)`` rows the buffer has.
+    Of those ``held_rows`` exist, a value of the step, and the passes
+    over the buffer (rows from tokens, tokens from rows, forward and
+    backward) run over them alone; the rows behind them are never
+    written and nothing reads them.
     """
     orig_shape = x.shape
     d = orig_shape[-1]
@@ -347,16 +516,14 @@ def topk_moe(x, params, *, k, held=None, **route):
             keys = jnp.where((keys >= first) & (keys < first + count),
                              keys - first, count)
             group_sizes = group_sizes[first:first + count]
+        aux = {**aux, "held_rows": jnp.sum(group_sizes)}
         order = jnp.argsort(keys, stable=True)
-        inverse = jnp.argsort(order)
-        if held is not None:
+        if held is None:
+            inverse = jnp.argsort(order)
+            rows = _dispatch(xt, order, inverse, k)
+        else:
             order = order[:n * min(k, count)]
-            # a grouped product leaves the rows outside its groups
-            # undefined, forward and backward: a slot that is not held
-            # reads zeros wherever its row would be read
-            inverse = jnp.where(inverse < jnp.sum(group_sizes), inverse,
-                                order.shape[0])
-        rows = _dispatch(xt, order, inverse, k, held is not None)
+            rows = _dispatch_held(xt, order, aux["held_rows"], k)
     with jax.named_scope("moe/experts"):
         wg, wi, wo = (params[name]["kernel"].astype(dtype)
                       for name in ("wg", "wi", "wo"))
@@ -364,6 +531,10 @@ def topk_moe(x, params, *, k, held=None, **route):
                   * grouped_matmul(rows, wi, group_sizes))
         y = grouped_matmul(hidden, wo, group_sizes)
     with jax.named_scope("moe/combine"):
-        y = _unsort(y, order, inverse, held is not None).reshape(n, k, d)
-        out = jnp.sum(y.astype(jnp.float32) * weights[..., None], axis=1)
-    return out.astype(dtype).reshape(orig_shape), aux
+        if held is None:
+            y = _unsort(y, order, inverse).reshape(n, k, d)
+            out = jnp.sum(y.astype(jnp.float32) * weights[..., None],
+                          axis=1).astype(dtype)
+        else:
+            out = _combine_held(y, weights, order, aux["held_rows"])
+    return out.reshape(orig_shape), aux

@@ -14,6 +14,7 @@ topology at once collide on libtpu's lock file.  Code that asks
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -289,12 +290,41 @@ def test_topk_moe_compiles_for_v5e_as_grouped_product_kernels(v5e):
                 if " scatter(" in line and "[131072,2048]" in line]
 
 
+def _outside_loop_bodies(text):
+    """The lines of an optimized HLO module that lie in no computation
+    a ``while`` names as its body or condition, nor in one called from
+    there (a fusion inside a loop body is its own computation)."""
+    computations, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+    called = {name: set(re.findall(
+        r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", "\n".join(lines)))
+        for name, lines in computations.items()}
+    inside = set()
+    todo = [m for lines in computations.values() for line in lines
+            if " while(" in line
+            for m in re.findall(r"(?:body|condition)=%?([\w.\-]+)", line)]
+    while todo:
+        name = todo.pop()
+        if name not in inside:
+            inside.add(name)
+            todo.extend(called.get(name, ()))
+    return [line for name, lines in computations.items()
+            if name not in inside for line in lines]
+
+
 @pytest.mark.parametrize("workload,grouped_products,kernels", [
     # nine grouped products, three flash kernels, softmax-xent's two
     ("olmoe_1b_7b-spmd-1chip", 9, 14),
     # five expert layers' grouped products, forward, recomputed and
-    # backward; six blocks' flash kernels; softmax-xent twice
-    ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * 9 + 6 * 3 + 4)],
+    # backward, and the four buffers a layer that nobody writes; six
+    # blocks' flash kernels; softmax-xent twice
+    ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * (9 + 4) + 6 * 3 + 4)],
     ids=["olmoe_1b_7b", "joyai_llm_flash"])
 def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
                                            kernels):
@@ -332,6 +362,22 @@ def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
     text = compiled.as_text()
     assert text.count("%ragged-dot-none") >= grouped_products
     assert text.count("tpu_custom_call") >= kernels
+    loops = [line for line in text.splitlines() if " while(" in line
+             and "/moe/" in line]
+    whole = [line for line in _outside_loop_bodies(text)
+             if " gather(" in line
+             and line.split(" = ", 1)[1].startswith(("bf16[131072,2048]",
+                                                     "f32[131072,2048]"))]
+    if workload.startswith("joyai"):
+        # the held experts' passes have the extent of the rows that
+        # exist: loops, and no gather of the whole buffer beside them
+        assert loops and not whole
+    else:
+        # every row exists: the three plain gathers, no loop, no scatter
+        assert whole and not loops
+        assert "bf16[131072,2048]" in text
+        assert not [line for line in text.splitlines()
+                    if " scatter(" in line and "[131072,2048]" in line]
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES

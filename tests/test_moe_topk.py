@@ -14,11 +14,13 @@ from horovod_tpu.parallel.moe import (init_moe_params, moe_param_shapes,
 N, D, F, E = 24, 16, 8, 6
 
 
-def per_token_loop(x, params, k):
+def per_token_loop(x, params, k, held=(0, E)):
     """One token at a time, one expert at a time: ``(out, load-balancing
-    term, z term, tokens per expert)``."""
+    term, z term, tokens per expert)``; of the chosen experts only the
+    ``held = (first, count)`` add to ``out``."""
     router, wg, wi, wo = (params[n]["kernel"]
                           for n in ("router", "wg", "wi", "wo"))
+    first, count = held
     outs, probs, lse, counts = [], [], [], jnp.zeros((E,), jnp.int32)
     for t in range(x.shape[0]):
         logits = x[t] @ router
@@ -27,8 +29,9 @@ def per_token_loop(x, params, k):
         out = jnp.zeros_like(x[t])
         for j in range(k):
             e = chosen[j]
-            out += p[e] * ((jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wi[e]))
-                           @ wo[e])
+            mine = (e >= first) & (e < first + count)
+            out += mine * p[e] * (
+                (jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wi[e])) @ wo[e])
             counts = counts.at[e].add(1)
         outs.append(out)
         probs.append(p)
@@ -175,12 +178,19 @@ def test_router_runs_in_float32_on_a_bfloat16_input():
     np.testing.assert_array_equal(got, want)
 
 
-def test_a_shifted_load_compiles_nothing_new():
-    """Static shapes, group sizes as data."""
+@pytest.mark.parametrize("held", [None, (0, 3)], ids=["all", "held"])
+def test_a_shifted_load_compiles_nothing_new(held):
+    """Static shapes; group sizes, and with ``held`` the rows that
+    exist (0 of them after the shift, the whole buffer before), as
+    data."""
     x, params, _ = inputs(7)
-    fn = jax.jit(lambda x, p: topk_moe(x, p, k=2))
+    fn = jax.jit(jax.grad(lambda x, p: jnp.sum(
+        topk_moe(x, p, k=2, held=held)[0]), (0, 1)))
+    if held is not None:
+        params = share(params, *held)
+    fn(jnp.abs(x), {**params, "router": skewed(params, (0, 1))["router"]})
     fn(x, params)
-    fn(jnp.abs(x), skewed(params, (0, 5)))
+    fn(jnp.abs(x), {**params, "router": skewed(params, (4, 5))["router"]})
     assert fn._cache_size() == 1
 
 
@@ -360,3 +370,75 @@ def test_no_slot_on_a_held_expert_gives_zeros_and_no_nan():
     grads = jax.grad(lambda x, p: jnp.vdot(fn(x, p), ct), (0, 1))(x, params)
     for leaf in jax.tree.leaves(grads):
         assert not np.any(np.asarray(leaf))
+
+
+CHUNK = 10  # rows a chunk in the test below; 48 rows in the buffer
+
+
+def flagged(x, params, held, h):
+    """``x`` and a router changed so that exactly the first ``h`` tokens
+    have one slot on a held expert (the first held one) and no other
+    token has any: the features are positive, the other held experts'
+    logits negative, the first one's large where the token is flagged
+    and 0 elsewhere, everyone else's positive."""
+    first, count = held
+    x = jnp.abs(x) + 0.1
+    x = x.at[:, 0].set(jnp.where(jnp.arange(N) < h, 5.0, 0.0))
+    kernel = jnp.abs(params["router"]["kernel"]).at[0].set(0.0)
+    kernel = kernel.at[:, first:first + count].set(-1.0)
+    kernel = kernel.at[:, first].set(0.0).at[0, first].set(4.0)
+    return x, {**params, "router": {"kernel": kernel}}
+
+
+@pytest.mark.parametrize("unwritten", [0.0, float("nan")],
+                         ids=["zeros", "nan"])
+@pytest.mark.parametrize("h", [0, 1, CHUNK, CHUNK + 1, 2 * N],
+                         ids=["none", "one", "a-chunk", "a-chunk-and-one",
+                              "the-bound"])
+def test_held_rows_of_any_extent_match_the_per_token_loop(h, unwritten,
+                                                          monkeypatch):
+    """The passes over the buffer run over ``H`` rows, a value of the
+    step: output, ``aux`` and every gradient leaf are the per-token
+    loop's at no row, one, exactly a chunk, a chunk and one, and the
+    bound (every slot on a held expert; the last chunk overlaps the one
+    before it), recomputed as the cell runs it.  What the rows that
+    nobody writes hold (anything, on the chip) reaches no result."""
+    from horovod_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_CHUNK_BYTES", CHUNK * D * 4)
+    monkeypatch.setattr(moe, "_unwritten", lambda after, shape, dtype:
+                        jnp.full(shape, unwritten, dtype))
+    held, k = (4, 2), 2
+    first, count = held
+    x, params, ct = inputs(15)
+    if h == 2 * N:
+        x, params = jnp.abs(x), skewed(params, (4, 5))
+    else:
+        x, params = flagged(x, params, held, h)
+
+    @jax.checkpoint
+    def layer(x, params):
+        out, aux = topk_moe(x, share(params, *held), k=k, held=held)
+        return (out, aux["load_balancing"], aux["router_z"]), aux
+
+    out, aux = jax.jit(layer)(x, params)
+    want = per_token_loop(x, params, k, held)
+    np.testing.assert_allclose(out[0], want[0], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(out[1], want[1], rtol=1e-6)
+    np.testing.assert_allclose(out[2], want[2], rtol=1e-6)
+    np.testing.assert_array_equal(aux["tokens_per_expert"], want[3])
+    assert aux["held_rows"].dtype == jnp.int32
+    assert int(aux["held_rows"]) == h == int(
+        aux["tokens_per_expert"][first:first + count].sum())
+
+    got = jax.jit(jax.grad(weighed(lambda x, p: layer(x, p)[0], ct),
+                           (0, 1)))(x, params)
+    want = jax.grad(weighed(
+        lambda x, p: per_token_loop(x, p, k, held)[:3], ct), (0, 1))(
+            x, params)
+    assert set(got[1]) == {"router", "wg", "wi", "wo"}
+    for name in got[1]:
+        np.testing.assert_allclose(
+            got[1][name]["kernel"], want[1][name]["kernel"], rtol=1e-4,
+            atol=1e-5, err_msg=name)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-5)
