@@ -18,4 +18,5 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     TransformerConfig,
     apply_with_aux,
     lm_loss,
+    looped_lm_loss,
 )

@@ -24,7 +24,11 @@ qk_norm=True, ffn="moe_topk")`` is OLMoE's; a block may also have
 ``attention=LatentAttention(...)``, ``positions="rope_pairs"`` and
 ``ffn=TopkExperts(scoring="sigmoid", ...)``, behind
 ``TransformerConfig.leading_dense`` dense SwiGLU layers and with a
-:class:`NextTokenModule` beside the model.
+:class:`NextTokenModule` beside the model; or
+``norm_placement="sandwich"`` (a norm after each branch too), in a
+stack that runs ``TransformerConfig.passes`` times with one set of
+weights and, with ``exit_gate``, gives every pass's exit to
+:func:`looped_lm_loss`.
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
@@ -40,6 +44,7 @@ from horovod_tpu.parallel.ring_attention import reference_attention
 
 
 NORMS = ("layer", "rms")
+NORM_PLACEMENTS = ("pre", "sandwich")
 POSITIONS = ("learned", "rope", "rope_pairs")
 ATTENTIONS = ("full",)
 FFNS = ("gelu", "swiglu", "moe_switch", "moe_topk")
@@ -80,12 +85,14 @@ class TopkExperts:
 class BlockSpec:
     """What a block is made of.  ``norm``: ``"layer"`` (LayerNorm with
     a bias, the fused kernel on TPU) or ``"rms"`` (RMSNorm, scale
-    only).  ``positions``: ``"learned"`` (a table added to the
-    embedding), ``"rope"`` (rotary in the rotate-half pairing, applied
-    to q and k before the attention function; no table) or
-    ``"rope_pairs"`` (rotary over the pairs ``(2i, 2i + 1)``).
-    ``qk_norm``: a norm of the block's kind over the whole q and k
-    projections, before the split into heads.  ``attention``:
+    only).  ``norm_placement``: ``"pre"`` (one norm before each branch,
+    ``x + f(norm(x))``) or ``"sandwich"`` (one before and one after,
+    ``x + norm(f(norm(x)))``: four norms a block).  ``positions``:
+    ``"learned"`` (a table added to the embedding), ``"rope"`` (rotary
+    in the rotate-half pairing, applied to q and k before the attention
+    function; no table) or ``"rope_pairs"`` (rotary over the pairs
+    ``(2i, 2i + 1)``).  ``qk_norm``: a norm of the block's kind over the
+    whole q and k projections, before the split into heads.  ``attention``:
     ``"full"`` (one fused q, k, v projection, heads of one width) or a
     :class:`LatentAttention`.  ``ffn``: ``"gelu"`` (dense
     up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x gate) * (x up)``
@@ -98,10 +105,12 @@ class BlockSpec:
     qk_norm: bool = False
     ffn: Union[str, TopkExperts] = "gelu"
     attention: Union[str, LatentAttention] = "full"
+    norm_placement: str = "pre"
 
     def __post_init__(self):
         for value, known, cls in (
                 (self.norm, NORMS, ()), (self.positions, POSITIONS, ()),
+                (self.norm_placement, NORM_PLACEMENTS, ()),
                 (self.ffn, FFNS, TopkExperts),
                 (self.attention, ATTENTIONS, LatentAttention)):
             if value not in known and not isinstance(value, cls):
@@ -141,6 +150,14 @@ class TransformerConfig:
     # lever for pushing per-chip batch (and usually MFU) once
     # activations, not weights, bound the batch size
     remat: bool = False
+    # the stack of blocks runs this many times with ONE set of weights,
+    # the final norm closing each pass: its output is that pass's exit
+    # and the next pass's input (1: every block once)
+    passes: int = 1
+    # one ``Linear(d_model, 1)`` over every pass's exit, shared by the
+    # passes; the model then returns the logits of EVERY exit, for
+    # :func:`looped_lm_loss`
+    exit_gate: bool = False
 
     def ffn_of(self, layer):
         """The feed-forward of block ``layer``: the per-layer pattern."""
@@ -409,14 +426,22 @@ class Block(nn.Module):
         """``router_bias [E]``: the balancing bias of this block's
         router, for a :class:`TopkExperts`."""
         cfg = self.cfg
+        sandwich = cfg.block.norm_placement == "sandwich"
         y = make_norm(cfg, "ln1")(x)
-        x = x + Attention(cfg, name="attn")(y.astype(cfg.dtype))
+        y = Attention(cfg, name="attn")(y.astype(cfg.dtype))
+        if sandwich:
+            y = make_norm(cfg, "ln1_post")(y)
+        x = x + y
         y = make_norm(cfg, "ln2")(x).astype(cfg.dtype)
         ffn = self.ffn or cfg.block.ffn
         if isinstance(ffn, TopkExperts):
-            return x + TopkMoeMlp(cfg, ffn, name="moe")(y, router_bias)
-        module, name = FEED_FORWARDS[ffn]
-        return x + module(cfg, name=name)(y)
+            y = TopkMoeMlp(cfg, ffn, name="moe")(y, router_bias)
+        else:
+            module, name = FEED_FORWARDS[ffn]
+            y = module(cfg, name=name)(y)
+        if sandwich:
+            y = make_norm(cfg, "ln2_post")(y)
+        return x + y
 
 
 def lm_loss(logits, tokens):
@@ -427,12 +452,51 @@ def lm_loss(logits, tokens):
     log-softmax; the logits walked in tiles sized by what fits VMEM,
     whatever the vocabulary divides by); the XLA/optax lowering
     elsewhere."""
-    labels = jnp.roll(tokens, -1, axis=-1)
+    return jnp.mean(_token_losses(logits, jnp.roll(tokens, -1, axis=-1)))
+
+
+def _token_losses(logits, labels):
+    """Per-token cross-entropy ``[...]`` in float32 of ``logits [...,
+    V]``: the fused kernel on TPU, the XLA lowering elsewhere."""
     if jax.default_backend() == "tpu":
         from horovod_tpu.ops.pallas.softmax_xent import softmax_xent
-        return jnp.mean(softmax_xent(logits, labels))
+        return softmax_xent(logits, labels)
     from horovod_tpu.ops.pallas.softmax_xent import softmax_xent_reference
-    return jnp.mean(softmax_xent_reference(logits, labels))
+    return softmax_xent_reference(logits, labels)
+
+
+def looped_lm_loss(logits, gate_logits, tokens, beta):
+    """The training loss of a model with an exit gate (a looped language
+    model, arXiv:2510.25741): ``(loss, aux)`` from the logits of every
+    exit ``[R, B, T, V]`` and the gate's logits ``[R, B, T]``.
+
+    With ``lambda^(r) = sigmoid(gate^(r))`` a token leaves at exit r
+    with probability ``p^(r) = lambda^(r) prod_{s<r} (1 - lambda^(s))``,
+    the last exit taking what is left (its own gate is not read), and
+
+        loss = mean_i [ sum_r p^(r)_i l^(r)_i - beta H(p_i) ]
+
+    with ``l^(r)_i`` the next-token cross-entropy of exit r at position
+    i (labels as :func:`lm_loss` has them) and ``H`` the entropy of the
+    exit distribution.  All in float32, the probabilities through their
+    logarithms.  ``aux``: ``exit_probability [R]`` (the mean of
+    ``p^(r)`` over the tokens), ``exit_losses [R]`` (the mean
+    cross-entropy of each exit) and ``exit_entropy``."""
+    with jax.named_scope("exit_loss"):
+        labels = jnp.broadcast_to(jnp.roll(tokens, -1, axis=-1),
+                                  gate_logits.shape)
+        losses = _token_losses(logits, labels)
+        gate = gate_logits.astype(jnp.float32)
+        log_stay = jax.nn.log_sigmoid(-gate)          # log(1 - lambda)
+        stayed = jnp.cumsum(log_stay, axis=0) - log_stay  # over s < r
+        log_p = stayed + jax.nn.log_sigmoid(gate).at[-1].set(0.0)
+        p = jnp.exp(log_p)
+        entropy = -jnp.sum(p * log_p, axis=0)
+        loss = jnp.mean(jnp.sum(p * losses, axis=0) - beta * entropy)
+    tokens_axes = tuple(range(1, p.ndim))
+    return loss, {"exit_probability": jnp.mean(p, axis=tokens_axes),
+                  "exit_losses": jnp.mean(losses, axis=tokens_axes),
+                  "exit_entropy": jnp.mean(entropy)}
 
 
 def _sown(state):
@@ -463,7 +527,10 @@ def apply_with_aux(model, params, tokens, *, router_bias=None,
     ``load_balancing`` and ``router_z`` (0 where no block has one),
     beside ``moe_layers`` (how many blocks were summed, for a mean) and
     the counter ``tokens_per_expert [layers, E]`` of the top-k blocks in
-    the order of the layers (``None`` without one).
+    the order of the layers (``None`` without one).  A model with an
+    exit gate returns the logits of every exit, and
+    ``aux["exit_gate_logits"] [R, B, T]`` is what
+    :func:`looped_lm_loss` takes beside them.
 
     ``router_bias [layers, E]``: the balancing biases of the
     :class:`TopkExperts` blocks, a row a block in the counter's order; the caller moves them after the step
@@ -493,6 +560,8 @@ def apply_with_aux(model, params, tokens, *, router_bias=None,
         for name, leaves in _sown(state).items():
             sown[name] += leaves
     counts = sown["moe_tokens_per_expert"]
+    if "exit_gate_logits" in state.get("intermediates", {}):
+        aux["exit_gate_logits"], = state["intermediates"]["exit_gate_logits"]
     aux.update(
         load_balancing=sum(sown["moe_aux_loss"], jnp.zeros((), jnp.float32)),
         router_z=sum(sown["moe_z_loss"], jnp.zeros((), jnp.float32)),
@@ -505,7 +574,16 @@ class Transformer(nn.Module):
     """Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (causal LM).
     ``router_bias [layers, E]``: see :func:`apply_with_aux`.  With
     ``return_hidden`` also the last block's output before the final
-    norm: ``(logits, hidden)``."""
+    norm: ``(logits, hidden)``.
+
+    With ``cfg.passes`` R > 1 the blocks are made once and the stack
+    runs R times, ``h^(r) = norm_f(block_N(... block_1(h^(r-1))))``: a
+    scan over the pass index with the N blocks in its body and the
+    parameters broadcast, so the parameter tree is the one-pass model's
+    and N block bodies are compiled, not R N.  With ``cfg.exit_gate``
+    the logits are those of EVERY exit through the one head, ``[R, B,
+    T, vocab]``, and the gate's logits ``[R, B, T]`` (float32) are sown
+    as ``exit_gate_logits`` for :func:`apply_with_aux`."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -518,17 +596,45 @@ class Transformer(nn.Module):
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
         block_cls = nn.remat(Block) if cfg.remat else Block
-        rows = 0  # blocks so far that read a row of router_bias
-        for i in range(cfg.n_layers):
-            ffn = cfg.ffn_of(i)
-            bias = None
-            if router_bias is not None and isinstance(ffn, TopkExperts):
-                bias, rows = router_bias[rows], rows + 1
-            x = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
-        hidden = x
-        x = make_norm(cfg, "ln_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          name="lm_head")(x.astype(cfg.dtype))
+
+        def one_pass(mdl, carry, _):
+            """The stack once, closed by the final norm; the carry is
+            ``(exit, hidden before the norm)``."""
+            x, _ = carry
+            rows = 0  # blocks so far that read a row of router_bias
+            for i in range(cfg.n_layers):
+                ffn = cfg.ffn_of(i)
+                bias = None
+                if router_bias is not None and isinstance(ffn, TopkExperts):
+                    bias, rows = router_bias[rows], rows + 1
+                x = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
+            out = make_norm(cfg, "ln_f")(x)
+            return (out, x), (out if cfg.exit_gate else None)
+
+        if cfg.passes == 1:
+            (x, hidden), exits = one_pass(self, (x, x), None)
+            exits = None if exits is None else exits[None]
+        else:
+            if any(cfg.ffn_of(i) not in ("gelu", "swiglu")
+                   for i in range(cfg.n_layers)):
+                raise ValueError(
+                    "passes > 1 over expert layers: what they sow is not "
+                    "carried out of the scan; nothing is built for it")
+            with jax.named_scope("loop"):
+                (x, hidden), exits = nn.scan(
+                    one_pass, variable_broadcast="params",
+                    split_rngs={"params": False},
+                    length=cfg.passes)(self, (x, x), None)
+        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                        name="lm_head")
+        if cfg.exit_gate:
+            with jax.named_scope("exit_gate"):
+                self.sow("intermediates", "exit_gate_logits", nn.Dense(
+                    1, dtype=jnp.float32, name="exit_gate")(exits)[..., 0])
+            with jax.named_scope("exit_head"):
+                logits = head(exits)
+        else:
+            logits = head(x.astype(cfg.dtype))
         return (logits, hidden) if return_hidden else logits
 
 

@@ -318,21 +318,19 @@ def _outside_loop_bodies(text):
             if name not in inside for line in lines]
 
 
-@pytest.mark.parametrize("workload,grouped_products,kernels", [
-    # nine grouped products, three flash kernels, softmax-xent's two
-    ("olmoe_1b_7b-spmd-1chip", 9, 14),
-    # five expert layers' grouped products, forward, recomputed and
-    # backward, and the four buffers a layer that nobody writes; six
-    # blocks' flash kernels; softmax-xent twice
-    ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * (9 + 4) + 6 * 3 + 4)],
-    ids=["olmoe_1b_7b", "joyai_llm_flash"])
-def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
-                                           kernels):
-    """A sparse cell as ``benchmark/run.py`` builds it (the ``spmd``
-    loop's own step over the family's loss) at published widths and the
-    cell's batch of 4 sequences of 4096: fits one chip."""
+@pytest.fixture(scope="module")
+def cell_step(v5e):
+    """``cell_step(workload, transformer=None)``: a cell's step as
+    ``benchmark/run.py`` builds it (the ``spmd`` loop's own step over
+    the family's loss) at published widths and the cell's own batch,
+    compiled for one described chip; with ``transformer``, the family is
+    handed that class for the program's ``Transformer``.  Each is
+    compiled once for the tests of this file."""
     import sys
 
+    import optax
+
+    import horovod_tpu.models
     from horovod_tpu.parallel import make_mesh
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -343,22 +341,53 @@ def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
         sys.path.pop(0)
     bench = load_by_path(os.path.join(repo, "benchmark", "run.py"),
                          "hvd_benchmark_run_chip_compile")
-    cell = bench.load_cell(repo, workload)
     mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
+    compiled = {}
 
-    import optax
+    def compile_step(workload, transformer=None):
+        if (workload, transformer) in compiled:
+            return compiled[workload, transformer]
+        cell = bench.load_cell(repo, workload)
+        opt, step = cell.loop.make_step(
+            cell, optax.adamw(**cell.job["optimizer"]["args"]), mesh)
+        params, extra = jax.eval_shape(
+            lambda key: cell.family.init(cell.config, cell.job, key),
+            jax.random.PRNGKey(0))
+        tokens = jax.ShapeDtypeStruct(
+            (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
+        was = horovod_tpu.models.Transformer
+        horovod_tpu.models.Transformer = transformer or was
+        try:
+            compiled[workload, transformer] = step.lower(
+                _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
+                _shaped(mesh, jax.eval_shape(opt.init, params), P()),
+                _shaped(mesh, tokens, P("hvd"))).compile()
+        finally:
+            horovod_tpu.models.Transformer = was
+        return compiled[workload, transformer]
 
-    opt, step = cell.loop.make_step(
-        cell, optax.adamw(**cell.job["optimizer"]["args"]), mesh)
-    params, extra = jax.eval_shape(
-        lambda key: cell.family.init(cell.config, cell.job, key),
-        jax.random.PRNGKey(0))
-    tokens = jax.ShapeDtypeStruct(
-        (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
-    compiled = step.lower(
-        _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
-        _shaped(mesh, jax.eval_shape(opt.init, params), P()),
-        _shaped(mesh, tokens, P("hvd"))).compile()
+    return compile_step
+
+
+def _fits_one_chip(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("workload,grouped_products,kernels", [
+    # nine grouped products, three flash kernels, softmax-xent's two
+    ("olmoe_1b_7b-spmd-1chip", 9, 14),
+    # five expert layers' grouped products, forward, recomputed and
+    # backward, and the four buffers a layer that nobody writes; six
+    # blocks' flash kernels; softmax-xent twice
+    ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * (9 + 4) + 6 * 3 + 4)],
+    ids=["olmoe_1b_7b", "joyai_llm_flash"])
+def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
+                                           grouped_products, kernels):
+    """A sparse cell at published widths and the cell's batch of 4
+    sequences of 4096: fits one chip."""
+    compiled = cell_step(workload)
     text = compiled.as_text()
     assert text.count("%ragged-dot-none") >= grouped_products
     assert text.count("tpu_custom_call") >= kernels
@@ -378,9 +407,67 @@ def test_sparse_cell_step_compiles_for_v5e(v5e, workload, grouped_products,
         assert "bf16[131072,2048]" in text
         assert not [line for line in text.splitlines()
                     if " scatter(" in line and "[131072,2048]" in line]
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+    assert _fits_one_chip(compiled)
+
+
+def test_looped_cell_step_compiles_for_v5e_as_one_set_of_block_bodies(
+        cell_step):
+    """``ouro_2_6b-spmd-1chip`` (8 blocks run 4 times, one sequence of
+    4096, every block application recomputed): the passes are a scan,
+    so the step holds the kernels of N = 8 block bodies, not of R N =
+    32: a flash forward a block in the forward loop; the recomputed
+    forward, dq and dk/dv a block in the backward loop; the loss
+    kernels once over all four exits."""
+    compiled = cell_step("ouro_2_6b-spmd-1chip")
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "[16,4096,128]" in line]
+    assert len(flash) == 8 * 4
+    assert text.count("tpu_custom_call") == 8 * 4 + 2
+    assert len([line for line in text.splitlines()
+                if " while(" in line]) == 2
+    inside = len(flash) - len([line for line in _outside_loop_bodies(text)
+                               if "tpu_custom_call" in line
+                               and "[16,4096,128]" in line])
+    assert inside == 8 * 4
+    # one head product over the 16,384 rows of the four exits
+    assert "bf16[16384,49152]" in text
+    assert _fits_one_chip(compiled)
+
+
+def _instructions(compiled):
+    """The optimized program's instructions without what names a source
+    line: metadata, the tables of files and functions ahead of the
+    computations, and the kernels' serialized bodies."""
+    out = []
+    for line in compiled.as_text().splitlines():
+        if not line.startswith((" ", "ENTRY", "%", "}", "ROOT")):
+            continue
+        line = re.sub(r", metadata=\{[^}]*\}", "", line)
+        if "tpu_custom_call" in line:
+            line = re.sub(r"backend_config=.*$", "", line)
+        out.append(line)
+    return out
+
+
+@pytest.mark.parametrize("workload", ["gpt2_medium-spmd-1chip",
+                                      "joyai_llm_flash-spmd-1chip"])
+def test_cells_without_passes_compile_to_the_step_from_before(cell_step,
+                                                              workload):
+    """With one pass, no sandwich norm and no gate the compiled step is
+    the one the model gave before it had them (``TransformerBefore``:
+    its ``__call__`` written out), instruction for instruction."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    try:
+        from test_transformer_looped import TransformerBefore
+    finally:
+        sys.path.pop(0)
+    now = _instructions(cell_step(workload))
+    before = _instructions(cell_step(workload, TransformerBefore))
+    assert len(now) > 2000 and len(now) == len(before)
+    assert now == before
 
 
 @pytest.mark.parametrize("chips,compression,hierarchical", [
