@@ -148,7 +148,10 @@ class TransformerConfig:
     # rematerialize each block's activations in backward (jax.checkpoint):
     # trades ~1/3 more FLOPs for O(layers) less activation HBM — the
     # lever for pushing per-chip batch (and usually MFU) once
-    # activations, not weights, bound the batch size
+    # activations, not weights, bound the batch size.  Saved of a block
+    # are its input and, where its attention is the flash kernel, the
+    # kernel's output and lse (``B H T (d_v x itemsize + 4)`` bytes a
+    # block), so the recomputation does not run that kernel again
     remat: bool = False
     # the stack of blocks runs this many times with ONE set of weights,
     # the final norm closing each pass: its output is that pass's exit
@@ -177,6 +180,17 @@ def default_attention():
         from horovod_tpu.ops.pallas.flash_attention import flash_attention
         return flash_attention
     return reference_attention
+
+
+def recomputed(block):
+    """``block`` under ``jax.checkpoint``: its forward pass runs again in
+    the backward pass, all but the flash forward kernel, whose output and
+    ``lse`` carry names (``flash_attention.SAVED_NAMES``) and are saved.
+    A block that does not run the kernel saves nothing, as under a plain
+    ``nn.remat``."""
+    from horovod_tpu.ops.pallas.flash_attention import SAVED_NAMES
+    return nn.remat(block, policy=jax.checkpoint_policies
+                    .save_only_these_names(*SAVED_NAMES))
 
 
 def rope(x, theta=10000.0, pairs=False):
@@ -595,7 +609,7 @@ class Transformer(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = recomputed(Block) if cfg.remat else Block
 
         def one_pass(mdl, carry, _):
             """The stack once, closed by the final norm; the carry is
@@ -660,7 +674,7 @@ class NextTokenModule(nn.Module):
              make_norm(cfg, "hnorm")(hidden)], axis=-1)
         x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                      name="eh_proj")(x)
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = recomputed(Block) if cfg.remat else Block
         x = block_cls(cfg, name="block")(x, router_bias)
         x = make_norm(cfg, "ln_f")(x).astype(cfg.dtype)
         return jnp.dot(x, head.astype(cfg.dtype))

@@ -41,6 +41,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 try:  # pltpu is importable on CPU too, but keep a guard for odd builds
@@ -467,6 +468,20 @@ _STATIC = ("scale", "causal", "block_q", "block_k", "interpret")
 _fwd_once = jax.jit(_fwd, static_argnames=_STATIC)
 _bwd_once = jax.jit(_bwd, static_argnames=_STATIC)
 
+# What the forward kernel hands the backward kernels carries these
+# names.  Outside ``jax.checkpoint`` a name is the identity; a checkpoint
+# whose policy is ``save_only_these_names(*SAVED_NAMES)`` keeps the two
+# arrays, and the recomputation then has no use for the forward kernel:
+# it is dead code and the compiler drops it.
+SAVED_OUT = "flash_attention_out"
+SAVED_LSE = "flash_attention_lse"
+SAVED_NAMES = (SAVED_OUT, SAVED_LSE)
+
+
+def _fwd_named(q3, k3, v3, **static):
+    out, lse = _fwd_once(q3, k3, v3, **static)
+    return checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret):
@@ -476,9 +491,9 @@ def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret)
+    out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret)
     return out, (q3, k3, v3, out, lse)
 
 
@@ -499,9 +514,9 @@ def _flash_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret):
 
 
 def _flash_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret)
+    out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret)
     return (out, lse), (q3, k3, v3, out, lse)
 
 
@@ -536,6 +551,15 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     ``return_lse=True`` additionally returns the logsumexp ``[B, H, T]``
     (differentiable), which lets callers combine partial attention
     results streaming-softmax style (ring attention's per-block use).
+
+    Under ``jax.checkpoint`` the forward kernel runs again in the
+    recomputation unless the checkpoint's policy saves its two results,
+    which carry the names ``SAVED_NAMES``
+    (``save_only_these_names(*SAVED_NAMES)``, as a recomputed block of
+    ``models/transformer.py`` has it): ``out [B H, T, d_v]`` in the input
+    dtype and ``lse [B H, T]`` in float32, ``B H T (d_v x itemsize + 4)``
+    bytes a call, are then kept from the forward pass and the backward
+    kernels read them.  Outside a checkpoint the names do nothing.
     """
     b, t, h, d_qk = q.shape
     t_kv, d_v = k.shape[1], v.shape[3]

@@ -13,6 +13,7 @@ topology at once collide on libtpu's lock file.  Code that asks
 "tpu" themselves; the program has no option for that.
 """
 
+import importlib
 import os
 import re
 
@@ -344,9 +345,12 @@ def cell_step(v5e):
     mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
     compiled = {}
 
-    def compile_step(workload, transformer=None):
-        if (workload, transformer) in compiled:
-            return compiled[workload, transformer]
+    def compile_step(workload, transformer=None, variant=None):
+        """``variant`` names what the caller changed around the call: a
+        step of its own in the cache."""
+        key = (workload, transformer, variant)
+        if key in compiled:
+            return compiled[key]
         cell = bench.load_cell(repo, workload)
         opt, step = cell.loop.make_step(
             cell, optax.adamw(**cell.job["optimizer"]["args"]), mesh)
@@ -358,13 +362,13 @@ def cell_step(v5e):
         was = horovod_tpu.models.Transformer
         horovod_tpu.models.Transformer = transformer or was
         try:
-            compiled[workload, transformer] = step.lower(
+            compiled[key] = step.lower(
                 _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
                 _shaped(mesh, jax.eval_shape(opt.init, params), P()),
                 _shaped(mesh, tokens, P("hvd"))).compile()
         finally:
             horovod_tpu.models.Transformer = was
-        return compiled[workload, transformer]
+        return compiled[key]
 
     return compile_step
 
@@ -380,7 +384,7 @@ def _fits_one_chip(compiled):
     ("olmoe_1b_7b-spmd-1chip", 9, 14),
     # five expert layers' grouped products, forward, recomputed and
     # backward, and the four buffers a layer that nobody writes; six
-    # blocks' flash kernels; softmax-xent twice
+    # blocks' flash kernels, each once; softmax-xent twice
     ("joyai_llm_flash-spmd-1chip", 5 * 9, 5 * (9 + 4) + 6 * 3 + 4)],
     ids=["olmoe_1b_7b", "joyai_llm_flash"])
 def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
@@ -401,6 +405,11 @@ def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
         # the held experts' passes have the extent of the rows that
         # exist: loops, and no gather of the whole buffer beside them
         assert loops and not whole
+        # a recomputed block does not run its forward kernel again:
+        # forward, dq and dk/dv once a block
+        assert len([line for line in text.splitlines()
+                    if "tpu_custom_call" in line
+                    and "[128,4096,192]" in line]) == 6 * 3
     else:
         # every row exists: the three plain gathers, no loop, no scatter
         assert whole and not loops
@@ -415,21 +424,24 @@ def test_looped_cell_step_compiles_for_v5e_as_one_set_of_block_bodies(
     """``ouro_2_6b-spmd-1chip`` (8 blocks run 4 times, one sequence of
     4096, every block application recomputed): the passes are a scan,
     so the step holds the kernels of N = 8 block bodies, not of R N =
-    32: a flash forward a block in the forward loop; the recomputed
-    forward, dq and dk/dv a block in the backward loop; the loss
-    kernels once over all four exits."""
+    32: a flash forward a block in the forward loop; dq and dk/dv a
+    block in the backward loop and NO forward kernel there (its output
+    and lse are saved, stacked over the passes); the loss kernels once
+    over all four exits."""
     compiled = cell_step("ouro_2_6b-spmd-1chip")
     text = compiled.as_text()
     flash = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "[16,4096,128]" in line]
-    assert len(flash) == 8 * 4
-    assert text.count("tpu_custom_call") == 8 * 4 + 2
+    assert len(flash) == 8 * 3
+    assert text.count("tpu_custom_call") == 8 * 3 + 2
     assert len([line for line in text.splitlines()
                 if " while(" in line]) == 2
     inside = len(flash) - len([line for line in _outside_loop_bodies(text)
                                if "tpu_custom_call" in line
                                and "[16,4096,128]" in line])
-    assert inside == 8 * 4
+    assert inside == 8 * 3
+    # what the backward loop reads in the recomputed kernel's place
+    assert "bf16[4,16,4096,128]" in text and "f32[4,16,4096]" in text
     # one head product over the 16,384 rows of the four exits
     assert "bf16[16384,49152]" in text
     assert _fits_one_chip(compiled)
@@ -468,6 +480,22 @@ def test_cells_without_passes_compile_to_the_step_from_before(cell_step,
     before = _instructions(cell_step(workload, TransformerBefore))
     assert len(now) > 2000 and len(now) == len(before)
     assert now == before
+
+
+@pytest.mark.parametrize("workload", ["gpt2_medium-spmd-1chip",
+                                      "olmoe_1b_7b-spmd-1chip"])
+def test_saved_names_are_nothing_in_a_step_without_recomputation(
+        cell_step, workload, monkeypatch):
+    """The flash forward rule names its output and lse for the policy
+    of a recomputed block; a step that recomputes nothing compiles to
+    the step without the names, instruction for instruction."""
+    named = _instructions(cell_step(workload))
+    # (the package's attribute of this name is the function)
+    monkeypatch.setattr(
+        importlib.import_module("horovod_tpu.ops.pallas.flash_attention"),
+        "checkpoint_name", lambda x, name: x)
+    unnamed = _instructions(cell_step(workload, variant="unnamed"))
+    assert len(named) > 2000 and named == unnamed
 
 
 @pytest.mark.parametrize("chips,compression,hierarchical", [

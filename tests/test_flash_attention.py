@@ -5,12 +5,15 @@ kernels of its own; this framework does).  Same test pattern as the rest:
 random tensors, numpy-level expectation, gradients via autograd.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from horovod_tpu.ops.pallas import flash_attention
+from horovod_tpu.ops.pallas.flash_attention import SAVED_NAMES
 from horovod_tpu.parallel import reference_attention
 
 
@@ -639,3 +642,61 @@ def test_flash_two_widths_lse_and_its_gradient():
     for got, want in zip(jax.grad(weighed(flash), (0, 1, 2))(q, k, v),
                          jax.grad(weighed(dense), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+# ------------------- under jax.checkpoint: the kernel's results are named
+def _checkpointed_blocks(policy, return_lse, n=4):
+    """``loss(q, k, v)`` through n ``jax.checkpoint``-ed layers of
+    attention at 192 / 128 (with ``return_lse`` the lse is used too)."""
+    def block(x, k, v):
+        if return_lse:
+            out, lse = flash_attention(x, k, v, causal=True,
+                                       return_lse=True)
+            out = out * jnp.tanh(lse).transpose(0, 2, 1)[..., None]
+        else:
+            out = flash_attention(x, k, v, causal=True)
+        return x + jnp.pad(out, ((0, 0),) * 3 + ((0, 64),))
+
+    block = jax.checkpoint(block, policy=policy)
+
+    def loss(q, k, v):
+        for _ in range(n):
+            q = block(q, k, v)
+        return jnp.sum(q ** 2)
+
+    return loss
+
+
+SAVES_THE_NAMES = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
+
+@pytest.mark.parametrize("return_lse", [False, True], ids=["out", "lse"])
+def test_flash_under_a_checkpoint_that_saves_its_names_runs_once(return_lse):
+    """Four checkpointed layers: the forward kernel four times and the
+    backward kernels four times where the checkpoint's policy saves the
+    two names; eight forward kernels under a plain checkpoint, which
+    the names do not change."""
+    q, k, v = _rand_two_widths(192, 128, t=64)
+
+    def calls(policy):
+        text = jax.jit(jax.value_and_grad(_checkpointed_blocks(
+            policy, return_lse), (0, 1, 2))).lower(q, k, v).as_text()
+        return (len(re.findall(r"call @_fwd(_\d+)?\(", text)),
+                len(re.findall(r"call @_bwd(_\d+)?\(", text)))
+
+    assert calls(None) == (8, 4)
+    assert calls(SAVES_THE_NAMES) == (4, 4)
+    # a policy that saves other names saves nothing of the kernel's
+    assert calls(jax.checkpoint_policies.save_only_these_names(
+        "something_else")) == (8, 4)
+
+
+@pytest.mark.parametrize("return_lse", [False, True], ids=["out", "lse"])
+def test_flash_saved_results_are_the_recomputed_ones_bit_for_bit(return_lse):
+    q, k, v = _rand_two_widths(192, 128, t=64)
+    got, want = (jax.jit(jax.value_and_grad(_checkpointed_blocks(
+        policy, return_lse), (0, 1, 2)))(q, k, v)
+        for policy in (SAVES_THE_NAMES, None))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a))) > 0
+        np.testing.assert_array_equal(a, b)

@@ -6,16 +6,22 @@ pass, the exit gate, every pass's exit through the one head, and
 sandwich norm and no gate the model is the one from before them, bit
 for bit."""
 
+import re
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src.ad_checkpoint import saved_residuals
 
 from horovod_tpu.models import (BlockSpec, LatentAttention, TopkExperts,
                                 Transformer, TransformerConfig,
-                                apply_with_aux, lm_loss, looped_lm_loss)
-from horovod_tpu.models.transformer import Block, make_norm
+                                apply_with_aux, lm_loss, looped_lm_loss,
+                                transformer)
+from horovod_tpu.models.transformer import Block, make_norm, recomputed
+from horovod_tpu.ops.pallas import flash_attention
+from horovod_tpu.parallel import reference_attention
 
 SANDWICH = BlockSpec(norm="rms", positions="rope", ffn="swiglu",
                      norm_placement="sandwich")
@@ -77,7 +83,8 @@ def test_sandwich_gated_tree_is_the_same_whatever_the_passes(passes):
 class TransformerBefore(nn.Module):
     """``Transformer.__call__`` as it stood before the passes, the
     sandwich norm and the gate, written out: what the cells that have
-    none of them ran."""
+    none of them ran (a recomputed block saves what the model's does:
+    ``recomputed``)."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -89,7 +96,7 @@ class TransformerBefore(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = nn.remat(Block) if cfg.remat else Block
+        block_cls = recomputed(Block) if cfg.remat else Block
         rows = 0
         for i in range(cfg.n_layers):
             ffn = cfg.ffn_of(i)
@@ -311,3 +318,102 @@ def test_looped_spec_trains_in_bfloat16_under_remat():
                                rtol=1e-5)
     forward = jax.jit(lambda p: loss_of(cfg, p)[0]).lower(params).as_text()
     assert forward.count("stablehlo.while") == 1
+
+
+# ------------- what a recomputed block saves: the flash kernel's results
+RECOMPUTED = {
+    "plain": dict(n_layers=3),
+    # score heads of 128 + 64 rotated = 192, value heads of 128
+    "latent": dict(
+        n_heads=2,
+        block=BlockSpec(norm="rms", positions="rope_pairs", ffn="swiglu",
+                        attention=LatentAttention(
+                            q_rank=24, kv_rank=16, nope_dim=128,
+                            rope_dim=64, v_dim=128))),
+    "looped": dict(block=SANDWICH, passes=4, exit_gate=True),
+}
+
+
+def recomputed_loss(kind, attn_fn=flash_attention):
+    """``(cfg, params, loss)`` of a spec of ``RECOMPUTED`` with every
+    block recomputed, its attention ``attn_fn``."""
+    cfg = TransformerConfig(**{**SIZES, **RECOMPUTED[kind]}, remat=True,
+                            attn_fn=attn_fn)
+    params = seeded(cfg)
+
+    def loss(p):
+        if cfg.exit_gate:
+            return loss_of(cfg, p)[0]
+        return lm_loss(Transformer(cfg).apply({"params": p}, TOKENS), TOKENS)
+
+    return cfg, params, loss
+
+
+def kernel_calls(loss, params):
+    """Call sites of the flash forward kernel's and of the backward
+    kernels' ``jit`` in the lowered value-and-gradient."""
+    text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
+    return (len(re.findall(r"call @_fwd(_\d+)?\(", text)),
+            len(re.findall(r"call @_bwd(_\d+)?\(", text)))
+
+
+@pytest.mark.parametrize("kind", sorted(RECOMPUTED))
+def test_a_recomputed_block_runs_each_flash_kernel_once(kind, monkeypatch):
+    """N recomputed blocks (under the scan over the passes: N bodies)
+    call the forward kernel N times and the two backward kernels N
+    times; under a plain ``nn.remat`` the forward kernel runs again in
+    every recomputation, 2 N."""
+    cfg, params, loss = recomputed_loss(kind)
+    n = cfg.n_layers
+    assert kernel_calls(loss, params) == (n, n)
+    monkeypatch.setattr(transformer, "recomputed", nn.remat)
+    assert kernel_calls(loss, params) == (2 * n, n)
+
+
+@pytest.mark.parametrize("kind", sorted(RECOMPUTED))
+def test_saving_the_kernels_results_changes_no_bit(kind, monkeypatch):
+    """The saved output and lse are what the recomputation would have
+    produced: the loss and every gradient leaf are bitwise those of a
+    plain ``nn.remat``.  (In float32: in bfloat16 the CPU's compiler
+    keeps float32 between two instructions where it can, which two
+    programs do in different places.)"""
+    _, params, loss = recomputed_loss(kind)
+    got = jax.jit(jax.value_and_grad(loss))(params)
+    monkeypatch.setattr(transformer, "recomputed", nn.remat)
+    want = jax.jit(jax.value_and_grad(loss))(params)
+    assert float(jnp.max(jnp.abs(got[1]["block_0"]["attn"]["out"]["kernel"]
+                                 ))) > 0
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+
+
+def saved(loss, params):
+    """What the backward pass is handed that is no argument, as sorted
+    ``(shape, dtype)``."""
+    return sorted((aval.shape, str(aval.dtype)) for aval, why
+                  in saved_residuals(loss, params)
+                  if "from the argument" not in why)
+
+
+@pytest.mark.parametrize("kind", sorted(RECOMPUTED))
+def test_a_recomputed_block_saves_what_its_attention_names(kind,
+                                                           monkeypatch):
+    """Through the flash kernel a block saves ``out [B H, T, d_v]`` in
+    the activation type and ``lse [B H, T]`` in float32 (stacked over
+    the passes under the scan) and nothing else more than a plain
+    ``nn.remat``; on the dense attention, which names nothing, it saves
+    no more than a plain ``nn.remat``."""
+    cfg, params, flash = recomputed_loss(kind)
+    _, _, dense = recomputed_loss(kind, reference_attention)
+    named, unnamed = saved(flash, params), saved(dense, params)
+    monkeypatch.setattr(transformer, "recomputed", nn.remat)
+    assert unnamed == saved(dense, params)
+    plain = saved(flash, params)
+    bh, t = TOKENS.shape[0] * cfg.n_heads, TOKENS.shape[1]
+    d_v = (cfg.block.attention.v_dim if kind == "latent"
+           else cfg.d_model // cfg.n_heads)
+    stack = (cfg.passes,) if cfg.passes > 1 else ()
+    more = [(stack + (bh, t, d_v), str(jnp.dtype(cfg.dtype))),
+            (stack + (bh, t), "float32")] * cfg.n_layers
+    assert named == sorted(plain + more)
