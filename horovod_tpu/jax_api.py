@@ -130,6 +130,18 @@ def allreduce_gradients(grads, named_axes=("hvd",), op=Average,
     return jax.tree.map(reduce_leaf, grads)
 
 
+def _scoped(name, transform):
+    """``transform`` with its update under ``jax.named_scope(name)``: the
+    compiled step's instructions then say whose they are
+    (``utils/trace.py:step_phases``); a scope changes no instruction."""
+
+    def update_fn(updates, state, params=None, **extra_args):
+        with jax.named_scope(name):
+            return transform.update(updates, state, params, **extra_args)
+
+    return optax.GradientTransformationExtraArgs(transform.init, update_fn)
+
+
 def DistributedOptimizer(optimizer, named_axes=("hvd",), op=Average,
                          compression=Compression.none,
                          backward_passes_per_step=1,
@@ -153,18 +165,22 @@ def DistributedOptimizer(optimizer, named_axes=("hvd",), op=Average,
         del params
         reduced = grads
         if named_axes:
-            reduced = allreduce_gradients(
-                grads, named_axes=named_axes, op=op, compression=compression)
+            with jax.named_scope("hvd/exchange"):
+                reduced = allreduce_gradients(
+                    grads, named_axes=named_axes, op=op,
+                    compression=compression)
         return reduced, state
 
     reduce_transform = optax.GradientTransformation(init_fn, update_fn)
-    chained = optax.chain(reduce_transform, optimizer)
+    chained = optax.chain(reduce_transform, _scoped("hvd/update", optimizer))
     if backward_passes_per_step > 1:
         if not average_aggregated_gradients:
             k = float(backward_passes_per_step)
             chained = optax.chain(optax.scale(k), chained)
-        chained = optax.MultiSteps(
-            chained, every_k_schedule=backward_passes_per_step)
+        # the accumulation is the update's too, the exchange inside it
+        # keeps its own name
+        chained = _scoped("hvd/update", optax.MultiSteps(
+            chained, every_k_schedule=backward_passes_per_step))
     return chained
 
 

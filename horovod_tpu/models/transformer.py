@@ -466,7 +466,9 @@ def lm_loss(logits, tokens):
     log-softmax; the logits walked in tiles sized by what fits VMEM,
     whatever the vocabulary divides by); the XLA/optax lowering
     elsewhere."""
-    return jnp.mean(_token_losses(logits, jnp.roll(tokens, -1, axis=-1)))
+    with jax.named_scope("loss"):
+        return jnp.mean(_token_losses(logits,
+                                      jnp.roll(tokens, -1, axis=-1)))
 
 
 def _token_losses(logits, labels):
@@ -496,7 +498,7 @@ def looped_lm_loss(logits, gate_logits, tokens, beta):
     logarithms.  ``aux``: ``exit_probability [R]`` (the mean of
     ``p^(r)`` over the tokens), ``exit_losses [R]`` (the mean
     cross-entropy of each exit) and ``exit_entropy``."""
-    with jax.named_scope("exit_loss"):
+    with jax.named_scope("loss"), jax.named_scope("exit_loss"):
         labels = jnp.broadcast_to(jnp.roll(tokens, -1, axis=-1),
                                   gate_logits.shape)
         losses = _token_losses(logits, labels)

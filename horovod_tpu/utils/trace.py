@@ -1,6 +1,10 @@
-"""The eager plane's request lifecycle, recorded where the work happens.
+"""What the program records about itself: the eager plane's request
+lifecycle where the work happens, and the compiled SPMD step by phase
+and scope from the names its own instructions carry.
 
-Two instruments, both always on (docs/observability.md):
+The eager plane has two instruments, both always on
+(docs/observability.md); the SPMD step's is at the end of this module
+(:func:`step_phases`):
 
 - **Spans on the profiler's clock.**  ``with trace.span("hvd.launch"):``
   is ``jax.profiler.TraceAnnotation`` itself.  Outside a ``jax.profiler``
@@ -38,6 +42,7 @@ Two instruments, both always on (docs/observability.md):
 
 import collections
 import itertools
+import re
 import time
 
 import jax
@@ -100,3 +105,176 @@ def eager_stats():
             queue_wait_us=sum(r[4] - r[3] for r in records) / n / 1e3,
             execute_us=sum(responses.values()) / len(responses) / 1e3)
     return stats
+
+
+# ----------------------------------------------- the compiled SPMD step
+PHASES = ("forward", "recompute", "backward", "exchange", "update",
+          "unnamed")
+_HEAD = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
+# the computations an instruction runs as its own events; a reducer
+# (``to_apply``) never shows as one
+_CALLED = re.compile(r"\b(?:calls|body|condition|branch_computations|"
+                     r"true_computation|false_computation)="
+                     r"(\{[^}]*\}|%?[\w.\-]+)")
+# components of a name that say how JAX got there, not where it is
+_HOW = re.compile(r"^(while|body|cond|checkpoint|rematted_computation|"
+                  r"closed_call|shard_map|pjit|branch_\d+_fun)$")
+_MODULE = re.compile(r"^[A-Za-z_]\w*$")
+# opcode and operands: the first lower-case word that opens a bracket
+# (a layout's ``T(8,128)`` and ``S(1)`` are capitals)
+_RAN = re.compile(r"(?:^| )([a-z][\w\-]*)\(([^)]*)\)")
+
+
+def phase_of(op_name):
+    """The phase of an instruction by its ``op_name`` (with its loop's
+    ahead of it, see :func:`step_phases`).  In this order:
+
+    1. ``rematted_computation`` in the name: **recompute** (a forward
+       pass run again inside the backward pass, ``jax.checkpoint``);
+    2. ``transpose(``: **backward**;
+    3. ``jvp(``: **forward** (``jax.value_and_grad`` traces the loss
+       under ``jvp`` and transposes it: JAX writes both markers itself);
+    4. ``hvd/exchange``: **exchange** (the gradient reduction of
+       ``DistributedOptimizer``);
+    5. any other name with a path (``hvd/update/...``, and what the
+       caller's step does around its gradient: ``apply_updates``, the
+       ``pmean`` of the loss): **update**;
+    6. no name, an argument's label (``params[...]``) or a bare
+       primitive (``reduce_sum``): **unnamed** (:func:`step_phases`
+       then reads it as its neighbours).
+    """
+    if "rematted_computation" in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    if "jvp(" in op_name:
+        return "forward"
+    if "hvd/exchange" in op_name:
+        return "exchange"
+    return "update" if "/" in op_name else "unnamed"
+
+
+def scope_of(op_name):
+    """The module path of an instruction below its model's name, the
+    copies of a block as one row: the components under the last
+    ``jvp(...)`` (the whole name where there is none) without the
+    primitive at the end, without what only says how JAX got there
+    (``jit(...)``, ``while/body``, ``checkpoint``, ``shard_map``), with
+    indices cut (``block_3`` -> ``block``, ``ln1`` -> ``ln``) and a
+    doubled name folded (``attn/attn/latent`` -> ``attn/latent``).  What
+    ``jvp(...)`` itself names leads the path when it is a scope
+    (``jvp(loss)``, ``jvp(mtp)``) and not when it is the model's class
+    (``jvp(Transformer)``: a capital).  ``""`` where nothing is left."""
+    parts = op_name.split("/")[:-1]
+    marked = [i for i, part in enumerate(parts) if "jvp(" in part]
+    if marked:
+        inner = parts[marked[-1]].rpartition("(")[2].rstrip(")")
+        parts = ([inner if inner[:1].islower() else ""]
+                 + parts[marked[-1] + 1:])
+    out = []
+    for part in parts:
+        if not _MODULE.match(part) or _HOW.match(part):
+            continue
+        part = re.sub(r"_?\d+", "", part)
+        if part and part != (out[-1] if out else None):
+            out.append(part)
+    return "/".join(out)
+
+
+def step_phases(step):
+    """A compiled step (``jitted.lower(...).compile()``, or its
+    ``as_text()``) by the names its own instructions carry:
+
+        instructions, fused, borrowed = step_phases(compiled)
+        instructions["fusion.123"] == ("backward", "block/mlp/up")
+        fused["fusion.123"] == {"backward", "update"}
+
+    ``instructions`` holds every instruction of the entry computation
+    and of every computation run from there that is not a fusion's
+    (loop bodies and conditions, branches, calls): the names a
+    profiler's ``XLA Ops`` events carry (docs/observability.md).  Phase
+    and scope are :func:`phase_of` and :func:`scope_of` of the
+    ``op_name`` in the instruction's metadata (the first, where the
+    compiler joined several).
+
+    - An instruction inside a loop is read with its loop's name ahead
+      of its own: a Pallas kernel in a scanned block keeps only
+      ``.../checkpoint/block_2/ln1`` of its path, and a copy the
+      compiler made there has no name at all; both are their loop's.
+    - A fusion goes where its own metadata says (the compiler gives it
+      the name of its product or root); ``fused`` has, for each fusion,
+      the phases of the named instructions inside it, so that a weight
+      gradient fused with its Adam update can be shown and not silently
+      given to one side.
+    - What the compiler made and left unnamed (a weight's prefetch
+      ``copy-start`` / ``copy-done``, a layout copy, the kernels it
+      lowers ``ragged_dot`` to, which keep ``op_name="ragged-dot-none"``
+      alone) is read as the instructions around it: the phase and scope
+      of the last of its operands to run (forward before recompute
+      before backward before exchange before update), or, where no
+      operand has one, of its first consumer.  ``borrowed`` holds the
+      names read so; what is still ``unnamed`` has no named neighbour.
+    """
+    text = step if isinstance(step, str) else step.as_text()
+    computations, entry, lines = {}, None, None
+    for line in text.splitlines():
+        if line.startswith("  ") and lines is not None:
+            name, equals, rest = line.lstrip(" ").partition(" = ")
+            ran = _RAN.search(rest)
+            if not equals or not ran:
+                continue
+            names = _OP_NAME.search(rest)
+            lines.append((
+                name.removeprefix("ROOT ").lstrip("%"),
+                names.group(1).split(";")[0] if names else "",
+                ran.group(1) == "fusion",
+                re.findall(r"%([\w.\-]+)", ran.group(2)),
+                [c for group in _CALLED.findall(rest)
+                 for c in re.findall(r"[\w.\-]+", group)]))
+            continue
+        head = _HEAD.match(line)
+        if head:
+            lines = computations[head.group(2)] = []
+            entry = head.group(2) if head.group(1) else entry
+    instructions, fused, borrowed = {}, {}, set()
+    todo, seen = [(entry, "")], []
+    while todo:
+        computation, loop = todo.pop()
+        if computation in seen or computation not in computations:
+            continue
+        seen.append(computation)
+        for name, op_name, is_fusion, _, called in computations[computation]:
+            whole = f"{loop}/{op_name}" if loop and op_name else (
+                op_name or loop)
+            instructions[name] = (phase_of(whole), scope_of(whole))
+            if is_fusion:
+                fused[name] = {
+                    phase_of(f"{loop}/{inner}" if loop else inner)
+                    for c in called
+                    for _, inner, _, _, _ in computations.get(c, ())
+                    if "/" in inner}
+            else:
+                todo.extend((c, whole) for c in called)
+
+    def named(name):
+        return instructions.get(name, ("unnamed",))[0] != "unnamed"
+
+    for computation in seen:
+        lines, consumer = computations[computation], {}
+        for name, _, _, operands, _ in lines:  # operands come first
+            for operand in operands:
+                consumer.setdefault(operand, name)
+            around = [instructions[o] for o in operands if named(o)]
+            if not named(name) and around:
+                # of several in that phase the last: a kernel's weights
+                # come after what it reads of the routing
+                instructions[name] = max(
+                    reversed(around),
+                    key=lambda found: PHASES.index(found[0]))
+                borrowed.add(name)
+        for name, _, _, _, _ in reversed(lines):  # consumers come last
+            if not named(name) and named(consumer.get(name)):
+                instructions[name] = instructions[consumer[name]]
+                borrowed.add(name)
+    return instructions, fused, borrowed

@@ -23,7 +23,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RANKS = 8
 
 DRIVER = """
-import glob, json, os, sys, time
+import glob, json, os, sys
 sys.path.insert(0, {repo!r})
 import jax, jax.numpy as jnp
 import horovod_tpu as hvd
@@ -49,11 +49,14 @@ options.python_tracer_level = 0
 options.host_tracer_level = 1
 jax.profiler.start_trace(out_dir, profiler_options=options)
 basics.run_parallel(one_allreduce)
-# the dispatcher leaves hvd.execute after it has handed out the results
-time.sleep(0.3)
-jax.profiler.stop_trace()
 report["log"] = list(trace.LOG)
 report["stats"] = hvd.eager_stats()
+# The trace ends on an event, not on a clock: the dispatcher leaves
+# hvd.execute after it has handed out the results and goes back into
+# hvd.wait_batch; shutdown joins it, so every span it was in is closed
+# and in the file, however loaded the machine is.
+hvd.shutdown()
+jax.profiler.stop_trace()
 path = glob.glob(os.path.join(out_dir, "plugins", "profile", "*",
                               "*.xplane.pb"))[-1]
 report["lines"] = [
@@ -61,7 +64,6 @@ report["lines"] = [
      for e in line.events if e.name.startswith("hvd.")]
     for plane in jax.profiler.ProfileData.from_file(path).planes
     if plane.name == "/host:CPU" for line in plane.lines]
-hvd.shutdown()
 report["log_after_shutdown"] = len(trace.LOG)
 trace.reset()
 report["log_after_reset"] = len(trace.LOG)
@@ -253,3 +255,137 @@ def test_eager_stats_means(log):
 
 def test_the_log_is_bounded():
     assert trace.LOG.maxlen == 65536
+
+
+# ------------------------- the compiled step's names, no program run
+@pytest.mark.parametrize("op_name,phase,scope", [
+    ("jit(per_shard)/jvp(Transformer)/block_3/attn/attn/latent/mul",
+     "forward", "block/attn/latent"),
+    ("jit(per_shard)/transpose(jvp(Transformer))/jvp(Transformer)/"
+     "checkpoint/rematted_computation/block_3/ln1/mul",
+     "recompute", "block/ln"),
+    ("jit(per_shard)/transpose(jvp(Transformer))/jvp(Transformer)/"
+     "checkpoint/block_3/moe/moe/experts/select_n",
+     "backward", "block/moe/experts"),
+    ("jit(per_shard)/transpose(jvp(mtp))/NextTokenModule/jvp(mtp)/"
+     "NextTokenModule/checkpoint/block/attn/attn/latent/add_any",
+     "backward", "mtp/NextTokenModule/block/attn/latent"),
+    ("jit(per_shard)/jvp(Transformer)/loop/while/body/closed_call/"
+     "Transformer.one_pass/block_2/mlp/jit(silu)/logistic",
+     "forward", "loop/block/mlp"),
+    ("jit(per_shard)/jvp(loss)/exit_loss/jit(log_sigmoid)/jit(softplus)/"
+     "log1p", "forward", "loss/exit_loss"),
+    ("jit(per_shard)/transpose(jvp(loss))/pallas_call", "backward", "loss"),
+    ("jit(per_shard)/jvp(ResNet)/BottleneckBlock_12/Conv_0/"
+     "conv_general_dilated", "forward", "BottleneckBlock/Conv"),
+    ("jit(per_shard)/jvp(Transformer)/embed/jit(_take)/scatter-add",
+     "forward", "embed"),
+    ("jit(per_shard)/shard_map/hvd/exchange/psum", "exchange",
+     "hvd/exchange"),
+    ("jit(per_shard)/hvd/update/hvd/exchange/psum", "exchange",
+     "hvd/update/hvd/exchange"),
+    ("jit(per_shard)/shard_map/hvd/update/jit(_where)/select_n", "update",
+     "hvd/update"),
+    # the caller's apply_updates and its pmean of the loss
+    ("jit(per_shard)/add", "update", ""),
+    ("jit(per_shard)/shard_map/psum", "update", ""),
+    # an argument's label, a bare primitive, no name
+    ("params[\\'block_0\\'][\\'ln1\\'][\\'scale\\']", "unnamed", ""),
+    ("reduce_sum", "unnamed", ""),
+    ("", "unnamed", ""),
+])
+def test_phase_and_scope_of_an_op_name(op_name, phase, scope):
+    assert trace.phase_of(op_name) == phase
+    assert trace.scope_of(op_name) == scope
+
+
+HLO = """HloModule jit_step
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %dot.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/transpose(jvp(Model))/dense/dot_general"}
+  %c = f32[]{:T(128)} constant(0.1)
+  ROOT %add.1 = f32[8]{0} add(%dot.1, %p), metadata={op_name="jit(step)/hvd/update/add" stack_frame_id=3}
+}
+
+%body (t: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %t = (s32[], f32[8]{0}) parameter(0)
+  %ln = f32[8]{0} custom-call(%t), custom_call_target="tpu_custom_call", metadata={op_name="Model.one_pass/block_1/ln1/pallas_call"}
+  %copy.7 = f32[8]{0} copy(%ln)
+  ROOT %tuple.2 = (s32[], f32[8]{0}) tuple(%t, %copy.7)
+}
+
+%cond (t: (s32[], f32[8])) -> pred[] {
+  %t.1 = (s32[], f32[8]{0}) parameter(0)
+  ROOT %lt = pred[] compare(%t.1, %t.1), direction=LT
+}
+
+%region_0.1 (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %sum = f32[] add(%a, %b), metadata={op_name="reduce_sum"}
+}
+
+ENTRY %main.5 (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0:T(8,128)S(1)} parameter(0), metadata={op_name="params[\\'w\\']"}
+  %while.1 = (s32[], f32[8]{0}) while(%x), condition=%cond, body=%body, metadata={op_name="jit(step)/jvp(Model)/loop/while"}
+  %fusion.9 = f32[8]{0} fusion(%x), kind=kOutput, calls=%fused_computation, metadata={op_name="jit(step)/transpose(jvp(Model))/dense/dot_general"}
+  %ragged-dot-none.1 = f32[8]{0:T(8,128)(2,1)} custom-call(%while.1, %fusion.9), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot-none"}
+  %copy-start.2 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%x)
+  %copy-done.2 = f32[8]{0} copy-done(%copy-start.2)
+  %iota.4 = s32[8]{0} iota(), iota_dimension=0
+  ROOT %all-reduce.3 = f32[8]{0} all-reduce(%copy-done.2), to_apply=%region_0.1, metadata={op_name="jit(step)/shard_map/hvd/exchange/psum;jit(step)/x"}
+}
+"""
+
+
+def test_step_phases_of_a_hand_written_program():
+    instructions, fused, borrowed = trace.step_phases(HLO)
+    assert instructions == {
+        "while.1": ("forward", "loop"),
+        # a kernel in a loop keeps the end of its path, a copy the
+        # compiler made there has no name: both are their loop's
+        "t": ("forward", "loop"),
+        "ln": ("forward", "loop/block/ln"),
+        "copy.7": ("forward", "loop"),
+        "tuple.2": ("forward", "loop"),
+        "t.1": ("forward", "loop"),
+        "lt": ("forward", "loop"),
+        # the first of the names the compiler joined
+        "all-reduce.3": ("exchange", "hvd/exchange"),
+        # where its own name says, whatever it holds
+        "fusion.9": ("backward", "dense"),
+        # what the compiler made and left unnamed: the kernel it lowered
+        # a product to runs when the last of its operands exists (the
+        # weights' cast is forward's, the gradient backward's); a
+        # prefetch with no named operand is its first consumer's, and
+        # so is the argument it fetches
+        "ragged-dot-none.1": ("backward", "dense"),
+        "copy-done.2": ("exchange", "hvd/exchange"),
+        "copy-start.2": ("exchange", "hvd/exchange"),
+        "x": ("forward", "loop"),
+        # no named neighbour
+        "iota.4": ("unnamed", ""),
+    }
+    # neither a fusion's instructions nor a reducer's are events
+    assert not {"dot.1", "add.1", "sum"} & set(instructions)
+    assert fused == {"fusion.9": {"backward", "update"}}
+    assert borrowed == {"ragged-dot-none.1", "copy-done.2", "copy-start.2",
+                        "x"}
+    assert set(trace.PHASES) >= {p for p, _ in instructions.values()}
+
+
+def test_step_phases_takes_a_compiled_step():
+    import jax
+    import jax.numpy as jnp
+
+    def step(w, x):
+        with jax.named_scope("hvd/update"):
+            return w - 0.1 * jax.grad(lambda w: jnp.sum((x @ w) ** 2))(w)
+
+    compiled = jax.jit(step).lower(jnp.ones((4, 4)),
+                                   jnp.ones((2, 4))).compile()
+    instructions, _, _ = trace.step_phases(compiled)
+    assert instructions == trace.step_phases(compiled.as_text())[0]
+    assert {"forward", "backward", "update"} <= {
+        p for p, _ in instructions.values()}
