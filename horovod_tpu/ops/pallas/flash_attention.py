@@ -22,11 +22,17 @@ framework ships one).  Design per the TPU architecture:
   (no iota, compare or select), and only the blocks the diagonal crosses
   run the masked body;
 - backward recomputes the forward blockwise from the saved logsumexp
-  (flash-attention-2 style): one kernel accumulates dq over K blocks, one
-  accumulates dk/dv over Q blocks.  The dk/dv kernel holds its scores
-  transposed (``k @ q.T -> [block_k, block_q]``), so the per-row scalars
-  broadcast from their lane rows as stored and no product has a
-  transposed left operand.
+  (flash-attention-2 style) in ONE kernel: a block pair's scores,
+  exponentials and ``dO v.T`` are computed once and dq, dk and dv all
+  come from them (five products a pair, the mathematics' own).  Its grid
+  is (batch*heads, k-blocks) with a loop over q blocks: dk and dv are
+  sums over q blocks and leave with the grid step; dq is a sum over k
+  blocks, so a head's whole dq is carried in float32 in VMEM across the
+  head's grid steps and written once.  The scores are held transposed
+  (``k @ q.T -> [block_k, block_q]``), so the per-row scalars broadcast
+  from their lane rows as stored and only dq's product (``ds.T @ k``)
+  has a transposed left operand.  The call states the scoped VMEM its
+  blocks need (``_bwd_vmem_bytes``).
 
 Layout: public API takes ``[B, T, H, D]`` (framework convention);
 kernels run on ``[B*H, T, D]``.  q and k share one width (``d_qk``, the
@@ -103,16 +109,16 @@ def _sds(shape, dtype, like):
 # ----------------------------------------------------- row-scalar packing
 #
 # Per-row scalars (logsumexp, delta) are natural [rows, 1] columns inside
-# the forward and dq kernels (rows = sublanes) but must not be stored to
+# the forward kernel (rows = sublanes) but must not be stored to
 # HBM broadcast across a 128-lane tile — that costs 128x the necessary
 # bandwidth and capped long-sequence backward (the bundled
 # jax.experimental kernel pays exactly this).  They are stored dense, one
 # q-block's scalars per lane row: HBM shape [bh, t/block_q, 1, block_q],
 # the bytes of [bh, t] (the singleton sublane axis satisfies the TPU
 # block-shape rule — the last two block dims must divide (8, 128) or equal
-# the array dims).  The dk/dv kernel, whose scores are [block_k, block_q],
-# broadcasts such a row as it is.  The forward and dq kernels turn column
-# into row and back once a q block with an MXU identity contraction, 128
+# the array dims).  The backward kernel, whose scores are [block_k,
+# block_q], broadcasts such a row as it is.  The forward kernel turns
+# column into row once a q block with an MXU identity contraction, 128
 # rows at a time — bit-exact for fp32 (one nonzero term per output) and
 # guaranteed to lower on any Mosaic version, unlike a reshape across the
 # minor-two dims.
@@ -129,14 +135,9 @@ def _col_to_row(c):
                                preferred_element_type=jnp.float32)
 
 
-def _row_to_col(r):
-    """[1, n] lane row -> [n, 1] fp32 column (MXU transpose)."""
-    return jax.lax.dot_general(_eye(r.shape[1]), r, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_PACK = 128  # lane width: the transposes above go 128 rows at a time
+_PACK = 128  # lane width: the transpose above goes 128 rows at a time
 _BLOCK = 512  # default block_q and block_k: see flash_attention()
+_VMEM_DEFAULT = 16 << 20  # the scope a call gets that asks for none
 
 
 def _store_row(ref, col):
@@ -147,15 +148,6 @@ def _store_row(ref, col):
         return
     for start in range(0, col.shape[0], _PACK):
         ref[:, start:start + _PACK] = _col_to_row(col[start:start + _PACK])
-
-
-def _load_col(ref):
-    """The [1, block_q] lane row ``ref`` views, as a [block_q, 1] column."""
-    if ref.shape[1] % _PACK:  # an odd block: whole, no lane slice
-        return _row_to_col(ref[...])
-    pieces = [_row_to_col(ref[:, start:start + _PACK])
-              for start in range(0, ref.shape[1], _PACK)]
-    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=0)
 
 
 def _is_pow2(scale):
@@ -278,72 +270,39 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
 
 # --------------------------------------------------------------- backward
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale, causal, block_q, block_k):
-    iq = pl.program_id(1)
-    t_kv = k_ref.shape[1]
-    d = q_ref.shape[2]
-
-    # a power-of-two scale rides on q (for the scores) and on the
-    # finished dq instead of on two [bq, bk] tiles a block pair
-    fold_scale = _is_pow2(scale)
-    q = q_ref[0] * scale if fold_scale else q_ref[0]
-    do = do_ref[0]
-    lse = _load_col(lse_ref.at[0, 0])                       # [bq, 1]
-    delta = _load_col(delta_ref.at[0, 0])
-
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-
-    def body(ik, dq, *, masked):
-        k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :]
-        s = _dot(q, k_blk, (1, 1))
-        if not fold_scale:
-            s = s * scale
-        p = jnp.exp(s - lse)                              # [bq, bk]
-        if masked:
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            # a fully-masked row carries lse = NEG_INF: mirror the
-            # forward's guard so it contributes zero gradient
-            p = jnp.where((q_pos >= k_pos) & (lse > _NEG_INF / 2), p, 0.0)
-        ds = p * (_dot(do, v_blk, (1, 1)) - delta)
-        if not fold_scale:
-            ds = ds * scale
-        return dq + _dot(ds.astype(k_blk.dtype), k_blk, (1, 0))
-
-    dq = _causal_loops(
-        body, jnp.zeros((block_q, d), jnp.float32),
-        _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
-                  t_kv=t_kv))
-    if fold_scale:
-        dq = dq * scale
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, block_k):
-    # scores are held transposed, [block_k, block_q]: a q block's lse and
-    # delta broadcast down the sublanes from the lane rows they are
-    # stored as, and p.T @ dO, ds.T @ q are plain products
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, scale, causal, block_q,
+                block_k):
+    # One k block of one head a grid step.  Scores are held transposed,
+    # [block_k, block_q]: a q block's lse and delta broadcast down the
+    # sublanes from the lane rows they are stored as, and p.T @ dO,
+    # ds.T @ q are plain products.  dq is summed over k blocks, which the
+    # grid walks: its float32 sum for the whole head lives in ``dq_acc``
+    # across the head's grid steps and is written once, at the last.
     ik = pl.program_id(1)
+    nk = pl.num_programs(1)
     t_q = q_ref.shape[1]
     nq = t_q // block_q
 
-    v_blk = v_ref[0]                                        # [bk, d]
-    # a power-of-two scale rides on k (for the scores) and on the
-    # finished dk instead of on two [bk, bq] tiles a block pair
+    v_blk = v_ref[0]                                        # [bk, d_v]
+    # a power-of-two scale rides on k (for the scores, and from there
+    # on dq) and on the finished dk instead of on two [bk, bq] tiles a
+    # block pair
     fold_scale = _is_pow2(scale)
     k_blk = k_ref[0] * scale if fold_scale else k_ref[0]
+
+    @pl.when(ik == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     k_pos = ik * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_k, block_q), 0)
 
     def body(iq, carry, *, masked):
         dk, dv = carry
-        q = q_ref[0, pl.ds(iq * block_q, block_q), :]
-        do = do_ref[0, pl.ds(iq * block_q, block_q), :]
+        rows = pl.ds(iq * block_q, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
         lse = lse_ref[0, pl.ds(iq, 1), 0, :]                # [1, bq]
         delta = delta_ref[0, pl.ds(iq, 1), 0, :]
         s = _dot(k_blk, q, (1, 1))                          # [bk, bq]
@@ -356,11 +315,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             # a fully-masked row carries lse = NEG_INF: mirror the
             # forward's guard so it contributes zero gradient
             p = jnp.where((q_pos >= k_pos) & (lse > _NEG_INF / 2), p, 0.0)
-        dv = dv + _dot(p.astype(do.dtype), do, (1, 0))      # [bk, d]
+        dv = dv + _dot(p.astype(do.dtype), do, (1, 0))      # [bk, d_v]
         ds = p * (_dot(v_blk, do, (1, 1)) - delta)          # [bk, bq]
         if not fold_scale:
             ds = ds * scale
-        dk = dk + _dot(ds.astype(q.dtype), q, (1, 0))       # [bk, d]
+        ds = ds.astype(q.dtype)
+        dk = dk + _dot(ds, q, (1, 0))                       # [bk, d_qk]
+        # the one product whose left operand is transposed
+        dq_acc[rows, :] += _dot(ds, k_blk, (0, 0))          # [bq, d_qk]
         return dk, dv
 
     if causal:
@@ -379,6 +341,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ik == nk - 1)
+    def _():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _padded_bytes(rows, cols, itemsize):
+    """What a ``[rows, cols]`` array takes in VMEM: the lanes padded to
+    128 and the sublanes to a tile of 32 bytes a lane."""
+    sublanes = 8 * 4 // itemsize
+    return (-(-rows // sublanes) * sublanes * -(-cols // _PACK) * _PACK
+            * itemsize)
+
+
+def _bwd_vmem_bytes(t, d_qk, d_v, block_q, block_k, itemsize):
+    """The scoped VMEM the backward call asks for, from its own blocks:
+    what the pipeline double-buffers (q, dO and dq of the whole head, k,
+    v, dk and dv a block, the packed row scalars), the head's float32 dq
+    and the float32 tiles and carries of one block pair, with a quarter
+    more; never under the scope a call gets that asks for none."""
+    whole = (2 * _padded_bytes(t, d_qk, itemsize)
+             + _padded_bytes(t, d_v, itemsize))
+    k_side = 2 * (_padded_bytes(block_k, d_qk, itemsize)
+                  + _padded_bytes(block_k, d_v, itemsize))
+    scalars = 2 * (t // block_q) * _padded_bytes(1, block_q, 4)
+    pair = (6 * _padded_bytes(block_k, block_q, 4)
+            + 2 * _padded_bytes(block_k, d_qk, 4)
+            + 2 * _padded_bytes(block_k, d_v, 4)
+            + 2 * _padded_bytes(block_q, d_qk, 4))
+    need = (2 * (whole + k_side + scalars)
+            + _padded_bytes(t, d_qk, 4) + pair)
+    return max(need + need // 4, _VMEM_DEFAULT)
 
 
 def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
@@ -399,46 +393,36 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
     # one q block's row scalars per lane row (a reshape, i.e. free)
     lse_b = lse.reshape(bh, nq, 1, block_q)
     delta_b = delta.reshape(bh, nq, 1, block_q)
-    dq_lse_spec = _vmem_spec((1, 1, 1, block_q), lambda b, i: (b, i, 0, 0))
-    dkv_lse_spec = _vmem_spec((1, nq, 1, block_q), lambda b, i: (b, 0, 0, 0))
-    kernel_args = dict(scale=scale, causal=causal, block_q=block_q,
-                       block_k=block_k)
+    lse_spec = _vmem_spec((1, nq, 1, block_q), lambda b, i: (b, 0, 0, 0))
+    whole_qk = _vmem_spec((1, t, d_qk), lambda b, i: (b, 0, 0))
+    block_qk = _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0))
+    block_v = _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0))
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kernel_args),
-        grid=(bh, nq),
-        in_specs=[
-            _vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_kv, d_qk), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, t_kv, d_v), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, block_q, d_v), lambda b, i: (b, i, 0)),
-            dq_lse_spec,
-            dq_lse_spec,
-        ],
-        out_specs=_vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
-        out_shape=_sds((bh, t, d_qk), q3.dtype, q3),
-        interpret=interpret,
-    )(q3, k3, v3, g, lse_b, delta_b)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kernel_args),
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
         grid=(bh, nk),
         in_specs=[
-            _vmem_spec((1, t, d_qk), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0)),
+            whole_qk,
+            block_qk,
+            block_v,
             _vmem_spec((1, t, d_v), lambda b, i: (b, 0, 0)),
-            dkv_lse_spec,
-            dkv_lse_spec,
+            lse_spec,
+            lse_spec,
         ],
-        out_specs=[
-            _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0)),
-        ],
+        # dq's block is the head's: it stays in VMEM over the head's k
+        # blocks and goes to HBM once
+        out_specs=[whole_qk, block_qk, block_v],
         out_shape=[
+            _sds((bh, t, d_qk), q3.dtype, q3),
             _sds((bh, t_kv, d_qk), k3.dtype, k3),
             _sds((bh, t_kv, d_v), v3.dtype, v3),
         ],
+        scratch_shapes=[pltpu.VMEM((t, d_qk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(
+                t, d_qk, d_v, block_q, block_k, q3.dtype.itemsize)),
         interpret=interpret,
     )(q3, k3, v3, g, lse_b, delta_b)
     return dq, dk, dv

@@ -186,17 +186,15 @@ def test_flash_in_ring_attention(causal):
 
 
 def test_mxu_transpose_helpers_exact():
-    """_col_to_row/_row_to_col: identity-matmul lane<->sublane moves must
-    be bit-exact for fp32 (one nonzero product per output element)."""
-    from horovod_tpu.ops.pallas.flash_attention import (_col_to_row,
-                                                       _row_to_col)
+    """_col_to_row: the identity-matmul sublane -> lane move the forward
+    kernel stores its lse through must be bit-exact for fp32 (one
+    nonzero product per output element)."""
+    from horovod_tpu.ops.pallas.flash_attention import _col_to_row
     rng = np.random.RandomState(7)
     col = jnp.asarray(rng.randn(128, 1).astype(np.float32))
     row = _col_to_row(col)
     assert row.shape == (1, 128)
     assert np.array_equal(np.asarray(row)[0], np.asarray(col)[:, 0])
-    back = _row_to_col(row)
-    assert np.array_equal(np.asarray(back), np.asarray(col))
 
 
 def test_packed_lse_layout_engaged_and_dense():
@@ -249,8 +247,9 @@ def test_packed_lse_layout_engaged_and_dense():
 def test_long_sequence_backward_packed():
     """T=4096 causal backward through the packed lse/delta layout — the
     long-sequence regime the round-2 broadcast layout capped (its dkv
-    kernel held full-T 128-lane tiles of both operands).  Both backward
-    kernels (dq; dk/dv) must produce finite, non-trivial gradients."""
+    kernel held full-T 128-lane tiles of both operands).  The backward
+    kernel carries dq over eight k blocks a head here; all three
+    gradients must be finite and non-trivial."""
     q, k, v = _rand(b=1, t=4096, h=1, seed=0)
     gq, gk, gv = jax.grad(
         lambda q, k, v: jnp.sum(
@@ -380,7 +379,7 @@ def _walk(jaxpr):
 
 
 def _flash_kernel_jaxprs(dtype, causal):
-    """``{kernel name: jaxpr}`` of the three kernels of one
+    """``{kernel name: jaxpr}`` of the two kernels of one
     forward-and-backward call at heads of 64."""
     rng = np.random.RandomState(6)
     q, k, v = (jnp.asarray(rng.randn(1, 256, 2, 64).astype(np.float32)
@@ -394,8 +393,7 @@ def _flash_kernel_jaxprs(dtype, causal):
         if eqn.primitive.name == "pallas_call":
             kernel = eqn.params["jaxpr"]
             kernels[kernel.debug_info.func_name] = kernel
-    assert sorted(kernels) == ["_bwd_dkv_kernel", "_bwd_dq_kernel",
-                               "_fwd_kernel"], sorted(kernels)
+    assert sorted(kernels) == ["_bwd_kernel", "_fwd_kernel"], sorted(kernels)
     return kernels
 
 
@@ -413,17 +411,28 @@ def _operand_products(jaxpr):
 def test_flash_products_take_operands_in_the_input_dtype(dtype, causal):
     """bfloat16 inputs reach the MXU as bfloat16 (with ``p`` and ``ds``
     rounded to it) and float32 inputs as float32; every product
-    accumulates in float32 and none has a transposed left operand."""
-    per_body = {"_fwd_kernel": 2, "_bwd_dq_kernel": 3, "_bwd_dkv_kernel": 4}
+    accumulates in float32.  The backward kernel makes the five products
+    of the mathematics a block pair (scores, ``dO v.T``, dv, dk, dq:
+    none computed twice), and only dq's has a transposed left operand."""
+    per_body = {"_fwd_kernel": 2, "_bwd_kernel": 5}
+    transposed = {"_fwd_kernel": 0, "_bwd_kernel": 1}
+    exps = {"_fwd_kernel": 2, "_bwd_kernel": 1}  # p and alpha; p alone
     bodies = 2 if causal else 1  # whole blocks; blocks on the diagonal
     for name, jaxpr in _flash_kernel_jaxprs(dtype, causal).items():
         products = _operand_products(jaxpr)
         assert len(products) == per_body[name] * bodies, (name, products)
+        lhs_contracts = []
         for eqn in products:
             assert [v.aval.dtype for v in eqn.invars] == [dtype, dtype], eqn
             assert eqn.outvars[0].aval.dtype == jnp.float32, eqn
             (lhs_contract, _), _ = eqn.params["dimension_numbers"]
-            assert tuple(lhs_contract) == (1,), (name, eqn)
+            lhs_contracts.append(tuple(lhs_contract))
+        assert lhs_contracts.count((0,)) == transposed[name] * bodies, name
+        assert lhs_contracts.count((1,)) == (
+            per_body[name] - transposed[name]) * bodies, name
+        # one round of exponentials a block pair
+        assert len([e for e in _walk(jaxpr) if e.primitive.name == "exp"
+                    ]) == exps[name] * bodies, name
 
 
 def test_flash_mask_work_only_on_the_diagonal():
@@ -547,8 +556,9 @@ def test_pick_block_prefers_lane_aligned_divisors(t, want, expected):
 
 def test_flash_default_blocks_are_the_swept_ones(monkeypatch):
     """Without arguments or HVD_FLASH_BLOCK_Q/K a [*, 1024, *, 64] call
-    runs 512 x 512 blocks (two q blocks a batch-head in every kernel's
-    grid) and matches the dense reference through them."""
+    runs 512 x 512 blocks (two q blocks a batch-head in the forward
+    kernel's grid, two k blocks in the backward kernel's) and matches
+    the dense reference through them."""
     monkeypatch.delenv("HVD_FLASH_BLOCK_Q", raising=False)
     monkeypatch.delenv("HVD_FLASH_BLOCK_K", raising=False)
     q, k, v = _rand(b=1, t=1024, h=1, d=64, seed=12)
@@ -557,7 +567,7 @@ def test_flash_default_blocks_are_the_swept_ones(monkeypatch):
         (0, 1, 2)))(q, k, v)
     grids = [eqn.params["grid_mapping"].grid for eqn in _walk(jaxpr.jaxpr)
              if eqn.primitive.name == "pallas_call"]
-    assert grids == [(1, 2)] * 3, grids
+    assert grids == [(1, 2)] * 2, grids
     _grads_match_reference(q, k, v, True)
 
 
@@ -642,6 +652,106 @@ def test_flash_two_widths_lse_and_its_gradient():
     for got, want in zip(jax.grad(weighed(flash), (0, 1, 2))(q, k, v),
                          jax.grad(weighed(dense), (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+# ------------------- the one backward kernel: dq carried over k blocks
+def _dense_out_and_lse(q, k, v, causal):
+    """Plain attention in float32 with its logsumexp ``[B, H, T]``, the
+    mask aligned at the start as the kernel's is."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision="highest") / np.sqrt(q.shape[-1])
+    if causal:
+        seen = (jnp.arange(q.shape[1])[:, None]
+                >= jnp.arange(k.shape[1])[None, :])
+        s = jnp.where(seen, s, -jnp.inf)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v,
+                     precision="highest")
+    return out, jax.nn.logsumexp(s, -1)
+
+
+@pytest.mark.parametrize(
+    "t,t_kv,d_qk,d_v,blocks,causal,with_lse,dtype", [
+        (256, 256, 192, 128, (128, 128), True, False, jnp.float32),
+        (128, 256, 32, 32, (64, 64), True, False, jnp.float32),
+        (384, 256, 32, 32, (128, 128), True, False, jnp.float32),
+        (192, 192, 32, 32, (96, 64), True, False, jnp.float32),
+        (192, 192, 24, 16, (32, 96), True, True, jnp.float32),
+        (512, 512, 64, 64, (128, 128), True, False, jnp.float32),
+        (512, 512, 64, 64, (256, 128), True, True, jnp.float32),
+        (512, 512, 32, 32, (128, 256), False, True, jnp.float32),
+        (256, 512, 32, 32, (128, 128), False, False, jnp.float32),
+        (512, 512, 192, 128, (128, 128), True, False, jnp.bfloat16),
+        (512, 512, 64, 64, (128, 128), True, True, jnp.bfloat16),
+        (256, 256, 80, 80, (128, 64), False, False, jnp.bfloat16),
+    ], ids=["widths-192-128", "t_kv-longer", "t_kv-shorter", "blocks-96-64",
+            "blocks-32-96-lse", "four-k-blocks", "four-k-blocks-lse",
+            "non-causal-lse", "non-causal-t_kv-longer", "bf16-192-128",
+            "bf16-lse", "bf16-non-causal-scale-no-power-of-two"])
+def test_flash_backward_one_kernel_against_plain_attention(
+        t, t_kv, d_qk, d_v, blocks, causal, with_lse, dtype):
+    """dq, dk and dv of the one backward kernel against ``jax.grad`` of
+    plain attention in float32: dq is a head's sum over its k blocks,
+    carried in the kernel from one grid step to the next, so the cases
+    have up to four k blocks a head, two heads a batch entry (the carry
+    starts again at each), ``t_kv != t``, blocks that 128 does not
+    divide, both widths, a cotangent on ``lse`` (folded into ``delta``),
+    no mask, and bfloat16 operands."""
+    rng = np.random.RandomState(t + t_kv + d_qk)
+    mk = lambda n, d: jnp.asarray(
+        rng.randn(2, n, 2, d).astype(np.float32)).astype(dtype)
+    q, k, v, ct = mk(t, d_qk), mk(t_kv, d_qk), mk(t_kv, d_v), mk(t, d_v)
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            total = jnp.vdot(out.astype(jnp.float32), ct.astype(jnp.float32))
+            return total + (jnp.sum(jnp.sin(lse)) if with_lse else 0.0)
+        return f
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, return_lse=True,
+                               block_q=blocks[0], block_k=blocks[1])
+
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: _dense_out_and_lse(
+        q, k, v, causal)), (0, 1, 2))(*(
+            x.astype(jnp.float32) for x in (q, k, v)))
+    for g, w, like in zip(got, want, (q, k, v)):
+        assert g.shape == like.shape and g.dtype == dtype
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+        else:  # the chip smoke's tolerance for bfloat16
+            assert _rel_err(g, w) <= 3e-2
+
+
+def test_flash_backward_states_the_vmem_it_needs():
+    """The backward call asks for the scoped VMEM its own blocks take
+    (q, dO and dq whole, the head's float32 dq, the tiles): more than
+    the compiler's default at the latent-attention cell's shape, the
+    default where that is enough, and never by a model's name."""
+    from horovod_tpu.ops.pallas.flash_attention import (_VMEM_DEFAULT,
+                                                       _bwd_vmem_bytes)
+
+    small = _bwd_vmem_bytes(1024, 64, 64, 512, 512, 2)
+    latent = _bwd_vmem_bytes(4096, 192, 128, 512, 512, 2)
+    assert small == _VMEM_DEFAULT == 16 << 20
+    # 192 lanes take 256: q and dq 2 MiB each and dO 1 MiB, twice; the
+    # float32 sum 4 MiB; four float32 tiles 4 MiB
+    assert 18 << 20 <= latent <= 48 << 20
+    assert _bwd_vmem_bytes(4096, 192, 128, 512, 512, 4) > latent
+    assert _bwd_vmem_bytes(8192, 192, 128, 512, 512, 2) > latent
+    q = jnp.zeros((1, 4096, 1, 192), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True).astype(jnp.float32)), (0, 1, 2)))(
+            q, q, q[..., :128])
+    params = [eqn.params["compiler_params"]["mosaic_tpu"]
+              for eqn in _walk(jaxpr.jaxpr)
+              if eqn.primitive.name == "pallas_call"
+              and eqn.params["jaxpr"].debug_info.func_name == "_bwd_kernel"]
+    assert len(params) == 1
+    assert params[0].vmem_limit_bytes == latent
+    assert tuple(params[0].dimension_semantics) == ("parallel", "arbitrary")
 
 
 # ------------------- under jax.checkpoint: the kernel's results are named

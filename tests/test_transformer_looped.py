@@ -351,7 +351,7 @@ def recomputed_loss(kind, attn_fn=flash_attention):
 
 def kernel_calls(loss, params):
     """Call sites of the flash forward kernel's and of the backward
-    kernels' ``jit`` in the lowered value-and-gradient."""
+    kernel's ``jit`` in the lowered value-and-gradient."""
     text = jax.jit(jax.value_and_grad(loss)).lower(params).as_text()
     return (len(re.findall(r"call @_fwd(_\d+)?\(", text)),
             len(re.findall(r"call @_bwd(_\d+)?\(", text)))
@@ -360,8 +360,8 @@ def kernel_calls(loss, params):
 @pytest.mark.parametrize("kind", sorted(RECOMPUTED))
 def test_a_recomputed_block_runs_each_flash_kernel_once(kind, monkeypatch):
     """N recomputed blocks (under the scan over the passes: N bodies)
-    call the forward kernel N times and the two backward kernels N
-    times; under a plain ``nn.remat`` the forward kernel runs again in
+    call the forward kernel N times and the backward kernel N times;
+    under a plain ``nn.remat`` the forward kernel runs again in
     every recomputation, 2 N."""
     cfg, params, loss = recomputed_loss(kind)
     n = cfg.n_layers
