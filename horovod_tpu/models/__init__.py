@@ -11,8 +11,10 @@ from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     BlockSpec,
+    GroupedAttention,
     LatentAttention,
     NextTokenModule,
+    Rotary,
     TopkExperts,
     Transformer,
     TransformerConfig,

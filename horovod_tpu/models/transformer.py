@@ -28,12 +28,19 @@ qk_norm=True, ffn="moe_topk")`` is OLMoE's; a block may also have
 ``norm_placement="sandwich"`` (a norm after each branch too), in a
 stack that runs ``TransformerConfig.passes`` times with one set of
 weights and, with ``exit_gate``, gives every pass's exit to
-:func:`looped_lm_loss`.
+:func:`looped_lm_loss`.  A model whose layers are not all of one kind
+gives ``TransformerConfig.pattern``, a period of specs: layer ``l`` is
+built from ``pattern[l % len(pattern)]``; with
+``attention=GroupedAttention(...)`` a kind of layer has its own count
+of query heads over grouped key-value heads, a sliding window or none,
+its own rotary recipe (:class:`Rotary`) and a gate a head on the
+attention's output.
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional, Tuple, Union
 
 import flax.linen as nn
@@ -65,6 +72,54 @@ class LatentAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class Rotary:
+    """A rotary recipe in the rotate-half pairing: the FIRST
+    ``fraction`` of a head's columns is turned (the rest pass), by the
+    frequencies ``theta^(-2i / D')`` over those ``D'`` columns.  With
+    ``factor`` they are YaRN's (arXiv:2309.00071, as Hugging Face's
+    ``_compute_yarn_parameters`` reads the keys): between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_len`` positions a linear ramp goes from the frequency as
+    it is to the frequency over ``factor``, and cos and sin are
+    multiplied by ``attention_factor``."""
+    theta: float = 10000.0
+    fraction: float = 1.0
+    factor: Optional[float] = None
+    original_len: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedAttention:
+    """Attention with grouped key-value heads: ``heads`` query heads of
+    ``head_dim`` read ``kv_heads`` keys and values (head ``h`` reads
+    ``h // (heads / kv_heads)``), through a q projection and a k/v
+    projection of their own.  ``window``: a query sees that many keys,
+    itself included (``None``: every key before it).  ``rotary``: the
+    recipe q and k are turned by.  ``gate``: ``"softplus"`` scales every
+    head's output by ``softplus(x W_g)``, one scalar a head and position
+    in float32, from the input the projections read (``None``: no
+    gate)."""
+    heads: int
+    kv_heads: int
+    head_dim: int
+    window: Optional[int] = None
+    rotary: Rotary = Rotary()
+    gate: Optional[str] = None
+
+    def __post_init__(self):
+        if self.heads % self.kv_heads:
+            raise ValueError(
+                f"GroupedAttention: {self.kv_heads} key-value heads do not "
+                f"divide {self.heads} query heads")
+        if self.gate not in (None, "softplus"):
+            raise ValueError(f"GroupedAttention: gate {self.gate!r} is "
+                             f"neither None nor 'softplus'")
+
+
+@dataclasses.dataclass(frozen=True)
 class TopkExperts:
     """A top-k expert layer (:func:`~horovod_tpu.parallel.moe.topk_moe`)
     told more than ``"moe_topk"`` says.  ``scoring``, ``renormalize``
@@ -93,10 +148,10 @@ class BlockSpec:
     function; no table) or ``"rope_pairs"`` (rotary over the pairs
     ``(2i, 2i + 1)``).  ``qk_norm``: a norm of the block's kind over the
     whole q and k projections, before the split into heads.  ``attention``:
-    ``"full"`` (one fused q, k, v projection, heads of one width) or a
-    :class:`LatentAttention`.  ``ffn``: ``"gelu"`` (dense
-    up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x gate) * (x up)``
-    down), ``"moe_switch"``
+    ``"full"`` (one fused q, k, v projection, heads of one width), a
+    :class:`LatentAttention` or a :class:`GroupedAttention`.  ``ffn``:
+    ``"gelu"`` (dense up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x
+    gate) * (x up)`` down), ``"moe_switch"``
     (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
     (:func:`~horovod_tpu.parallel.moe.topk_moe` as OLMoE has it) or a
     :class:`TopkExperts`."""
@@ -104,7 +159,7 @@ class BlockSpec:
     positions: str = "learned"
     qk_norm: bool = False
     ffn: Union[str, TopkExperts] = "gelu"
-    attention: Union[str, LatentAttention] = "full"
+    attention: Union[str, LatentAttention, GroupedAttention] = "full"
     norm_placement: str = "pre"
 
     def __post_init__(self):
@@ -112,7 +167,8 @@ class BlockSpec:
                 (self.norm, NORMS, ()), (self.positions, POSITIONS, ()),
                 (self.norm_placement, NORM_PLACEMENTS, ()),
                 (self.ffn, FFNS, TopkExperts),
-                (self.attention, ATTENTIONS, LatentAttention)):
+                (self.attention, ATTENTIONS,
+                 (LatentAttention, GroupedAttention))):
             if value not in known and not isinstance(value, cls):
                 raise ValueError(f"BlockSpec: {value!r} is none of {known}")
 
@@ -129,6 +185,11 @@ class TransformerConfig:
     # attn_fn(q, k, v, causal=..., scale=...) — swap in ring/ulysses/pallas
     attn_fn: Optional[Callable] = None
     block: BlockSpec = BlockSpec()
+    # a period of specs for a model whose layers are not all of one
+    # kind: layer l is ``pattern[l % len(pattern)]`` (empty: every layer
+    # is ``block``).  The specs agree on what the model has once: the
+    # norm's kind and whether positions are a learned table
+    pattern: Tuple[BlockSpec, ...] = ()
     # every k-th block uses a switch-MoE FFN whatever ``block.ffn``
     # says (0 = every block as ``block`` has it)
     moe_every: int = 0
@@ -162,13 +223,34 @@ class TransformerConfig:
     # :func:`looped_lm_loss`
     exit_gate: bool = False
 
+    def __post_init__(self):
+        once = {(spec.norm, spec.positions == "learned")
+                for spec in self.pattern}
+        if len(once) > 1:
+            raise ValueError(
+                "TransformerConfig.pattern: the specs differ in the norm's "
+                "kind or in whether positions are learned, which the model "
+                "has once")
+        if self.pattern and self.block != self.pattern[0]:
+            # what the model reads once (final norm, position table) it
+            # reads off ``block``
+            object.__setattr__(self, "block", self.pattern[0])
+
+    def at(self, layer):
+        """The configuration block ``layer`` is built from: this one
+        with the layer's spec of the pattern as its ``block``."""
+        if not self.pattern:
+            return self
+        return dataclasses.replace(
+            self, block=self.pattern[layer % len(self.pattern)], pattern=())
+
     def ffn_of(self, layer):
         """The feed-forward of block ``layer``: the per-layer pattern."""
         if layer < self.leading_dense:
             return "swiglu"
         if self.moe_every and (layer + 1) % self.moe_every == 0:
             return "moe_switch"
-        return self.block.ffn
+        return self.at(layer).block.ffn
 
 
 def default_attention():
@@ -214,6 +296,45 @@ def rope(x, theta=10000.0, pairs=False):
     x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def rotary_table(recipe, dim):
+    """The ``dim // 2`` frequencies (float32) a head's first ``dim``
+    columns are turned by under ``recipe`` (a :class:`Rotary`), and the
+    factor on cos and sin."""
+    i = jnp.arange(dim // 2, dtype=jnp.float32)
+    inv_freq = recipe.theta ** (-2 * i / dim)
+    if recipe.factor is None:
+        return inv_freq, 1.0
+
+    def turns(beta):
+        # the dimension that turns ``beta`` times over the original length
+        return (dim * math.log(recipe.original_len / (beta * 2 * math.pi))
+                / (2 * math.log(recipe.theta)))
+
+    low = max(math.floor(turns(recipe.beta_fast)), 0)
+    high = min(math.ceil(turns(recipe.beta_slow)), dim - 1)
+    ramp = jnp.clip((i - low) / max(high - low, 0.001), 0, 1)
+    return (inv_freq * (1 - ramp) + inv_freq / recipe.factor * ramp,
+            recipe.attention_factor)
+
+
+def rotate(x, recipe):
+    """``x [..., T, H, D]`` turned as ``recipe`` (a :class:`Rotary`)
+    says: the first ``fraction`` of the columns in the rotate-half
+    pairing within them, scaled by the recipe's factor; the rest pass.
+    Computed in float32, returned in ``x.dtype``."""
+    t, d = x.shape[-3], x.shape[-1]
+    dim = int(d * recipe.fraction)
+    half = dim // 2
+    inv_freq, factor = rotary_table(recipe, dim)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * inv_freq
+    cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:dim]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., dim:]],
+        axis=-1).astype(x.dtype)
 
 
 class RMSNorm(nn.Module):
@@ -293,15 +414,56 @@ def latent_qkv(cfg, x):
     return q, k, kv[..., spec.nope_dim:]
 
 
+def grouped_attention(cfg, x):
+    """Attention of a :class:`GroupedAttention` on ``x [..., T, d]``
+    (submodules of the :class:`Attention` that calls it).  Under the
+    scope ``attn/window`` where the kind has a window and
+    ``attn/global`` where it has none, the attention function's call
+    under ``flash`` inside it and the gate under ``attn/gate``."""
+    spec = cfg.block.attention
+
+    def kind():
+        return jax.named_scope(
+            "attn/global" if spec.window is None else "attn/window")
+
+    def dense(features, name):
+        return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype,
+                               name=name)
+
+    with kind():
+        q = dense((spec.heads, spec.head_dim), "q")(x)
+        kv = dense((2, spec.kv_heads, spec.head_dim), "kv")(x)
+        k, v = kv[..., 0, :, :], kv[..., 1, :, :]
+        with jax.named_scope("rope"):
+            q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
+        attn = cfg.attn_fn or default_attention()
+        with jax.named_scope("flash"):
+            # a function that knows no window is not asked for one
+            window = {} if spec.window is None else {"window": spec.window}
+            o = attn(q, k, v, causal=True, **window)
+    if spec.gate:
+        with jax.named_scope("attn/gate"):
+            gate = jax.nn.softplus(nn.Dense(
+                spec.heads, use_bias=False, dtype=jnp.float32,
+                name="gate")(x))
+            o = (o * gate[..., None]).astype(o.dtype)
+    with kind():
+        return dense(cfg.d_model, "out")(o.reshape(o.shape[:-2] + (-1,)))
+
+
 class Attention(nn.Module):
     """Causal self-attention of the kind ``cfg.block.attention`` names;
     the attention function is handed heads of the scores' width for q
-    and k and of the values' width for v."""
+    and k and of the values' width for v (and, by a
+    :class:`GroupedAttention`, fewer heads of k and v than of q and its
+    window)."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        if isinstance(cfg.block.attention, GroupedAttention):
+            return grouped_attention(cfg, x)
         latent = isinstance(cfg.block.attention, LatentAttention)
         q, k, v = (latent_qkv if latent else full_qkv)(cfg, x)
         attn = cfg.attn_fn or default_attention()
@@ -623,7 +785,7 @@ class Transformer(nn.Module):
                 bias = None
                 if router_bias is not None and isinstance(ffn, TopkExperts):
                     bias, rows = router_bias[rows], rows + 1
-                x = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
+                x = block_cls(cfg.at(i), ffn=ffn, name=f"block_{i}")(x, bias)
             out = make_norm(cfg, "ln_f")(x)
             return (out, x), (out if cfg.exit_gate else None)
 

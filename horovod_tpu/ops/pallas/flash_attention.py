@@ -21,6 +21,20 @@ framework ships one).  Design per the TPU architecture:
   never visited, blocks every row sees whole run with no mask work at all
   (no iota, compare or select), and only the blocks the diagonal crosses
   run the masked body;
+- a sliding ``window`` (query i sees the keys ``i - window < j <= i``) is
+  in the **loop bounds** too, forward and backward: K blocks that lie
+  wholly before a q block's window are not visited either, so a q block
+  visits the blocks that hold an allowed pair and no other (at 512 x 512
+  and a window of 512: two, whatever T), only the blocks an edge (the
+  diagonal or the window's far edge) crosses run the masked body, and
+  the backward's k block visits only the q blocks inside its window;
+- **grouped key-value heads** are read through the index map: q has
+  ``H`` heads, k and v ``G`` (``H = G x group``), head ``h`` of q reads
+  head ``h // group`` of k and v where they lie, so k and v are never
+  repeated to ``H`` heads in HBM.  The heads of a group are neighbours
+  on the grid, so a group's k and v are fetched once; dk and dv of a
+  key-value head are summed over its group's query heads in float32 in
+  VMEM and leave once, with the group's last head;
 - backward recomputes the forward blockwise from the saved logsumexp
   (flash-attention-2 style) in ONE kernel: a block pair's scores,
   exponentials and ``dO v.T`` are computed once and dq, dk and dv all
@@ -173,20 +187,60 @@ def _causal_loops(body, carry, bounds):
     return carry
 
 
-def _k_bounds(iq, *, causal, block_q, block_k, t_kv):
+def _k_bounds(iq, *, causal, block_q, block_k, t_kv, window=None):
     """K-block ranges for q block ``iq``: whole blocks, then the blocks
-    the diagonal crosses; blocks past it are not visited."""
+    the diagonal crosses; blocks past it are not visited.  With a
+    ``window`` the blocks wholly before it are not visited either, and
+    the blocks its far edge crosses come first, masked."""
     if not causal:
         return [(0, t_kv // block_k, False)]
     seen = jnp.minimum((iq + 1) * block_q + block_k - 1, t_kv) // block_k
     whole = jnp.minimum((iq * block_q + 1) // block_k, seen)
-    return [(0, whole, False), (whole, seen, True)]
+    if window is None:
+        return [(0, whole, False), (whole, seen, True)]
+    # the first block with a key the block's first row sees, and the
+    # first whose every key the block's last row still sees
+    first = jnp.maximum(iq * block_q - window + 1, 0) // block_k
+    inside = jnp.clip(
+        jnp.maximum((iq + 1) * block_q - window + block_k - 1, 0) // block_k,
+        first, seen)
+    whole = jnp.clip(whole, inside, seen)
+    return [(first, inside, True), (inside, whole, False),
+            (whole, seen, True)]
+
+
+def _q_bounds(ik, *, causal, block_q, block_k, nq, window=None):
+    """Q-block ranges for k block ``ik`` (the backward's loop): q blocks
+    before this k block see none of it, the blocks the diagonal crosses
+    run masked, the rest see all of it; with a ``window`` the q blocks
+    wholly past it are not visited and the blocks its far edge crosses
+    come last, masked."""
+    if not causal:
+        return [(0, nq, False)]
+    first = (ik * block_k) // block_q
+    whole = jnp.clip(((ik + 1) * block_k + block_q - 2) // block_q,
+                     first, nq)
+    if window is None:
+        return [(first, whole, True), (whole, nq, False)]
+    last = jnp.minimum(((ik + 1) * block_k + window - 2) // block_q + 1, nq)
+    whole = jnp.minimum(whole, last)
+    inside = jnp.clip((ik * block_k + window) // block_q, whole, last)
+    return [(first, whole, True), (whole, inside, False),
+            (inside, last, True)]
+
+
+def _allowed(q_pos, k_pos, window):
+    """The pairs a masked block keeps: the key at or before the query
+    and, with a ``window``, fewer than ``window`` positions before it."""
+    if window is None:
+        return q_pos >= k_pos
+    return (q_pos >= k_pos) & (q_pos - k_pos < window)
 
 
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k):
+                block_q, block_k, window=None):
     # q_ref: [block_q, d_qk]; k_ref: [t_kv, d_qk]; v_ref: [t_kv, d_v];
     # o_ref: [block_q, d_v]; lse_ref: [1, block_q], one lane per row
     iq = pl.program_id(1)
@@ -214,7 +268,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
         if masked:
             k_pos = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(_allowed(q_pos, k_pos, window), s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)                       # [bq, 1]
@@ -233,27 +287,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
          jnp.zeros((block_q, 1), jnp.float32),
          jnp.zeros((block_q, d_v), jnp.float32)),
         _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
-                  t_kv=t_kv))
+                  t_kv=t_kv, window=window))
 
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[0] = (o / l_safe).astype(o_ref.dtype)
     _store_row(lse_ref.at[0, 0], m + jnp.log(l_safe))
 
 
-def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
-    """Returns ``(out [bh, t, d_v], lse [bh, t])``."""
+def _kv_head(group):
+    """The index map of k's and v's whole head for q's head ``b`` of
+    ``[B H, ...]``: with ``H = G x group`` heads in q's order, head
+    ``b // group`` of ``[B G, ...]``."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
+
+
+def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret,
+         window=None):
+    """Returns ``(out [bh, t, d_v], lse [bh, t])``; k3 and v3 may have
+    fewer heads than q3 (``bh`` a multiple of theirs)."""
     bh, t, d_qk = q3.shape
     t_kv, d_v = v3.shape[1:]
     nq = t // block_q
+    kv_head = _kv_head(bh // k3.shape[0])
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window),
         grid=(bh, nq),
         in_specs=[
             _vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
-            _vmem_spec((1, t_kv, d_qk), lambda b, i: (b, 0, 0)),
-            _vmem_spec((1, t_kv, d_v), lambda b, i: (b, 0, 0)),
+            _vmem_spec((1, t_kv, d_qk), kv_head),
+            _vmem_spec((1, t_kv, d_v), kv_head),
         ],
         out_specs=[
             _vmem_spec((1, block_q, d_v), lambda b, i: (b, i, 0)),
@@ -271,14 +337,19 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret):
 # --------------------------------------------------------------- backward
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, *, scale, causal, block_q,
-                block_k):
+                dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale, causal,
+                block_q, block_k, window=None, group=1):
     # One k block of one head a grid step.  Scores are held transposed,
     # [block_k, block_q]: a q block's lse and delta broadcast down the
     # sublanes from the lane rows they are stored as, and p.T @ dO,
     # ds.T @ q are plain products.  dq is summed over k blocks, which the
     # grid walks: its float32 sum for the whole head lives in ``dq_acc``
     # across the head's grid steps and is written once, at the last.
+    # With grouped key-value heads (``group`` > 1) the grid's heads are
+    # q's and k_ref, v_ref are the group's: dk and dv are sums over the
+    # group's heads too, which the grid also walks, so the float32 sums
+    # of the whole key-value head live in ``kv_acc`` across the group's
+    # grid steps and leave block by block with the group's last head.
     ik = pl.program_id(1)
     nk = pl.num_programs(1)
     t_q = q_ref.shape[1]
@@ -314,7 +385,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_k, block_q), 1)
             # a fully-masked row carries lse = NEG_INF: mirror the
             # forward's guard so it contributes zero gradient
-            p = jnp.where((q_pos >= k_pos) & (lse > _NEG_INF / 2), p, 0.0)
+            p = jnp.where(_allowed(q_pos, k_pos, window)
+                          & (lse > _NEG_INF / 2), p, 0.0)
         dv = dv + _dot(p.astype(do.dtype), do, (1, 0))      # [bk, d_v]
         ds = p * (_dot(v_blk, do, (1, 1)) - delta)          # [bk, bq]
         if not fold_scale:
@@ -325,22 +397,35 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[rows, :] += _dot(ds, k_blk, (0, 0))          # [bq, d_qk]
         return dk, dv
 
-    if causal:
-        # q blocks before this k block see none of it, the blocks the
-        # diagonal crosses run masked, the rest see all of it
-        first = (ik * block_k) // block_q
-        whole = jnp.clip(((ik + 1) * block_k + block_q - 2) // block_q,
-                         first, nq)
-        bounds = [(first, whole, True), (whole, nq, False)]
-    else:
-        bounds = [(0, nq, False)]
+    bounds = _q_bounds(ik, causal=causal, block_q=block_q, block_k=block_k,
+                       nq=nq, window=window)
     dk, dv = _causal_loops(
         body, (jnp.zeros(k_blk.shape, jnp.float32),
                jnp.zeros(v_blk.shape, jnp.float32)), bounds)
     if fold_scale:
         dk = dk * scale
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+    else:
+        dk_acc, dv_acc = kv_acc
+        member = pl.program_id(0) % group
+        k_rows = pl.ds(ik * block_k, block_k)
+
+        @pl.when(member == 0)
+        def _():
+            dk_acc[k_rows, :] = dk
+            dv_acc[k_rows, :] = dv
+
+        @pl.when(member > 0)
+        def _():
+            dk_acc[k_rows, :] += dk
+            dv_acc[k_rows, :] += dv
+
+        @pl.when(member == group - 1)
+        def _():
+            dk_ref[0] = dk_acc[k_rows, :].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[k_rows, :].astype(dv_ref.dtype)
 
     @pl.when(ik == nk - 1)
     def _():
@@ -355,12 +440,15 @@ def _padded_bytes(rows, cols, itemsize):
             * itemsize)
 
 
-def _bwd_vmem_bytes(t, d_qk, d_v, block_q, block_k, itemsize):
+def _bwd_vmem_bytes(t, d_qk, d_v, block_q, block_k, itemsize, group=1,
+                    t_kv=None):
     """The scoped VMEM the backward call asks for, from its own blocks:
     what the pipeline double-buffers (q, dO and dq of the whole head, k,
     v, dk and dv a block, the packed row scalars), the head's float32 dq
-    and the float32 tiles and carries of one block pair, with a quarter
-    more; never under the scope a call gets that asks for none."""
+    and the float32 tiles and carries of one block pair (with grouped
+    key-value heads also the float32 dk and dv of the whole key-value
+    head), with a quarter more; never under the scope a call gets that
+    asks for none."""
     whole = (2 * _padded_bytes(t, d_qk, itemsize)
              + _padded_bytes(t, d_v, itemsize))
     k_side = 2 * (_padded_bytes(block_k, d_qk, itemsize)
@@ -372,16 +460,20 @@ def _bwd_vmem_bytes(t, d_qk, d_v, block_q, block_k, itemsize):
             + 2 * _padded_bytes(block_q, d_qk, 4))
     need = (2 * (whole + k_side + scalars)
             + _padded_bytes(t, d_qk, 4) + pair)
+    if group > 1:
+        need += (_padded_bytes(t_kv or t, d_qk, 4)
+                 + _padded_bytes(t_kv or t, d_v, 4))
     return max(need + need // 4, _VMEM_DEFAULT)
 
 
 def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
-         g_lse=None):
+         g_lse=None, window=None):
     q3, k3, v3, out, lse = res
     bh, t, d_qk = q3.shape
     t_kv, d_v = v3.shape[1:]
     nq = t // block_q
     nk = t_kv // block_k
+    group = bh // k3.shape[0]
 
     # delta_i = rowsum(dO * O) — cheap elementwise, leave it to XLA.
     # A cotangent on lse folds in exactly here: d s = p*(dp - delta)*scale
@@ -395,34 +487,55 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
     delta_b = delta.reshape(bh, nq, 1, block_q)
     lse_spec = _vmem_spec((1, nq, 1, block_q), lambda b, i: (b, 0, 0, 0))
     whole_qk = _vmem_spec((1, t, d_qk), lambda b, i: (b, 0, 0))
-    block_qk = _vmem_spec((1, block_k, d_qk), lambda b, i: (b, i, 0))
-    block_v = _vmem_spec((1, block_k, d_v), lambda b, i: (b, i, 0))
+    scratch = [pltpu.VMEM((t, d_qk), jnp.float32)]
+    if group == 1:
+        def k_block(b, i):
+            return b, i, 0
+
+        dk_block = k_block
+    else:
+        scratch += [pltpu.VMEM((t_kv, d_qk), jnp.float32),
+                    pltpu.VMEM((t_kv, d_v), jnp.float32)]
+
+        def k_block(b, i):
+            return b // group, i, 0
+
+        def dk_block(b, i):
+            # the group's earlier heads write nothing: their block stays
+            # the group's first, which the last head writes first, so
+            # nothing goes to HBM before it holds the group's sum
+            return b // group, jnp.where(b % group == group - 1, i, 0), 0
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+                          block_q=block_q, block_k=block_k, window=window,
+                          group=group),
         grid=(bh, nk),
         in_specs=[
             whole_qk,
-            block_qk,
-            block_v,
+            _vmem_spec((1, block_k, d_qk), k_block),
+            _vmem_spec((1, block_k, d_v), k_block),
             _vmem_spec((1, t, d_v), lambda b, i: (b, 0, 0)),
             lse_spec,
             lse_spec,
         ],
         # dq's block is the head's: it stays in VMEM over the head's k
         # blocks and goes to HBM once
-        out_specs=[whole_qk, block_qk, block_v],
+        out_specs=[whole_qk, _vmem_spec((1, block_k, d_qk), dk_block),
+                   _vmem_spec((1, block_k, d_v), dk_block)],
         out_shape=[
             _sds((bh, t, d_qk), q3.dtype, q3),
-            _sds((bh, t_kv, d_qk), k3.dtype, k3),
-            _sds((bh, t_kv, d_v), v3.dtype, v3),
+            _sds(k3.shape, k3.dtype, k3),
+            _sds(v3.shape, v3.dtype, v3),
         ],
-        scratch_shapes=[pltpu.VMEM((t, d_qk), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # a group's heads add into one dk and dv: in order
+            dimension_semantics=("parallel" if group == 1 else "arbitrary",
+                                 "arbitrary"),
             vmem_limit_bytes=_bwd_vmem_bytes(
-                t, d_qk, d_v, block_q, block_k, q3.dtype.itemsize)),
+                t, d_qk, d_v, block_q, block_k, q3.dtype.itemsize, group,
+                t_kv)),
         interpret=interpret,
     )(q3, k3, v3, g, lse_b, delta_b)
     return dq, dk, dv
@@ -448,7 +561,7 @@ def _pick_block(t, want):
 # A transformer calls this once a layer with the same shapes: under
 # ``jit`` the kernels are traced once and lowered once for all of them,
 # not once a call (GPT-2 medium's step: 72 kernel bodies down to 3).
-_STATIC = ("scale", "causal", "block_q", "block_k", "interpret")
+_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "window")
 _fwd_once = jax.jit(_fwd, static_argnames=_STATIC)
 _bwd_once = jax.jit(_bwd, static_argnames=_STATIC)
 
@@ -467,48 +580,54 @@ def _fwd_named(q3, k3, v3, **static):
     return checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, window):
     out, _ = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k, interpret=interpret)
+                       block_q=block_q, block_k=block_k, interpret=interpret,
+                       window=window)
     return out
 
 
-def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
+               window):
     out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, g):
     return _bwd_once(res, g, scale=scale, causal=causal, block_q=block_q,
-                     block_k=block_k, interpret=interpret)
+                     block_k=block_k, interpret=interpret, window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret,
+               window):
     """Like ``_flash`` but also returns the logsumexp — the streaming-
     softmax state ring attention needs to combine per-block results."""
     return _fwd_once(q3, k3, v3, scale=scale, causal=causal,
-                     block_q=block_q, block_k=block_k, interpret=interpret)
+                     block_q=block_q, block_k=block_k, interpret=interpret,
+                     window=window)
 
 
-def _flash_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret):
+def _flash_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
+                   window):
     out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret)
+                          interpret=interpret, window=window)
     return (out, lse), (q3, k3, v3, out, lse)
 
 
-def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, window, res,
+                   g):
     g_out, g_lse = g
     return _bwd_once(res, g_out, scale=scale, causal=causal,
                      block_q=block_q, block_k=block_k, interpret=interpret,
-                     g_lse=g_lse)
+                     g_lse=g_lse, window=window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -522,10 +641,18 @@ def _env_block(name, default):
 
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None, return_lse=False):
+                    block_k=None, interpret=None, return_lse=False,
+                    window=None):
     """Flash multi-head attention: q ``[B, T, H, d_qk]``, k ``[B, T_kv,
-    H, d_qk]``, v ``[B, T_kv, H, d_v]`` -> ``[B, T, H, d_v]``; ``scale``
+    G, d_qk]``, v ``[B, T_kv, G, d_v]`` -> ``[B, T, H, d_v]``; ``scale``
     defaults to ``1 / sqrt(d_qk)``.
+
+    **Grouped key-value heads**: ``G`` divides ``H``, and q's head ``h``
+    reads head ``h // (H / G)`` of k and v where they lie; dk and dv
+    come back with ``G`` heads, each the sum over its query heads.
+    ``window``: with ``causal``, query i sees the keys ``i - window < j
+    <= i`` (``window`` of them, itself included) of a k and v as long as
+    q; the kernels visit the blocks that hold such a pair and no other.
 
     Differentiable (custom VJP with Pallas backward kernels).  On
     non-TPU backends runs in Pallas interpret mode (tests);
@@ -547,6 +674,16 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     """
     b, t, h, d_qk = q.shape
     t_kv, d_v = k.shape[1], v.shape[3]
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(
+            f"flash_attention: {h} query heads over {k.shape[2]} key and "
+            f"{v.shape[2]} value heads: the key-value heads must be as "
+            f"many and divide the query heads")
+    if window is not None and (not causal or t_kv != t or window < 1):
+        raise ValueError(
+            f"flash_attention: a window ({window}) is at least 1 and "
+            f"needs causal=True and keys as long as the queries "
+            f"({t_kv} for {t})")
     if scale is None:
         scale = 1.0 / math.sqrt(d_qk)
     if interpret is None:
@@ -568,15 +705,15 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     block_k = _pick_block(t_kv, block_k)
 
     def to3(x):
-        tt = x.shape[1]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, tt, x.shape[3])
+        tt, heads = x.shape[1:3]
+        return x.transpose(0, 2, 1, 3).reshape(b * heads, tt, x.shape[3])
 
     if return_lse:
         out3, lse3 = _flash_lse(to3(q), to3(k), to3(v), scale, causal,
-                                block_q, block_k, interpret)
+                                block_q, block_k, interpret, window)
         out = out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
         return out, lse3.reshape(b, h, t)
 
     out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
-                  interpret)
+                  interpret, window)
     return out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)

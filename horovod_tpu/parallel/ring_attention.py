@@ -202,16 +202,27 @@ def ring_self_attention(q, k, v, mesh, *, axis_name="sp", causal=False,
     return fn(q, k, v)
 
 
-def reference_attention(q, k, v, *, causal=False, scale=None):
-    """Dense single-device reference (for tests and small sequences)."""
+def reference_attention(q, k, v, *, causal=False, scale=None, window=None):
+    """Dense single-device reference (for tests and small sequences).
+    k and v may have fewer heads than q (grouped key-value heads: q's
+    head ``h`` of ``H`` reads head ``h // (H / G)`` of their ``G``);
+    with ``window`` (and ``causal``) query i sees the keys ``i - window
+    < j <= i``."""
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if window is not None and not causal:
+        raise ValueError("reference_attention: a window needs causal=True")
+    group = q.shape[2] // k.shape[2]
+    if group > 1:  # dense: a group's query heads each get a copy
+        k, v = (jnp.repeat(u, group, axis=2) for u in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     if causal:
         tq, tk = s.shape[-2], s.shape[-1]
         msk = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        if window is not None:
+            msk &= jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :] < window
         s = jnp.where(msk[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p,

@@ -504,6 +504,80 @@ def test_looped_cell_step_compiles_for_v5e_as_one_set_of_block_bodies(
     assert _fits_one_chip(compiled)
 
 
+@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
+                         ids=["sliding-72over8", "full-48over8"])
+def test_flash_backward_with_grouped_heads_within_the_v5e_vmem(v5e, heads,
+                                                               window):
+    """The backward pass at ``laguna_s_2_1-spmd-1chip``'s two kernel
+    shapes (T 8192, heads of 128, 72 or 48 query heads over 8 key-value
+    heads, a window of 512 or none): ONE custom call that takes q as
+    ``[H, T, d]`` and k and v as ``[8, T, d]``, never repeated to q's
+    heads, and gives dq with q's heads and dk, dv with 8, each the sum
+    over its group formed in float32 in VMEM.  It holds q, dO, dq and
+    the float32 dq of a whole head and the float32 dk and dv of a whole
+    key-value head: the call states that from its own blocks and the
+    kernel's compiler takes no more."""
+    from horovod_tpu.ops.pallas.flash_attention import _bwd, _bwd_vmem_bytes
+
+    t, d = 8192, 128
+
+    def bwd(q, k, v, out, lse, g):
+        return _bwd((q, k, v, out, lse), g, scale=d ** -0.5, causal=True,
+                    block_q=512, block_k=512, interpret=False, window=window)
+
+    q, kv = (_on(v5e[0], (h, t, d), jnp.bfloat16) for h in (heads, 8))
+    text = _compile(bwd, q, kv, kv, q,
+                    _on(v5e[0], (heads, t), jnp.float32), q).as_text()
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1
+    wide, narrow = f"bf16[{heads},{t},{d}]", f"bf16[8,{t},{d}]"
+    result, operands = calls[0].split(" custom-call(", 1)
+    assert re.findall(r"\w+\[[\d,]+\]", result) == [wide, narrow, narrow]
+    assert operands.split("operand_layout_constraints={", 1)[1].startswith(
+        f"{wide}{{2,1,0}}, {narrow}{{2,1,0}}, {narrow}{{2,1,0}}, "
+        f"{wide}{{2,1,0}}")
+    stated, used = _scoped_vmem(calls[0])
+    assert stated == _bwd_vmem_bytes(t, d, d, 512, 512, 2, heads // 8)
+    assert used <= stated <= 128 << 20
+
+
+def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
+    """``laguna_s_2_1-spmd-1chip`` at published widths and the cell's one
+    sequence of 8192, every block recomputed: fits one chip; a flash
+    forward and ONE backward kernel a block and no forward kernel in the
+    recomputation (its output and lse are saved); q of a sliding layer
+    ``[72, 8192, 128]``, of a full layer ``[48, 8192, 128]``, and in
+    the k and v places ``[8, 8192, 128]``: no array of k or v has the
+    query heads' count, and nothing broadcasts one to it."""
+    compiled = cell_step("laguna_s_2_1-spmd-1chip")
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line
+             and "/flash/" in line]
+    narrow = "bf16[8,8192,128]{2,1,0}"
+    seen = []
+    for line in flash:
+        operands = line.split("operand_layout_constraints={", 1)[1]
+        q, k, v = re.findall(r"bf16\[[\d,]+\]\{2,1,0\}", operands)[:3]
+        assert k == v == narrow, operands[:200]
+        heads = int(re.match(r"bf16\[(\d+),8192,128\]", q).group(1))
+        seen.append((heads, "window" if "/attn/window/" in line else "global",
+                     "bwd" if "jit(_bwd)" in line else "fwd"))
+        assert "rematted_computation" not in line
+    assert sorted(seen) == sorted(
+        [(48, "global", way) for way in ("fwd", "bwd")] * 2
+        + [(72, "window", way) for way in ("fwd", "bwd")] * 3)
+    # k and v are never made as wide as q
+    assert not [line for line in text.splitlines()
+                if " broadcast(" in line and re.search(
+                    r"= bf16\[(1,)?(72|48),8192,128\]", line)]
+    assert not re.search(r"bf16\[(1,)?8192,8,(6|9),128\]", text)
+    # the held experts' grouped products, forward, recomputed, backward
+    assert text.count("%ragged-dot-none") >= 4 * 9
+    assert _fits_one_chip(compiled)
+
+
 def _instructions(compiled):
     """The optimized program's instructions without what names a source
     line: metadata, the tables of files and functions ahead of the
