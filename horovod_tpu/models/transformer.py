@@ -46,6 +46,7 @@ from typing import Any, Callable, Optional, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.parallel.ring_attention import reference_attention
 
@@ -209,10 +210,13 @@ class TransformerConfig:
     # rematerialize each block's activations in backward (jax.checkpoint):
     # trades ~1/3 more FLOPs for O(layers) less activation HBM — the
     # lever for pushing per-chip batch (and usually MFU) once
-    # activations, not weights, bound the batch size.  Saved of a block
-    # are its input and, where its attention is the flash kernel, the
-    # kernel's output and lse (``B H T (d_v x itemsize + 4)`` bytes a
-    # block), so the recomputation does not run that kernel again
+    # activations, not weights, bound the batch size.  Kept of a block
+    # are its input and what ``kept_names`` lists (``kept_bytes`` gives
+    # the bytes): the flash kernel's output and lse; with one pass also
+    # the sum after attention (``B T d_model x itemsize``), the kernel's
+    # q, k and v as a set where none is larger than its output (``B T (H
+    # + 2 G) d x itemsize``; not 192 over 128) and latent attention's two
+    # narrow first products.  What made them is not run again
     remat: bool = False
     # the stack of blocks runs this many times with ONE set of weights,
     # the final norm closing each pass: its output is that pass's exit
@@ -264,15 +268,72 @@ def default_attention():
     return reference_attention
 
 
-def recomputed(block):
+# What a block names for the policy of ``recomputed``: results the
+# backward pass reads (or that stand between it and what it reads), cheap
+# to hold and dear to make again.  Outside a checkpoint a name is the
+# identity.
+KEPT_SUM = "block_after_attention"   # x + attention(x): ln2's input
+KEPT_Q_A = "latent_q_a"              # latent attention's x W_qa
+KEPT_KV_A = "latent_kv_a"            # latent attention's x W_kva
+KEPT_NAMES = (KEPT_SUM, KEPT_Q_A, KEPT_KV_A)
+
+
+def kept_names(cfg):
+    """The names a recomputed block of ``cfg`` keeps from its forward
+    pass: the flash kernel's ``SAVED_NAMES`` (its output and lse), its
+    ``SAVED_INPUT_NAMES`` (q, k and v as it reads them, which the
+    kernel's call carries only where they are no larger than its
+    output) and the model's own ``KEPT_NAMES``.
+
+    Under a loop over passes (``cfg.passes > 1``) ``SAVED_NAMES`` alone:
+    there every kept array is stacked once a pass and the loop's tuple
+    holds the stack twice, and the sandwich norm such a model has after
+    its attention reads the output projection's result anyway.  That is
+    how the program is built, which the code reads off its own
+    configuration; it is no model's name."""
+    from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
+                                                        SAVED_NAMES)
+    if cfg.passes > 1:
+        return SAVED_NAMES
+    return SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
+
+
+def recomputed(block, cfg):
     """``block`` under ``jax.checkpoint``: its forward pass runs again in
-    the backward pass, all but the flash forward kernel, whose output and
-    ``lse`` carry names (``flash_attention.SAVED_NAMES``) and are saved.
-    A block that does not run the kernel saves nothing, as under a plain
-    ``nn.remat``."""
-    from horovod_tpu.ops.pallas.flash_attention import SAVED_NAMES
+    the backward pass, all but what made the arrays ``kept_names(cfg)``
+    names, which are kept: the flash forward kernel, the output
+    projection and, where the kernel's inputs are kept, everything ahead
+    of the kernel but the norm.  A block that names nothing keeps
+    nothing, as under a plain ``nn.remat``."""
     return nn.remat(block, policy=jax.checkpoint_policies
-                    .save_only_these_names(*SAVED_NAMES))
+                    .save_only_these_names(*kept_names(cfg)))
+
+
+def kept_bytes(cfg, batch, seq, layer=0):
+    """``{name: bytes}`` of what ONE application of recomputed block
+    ``layer`` keeps by name on ``[batch, seq]`` tokens through the flash
+    kernel (its input, which every checkpoint keeps, is ``batch seq
+    d_model x itemsize`` more)."""
+    from horovod_tpu.ops.pallas.flash_attention import saved_bytes
+
+    cfg = cfg.at(layer)
+    spec = cfg.block.attention
+    heads, groups = cfg.n_heads, cfg.n_heads
+    d_qk = d_v = cfg.head_dim or cfg.d_model // cfg.n_heads
+    if isinstance(spec, GroupedAttention):
+        heads, groups, d_qk, d_v = (spec.heads, spec.kv_heads,
+                                    spec.head_dim, spec.head_dim)
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    kept = {KEPT_SUM: batch * seq * cfg.d_model * itemsize}
+    if isinstance(spec, LatentAttention):
+        d_qk, d_v = spec.nope_dim + spec.rope_dim, spec.v_dim
+        kept[KEPT_Q_A] = batch * seq * spec.q_rank * itemsize
+        kept[KEPT_KV_A] = batch * seq * (spec.kv_rank
+                                         + spec.rope_dim) * itemsize
+    q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
+               for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
+    kept.update(saved_bytes(q, k, v))
+    return {name: kept[name] for name in kept_names(cfg) if name in kept}
 
 
 def rope(x, theta=10000.0, pairs=False):
@@ -398,9 +459,12 @@ def latent_qkv(cfg, x):
                                name=name)
 
     with jax.named_scope("attn/latent"):
-        c_q = make_norm(cfg, "q_a_norm")(dense(spec.q_rank, "q_a")(x))
+        # the norms are made again from the two narrow results kept
+        c_q = make_norm(cfg, "q_a_norm")(checkpoint_name(
+            dense(spec.q_rank, "q_a")(x), KEPT_Q_A))
         q = dense((h, spec.nope_dim + spec.rope_dim), "q_b")(c_q)
-        kv_a = dense(spec.kv_rank + spec.rope_dim, "kv_a")(x)
+        kv_a = checkpoint_name(
+            dense(spec.kv_rank + spec.rope_dim, "kv_a")(x), KEPT_KV_A)
         c_kv = make_norm(cfg, "kv_a_norm")(kv_a[..., :spec.kv_rank])
         kv = dense((h, spec.nope_dim + spec.v_dim), "kv_b")(c_kv)
         k_rope = rope(kv_a[..., None, spec.kv_rank:], cfg.rope_theta, pairs)
@@ -607,7 +671,7 @@ class Block(nn.Module):
         y = Attention(cfg, name="attn")(y.astype(cfg.dtype))
         if sandwich:
             y = make_norm(cfg, "ln1_post")(y)
-        x = x + y
+        x = checkpoint_name(x + y, KEPT_SUM)
         y = make_norm(cfg, "ln2")(x).astype(cfg.dtype)
         ffn = self.ffn or cfg.block.ffn
         if isinstance(ffn, TopkExperts):
@@ -773,7 +837,7 @@ class Transformer(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = recomputed(Block) if cfg.remat else Block
+        block_cls = recomputed(Block, cfg) if cfg.remat else Block
 
         def one_pass(mdl, carry, _):
             """The stack once, closed by the final norm; the carry is
@@ -838,7 +902,7 @@ class NextTokenModule(nn.Module):
              make_norm(cfg, "hnorm")(hidden)], axis=-1)
         x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                      name="eh_proj")(x)
-        block_cls = recomputed(Block) if cfg.remat else Block
+        block_cls = recomputed(Block, cfg) if cfg.remat else Block
         x = block_cls(cfg, name="block")(x, router_bias)
         x = make_norm(cfg, "ln_f")(x).astype(cfg.dtype)
         return jnp.dot(x, head.astype(cfg.dtype))

@@ -573,6 +573,33 @@ _bwd_once = jax.jit(_bwd, static_argnames=_STATIC)
 SAVED_OUT = "flash_attention_out"
 SAVED_LSE = "flash_attention_lse"
 SAVED_NAMES = (SAVED_OUT, SAVED_LSE)
+# The kernel's inputs as it reads them (``[B H, T, d]``, after the
+# transposes) carry these, AS A SET and only where each of the three is
+# no larger than ``out`` (``saved_bytes``): the backward kernel reads all
+# three, so two of three buy nothing.  A policy that keeps them too makes
+# the projections, the rotation and the transposes ahead of the kernel
+# dead code in the recomputation.
+SAVED_INPUT_NAMES = ("flash_attention_q", "flash_attention_k",
+                     "flash_attention_v")
+
+
+def saved_bytes(q, k, v):
+    """``{name: bytes}`` of what one call on q ``[B, T, H, d_qk]``, k
+    ``[B, T_kv, G, d_qk]``, v ``[B, T_kv, G, d_v]`` (arrays or anything
+    with their ``shape`` and ``dtype``) gives names to: ``out`` and
+    ``lse`` always (``SAVED_NAMES``), and q, k, v in the kernel's layout
+    (``SAVED_INPUT_NAMES``) where none of the three is larger than
+    ``out`` in bytes.  That is ``d_qk <= d_v`` with k and v no longer
+    and of no more heads than q: grouped or plain heads of one width; not
+    latent attention's 192 over 128, whose q and k are 1.5 x ``out``."""
+    itemsize = jnp.dtype(q.dtype).itemsize
+    rows = math.prod(q.shape[:-1])                     # B T H
+    kept = {SAVED_OUT: rows * v.shape[-1] * itemsize, SAVED_LSE: rows * 4}
+    inputs = {name: math.prod(x.shape) * itemsize
+              for name, x in zip(SAVED_INPUT_NAMES, (q, k, v))}
+    if max(inputs.values()) <= kept[SAVED_OUT]:
+        kept.update(inputs)
+    return kept
 
 
 def _fwd_named(q3, k3, v3, **static):
@@ -670,7 +697,11 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     ``models/transformer.py`` has it): ``out [B H, T, d_v]`` in the input
     dtype and ``lse [B H, T]`` in float32, ``B H T (d_v x itemsize + 4)``
     bytes a call, are then kept from the forward pass and the backward
-    kernels read them.  Outside a checkpoint the names do nothing.
+    kernel reads them.  Where none of them is larger than ``out``
+    (:func:`saved_bytes`), q, k and v as the kernel reads them carry
+    ``SAVED_INPUT_NAMES``: a policy that keeps those too has nothing
+    ahead of the kernel left to recompute for it.  Outside a checkpoint
+    the names do nothing.
     """
     b, t, h, d_qk = q.shape
     t_kv, d_v = k.shape[1], v.shape[3]
@@ -708,12 +739,14 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
         tt, heads = x.shape[1:3]
         return x.transpose(0, 2, 1, 3).reshape(b * heads, tt, x.shape[3])
 
+    qkv3 = to3(q), to3(k), to3(v)
+    if SAVED_INPUT_NAMES[0] in saved_bytes(q, k, v):
+        qkv3 = map(checkpoint_name, qkv3, SAVED_INPUT_NAMES)
     if return_lse:
-        out3, lse3 = _flash_lse(to3(q), to3(k), to3(v), scale, causal,
-                                block_q, block_k, interpret, window)
+        out3, lse3 = _flash_lse(*qkv3, scale, causal, block_q, block_k,
+                                interpret, window)
         out = out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
         return out, lse3.reshape(b, h, t)
 
-    out3 = _flash(to3(q), to3(k), to3(v), scale, causal, block_q, block_k,
-                  interpret, window)
+    out3 = _flash(*qkv3, scale, causal, block_q, block_k, interpret, window)
     return out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)

@@ -467,6 +467,14 @@ def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
         assert len([line for line in text.splitlines()
                     if "tpu_custom_call" in line
                     and "[128,4096,192]" in line]) == 6 * 2
+        # nor the output projection and latent attention's two narrow
+        # first products, whose results it keeps; q and k at 192 are
+        # wider than the kernel's output, so ``q_b`` and ``kv_b`` run
+        # again
+        assert not _products(_recomputed(
+            text, "/attn/out/", "/attn/latent/q_a/", "/attn/latent/kv_a/"))
+        assert len(_products(_recomputed(text, "/attn/latent/q_b/"))) == 6
+        assert len(_products(_recomputed(text, "/attn/latent/kv_b/"))) == 6
     else:
         # every row exists: the three plain gathers, no loop, no scatter
         assert whole and not loops
@@ -575,7 +583,34 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     assert not re.search(r"bf16\[(1,)?8192,8,(6|9),128\]", text)
     # the held experts' grouped products, forward, recomputed, backward
     assert text.count("%ragged-dot-none") >= 4 * 9
+    # a block keeps the kernel's q, k and v and the sum after attention:
+    # the recomputation makes no projection of attention, no rotation and
+    # no copy into the kernel's layout again.  Left under the
+    # projections' scopes are their weights' casts to bfloat16, which the
+    # backward products read
+    assert _products(_recomputed(text, "/mlp/up/"))
+    ahead = _recomputed(
+        text, "/attn/window/q/", "/attn/window/kv/", "/attn/window/out/",
+        "/attn/global/q/", "/attn/global/kv/", "/attn/global/out/")
+    assert ahead and all('/convert_element_type"' in line for line in ahead)
+    assert not _recomputed(text, "/rope/", "/flash/")
     assert _fits_one_chip(compiled)
+
+
+def _recomputed(text, *scopes):
+    """The instructions of a compiled step that a checkpoint makes again
+    (``rematted_computation`` in their ``op_name``) under one of
+    ``scopes``."""
+    return [line for line in text.splitlines()
+            if "rematted_computation" in line
+            and any(scope in line for scope in scopes)]
+
+
+def _products(lines):
+    """Those of ``lines`` that are a matrix product's instruction (a
+    fusion it went into carries the same ``op_name`` beside it)."""
+    return [line for line in lines
+            if " convolution(" in line and '/dot_general"' in line]
 
 
 def _instructions(compiled):
@@ -622,11 +657,36 @@ def test_saved_names_are_nothing_in_a_step_without_recomputation(
     the step without the names, instruction for instruction."""
     named = _instructions(cell_step(workload))
     # (the package's attribute of this name is the function)
-    monkeypatch.setattr(
-        importlib.import_module("horovod_tpu.ops.pallas.flash_attention"),
-        "checkpoint_name", lambda x, name: x)
+    for module in ("horovod_tpu.ops.pallas.flash_attention",
+                   "horovod_tpu.models.transformer"):
+        monkeypatch.setattr(importlib.import_module(module),
+                            "checkpoint_name", lambda x, name: x)
     unnamed = _instructions(cell_step(workload, variant="unnamed"))
     assert len(named) > 2000 and named == unnamed
+
+
+def test_looped_cell_step_keeps_what_it_kept(cell_step, monkeypatch):
+    """Under the loop over the passes a recomputed block keeps the flash
+    kernel's output and lse and nothing new (the cell has 0.45 GiB left
+    and the loop's tuple holds every kept array twice): the step is the
+    one compiled with the policy from before and with none of the new
+    names, instruction for instruction."""
+    import flax.linen as nn
+
+    from horovod_tpu.models import transformer
+
+    now = _instructions(cell_step("ouro_2_6b-spmd-1chip"))
+    flash = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+    name = flash.checkpoint_name
+    monkeypatch.setattr(flash, "checkpoint_name", lambda x, n: (
+        name(x, n) if n in flash.SAVED_NAMES else x))
+    monkeypatch.setattr(transformer, "checkpoint_name", lambda x, n: x)
+    monkeypatch.setattr(transformer, "recomputed", lambda block, cfg: nn.remat(
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            *flash.SAVED_NAMES)))
+    before = _instructions(cell_step("ouro_2_6b-spmd-1chip",
+                                     variant="policy-before"))
+    assert len(now) > 2000 and now == before
 
 
 @pytest.mark.parametrize("chips,compression,hierarchical", [

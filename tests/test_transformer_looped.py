@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 from jax._src.ad_checkpoint import saved_residuals
 
-from horovod_tpu.models import (BlockSpec, LatentAttention, TopkExperts,
+from horovod_tpu.models import (BlockSpec, GroupedAttention,
+                                LatentAttention, TopkExperts,
                                 Transformer, TransformerConfig,
                                 apply_with_aux, lm_loss, looped_lm_loss,
                                 transformer)
@@ -96,7 +97,7 @@ class TransformerBefore(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = recomputed(Block) if cfg.remat else Block
+        block_cls = recomputed(Block, cfg) if cfg.remat else Block
         rows = 0
         for i in range(cfg.n_layers):
             ffn = cfg.ffn_of(i)
@@ -331,7 +332,19 @@ RECOMPUTED = {
                             q_rank=24, kv_rank=16, nope_dim=128,
                             rope_dim=64, v_dim=128))),
     "looped": dict(block=SANDWICH, passes=4, exit_gate=True),
+    # 6 and 4 query heads over 2 key-value heads, with a window and without
+    "grouped_window": dict(block=BlockSpec(
+        norm="rms", positions="rope", ffn="swiglu",
+        attention=GroupedAttention(heads=6, kv_heads=2, head_dim=8, window=4,
+                                   gate="softplus"))),
+    "grouped_full": dict(block=BlockSpec(
+        norm="rms", positions="rope", ffn="swiglu",
+        attention=GroupedAttention(heads=4, kv_heads=2, head_dim=8))),
 }
+
+
+def plain_remat(block, cfg):
+    return nn.remat(block)
 
 
 def recomputed_loss(kind, attn_fn=flash_attention):
@@ -366,21 +379,22 @@ def test_a_recomputed_block_runs_each_flash_kernel_once(kind, monkeypatch):
     cfg, params, loss = recomputed_loss(kind)
     n = cfg.n_layers
     assert kernel_calls(loss, params) == (n, n)
-    monkeypatch.setattr(transformer, "recomputed", nn.remat)
+    monkeypatch.setattr(transformer, "recomputed", plain_remat)
     assert kernel_calls(loss, params) == (2 * n, n)
 
 
 @pytest.mark.parametrize("kind", sorted(RECOMPUTED))
 def test_saving_the_kernels_results_changes_no_bit(kind, monkeypatch):
-    """The saved output and lse are what the recomputation would have
+    """What a block keeps is what the recomputation would have
     produced: the loss and every gradient leaf are bitwise those of a
-    plain ``nn.remat``.  (In float32: in bfloat16 the CPU's compiler
-    keeps float32 between two instructions where it can, which two
-    programs do in different places.)"""
+    plain ``nn.remat``.  (Instruction by instruction, with no ``jit``
+    around the gradient: compiled whole, a program that recomputes less
+    is fused elsewhere and the CPU's compiler sums a product in another
+    order, 1e-7 apart in float32.)"""
     _, params, loss = recomputed_loss(kind)
-    got = jax.jit(jax.value_and_grad(loss))(params)
-    monkeypatch.setattr(transformer, "recomputed", nn.remat)
-    want = jax.jit(jax.value_and_grad(loss))(params)
+    got = jax.value_and_grad(loss)(params)
+    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    want = jax.value_and_grad(loss)(params)
     assert float(jnp.max(jnp.abs(got[1]["block_0"]["attn"]["out"]["kernel"]
                                  ))) > 0
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
@@ -400,20 +414,44 @@ def saved(loss, params):
 def test_a_recomputed_block_saves_what_its_attention_names(kind,
                                                            monkeypatch):
     """Through the flash kernel a block saves ``out [B H, T, d_v]`` in
-    the activation type and ``lse [B H, T]`` in float32 (stacked over
-    the passes under the scan) and nothing else more than a plain
-    ``nn.remat``; on the dense attention, which names nothing, it saves
-    no more than a plain ``nn.remat``."""
+    the activation type and ``lse [B H, T]`` in float32, under the scan
+    stacked over the passes and nothing else; with one pass also the
+    sum after attention, q, k and v in the kernel's layout where none is
+    wider than ``out`` (not at 192 over 128) and latent attention's two
+    narrow products: nothing else more than a plain ``nn.remat``.  On
+    the dense attention the kernel's names are not there."""
     cfg, params, flash = recomputed_loss(kind)
     _, _, dense = recomputed_loss(kind, reference_attention)
     named, unnamed = saved(flash, params), saved(dense, params)
-    monkeypatch.setattr(transformer, "recomputed", nn.remat)
-    assert unnamed == saved(dense, params)
-    plain = saved(flash, params)
-    bh, t = TOKENS.shape[0] * cfg.n_heads, TOKENS.shape[1]
-    d_v = (cfg.block.attention.v_dim if kind == "latent"
-           else cfg.d_model // cfg.n_heads)
+    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    plain, plain_dense = saved(flash, params), saved(dense, params)
+    b, t = TOKENS.shape
+    spec = cfg.block.attention
+    heads = groups = cfg.n_heads
+    d_qk = d_v = cfg.d_model // cfg.n_heads
+    if kind == "latent":
+        d_qk, d_v = spec.nope_dim + spec.rope_dim, spec.v_dim
+    elif kind.startswith("grouped"):
+        heads, groups, d_qk, d_v = (spec.heads, spec.kv_heads,
+                                    spec.head_dim, spec.head_dim)
+    kind_of = str(jnp.dtype(cfg.dtype))
     stack = (cfg.passes,) if cfg.passes > 1 else ()
-    more = [(stack + (bh, t, d_v), str(jnp.dtype(cfg.dtype))),
-            (stack + (bh, t), "float32")] * cfg.n_layers
-    assert named == sorted(plain + more)
+    kernel = [(stack + (b * heads, t, d_v), kind_of),
+              (stack + (b * heads, t), "float32")]
+    model = []
+    if cfg.passes == 1:
+        model = [((b, t, cfg.d_model), kind_of)]
+        if kind == "latent":
+            model += [((b, t, spec.q_rank), kind_of),
+                      ((b, t, spec.kv_rank + spec.rope_dim), kind_of)]
+        else:
+            kernel += [((b * heads, t, d_qk), kind_of),
+                       ((b * groups, t, d_qk), kind_of),
+                       ((b * groups, t, d_v), kind_of)]
+        if kind == "plain":
+            # the reference LayerNorm's jitted ``_var`` hands the kept sum
+            # on to its backward half: a second line of this account for
+            # the same array
+            model *= 2
+    assert named == sorted(plain + (kernel + model) * cfg.n_layers)
+    assert unnamed == sorted(plain_dense + model * cfg.n_layers)

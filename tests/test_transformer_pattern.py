@@ -153,7 +153,7 @@ def test_recomputed_blocks_of_a_pattern_give_the_same_gradients():
     got = jax.grad(loss(Transformer(config(remat=True))))(params)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
-    assert issubclass(recomputed(Block), nn.Module)
+    assert issubclass(recomputed(Block, cfg), nn.Module)
 
 
 def test_the_compiled_step_carries_the_four_scopes():
