@@ -15,6 +15,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     LatentAttention,
     NextTokenModule,
     Rotary,
+    ShortConv,
     TopkExperts,
     Transformer,
     TransformerConfig,

@@ -33,8 +33,10 @@ gives ``TransformerConfig.pattern``, a period of specs: layer ``l`` is
 built from ``pattern[l % len(pattern)]``; with
 ``attention=GroupedAttention(...)`` a kind of layer has its own count
 of query heads over grouped key-value heads, a sliding window or none,
-its own rotary recipe (:class:`Rotary`) and a gate a head on the
-attention's output.
+its own rotary recipe (:class:`Rotary`), a norm over each head of q
+and k and a gate a head on the attention's output.  A block's mixer need
+not be attention: ``attention=ShortConv(...)`` is a doubly gated causal
+convolution of a few taps along the sequence (:class:`ShortConvMixer`).
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
@@ -102,13 +104,17 @@ class GroupedAttention:
     recipe q and k are turned by.  ``gate``: ``"softplus"`` scales every
     head's output by ``softplus(x W_g)``, one scalar a head and position
     in float32, from the input the projections read (``None``: no
-    gate)."""
+    gate).  ``qk_norm``: an RMSNorm over the ``head_dim`` columns of
+    EVERY head of q and of k, one scale of ``head_dim`` each shared by
+    the heads, before the rotation (``BlockSpec.qk_norm`` norms the
+    whole projection, which is another function)."""
     heads: int
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
     rotary: Rotary = Rotary()
     gate: Optional[str] = None
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.heads % self.kv_heads:
@@ -118,6 +124,19 @@ class GroupedAttention:
         if self.gate not in (None, "softplus"):
             raise ValueError(f"GroupedAttention: gate {self.gate!r} is "
                              f"neither None nor 'softplus'")
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConv:
+    """A mixer that is no attention (:class:`ShortConvMixer`): a causal
+    depthwise convolution of ``taps`` taps along the sequence between
+    two gates.  A position reads itself and the ``taps - 1`` before it;
+    no state but those, no kernel, no positions."""
+    taps: int = 3
+
+    def __post_init__(self):
+        if self.taps < 1:
+            raise ValueError(f"ShortConv: {self.taps} taps")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,8 +168,9 @@ class BlockSpec:
     function; no table) or ``"rope_pairs"`` (rotary over the pairs
     ``(2i, 2i + 1)``).  ``qk_norm``: a norm of the block's kind over the
     whole q and k projections, before the split into heads.  ``attention``:
-    ``"full"`` (one fused q, k, v projection, heads of one width), a
-    :class:`LatentAttention` or a :class:`GroupedAttention`.  ``ffn``:
+    the block's mixer: ``"full"`` (one fused q, k, v projection, heads of
+    one width), a :class:`LatentAttention`, a :class:`GroupedAttention`
+    or a :class:`ShortConv`, which is no attention.  ``ffn``:
     ``"gelu"`` (dense up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x
     gate) * (x up)`` down), ``"moe_switch"``
     (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
@@ -160,7 +180,8 @@ class BlockSpec:
     positions: str = "learned"
     qk_norm: bool = False
     ffn: Union[str, TopkExperts] = "gelu"
-    attention: Union[str, LatentAttention, GroupedAttention] = "full"
+    attention: Union[str, LatentAttention, GroupedAttention,
+                     ShortConv] = "full"
     norm_placement: str = "pre"
 
     def __post_init__(self):
@@ -169,7 +190,7 @@ class BlockSpec:
                 (self.norm_placement, NORM_PLACEMENTS, ()),
                 (self.ffn, FFNS, TopkExperts),
                 (self.attention, ATTENTIONS,
-                 (LatentAttention, GroupedAttention))):
+                 (LatentAttention, GroupedAttention, ShortConv))):
             if value not in known and not isinstance(value, cls):
                 raise ValueError(f"BlockSpec: {value!r} is none of {known}")
 
@@ -290,11 +311,20 @@ def kept_names(cfg):
     holds the stack twice, and the sandwich norm such a model has after
     its attention reads the output projection's result anyway.  That is
     how the program is built, which the code reads off its own
-    configuration; it is no model's name."""
+    configuration; it is no model's name.
+
+    Where no block of ``cfg`` has a kernel (every mixer a
+    :class:`ShortConv`; ``cfg.at(layer)`` is such a configuration for a
+    conv layer of a mixed pattern) there is nothing of the flash kernel
+    to name: the sum after the mixer alone.  One policy serves a mixed
+    pattern: a name no block sets keeps nothing."""
     from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                         SAVED_NAMES)
     if cfg.passes > 1:
         return SAVED_NAMES
+    if all(isinstance(spec.attention, ShortConv)
+           for spec in cfg.pattern or (cfg.block,)):
+        return (KEPT_SUM,)
     return SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
 
 
@@ -313,7 +343,9 @@ def kept_bytes(cfg, batch, seq, layer=0):
     """``{name: bytes}`` of what ONE application of recomputed block
     ``layer`` keeps by name on ``[batch, seq]`` tokens through the flash
     kernel (its input, which every checkpoint keeps, is ``batch seq
-    d_model x itemsize`` more)."""
+    d_model x itemsize`` more).  A layer whose mixer is a
+    :class:`ShortConv` has no kernel and no q, k or v: the sum after the
+    mixer is all it names."""
     from horovod_tpu.ops.pallas.flash_attention import saved_bytes
 
     cfg = cfg.at(layer)
@@ -330,9 +362,10 @@ def kept_bytes(cfg, batch, seq, layer=0):
         kept[KEPT_Q_A] = batch * seq * spec.q_rank * itemsize
         kept[KEPT_KV_A] = batch * seq * (spec.kv_rank
                                          + spec.rope_dim) * itemsize
-    q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
-               for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
-    kept.update(saved_bytes(q, k, v))
+    if not isinstance(spec, ShortConv):
+        q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
+                   for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
+        kept.update(saved_bytes(q, k, v))
     return {name: kept[name] for name in kept_names(cfg) if name in kept}
 
 
@@ -482,8 +515,9 @@ def grouped_attention(cfg, x):
     """Attention of a :class:`GroupedAttention` on ``x [..., T, d]``
     (submodules of the :class:`Attention` that calls it).  Under the
     scope ``attn/window`` where the kind has a window and
-    ``attn/global`` where it has none, the attention function's call
-    under ``flash`` inside it and the gate under ``attn/gate``."""
+    ``attn/global`` where it has none, the norm of the heads under
+    ``qk_norm`` and the attention function's call under ``flash``
+    inside it, and the gate under ``attn/gate``."""
     spec = cfg.block.attention
 
     def kind():
@@ -498,6 +532,11 @@ def grouped_attention(cfg, x):
         q = dense((spec.heads, spec.head_dim), "q")(x)
         kv = dense((2, spec.kv_heads, spec.head_dim), "kv")(x)
         k, v = kv[..., 0, :, :], kv[..., 1, :, :]
+        if spec.qk_norm:
+            with jax.named_scope("qk_norm"):
+                # over each head's columns, one scale shared by the heads
+                q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
+                k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
         with jax.named_scope("rope"):
             q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
         attn = cfg.attn_fn or default_attention()
@@ -535,6 +574,55 @@ class Attention(nn.Module):
         o = o.reshape(o.shape[:-2] + (-1,))
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="out")(o)
+
+
+def _taps_init(key, shape, dtype=jnp.float32):
+    """Uniform in ``+- 1 / sqrt(taps)``: a depthwise ``Conv1d``'s
+    default start (fan-in = the taps)."""
+    bound = 1 / math.sqrt(shape[0])
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+class ShortConvMixer(nn.Module):
+    """The mixer of a :class:`ShortConv` block on ``x [..., T, d]``: no
+    attention, no bias, no non-linearity but two gates,
+
+        (b, c, h) = split3(x W_in);  g = b * h
+        s[t] = sum_j w[j] * g[t - (taps - 1) + j]     (rows before 0: zero)
+        y = (c * s) W_out
+
+    with ``W_in [d, 3 d]``, ``w [taps, d]`` (a depthwise causal
+    convolution along ``T``: the LAST tap weighs the position itself, the
+    first the one ``taps - 1`` before it) and ``W_out [d, d]``.  The taps
+    are that many shifted multiply-adds over ``g`` padded ahead with
+    zero rows, in the activation dtype with the products summed in
+    float32; JAX differentiates them.  All of it runs under the scope
+    ``mixer/conv``: the two products under ``in`` and ``out``, the
+    gates and the taps (elementwise, bound by HBM) under ``gate_conv``."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg, taps = self.cfg, self.cfg.block.attention.taps
+        d, t = cfg.d_model, x.shape[-2]
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        with jax.named_scope("mixer/conv"):
+            bch = dense(3 * d, "in")(x)
+            w = self.param("kernel", _taps_init, (taps, d), jnp.float32)
+            with jax.named_scope("gate_conv"):
+                b, c, h = (bch[..., i * d:(i + 1) * d] for i in range(3))
+                ahead = [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)]
+                g = jnp.pad(b * h, ahead)
+                # operands in the activation dtype, as a Dense's kernel
+                w = w.astype(cfg.dtype).astype(jnp.float32)
+                s = sum(g[..., j:j + t, :].astype(jnp.float32) * w[j]
+                        for j in range(taps))
+                y = c * s.astype(cfg.dtype)
+            return dense(d, "out")(y)
 
 
 class Mlp(nn.Module):
@@ -667,8 +755,11 @@ class Block(nn.Module):
         router, for a :class:`TopkExperts`."""
         cfg = self.cfg
         sandwich = cfg.block.norm_placement == "sandwich"
-        y = make_norm(cfg, "ln1")(x)
-        y = Attention(cfg, name="attn")(y.astype(cfg.dtype))
+        y = make_norm(cfg, "ln1")(x).astype(cfg.dtype)
+        if isinstance(cfg.block.attention, ShortConv):
+            y = ShortConvMixer(cfg, name="mixer")(y)
+        else:
+            y = Attention(cfg, name="attn")(y)
         if sandwich:
             y = make_norm(cfg, "ln1_post")(y)
         x = checkpoint_name(x + y, KEPT_SUM)

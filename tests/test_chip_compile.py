@@ -512,41 +512,43 @@ def test_looped_cell_step_compiles_for_v5e_as_one_set_of_block_bodies(
     assert _fits_one_chip(compiled)
 
 
-@pytest.mark.parametrize("heads,window", [(72, 512), (48, None)],
-                         ids=["sliding-72over8", "full-48over8"])
-def test_flash_backward_with_grouped_heads_within_the_v5e_vmem(v5e, heads,
-                                                               window):
+@pytest.mark.parametrize("heads,groups,d,window", [
+    (72, 8, 128, 512), (48, 8, 128, None), (64, 16, 64, None)],
+    ids=["sliding-72over8", "full-48over8", "full-64over16-heads-of-64"])
+def test_flash_backward_with_grouped_heads_within_the_v5e_vmem(
+        v5e, heads, groups, d, window):
     """The backward pass at ``laguna_s_2_1-spmd-1chip``'s two kernel
     shapes (T 8192, heads of 128, 72 or 48 query heads over 8 key-value
-    heads, a window of 512 or none): ONE custom call that takes q as
-    ``[H, T, d]`` and k and v as ``[8, T, d]``, never repeated to q's
-    heads, and gives dq with q's heads and dk, dv with 8, each the sum
-    over its group formed in float32 in VMEM.  It holds q, dO, dq and
-    the float32 dq of a whole head and the float32 dk and dv of a whole
-    key-value head: the call states that from its own blocks and the
-    kernel's compiler takes no more."""
+    heads, a window of 512 or none) and at ``lfm2_24b_a2b-spmd-1chip``'s
+    (two sequences' 32 query heads of 64 over their 8 key-value heads):
+    ONE custom call that takes q as ``[H, T, d]`` and k and v as ``[G,
+    T, d]``, never repeated to q's heads, and gives dq with q's heads
+    and dk, dv with G, each the sum over its group formed in float32 in
+    VMEM.  It holds q, dO, dq and the float32 dq of a whole head and the
+    float32 dk and dv of a whole key-value head: the call states that
+    from its own blocks and the kernel's compiler takes no more."""
     from horovod_tpu.ops.pallas.flash_attention import _bwd, _bwd_vmem_bytes
 
-    t, d = 8192, 128
+    t = 8192
 
     def bwd(q, k, v, out, lse, g):
         return _bwd((q, k, v, out, lse), g, scale=d ** -0.5, causal=True,
                     block_q=512, block_k=512, interpret=False, window=window)
 
-    q, kv = (_on(v5e[0], (h, t, d), jnp.bfloat16) for h in (heads, 8))
+    q, kv = (_on(v5e[0], (h, t, d), jnp.bfloat16) for h in (heads, groups))
     text = _compile(bwd, q, kv, kv, q,
                     _on(v5e[0], (heads, t), jnp.float32), q).as_text()
     calls = [line for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert len(calls) == 1
-    wide, narrow = f"bf16[{heads},{t},{d}]", f"bf16[8,{t},{d}]"
+    wide, narrow = f"bf16[{heads},{t},{d}]", f"bf16[{groups},{t},{d}]"
     result, operands = calls[0].split(" custom-call(", 1)
     assert re.findall(r"\w+\[[\d,]+\]", result) == [wide, narrow, narrow]
     assert operands.split("operand_layout_constraints={", 1)[1].startswith(
         f"{wide}{{2,1,0}}, {narrow}{{2,1,0}}, {narrow}{{2,1,0}}, "
         f"{wide}{{2,1,0}}")
     stated, used = _scoped_vmem(calls[0])
-    assert stated == _bwd_vmem_bytes(t, d, d, 512, 512, 2, heads // 8)
+    assert stated == _bwd_vmem_bytes(t, d, d, 512, 512, 2, heads // groups)
     assert used <= stated <= 128 << 20
 
 
@@ -595,6 +597,60 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     assert ahead and all('/convert_element_type"' in line for line in ahead)
     assert not _recomputed(text, "/rope/", "/flash/")
     assert _fits_one_chip(compiled)
+
+
+def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
+    """``lfm2_24b_a2b-spmd-1chip`` at published widths and the cell's
+    two sequences of 8192, every block recomputed: fits one chip with
+    room (8.3 GiB); ONE flash forward and ONE backward kernel, the
+    attention layer's, q ``[64, 8192, 64]`` over k and v ``[16, 8192,
+    64]`` (two sequences' 32 query heads over their 8 key-value heads,
+    never repeated to q's count) and no forward kernel in the
+    recomputation; the four conv mixers under their scopes, forward and
+    backward, with no kernel and no convolution primitive of their own:
+    the taps are elementwise; a conv block's recomputation makes ``in``
+    again and not ``out``, whose result is in the kept sum."""
+    compiled = cell_step("lfm2_24b_a2b-spmd-1chip")
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line
+             and "/flash/" in line]
+    assert len(flash) == 2
+    for line in flash:
+        assert "/block_1/attn/attn/global/flash/" in line
+        assert "rematted_computation" not in line
+        operands = line.split("operand_layout_constraints={", 1)[1]
+        assert re.findall(r"bf16\[[\d,]+\]\{2,1,0\}", operands)[:3] == [
+            "bf16[64,8192,64]{2,1,0}", "bf16[16,8192,64]{2,1,0}",
+            "bf16[16,8192,64]{2,1,0}"]
+    assert sorted("bwd" if "jit(_bwd)" in line else "fwd"
+                  for line in flash) == ["bwd", "fwd"]
+    # k and v are never made as wide as q
+    assert not [line for line in text.splitlines()
+                if " broadcast(" in line and re.search(
+                    r"= bf16\[(1,)?(2,)?(64|32),8192,64\]", line)]
+    assert not re.search(r"bf16\[(2,)?8192,8,4,64\]", text)
+    # every conv mixer, forward and backward, by its scopes
+    for block in (0, 2, 3, 4):
+        for scope in ("in", "gate_conv", "out"):
+            path = f"/block_{block}/mixer/mixer/conv/{scope}/"
+            assert f"jvp(Transformer)){path}" in text or (
+                f"jvp(Transformer)/checkpoint{path}" in text), path
+        again = _recomputed(text, f"/block_{block}/mixer/mixer/conv/")
+        assert len(_products(again)) == 1
+        assert _products(again) == _products(_recomputed(
+            text, f"/block_{block}/mixer/mixer/conv/in/"))
+    # the taps are shifted multiply-adds: no convolution under their scope
+    assert not [line for line in text.splitlines()
+                if " convolution(" in line and "/gate_conv/" in line]
+    assert "/attn/global/qk_norm/" in text
+    # the held experts' grouped products, forward, recomputed, backward
+    assert text.count("%ragged-dot-none") >= 4 * 9
+    assert _fits_one_chip(compiled)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) < 8.5 * 2 ** 30
 
 
 def _recomputed(text, *scopes):

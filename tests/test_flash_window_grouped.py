@@ -104,6 +104,26 @@ def test_two_widths_without_a_window_and_with_one(heads, kv_heads):
             np.testing.assert_allclose(g, wg, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "w24"])
+def test_thirty_two_heads_of_64_over_eight(window):
+    """Grouped heads of 64 (32 query heads over 8 key-value heads,
+    groups of 4: ``lfm2_24b_a2b-spmd-1chip``'s full-attention layer; PR
+    25 swept heads of 64 with as many key-value heads as query heads, PR
+    38's index map ran at 128): forward and the three gradients, dk and
+    dv the sums over their four query heads."""
+    q, k, v, w = inputs(32, 8, d_qk=64, d_v=64, seed=3)
+    want, want_grads = value_and_grads(
+        lambda q, k, v: dense(q, k, v, window), q, k, v, w)
+    np.testing.assert_allclose(flash(window)(q, k, v),
+                               dense(q, k, v, window), rtol=1e-5, atol=2e-6)
+    got, got_grads = value_and_grads(flash(window), q, k, v, w)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    for g, wg in zip(got_grads, want_grads):
+        assert g.shape == wg.shape
+        np.testing.assert_allclose(g, wg, rtol=1e-4, atol=1e-5)
+    assert got_grads[1].shape == (2, T, 8, 64)
+
+
 def test_blocks_that_differ_and_the_lse():
     """block_q != block_k, and the logsumexp of a windowed row."""
     q, k, v, _ = inputs(6, 2, t=96)

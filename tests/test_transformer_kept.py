@@ -281,7 +281,15 @@ Q, K, V = SAVED_INPUT_NAMES
     # under the passes: what PR 34 kept, an application
     ("ouro_2_6b-spmd-1chip", 0, {
         SAVED_OUT: 16_777_216, SAVED_LSE: 262_144}),
-], ids=["laguna-sliding", "laguna-full", "joyai", "ouro"])
+    # a conv layer has no kernel: the sum after its mixer, 67.1 MB
+    ("lfm2_24b_a2b-spmd-1chip", 0, {KEPT_SUM: 67_108_864}),
+    ("lfm2_24b_a2b-spmd-1chip", 4, {KEPT_SUM: 67_108_864}),
+    # its attention layer: q [64, 8192, 64] + k, v [16, 8192, 64]
+    ("lfm2_24b_a2b-spmd-1chip", 1, {
+        SAVED_OUT: 67_108_864, SAVED_LSE: 2_097_152, Q: 67_108_864,
+        K: 16_777_216, V: 16_777_216, KEPT_SUM: 67_108_864}),
+], ids=["laguna-sliding", "laguna-full", "joyai", "ouro", "lfm2-conv-dense",
+        "lfm2-conv", "lfm2-full"])
 def test_kept_bytes_of_the_cells_blocks_by_hand(cell_config, workload, layer,
                                                 want):
     cfg, batch, seq = cell_config(workload)
