@@ -21,6 +21,15 @@ framework ships one).  Design per the TPU architecture:
   never visited, blocks every row sees whole run with no mask work at all
   (no iota, compare or select), and only the blocks the diagonal crosses
   run the masked body;
+- both kernels hold their scores **transposed**, ``k @ q.T -> [block_k,
+  block_q]``: a q block's row scalars (the forward's running max, sum and
+  rescale; the backward's ``lse`` and ``delta``) are lane rows that
+  broadcast down the sublanes, and a reduction over the keys runs down
+  the sublanes.  The forward accumulates its output transposed too
+  (``o.T [d_v, block_q] += v.T @ p``: the small operand is the one that
+  is turned) and turns it once a q block; a masked tile pays one select
+  (the scores against ``-inf``; the max never falls under a finite
+  floor, so a row that sees nothing in a tile needs no guard);
 - a sliding ``window`` (query i sees the keys ``i - window < j <= i``) is
   in the **loop bounds** too, forward and backward: K blocks that lie
   wholly before a q block's window are not visited either, so a q block
@@ -42,9 +51,7 @@ framework ships one).  Design per the TPU architecture:
   is (batch*heads, k-blocks) with a loop over q blocks: dk and dv are
   sums over q blocks and leave with the grid step; dq is a sum over k
   blocks, so a head's whole dq is carried in float32 in VMEM across the
-  head's grid steps and written once.  The scores are held transposed
-  (``k @ q.T -> [block_k, block_q]``), so the per-row scalars broadcast
-  from their lane rows as stored and only dq's product (``ds.T @ k``)
+  head's grid steps and written once.  Only dq's product (``ds.T @ k``)
   has a transposed left operand.  The call states the scoped VMEM its
   blocks need (``_bwd_vmem_bytes``).
 
@@ -122,46 +129,22 @@ def _sds(shape, dtype, like):
 
 # ----------------------------------------------------- row-scalar packing
 #
-# Per-row scalars (logsumexp, delta) are natural [rows, 1] columns inside
-# the forward kernel (rows = sublanes) but must not be stored to
-# HBM broadcast across a 128-lane tile — that costs 128x the necessary
-# bandwidth and capped long-sequence backward (the bundled
+# Per-row scalars (the running max and sum, logsumexp, delta) must not be
+# stored to HBM broadcast across a 128-lane tile — that costs 128x the
+# necessary bandwidth and capped long-sequence backward (the bundled
 # jax.experimental kernel pays exactly this).  They are stored dense, one
 # q-block's scalars per lane row: HBM shape [bh, t/block_q, 1, block_q],
 # the bytes of [bh, t] (the singleton sublane axis satisfies the TPU
 # block-shape rule — the last two block dims must divide (8, 128) or equal
-# the array dims).  The backward kernel, whose scores are [block_k,
-# block_q], broadcasts such a row as it is.  The forward kernel turns
-# column into row once a q block with an MXU identity contraction, 128
-# rows at a time — bit-exact for fp32 (one nonzero term per output) and
-# guaranteed to lower on any Mosaic version, unlike a reshape across the
-# minor-two dims.
+# the array dims).  Both kernels hold their scores transposed, [block_k,
+# block_q], so a q block's scalars ARE such a lane row inside them: it
+# broadcasts down the sublanes as it is, a reduction over the keys runs
+# down the sublanes, and the forward kernel stores its ``lse`` row as it
+# stands.
 
-def _eye(n):
-    return (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-            == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-            ).astype(jnp.float32)
-
-
-def _col_to_row(c):
-    """[n, 1] fp32 column -> [1, n] lane row (MXU transpose)."""
-    return jax.lax.dot_general(c, _eye(c.shape[0]), (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-
-
-_PACK = 128  # lane width: the transpose above goes 128 rows at a time
+_PACK = 128  # lane width
 _BLOCK = 512  # default block_q and block_k: see flash_attention()
 _VMEM_DEFAULT = 16 << 20  # the scope a call gets that asks for none
-
-
-def _store_row(ref, col):
-    """Write the [block_q, 1] column ``col`` into the [1, block_q] lane
-    row ``ref`` views."""
-    if col.shape[0] % _PACK:  # an odd block: whole, no lane slice
-        ref[...] = _col_to_row(col)
-        return
-    for start in range(0, col.shape[0], _PACK):
-        ref[:, start:start + _PACK] = _col_to_row(col[start:start + _PACK])
 
 
 def _is_pow2(scale):
@@ -242,7 +225,12 @@ def _allowed(q_pos, k_pos, window):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 block_q, block_k, window=None):
     # q_ref: [block_q, d_qk]; k_ref: [t_kv, d_qk]; v_ref: [t_kv, d_v];
-    # o_ref: [block_q, d_v]; lse_ref: [1, block_q], one lane per row
+    # o_ref: [block_q, d_v]; lse_ref: [1, block_q], one lane per row.
+    # Scores are held transposed, [block_k, block_q], as in the backward:
+    # the running max, sum and rescale of a q block are lane rows (four
+    # registers at 512, where a column takes 64 with one lane in use),
+    # the max and the sum over the keys run down the sublanes, and the
+    # output is accumulated transposed, [d_v, block_q], and turned once.
     iq = pl.program_id(1)
     t_kv = k_ref.shape[1]
     d_v = v_ref.shape[2]
@@ -255,43 +243,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
     if fold_scale:
         q = q * scale
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-
     def body(ik, carry, *, masked):
-        m, l, o = carry
+        m, l, o_t = carry
         k_blk = k_ref[0, pl.ds(ik * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(ik * block_k, block_k), :]
-        s = _dot(q, k_blk, (1, 1))                       # [bq, bk]
+        s = _dot(k_blk, q, (1, 1))                       # [bk, bq]
         if not fold_scale:
             s = s * scale
         if masked:
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(_allowed(q_pos, k_pos, window), s, _NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            # q_pos >= k_pos, and with a window q_pos - k_pos < window,
+            # as a row of query offsets (along the lanes) against a
+            # column of key offsets (down the sublanes) plus one or two
+            # scalars: one select over the tile.  (The two iotas are
+            # made here: carried into the loops from outside they cost
+            # 1-3% of the kernel on the chip.)  A masked score is -inf
+            # and m is never under _NEG_INF, so p is 0 there whatever
+            # the row has seen: a row that sees nothing in this tile
+            # keeps its m, l and o_t (alpha = 1) with no guard on p.
+            q_off = jax.lax.broadcasted_iota(jnp.int32, (1, block_q), 1)
+            k_pos = (jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+                     + (ik * block_k - iq * block_q))
+            keep = q_off >= k_pos
+            if window is not None:
+                keep = keep & (q_off < k_pos + window)
+            s = jnp.where(keep, s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)                       # [bq, 1]
-        if masked:
-            # a row that has seen nothing yet has m = m_new = NEG_INF and
-            # would read exp(0) = 1 off its masked entries
-            p = jnp.where(m_new > _NEG_INF / 2, p, 0.0)
-            alpha = jnp.where(m > _NEG_INF / 2, alpha, 0.0)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o = o * alpha + _dot(p.astype(v_blk.dtype), v_blk, (1, 0))
-        return m_new, l, o
+        alpha = jnp.exp(m - m_new)                       # [1, bq]
+        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+        # v.T @ p: the small operand is the one that is turned
+        o_t = o_t * alpha + _dot(v_blk, p.astype(v_blk.dtype), (0, 0))
+        return m_new, l, o_t
 
-    m, l, o = _causal_loops(
+    m, l, o_t = _causal_loops(
         body,
-        (jnp.full((block_q, 1), _NEG_INF, jnp.float32),
-         jnp.zeros((block_q, 1), jnp.float32),
-         jnp.zeros((block_q, d_v), jnp.float32)),
+        (jnp.full((1, block_q), _NEG_INF, jnp.float32),
+         jnp.zeros((1, block_q), jnp.float32),
+         jnp.zeros((d_v, block_q), jnp.float32)),
         _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
                   t_kv=t_kv, window=window))
 
     l_safe = jnp.where(l > 0, l, 1.0)
-    o_ref[0] = (o / l_safe).astype(o_ref.dtype)
-    _store_row(lse_ref.at[0, 0], m + jnp.log(l_safe))
+    o_ref[0] = (o_t * (1.0 / l_safe)).T.astype(o_ref.dtype)
+    lse_ref[0, 0] = m + jnp.log(l_safe)
 
 
 def _kv_head(group):

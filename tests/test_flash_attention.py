@@ -185,16 +185,37 @@ def test_flash_in_ring_attention(causal):
                                rtol=2e-4, atol=2e-4)
 
 
-def test_mxu_transpose_helpers_exact():
-    """_col_to_row: the identity-matmul sublane -> lane move the forward
-    kernel stores its lse through must be bit-exact for fp32 (one
-    nonzero product per output element)."""
-    from horovod_tpu.ops.pallas.flash_attention import _col_to_row
-    rng = np.random.RandomState(7)
-    col = jnp.asarray(rng.randn(128, 1).astype(np.float32))
-    row = _col_to_row(col)
-    assert row.shape == (1, 128)
-    assert np.array_equal(np.asarray(row)[0], np.asarray(col)[:, 0])
+def test_forward_statistics_are_lane_rows():
+    """The forward kernel holds its scores transposed, ``[block_k,
+    block_q]``: the running max and sum of a q block are carried as
+    ``[1, block_q]`` lane rows and the output transposed, ``[d_v,
+    block_q]``; nothing in the kernel is a ``[block_q, 1]`` column, and
+    ``lse`` leaves as the row it is: no product moves it there (the
+    kernel's only products are the two on its operands, a body)."""
+    from horovod_tpu.ops.pallas.flash_attention import _fwd
+    import functools as ft
+    bh, t, d, block, block_k = 2, 256, 32, 128, 64
+    q3 = jnp.zeros((bh, t, d), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(ft.partial(
+        _fwd, scale=d ** -0.5, causal=True, block_q=block, block_k=block_k,
+        interpret=True))(q3, q3, q3)
+    (kernel,) = [eqn.params["jaxpr"] for eqn in jaxpr.jaxpr.eqns
+                 if eqn.primitive.name == "pallas_call"]
+    loops = [eqn for eqn in kernel.eqns
+             if eqn.primitive.name in ("while", "scan")]
+    assert len(loops) == 2  # whole blocks; blocks on the diagonal
+    for loop in loops:
+        carried = sorted(tuple(v.aval.shape) for v in loop.outvars)
+        assert carried[-3:] == [(1, block), (1, block), (d, block)], carried
+    shapes = {tuple(v.aval.shape) for eqn in _walk(kernel)
+              for v in eqn.outvars if hasattr(v.aval, "shape")}
+    assert (block, 1) not in shapes and (1, block) in shapes
+    products = [eqn for eqn in _walk(kernel)
+                if eqn.primitive.name == "dot_general"]
+    assert len(products) == 2 * len(loops) == len(_operand_products(kernel))
+    # the kernel's last act is to store that row
+    assert kernel.eqns[-1].primitive.name == "swap"
+    assert tuple(kernel.eqns[-1].invars[1].aval.shape) == (1, block)
 
 
 def test_packed_lse_layout_engaged_and_dense():
@@ -413,9 +434,11 @@ def test_flash_products_take_operands_in_the_input_dtype(dtype, causal):
     rounded to it) and float32 inputs as float32; every product
     accumulates in float32.  The backward kernel makes the five products
     of the mathematics a block pair (scores, ``dO v.T``, dv, dk, dq:
-    none computed twice), and only dq's has a transposed left operand."""
+    none computed twice), and only dq's has a transposed left operand;
+    of the forward's two, ``v.T @ p`` has (the scores are transposed, so
+    the small operand is the one that is turned)."""
     per_body = {"_fwd_kernel": 2, "_bwd_kernel": 5}
-    transposed = {"_fwd_kernel": 0, "_bwd_kernel": 1}
+    transposed = {"_fwd_kernel": 1, "_bwd_kernel": 1}
     exps = {"_fwd_kernel": 2, "_bwd_kernel": 1}  # p and alpha; p alone
     bodies = 2 if causal else 1  # whole blocks; blocks on the diagonal
     for name, jaxpr in _flash_kernel_jaxprs(dtype, causal).items():
@@ -450,13 +473,27 @@ def test_flash_mask_work_only_on_the_diagonal():
                     eqn.params[body_of[eqn.primitive.name]].jaxpr)}
                 for eqn in jaxpr.eqns if eqn.primitive.name in body_of]
 
-    for name, jaxpr in _flash_kernel_jaxprs(jnp.bfloat16, True).items():
+    causal = _flash_kernel_jaxprs(jnp.bfloat16, True)
+    for name, jaxpr in causal.items():
         bodies = loops(jaxpr)
         assert len(bodies) == 2, (name, len(bodies))
         masked = [bool(body & mask_work) for body in bodies]
         assert sorted(masked) == [False, True], (name, bodies)
         assert all("dot_general" in body and "exp" in body
                    for body in bodies), name
+    # the forward's masked tile pays ONE select over the tile, on the
+    # scores: none on p and none on the rescale (no guard for a row that
+    # has seen nothing), and its iotas are a row of query offsets and a
+    # column of key offsets, none of the tile's size
+    (masked_body,) = [
+        list(_walk(eqn.params["body_jaxpr"].jaxpr))
+        for eqn in causal["_fwd_kernel"].eqns if eqn.primitive.name == "while"
+        and "select_n" in {e.primitive.name for e in _walk(
+            eqn.params["body_jaxpr"].jaxpr)}]
+    names = [e.primitive.name for e in masked_body]
+    assert names.count("select_n") == 1 and names.count("ge") == 1
+    assert sorted(tuple(e.outvars[0].aval.shape) for e in masked_body
+                  if e.primitive.name == "iota") == [(1, 256), (256, 1)]
     for name, jaxpr in _flash_kernel_jaxprs(jnp.bfloat16, False).items():
         bodies = loops(jaxpr)
         assert len(bodies) == 1 and not bodies[0] & mask_work, (name, bodies)
@@ -655,19 +692,127 @@ def test_flash_two_widths_lse_and_its_gradient():
 
 
 # ------------------- the one backward kernel: dq carried over k blocks
-def _dense_out_and_lse(q, k, v, causal):
+def _dense_out_and_lse(q, k, v, causal, window=None):
     """Plain attention in float32 with its logsumexp ``[B, H, T]``, the
-    mask aligned at the start as the kernel's is."""
+    mask aligned at the start as the kernel's is, k and v repeated to
+    q's heads."""
     q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    k, v = (jnp.repeat(u, q.shape[2] // k.shape[2], axis=2) for u in (k, v))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    precision="highest") / np.sqrt(q.shape[-1])
     if causal:
-        seen = (jnp.arange(q.shape[1])[:, None]
-                >= jnp.arange(k.shape[1])[None, :])
+        behind = (jnp.arange(q.shape[1])[:, None]
+                  - jnp.arange(k.shape[1])[None, :])
+        seen = behind >= 0
+        if window is not None:
+            seen = seen & (behind < window)
         s = jnp.where(seen, s, -jnp.inf)
     out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v,
                      precision="highest")
     return out, jax.nn.logsumexp(s, -1)
+
+
+# ------------------------- the forward kernel at each kind of cell shape
+def _forward_inputs(t, t_kv, heads, kv_heads, d_qk, d_v, dtype, seed=8):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(*shape).astype(np.float32)
+                             ).astype(dtype)
+                 for shape in ((1, t, heads, d_qk), (1, t_kv, kv_heads, d_qk),
+                               (1, t_kv, kv_heads, d_v)))
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+# a small size of each kind of shape the benchmark's cells run the
+# forward kernel at, blocks of 32: (t, t_kv, query heads, key-value
+# heads, d_qk, d_v, causal, window, dtype)
+FORWARD_CASES = {
+    "heads-of-64": (128, 128, 2, 2, 64, 64, True, None, BF16),
+    "heads-of-128": (128, 128, 2, 2, 128, 128, True, None, BF16),
+    "192-over-128": (128, 128, 2, 2, 192, 128, True, None, BF16),
+    "grouped-6-to-1": (128, 128, 6, 1, 128, 128, True, None, BF16),
+    "grouped-9-to-1-window": (128, 128, 9, 1, 128, 128, True, 32, BF16),
+    "grouped-4-to-1-heads-of-64": (128, 128, 8, 2, 64, 64, True, None, BF16),
+    "window-equal-to-the-block": (128, 128, 2, 2, 64, 64, True, 32, F32),
+    "window-smaller-than-the-block": (128, 128, 2, 2, 64, 64, True, 8, F32),
+    "window-no-multiple-of-the-block": (128, 128, 2, 2, 64, 64, True, 40,
+                                        F32),
+    "keys-longer-than-queries": (64, 128, 2, 2, 64, 64, True, None, F32),
+    "keys-shorter-than-queries": (128, 64, 2, 2, 64, 64, True, None, F32),
+    "non-causal": (128, 128, 2, 2, 64, 64, False, None, BF16),
+    "non-causal-keys-longer": (64, 128, 2, 2, 32, 32, False, None, F32),
+    "float32-192-over-128": (128, 128, 2, 1, 192, 128, True, None, F32),
+}
+
+
+@pytest.mark.parametrize("case", FORWARD_CASES)
+def test_flash_forward_out_and_lse_at_each_kind_of_cell_shape(case):
+    """``out`` and ``lse`` of the forward kernel against plain attention
+    in float32: heads of 64, of 128, 192 over 128, grouped key-value
+    heads, a window equal to the block, smaller and no multiple of it,
+    keys longer and shorter than the queries, no mask at all, float32."""
+    t, t_kv, heads, kv_heads, d_qk, d_v, causal, window, dtype = \
+        FORWARD_CASES[case]
+    q, k, v = _forward_inputs(t, t_kv, heads, kv_heads, d_qk, d_v, dtype)
+    out, lse = flash_attention(q, k, v, causal=causal, window=window,
+                               block_q=32, block_k=32, return_lse=True)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    assert out.shape == (1, t, heads, d_v) and lse.shape == (1, heads, t)
+    want_out, want_lse = _dense_out_and_lse(q, k, v, causal, window)
+    # the kernel rounds p to the input dtype at its product
+    tol = 2e-5 if dtype == F32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), want_out,
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("block_q,block_k,window,unseen", [
+    # the window's far edge: the block before the diagonal's comes first
+    # and rows 7 to 15 of a q block see none of its keys
+    (16, 16, 8, "rows that see nothing in a visited block"),
+    # a q block of two k blocks and a window of 4: rows 19 to 31 see no
+    # key of the first block they visit
+    (32, 16, 4, "a row whose first visited tile is fully masked")],
+    ids=["far-edge-block", "first-tile-fully-masked"])
+def test_flash_forward_rows_that_see_nothing_in_a_tile(block_q, block_k,
+                                                       window, unseen):
+    """A row that sees no key of a tile it visits keeps its running max,
+    sum and output through it (the masked scores are -inf and the max is
+    never under the finite floor, so no guard on the tile is needed):
+    ``lse`` is finite, ``out`` and the gradients through the backward
+    kernel hold no NaN and are plain attention's."""
+    from horovod_tpu.ops.pallas.flash_attention import _k_bounds
+    t = 64
+    blind = []  # q blocks with a row that sees no key of its first tile
+    for iq in range(t // block_q):
+        (first, _, masked), *_ = _k_bounds(
+            iq, causal=True, block_q=block_q, block_k=block_k, t_kv=t,
+            window=window)
+        i = np.arange(iq * block_q, (iq + 1) * block_q)[:, None]
+        j = np.arange(int(first) * block_k, (int(first) + 1) * block_k)
+        sees = ((j <= i) & (i - j < window)).any(axis=1)
+        assert masked
+        blind += [] if sees.all() else [iq]
+    assert blind, unseen
+    q, k, v = _forward_inputs(t, t, 4, 2, 16, 16, F32)
+
+    def loss(attn):
+        def f(q, k, v):
+            out, lse = attn(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(lse), (out, lse)
+        return jax.value_and_grad(f, (0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, (out, lse)), grads = loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=block_q,
+        block_k=block_k, return_lse=True))
+    (_, (want_out, want_lse)), want = loss(
+        lambda q, k, v: _dense_out_and_lse(q, k, v, True, window))
+    assert np.isfinite(np.asarray(lse)).all()
+    for got in (out, *grads):
+        assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=1e-5, atol=1e-5)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize(
