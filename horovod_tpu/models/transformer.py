@@ -48,6 +48,7 @@ from typing import Any, Callable, Optional, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.parallel.ring_attention import reference_attention
@@ -369,27 +370,82 @@ def kept_bytes(cfg, batch, seq, layer=0):
     return {name: kept[name] for name in kept_names(cfg) if name in kept}
 
 
+@jax.custom_vjp
+def turn(x, c, s, p):
+    """A rotary turn of ``x [..., T, H, D]`` as ONE pass over whole heads,
+
+        turn(x) = (x32 * c + (x @ p) * s).astype(x.dtype)
+
+    with ``x32`` ``x`` in float32 and the product summed in float32.
+    ``p [D, D]`` (in ``x.dtype``) holds one +1 or -1 in each turned
+    column, so ``x @ p`` puts every column's partner in its place with
+    its sign and only moves values; ``c`` and ``s`` ``[T, 1, D]``
+    (float32) are the cosines and sines at every column, 1 and 0 where a
+    column passes (:func:`rotary_operands` makes all three).  The
+    cotangent is turned back by the same pass with ``-p``: ``p.T = -p``
+    and ``s`` is the same on both columns of a pair, so ``(g * s) @ p.T
+    = (g @ -p) * s`` and the product's operands stay in ``x.dtype``.
+    Nothing is kept for it but the tables."""
+    # float32 operands would go through the MXU in one bfloat16 pass
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    partner = jax.lax.dot_general(
+        x, p, (((x.ndim - 1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * c + partner * s).astype(x.dtype)
+
+
+def _turn_fwd(x, c, s, p):
+    return turn(x, c, s, p), (c, s, p)
+
+
+def _turn_bwd(tables, g):
+    c, s, p = tables
+    return turn(g, c, s, -p), None, None, None
+
+
+turn.defvjp(_turn_fwd, _turn_bwd)
+
+
+def rotary_operands(x, inv_freq, factor=1.0, start=0, pairs=False):
+    """``(c, s, p)`` of :func:`turn` for ``x [..., T, H, D]``: the ``2
+    len(inv_freq)`` columns from ``start`` on are turned, position ``t``
+    by the angles ``t * inv_freq`` with cos and sin times ``factor``;
+    the rest pass.  The i-th pair is the columns ``(i, i + len(inv_freq))``
+    of those (the rotate-half pairing) or, with ``pairs``, the
+    neighbours ``(2i, 2i + 1)``."""
+    t, d, half = x.shape[-3], x.shape[-1], inv_freq.shape[0]
+    i = np.arange(half)
+    first, second = ((start + 2 * i, start + 2 * i + 1) if pairs
+                     else (start + i, start + half + i))
+    # every column's frequency: its pair's; 0, and no turn, where it passes
+    freq = jnp.pad(jnp.repeat(inv_freq, 2) if pairs else jnp.tile(inv_freq, 2),
+                   (start, d - start - 2 * half))
+    turned = np.zeros(d, bool)
+    turned[first] = turned[second] = True
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * freq  # [T, 1, D]
+    c = jnp.where(turned, jnp.cos(angle) * factor, 1.0)
+    s = jnp.where(turned, jnp.sin(angle) * factor, 0.0)
+    # (x p)[first] = -x[second], (x p)[second] = +x[first]
+    p = np.zeros((d, d), np.float32)
+    p[second, first], p[first, second] = -1, 1
+    return c, s, jnp.asarray(p, x.dtype)
+
+
+def _inv_freq(theta, dim):
+    return theta ** (-jnp.arange(dim // 2, dtype=jnp.float32) * 2 / dim)
+
+
 def rope(x, theta=10000.0, pairs=False):
     """Rotary position embedding of ``x [..., T, H, D]``: each pair of
     columns ``(x1, x2)`` is turned by ``angle(t, i) = t * theta^(-2i /
     D)``, i < D / 2, to ``(x1 cos - x2 sin, x2 cos + x1 sin)``.  The
     i-th pair is columns ``(i, i + D / 2)`` (the rotate-half form: ``x *
     cos + rotate_half(x) * sin``) or, with ``pairs``, the neighbours
-    ``(2i, 2i + 1)``.  Computed in float32, returned in ``x.dtype``."""
-    t, d = x.shape[-3], x.shape[-1]
-    half = d // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2 / d)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * inv_freq
-    cos, sin = jnp.cos(angle), jnp.sin(angle)        # [T, 1, D / 2]
-    x32 = x.astype(jnp.float32)
-    if pairs:
-        x32 = x32.reshape(x.shape[:-1] + (half, 2))
-        x1, x2 = x32[..., 0], x32[..., 1]
-        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                         axis=-1).reshape(x.shape).astype(x.dtype)
-    x1, x2 = x32[..., :half], x32[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    ``(2i, 2i + 1)``.  Computed in float32, returned in ``x.dtype``
+    (:func:`turn`)."""
+    return turn(x, *rotary_operands(x, _inv_freq(theta, x.shape[-1]),
+                                    pairs=pairs))
 
 
 def rotary_table(recipe, dim):
@@ -417,18 +473,9 @@ def rotate(x, recipe):
     """``x [..., T, H, D]`` turned as ``recipe`` (a :class:`Rotary`)
     says: the first ``fraction`` of the columns in the rotate-half
     pairing within them, scaled by the recipe's factor; the rest pass.
-    Computed in float32, returned in ``x.dtype``."""
-    t, d = x.shape[-3], x.shape[-1]
-    dim = int(d * recipe.fraction)
-    half = dim // 2
-    inv_freq, factor = rotary_table(recipe, dim)
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None, None] * inv_freq
-    cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
-    x32 = x.astype(jnp.float32)
-    x1, x2 = x32[..., :half], x32[..., half:dim]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x32[..., dim:]],
-        axis=-1).astype(x.dtype)
+    Computed in float32, returned in ``x.dtype`` (:func:`turn`)."""
+    dim = int(x.shape[-1] * recipe.fraction)
+    return turn(x, *rotary_operands(x, *rotary_table(recipe, dim)))
 
 
 class RMSNorm(nn.Module):
@@ -501,9 +548,10 @@ def latent_qkv(cfg, x):
         c_kv = make_norm(cfg, "kv_a_norm")(kv_a[..., :spec.kv_rank])
         kv = dense((h, spec.nope_dim + spec.v_dim), "kv_b")(c_kv)
         k_rope = rope(kv_a[..., None, spec.kv_rank:], cfg.rope_theta, pairs)
-        q = jnp.concatenate(
-            [q[..., :spec.nope_dim],
-             rope(q[..., spec.nope_dim:], cfg.rope_theta, pairs)], axis=-1)
+        # the whole head in one pass: its first nope_dim columns pass
+        q = turn(q, *rotary_operands(
+            q, _inv_freq(cfg.rope_theta, spec.rope_dim),
+            start=spec.nope_dim, pairs=pairs))
         k = jnp.concatenate(
             [kv[..., :spec.nope_dim],
              jnp.broadcast_to(k_rope, kv.shape[:-1] + (spec.rope_dim,))],

@@ -389,10 +389,8 @@ def test_topk_moe_compiles_for_v5e_as_grouped_product_kernels(v5e):
                 if " scatter(" in line and "[131072,2048]" in line]
 
 
-def _outside_loop_bodies(text):
-    """The lines of an optimized HLO module that lie in no computation
-    a ``while`` names as its body or condition, nor in one called from
-    there (a fusion inside a loop body is its own computation)."""
+def _computations(text):
+    """``{name: lines}`` of an optimized HLO module's computations."""
     computations, name = {}, None
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$", line)
@@ -401,6 +399,14 @@ def _outside_loop_bodies(text):
             computations[name] = []
         elif name is not None:
             computations[name].append(line)
+    return computations
+
+
+def _outside_loop_bodies(text):
+    """The lines of an optimized HLO module that lie in no computation
+    a ``while`` names as its body or condition, nor in one called from
+    there (a fusion inside a loop body is its own computation)."""
+    computations = _computations(text)
     called = {name: set(re.findall(
         r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", "\n".join(lines)))
         for name, lines in computations.items()}
@@ -631,13 +637,17 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     # the recomputation makes no projection of attention, no rotation and
     # no copy into the kernel's layout again.  Left under the
     # projections' scopes are their weights' casts to bfloat16, which the
-    # backward products read
+    # backward products read, and under ``rope`` the tables ``[T, D]`` the
+    # backward turn reads (``turn`` keeps nothing else): no array with
+    # heads in it
     assert _products(_recomputed(text, "/mlp/up/"))
     ahead = _recomputed(
         text, "/attn/window/q/", "/attn/window/kv/", "/attn/window/out/",
         "/attn/global/q/", "/attn/global/kv/", "/attn/global/out/")
     assert ahead and all('/convert_element_type"' in line for line in ahead)
-    assert not _recomputed(text, "/rope/", "/flash/")
+    assert not _recomputed(text, "/flash/")
+    assert not [line for line in _recomputed(text, "/rope/") if re.search(
+        r"\[(1,)?(8192,(72|48|8)|(72|48|8),8192),\d+\]", line)]
     assert _fits_one_chip(compiled)
 
 
@@ -693,6 +703,66 @@ def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes
             ) < 8.5 * 2 ** 30
+
+
+def _results_in_memory(text, *scopes):
+    """``[(result types, line)]`` of a compiled step's instructions under
+    one of ``scopes`` whose results are arrays in HBM: those of the
+    entry and of loop bodies, not what a fusion holds inside."""
+    computations = _computations(text)
+    inside = {m for lines in computations.values() for line in lines
+              if " fusion(" in line or "to_apply=" in line
+              for m in re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line)}
+    found = []
+    for name, lines in computations.items():
+        for line in lines if name not in inside else ():
+            result = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", line)
+            scope = re.search(r'op_name="([^"]*)"', line)
+            if result and scope and any(s in scope.group(1) for s in scopes):
+                found.append((re.findall(r"\w+\[[\d,]*\]", result.group(1)),
+                              line))
+    return found
+
+
+@pytest.mark.parametrize("workload,scopes,whole,pieces,operands", [
+    ("laguna_s_2_1-spmd-1chip",
+     ("/rope/", "/attn/window/q/", "/attn/window/kv/", "/attn/global/q/",
+      "/attn/global/kv/"),
+     ("f32[1,8192,72,128]", "f32[1,8192,48,128]"),
+     (r"72,64\]", r"48,64\]", r"48,32\]", r"8,64\]", r"8,32\]"),
+     {"bf16[72,8192,128]", "bf16[48,8192,128]", "bf16[8,8192,128]"}),
+    ("joyai_llm_flash-spmd-1chip", ("/attn/latent/",),
+     ("f32[4,4096,32,192]",), (r"32,32,1\]", r"32,32,2\]"),
+     {"bf16[128,4096,192]", "bf16[128,4096,128]"})],
+    ids=["laguna_s_2_1", "joyai_llm_flash"])
+def test_rotary_turn_is_a_pass_over_whole_heads_in_the_cell_step(
+        cell_step, workload, scopes, whole, pieces, operands):
+    """The rotation of q and k (``turn``: ``x c + (x P) s``) in a cell's
+    step, the one the tests above compiled: nothing under ``rope`` or a
+    projection's scope writes q in float32 (the projection's result is
+    the activation dtype's, not a float32 array the turn then reads) and
+    nothing writes a piece of a head (a half, a quarter, a pair), forward
+    or backward; the flash kernels still take ``[B H, T, d]`` in
+    bfloat16."""
+    text = cell_step(workload).as_text()
+    written = _results_in_memory(text, *scopes)
+    assert len(written) > 50
+    for results, line in written:
+        assert not set(results) & set(whole), line[:300]
+        assert not [r for r in results for piece in pieces
+                    if re.search(piece, r)], line[:300]
+    # the turn is there, by its product, forward and backward
+    assert [line for _, line in written if '/dot_general"' in line
+            and ("/rope/" in line or "/attn/latent/dot_general" in line)
+            and "transpose(jvp(" in line]
+    seen = set()
+    for line in text.splitlines():
+        if " custom-call(" in line and "tpu_custom_call" in line and (
+                "jit(_fwd)" in line or "jit(_bwd)" in line):
+            q, k, v = re.findall(r"(bf16\[[\d,]+\])\{2,1,0\}", line.split(
+                "operand_layout_constraints={", 1)[1])[:3]
+            seen |= {q, k, v}
+    assert seen == operands
 
 
 def _recomputed(text, *scopes):

@@ -164,9 +164,11 @@ def test_a_latent_block_makes_its_wide_products_again_and_not_the_narrow():
     ``kv_b`` run again; ``q_a``, ``kv_a`` and ``out`` do not."""
     cfg, params, loss = model_and_loss("latent", remat=True)
     again = recomputation(loss, params)
+    # (the scope's own two are the rotary turns of q and of the shared
+    # key, ``x @ P``: made again with ``q_b`` and ``kv_a``'s slice)
     assert products(again) == sorted(
         ["attn/attn/latent/q_b", "attn/attn/latent/kv_b", "mlp/gate",
-         "mlp/up"] * cfg.n_layers)
+         "mlp/up"] * cfg.n_layers + ["attn/attn/latent/"] * 2 * cfg.n_layers)
     assert not [eqn for eqn in again if eqn[0] == "pallas_call"]
 
 
@@ -185,9 +187,10 @@ def test_a_block_under_passes_keeps_the_kernels_two_results_alone():
     ``down``, whose result the sandwich norm after it reads)."""
     cfg, params, loss = model_and_loss("looped", remat=True)
     assert kept_names(cfg) == SAVED_NAMES
+    # (``attn/attn/rope/``: the rotary turns of q and k, ``x @ P``)
     assert products(recomputation(loss, params)) == sorted(
         ["attn/qkv", "attn/out", "mlp/gate", "mlp/up", "mlp/down"]
-        * cfg.n_layers)
+        * cfg.n_layers + ["attn/attn/rope/"] * 2 * cfg.n_layers)
     assert set(kept_bytes(cfg, 2, 16)) == set(SAVED_NAMES)
     one = TransformerConfig(**{**SIZES, **KINDS["looped"], "passes": 1})
     assert kept_names(one) == SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
