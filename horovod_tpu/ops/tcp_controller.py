@@ -390,6 +390,33 @@ class CoordinatorService(network.MuxService):
             return network.AckResponse()
         return super()._handle(req, client_address)
 
+    def wait_for_departures(self, own_rank):
+        """Job end on the coordinator's host, which leaves last: return
+        once every other rank has deregistered (its ``ShutdownMsg``), has
+        been silent for its liveness window (it left without saying so),
+        or an abort stands.  A rank still inside the last collective
+        (its go-ahead not yet written, its session mid-resume) needs
+        this service after rank 0 itself is through with it; closing at
+        once, the rank fails its last collective naming the coordinator
+        (reference: the background loop ends when EVERY rank has asked
+        to shut down)."""
+        while True:
+            now = time.monotonic()
+            with self._cv:
+                if self._abort is not None:
+                    return
+                # (with liveness off a silent rank is given the window
+                # it would have by default)
+                windows = {
+                    r: (self._deadline_for_locked(r) if self._liveness > 0
+                        else env_util.DEFAULT_LIVENESS_TIMEOUT_SECONDS)
+                    for r in self._last_seen
+                    if r != own_rank and r not in self._draining}
+                if not any(now - self._last_seen[r] <= window
+                           for r, window in windows.items()):
+                    return
+            time.sleep(0.02)
+
     # -------------------------------------------------- abort + liveness
     def _abort_result(self):
         # sticky flag, set-once before the waiter events fire: callers
@@ -1220,14 +1247,19 @@ class TcpController:
         self._mux = None            # guarded by self._mux_lock
         self._mux_lock = threading.Lock()
         self._key = None
-        self._peer_service = None
-        self._ring = None
+        # the peer mailbox: its own handler threads reach back for it
+        # (a pushed abort), request threads purge through it, the main
+        # thread's ``start`` and teardown set it
+        self._peer_service = None   # guarded by self._abort_lock
+        # the world's ring plane: request threads read it while the main
+        # thread's teardown (shutdown, reconfiguration) takes it away
+        self._ring = None           # guarded by self._rings_lock
         # per-group ring planes (docs/groups.md): each live group gets
         # its own RingPlane (own send queue, sender thread and stripe
         # connections) lazily on first grouped ring round, sharing the
         # one PeerService mailbox — the concurrency lever that lets two
-        # groups' rounds be in flight at once; guarded by _rings_lock
-        self._rings = {}
+        # groups' rounds be in flight at once
+        self._rings = {}            # guarded by self._rings_lock
         self._rings_lock = threading.Lock()
         self._ring_threshold = env_util.get_int(
             env_util.HVD_TCP_RING_THRESHOLD, DEFAULT_RING_THRESHOLD)
@@ -1364,26 +1396,30 @@ class TcpController:
 
         # peer mailbox for the ring data plane (epoch-stamped: stale
         # chunks from a pre-reconfiguration ring are refused at framing)
-        self._peer_service = PeerService(self._key, epoch=self._epoch)
+        peer_service = PeerService(self._key, epoch=self._epoch)
         # a peer-pushed abort must fail negotiation-blocked handles too,
         # not only blocked ring recvs (no re-fan-out: the pusher
         # already reached every peer)
-        self._peer_service.abort_callback = self._on_peer_abort
+        peer_service.abort_callback = self._on_peer_abort
+        with self._abort_lock:
+            self._peer_service = peer_service
         if addr is not None:
             from horovod_tpu.run import http_client
-            tagged = [(iface, ip, self._peer_service.port)
+            tagged = [(iface, ip, peer_service.port)
                       for iface, ip in network.local_interfaces().items()]
-            tagged.append(("lo", "127.0.0.1", self._peer_service.port))
+            tagged.append(("lo", "127.0.0.1", peer_service.port))
             http_client.put(addr, int(port), self._scope(PEERS_SCOPE),
                             str(self._rank),
                             ";".join(f"{i}={ip}:{p}"
                                      for i, ip, p in tagged).encode())
-            self._ring = RingPlane(
-                self._rank, self._peer_service, self._resolve_peer,
+            ring = RingPlane(
+                self._rank, peer_service, self._resolve_peer,
                 resolve_bulk=self._resolve_stripe,
                 segment_bytes=self._config.ring_segment_bytes,
                 stripes=self._config.ring_stripes,
                 epoch=self._epoch)
+            with self._rings_lock:
+                self._ring = ring
 
         # peer liveness: a background heartbeat per worker keeps the
         # coordinator's last-seen table fresh AND carries the abort
@@ -1630,6 +1666,7 @@ class TcpController:
             self._abort_state = (origin_rank, reason)
             inflight = list(self._inflight.values())
             self._inflight.clear()
+            peer_service = self._peer_service
         self._log.error("aborting collectives (origin rank %s): %s",
                         origin_rank, reason)
         # push to every peer mailbox BEFORE waking local waiters: a
@@ -1639,11 +1676,18 @@ class TcpController:
         # for peers the push cannot reach
         if fan_out:
             self._push_abort_to_peers(origin_rank, reason)
-        if self._peer_service is not None:
-            self._peer_service.abort(origin_rank, reason)
+        if peer_service is not None:
+            peer_service.abort(origin_rank, reason)
         exc = make_abort_error(origin_rank, reason)
         for handle in inflight:
             handle.set_error(exc)
+
+    def _peer_mailbox(self):
+        """The peer service as it stands (None before ``start`` and
+        after a teardown): the caller keeps the reference it was
+        given."""
+        with self._abort_lock:
+            return self._peer_service
 
     def _on_peer_abort(self, origin_rank, reason):
         """PeerService push receipt: apply locally, no re-fan-out."""
@@ -1679,23 +1723,31 @@ class TcpController:
         delays only its own slice and the deadline still bounds the
         whole fan-out; heartbeats remain the backstop for peers the
         pool never reached."""
-        if self._ring is None:
+        ring = self._ring_for(None)
+        if ring is None:
             return
 
         deadline = time.monotonic() + budget
 
         def push_one(rank):
+            # ``send``, not ``post``: the peer's answer says that it HAS
+            # applied the abort.  A frame only written can lose the race
+            # against this process's exit on the peer's other
+            # connections, and the peer then names the coordinator, not
+            # the culprit
+            msg = network.AbortMsg(origin_rank, reason)
             try:
-                cached = self._ring.cached_peer(rank)
+                wait = max(0.1, deadline - time.monotonic())
+                cached = ring.cached_peer(rank)
                 if cached is not None:
-                    cached.post(network.AbortMsg(origin_rank, reason))
+                    cached.send(msg, timeout=wait)
                     return
                 client = network.MuxClient(
                     self._peer_addrs(rank, resolve_timeout=2.0,
                                      retry_for=0),
                     self._key, timeout=2, retry_for=0, peer=rank)
                 try:
-                    client.post(network.AbortMsg(origin_rank, reason))
+                    client.send(msg, timeout=wait)
                 finally:
                     client.close()
             except Exception:  # noqa: BLE001 — heartbeat backstop
@@ -1782,14 +1834,16 @@ class TcpController:
         """The ring plane a round runs on: the world plane, or the
         group's own lazily-built plane (same resolver + PeerService,
         independent sender/stripes so concurrent groups never share a
-        send queue)."""
-        if not group:
-            return self._ring
+        send queue).  The world plane is None before ``start`` built it
+        and after a teardown took it: the caller keeps the reference it
+        was given."""
         with self._rings_lock:
+            if not group:
+                return self._ring
             plane = self._rings.get(group)
             if plane is None:
                 plane = RingPlane(
-                    self._rank, self._peer_service, self._resolve_peer,
+                    self._rank, self._peer_mailbox(), self._resolve_peer,
                     resolve_bulk=self._resolve_stripe,
                     segment_bytes=self._config.ring_segment_bytes,
                     stripes=self._config.ring_stripes,
@@ -1798,7 +1852,7 @@ class TcpController:
             return plane
 
     def _use_ring(self, req_type, nbytes):
-        if self._ring is None or self._size <= 1:
+        if self._size <= 1 or self._ring_for(None) is None:
             return False
         rtype = RequestType(req_type)
         if rtype == RequestType.ALLGATHER:
@@ -2021,8 +2075,9 @@ class TcpController:
         except BaseException as exc:
             # drop any chunks of the aborted round so nothing lingers
             # (a retry gets a fresh ring_id and can never match them) …
-            if self._peer_service is not None:
-                self._peer_service.purge(resp.ring_id)
+            peer_service = self._peer_mailbox()
+            if peer_service is not None:
+                peer_service.purge(resp.ring_id)
             # … then turn the local failure (recv timeout, codec error,
             # dead neighbor) into a coordinated abort: the OTHER ranks of
             # this round are blocked on chunks this rank will never send,
@@ -2128,18 +2183,18 @@ class TcpController:
         teardown walk the same list)."""
         with self._rings_lock:
             planes = list(self._rings.values())
-        if self._ring is not None:
-            planes.append(self._ring)
+            if self._ring is not None:
+                planes.append(self._ring)
         return planes
 
     def _close_ring_planes(self):
         with self._rings_lock:
-            planes, self._rings = list(self._rings.values()), {}
+            planes = list(self._rings.values())
+            if self._ring is not None:
+                planes.append(self._ring)
+            self._rings, self._ring = {}, None
         for plane in planes:
             plane.close()
-        if self._ring is not None:
-            self._ring.close()
-            self._ring = None
 
     def tuned_params(self):
         """Same surface as the native controller (reference:
@@ -2170,9 +2225,10 @@ class TcpController:
         if mux is not None:
             mux.close()
         self._close_ring_planes()
-        if self._peer_service is not None:
-            self._peer_service.shutdown()
-            self._peer_service = None
+        with self._abort_lock:
+            peer_service, self._peer_service = self._peer_service, None
+        if peer_service is not None:
+            peer_service.shutdown()
         if self._coordinator is not None:
             self._coordinator.shutdown()
             self._coordinator = None
@@ -2195,14 +2251,17 @@ class TcpController:
             except Exception:  # noqa: BLE001 — coordinator may be gone
                 pass
         self._merge_timelines()
+        if self._coordinator is not None and not aborted:
+            self._coordinator.wait_for_departures(self._rank)
         with self._mux_lock:
             mux, self._mux = self._mux, None
         if mux is not None:
             mux.close()
         self._close_ring_planes()
-        if self._peer_service is not None:
-            self._peer_service.shutdown()
-            self._peer_service = None
+        with self._abort_lock:
+            peer_service, self._peer_service = self._peer_service, None
+        if peer_service is not None:
+            peer_service.shutdown()
         if self._coordinator is not None:
             self._coordinator.shutdown()
             self._coordinator = None

@@ -152,19 +152,26 @@ def run(fn, args=(), kwargs=None, num_proc=None, start_timeout=None,
         # fewer slots than num_proc): collect in a thread, watch the
         # tasks' start registrations in the rendezvous KV.
         import threading
+        import time as time_mod
+        import uuid
 
+        # the collect runs under a job group of its own, so that a gang
+        # that never fills can be cancelled (reference:
+        # spark/runner.py setJobGroup / cancelJobGroup around the wait
+        # for the tasks' registration)
+        job_group = f"horovod.spark.run.{uuid.uuid4().hex}"
         box = {}
 
         def _collect():
             try:
+                sc.setJobGroup(job_group, "Horovod Spark Run",
+                               interruptOnCancel=True)
                 box["results"] = mapped.collect()
             except BaseException as exc:  # noqa: BLE001 — re-raised below
                 box["error"] = exc
 
         thread = threading.Thread(target=_collect, daemon=True)
         thread.start()
-        import time as time_mod
-
         deadline = time_mod.monotonic() + start_timeout
         started = set()
         while thread.is_alive() and len(started) < num_proc:
@@ -174,6 +181,11 @@ def run(fn, args=(), kwargs=None, num_proc=None, start_timeout=None,
                     started.add(i)
             if (len(started) < num_proc
                     and time_mod.monotonic() > deadline):
+                # the tasks that did start sit in their first collective
+                # waiting for ranks that will never come: a slot each,
+                # held for ever, unless the job is cancelled here
+                sc.cancelJobGroup(job_group)
+                thread.join(timeout=10)
                 raise RuntimeError(
                     f"Spark could not start all {num_proc} training "
                     f"tasks within start_timeout={start_timeout}s "

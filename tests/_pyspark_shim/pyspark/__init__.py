@@ -20,6 +20,9 @@ spark attachment depends on):
     process, no result file) is RESCHEDULED alone up to
     ``spark.task.maxFailures`` (4; override
     ``SPARK_SHIM_MAX_FAILURES``) while its peers keep their results,
+  - ``sc.setJobGroup(group, ...)`` in the thread that collects and
+    ``sc.cancelJobGroup(group)`` from another: the group's live tasks
+    are killed and its ``collect()`` raises, with no retry,
   - ``TaskContext.get()`` / ``BarrierTaskContext.get()`` work
     executor-side with ``partitionId`` / ``attemptNumber`` /
     ``stageAttemptNumber``, and barrier tasks can
@@ -34,6 +37,7 @@ import pickle
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 
 import cloudpickle
@@ -115,7 +119,8 @@ def _max_task_failures():
 
 
 class _MappedRDD:
-    def __init__(self, partitions, f, barrier):
+    def __init__(self, sc, partitions, f, barrier):
+        self._sc = sc
         self._partitions = partitions
         self._f = f
         self._barrier = barrier
@@ -160,11 +165,23 @@ class _MappedRDD:
     # -------------------------------------------------------------- modes
     def collect(self):
         workdir = tempfile.mkdtemp(prefix="pyspark_shim_")
+        cancelled = self._sc._cancel_event_of_this_thread()
         if self._barrier:
-            return self._collect_barrier(workdir)
-        return self._collect_rescheduling(workdir)
+            return self._collect_barrier(workdir, cancelled)
+        return self._collect_rescheduling(workdir, cancelled)
 
-    def _collect_barrier(self, workdir):
+    @staticmethod
+    def _kill_cancelled(procs):
+        """``cancelJobGroup(..., interruptOnCancel=True)``: the group's
+        running tasks are killed and the job fails, with no retry."""
+        for proc in procs:
+            if proc.poll() is None:
+                proc.kill()
+        for proc in procs:
+            proc.wait()
+        raise RuntimeError("Job cancelled part of cancelled job group")
+
+    def _collect_barrier(self, workdir, cancelled):
         """Gang semantics: first task failure kills the whole gang, then
         the stage retries AS A WHOLE (fresh attempt for every task) up
         to the consecutive-attempts cap — Spark: 'Barrier stage will be
@@ -177,6 +194,8 @@ class _MappedRDD:
             error = None
             pending = set(range(len(procs)))
             while pending and error is None:
+                if cancelled.is_set():
+                    self._kill_cancelled([proc for proc, _ in procs])
                 progressed = False
                 for index in sorted(pending):
                     proc, result_path = procs[index]
@@ -215,7 +234,7 @@ class _MappedRDD:
             f"{_max_stage_attempts()} times; last failure in task "
             f"{index}:\n{data}")
 
-    def _collect_rescheduling(self, workdir):
+    def _collect_rescheduling(self, workdir, cancelled):
         """Non-barrier semantics: each failed/lost task is rescheduled
         ALONE (peers keep running and keep their results) until
         task.maxFailures, then the job aborts."""
@@ -225,6 +244,8 @@ class _MappedRDD:
         results = [None] * n
         done = set()
         while len(done) < n:
+            if cancelled.is_set():
+                self._kill_cancelled([proc for proc, _ in live.values()])
             progressed = False
             for index in sorted(live):
                 proc, result_path = live[index]
@@ -264,21 +285,42 @@ class _MappedRDD:
 
 
 class _RDD:
-    def __init__(self, partitions, barrier=False):
+    def __init__(self, sc, partitions, barrier=False):
+        self._sc = sc
         self._partitions = partitions
         self._is_barrier = barrier
 
     def barrier(self):
-        return _RDD(self._partitions, barrier=True)
+        return _RDD(self._sc, self._partitions, barrier=True)
 
     def mapPartitionsWithIndex(self, f):  # noqa: N802 — pyspark API
-        return _MappedRDD(self._partitions, f, self._is_barrier)
+        return _MappedRDD(self._sc, self._partitions, f, self._is_barrier)
 
 
 class SparkContext:
     def __init__(self, parallelism):
         self.defaultParallelism = parallelism
         self._local_properties = {}
+        self._thread = threading.local()   # job group: one a thread
+        self._cancel_events = {}
+        self._lock = threading.Lock()
+
+    def setJobGroup(self, groupId, description,  # noqa: N802, N803
+                    interruptOnCancel=False):  # noqa: N803 — pyspark API
+        del description, interruptOnCancel
+        self._thread.group = groupId
+
+    def cancelJobGroup(self, groupId):  # noqa: N802, N803 — pyspark API
+        self._cancel_event(groupId).set()
+
+    def _cancel_event(self, group):
+        with self._lock:
+            return self._cancel_events.setdefault(group, threading.Event())
+
+    def _cancel_event_of_this_thread(self):
+        group = getattr(self._thread, "group", None)
+        return threading.Event() if group is None else \
+            self._cancel_event(group)
 
     def parallelize(self, seq, numSlices=None):  # noqa: N803 — pyspark API
         seq = list(seq)
@@ -286,7 +328,7 @@ class SparkContext:
         parts = [[] for _ in range(n)]
         for i, item in enumerate(seq):
             parts[i * n // max(len(seq), 1)].append(item)
-        return _RDD(parts)
+        return _RDD(self, parts)
 
     def setLocalProperty(self, key, value):  # noqa: N802 — pyspark API
         self._local_properties[key] = value

@@ -6,7 +6,10 @@ devices so sharding/collective code paths are exercised for real.
 Env vars MUST be set before jax is imported anywhere.
 """
 
+import contextlib
 import os
+import signal
+import threading
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -57,6 +60,41 @@ def pytest_configure(config):
         "them unfiltered")
 
 
+# The one limit on a test, in seconds.  Whatever a test waits for in a
+# child (``spawn_tcp_ranks``, ``subprocess.run``) it waits for less long
+# than this, so that the child is reaped by its own call with what it
+# printed in the message; the alarm is what is left when that fails.
+LIMIT = 200
+
+
+@contextlib.contextmanager
+def time_limit(name):
+    """Fail ``name``'s test where it stands once it has run ``LIMIT``
+    seconds: an alarm in the main thread, where ``communicate``, ``join``
+    and ``sleep`` can be interrupted (no plugin: none is installed)."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def over(signum, frame):
+        pytest.fail(f"{name} ran past the suite's limit of {LIMIT} s "
+                    f"(tests/conftest.py LIMIT)", pytrace=False)
+
+    was = signal.signal(signal.SIGALRM, over)
+    signal.setitimer(signal.ITIMER_REAL, LIMIT)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, was)
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    with time_limit(request.node.nodeid):
+        yield
+
+
 @pytest.fixture(scope="session")
 def hvd():
     """Session-wide initialized horovod_tpu (device-rank mode, 8 ranks)."""
@@ -65,6 +103,16 @@ def hvd():
     hvd_module.init()
     yield hvd_module
     hvd_module.shutdown()
+
+
+@pytest.fixture(autouse=True)
+def _hvd_up_again(request):
+    """A test that shut the session's runtime down (``hvd.shutdown()``
+    in its own process) would fail every later test of its worker:
+    ``init`` after it, which returns at once where the runtime is up."""
+    yield
+    if "hvd" in request.fixturenames:
+        request.getfixturevalue("hvd").init()
 
 
 @pytest.fixture(scope="session")
@@ -86,69 +134,89 @@ def spawn_tcp_ranks(n, script, extra_env=None, timeout=90,
     the elastic tests, which enter via ``hvd.elastic.wait_for_membership``
     instead of ``hvd.init``.
 
-    Returns [(returncode, stdout, stderr)] per rank.  Every child is
-    reaped on ANY exit path: a spawn failure or per-rank timeout kills
-    and joins the remaining workers instead of leaking them past the
-    test (they would hold the rendezvous port and skew later timings).
+    Returns [(returncode, stdout, stderr)] per rank.  Each rank writes
+    to files of its own, never to a pipe: a pipe holds 64 KiB, the ranks
+    are waited for one after the other, and a rank that had more to say
+    than that before an earlier one ended would block in ``write`` and
+    read to its peers as stalled or dead.  (XLA's note on loading a
+    cached CPU executable, some KiB a line and one for each program a
+    rank loads, is left out of ``stderr``.)  Every child is reaped on
+    ANY exit path: a spawn failure or a timeout kills and joins the
+    remaining workers instead of leaking them past the test (they would
+    hold the rendezvous port and skew later timings), and a timeout
+    fails the test with what every rank had printed.
     """
     import base64
     import subprocess
     import sys
+    import tempfile
+    import time
 
     from horovod_tpu.run.http_server import RendezvousServer
     from horovod_tpu.run.service import secret
 
-    path = os.path.join("/tmp", f"hvd_ft_worker_{os.getpid()}.py")
-    with open(path, "w") as f:
-        f.write(script)
     server = RendezvousServer()
     port = server.start()
     key = base64.b64encode(secret.make_secret_key()).decode()
     size = n if world_size is None else world_size
     procs = []
-    reaped = set()
-    try:
-        for r in range(n):
-            env = dict(os.environ)
-            env["PYTHONPATH"] = _REPO + os.pathsep + env.get(
-                "PYTHONPATH", "")
-            env.update({
-                "HVD_RANK": str(r), "HVD_SIZE": str(size),
-                "HVD_LOCAL_RANK": str(r), "HVD_LOCAL_SIZE": str(size),
-                "HVD_CROSS_RANK": "0", "HVD_CROSS_SIZE": "1",
-                "HVD_RENDEZVOUS_ADDR": "127.0.0.1",
-                "HVD_RENDEZVOUS_PORT": str(port),
-                "HVD_SECRET_KEY": key,
-            })
-            if extra_env:
-                env.update(extra_env)
-            procs.append(subprocess.Popen(
-                [sys.executable, path], env=env,
-                stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True))
-        results = []
-        import time
-        deadline = time.monotonic() + timeout
-        for i, p in enumerate(procs):
-            remaining = max(1.0, deadline - time.monotonic())
-            out, err = p.communicate(timeout=remaining)
-            reaped.add(i)
-            results.append((p.returncode, out, err))
-        return results
-    finally:
-        # reap EVERYTHING still alive (spawn failure, timeout, or any
-        # other exception above): kill, then join — a killed child left
-        # un-waited would linger as a zombie holding its pipes
-        for i, p in enumerate(procs):
-            if i in reaped:
-                continue
-            if p.poll() is None:
-                p.kill()
+    with tempfile.TemporaryDirectory(prefix="hvd_ft_") as work:
+        path = os.path.join(work, "worker.py")
+        with open(path, "w") as f:
+            f.write(script)
+
+        def said(r):
+            out, err = (open(os.path.join(work, f"{stream}.{r}"),
+                             errors="replace").read()
+                        for stream in ("out", "err"))
+            return out, "\n".join(line for line in err.splitlines()
+                                  if "cpu_aot_loader.cc" not in line)
+
+        def reap():
+            # kill, then join: a killed child left un-waited would
+            # linger as a zombie
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+                    p.wait()
+
+        try:
+            for r in range(n):
+                env = dict(os.environ)
+                env["PYTHONPATH"] = _REPO + os.pathsep + env.get(
+                    "PYTHONPATH", "")
+                env.update({
+                    "HVD_RANK": str(r), "HVD_SIZE": str(size),
+                    "HVD_LOCAL_RANK": str(r), "HVD_LOCAL_SIZE": str(size),
+                    "HVD_CROSS_RANK": "0", "HVD_CROSS_SIZE": "1",
+                    "HVD_RENDEZVOUS_ADDR": "127.0.0.1",
+                    "HVD_RENDEZVOUS_PORT": str(port),
+                    "HVD_SECRET_KEY": key,
+                })
+                if extra_env:
+                    env.update(extra_env)
+                with open(os.path.join(work, f"out.{r}"), "w") as out, \
+                        open(os.path.join(work, f"err.{r}"), "w") as err:
+                    procs.append(subprocess.Popen(
+                        [sys.executable, path], env=env,
+                        stdout=out, stderr=err))
+            deadline = time.monotonic() + timeout
             try:
-                p.communicate(timeout=15)
-            except Exception:  # noqa: BLE001 — reaping is best-effort
-                pass
-        server.stop()
+                for p in procs:
+                    p.wait(timeout=max(1.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                reap()
+                # say what every rank had printed, not only that one hung
+                raise AssertionError(
+                    f"ranks still running after {timeout}s:\n" + "\n".join(
+                        f"--- rank {r} exit {p.returncode}\n"
+                        f"{out[-1500:]}\n{err[-3000:]}"
+                        for r, p in enumerate(procs)
+                        for out, err in [said(r)])) from None
+            return [(p.returncode, *said(r)) for r, p in enumerate(procs)]
+        finally:
+            reap()    # a spawn failure or any other exception above
+            server.stop()
 
 
 PYSPARK_SHIM = os.path.join(_REPO, "tests", "_pyspark_shim")

@@ -275,7 +275,7 @@ def test_autotune_end_to_end_through_collectives(tmp_path):
     })
     result = subprocess.run(
         [sys.executable, "-c", AUTOTUNE_E2E_SCRIPT], env=env,
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=180,
         cwd=os.path.dirname(os.path.dirname(__file__)))
     assert result.returncode == 0, result.stderr[-3000:]
     assert "AUTOTUNE-E2E OK" in result.stdout
@@ -348,7 +348,7 @@ def test_tcp_autotune_synchronized_across_ranks(tmp_path):
     import subprocess
     import sys
 
-    path = "/tmp/hvd_autotune_tcp_worker.py"
+    path = str(tmp_path / "hvd_autotune_tcp_worker.py")
     with open(path, "w") as f:
         f.write(TCP_AUTOTUNE_SCRIPT)
     log = tmp_path / "autotune_tcp.csv"
@@ -365,7 +365,7 @@ def test_tcp_autotune_synchronized_across_ranks(tmp_path):
     hvdrun = os.path.join(repo, "bin", "hvdrun")
     result = subprocess.run(
         [sys.executable, hvdrun, "-np", "4", sys.executable, path],
-        env=env, capture_output=True, text=True, timeout=600)
+        env=env, capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, \
         result.stdout[-2000:] + result.stderr[-3000:]
     for r in range(4):
@@ -425,7 +425,7 @@ def test_gmesh_autotune_synchronized(tmp_path):
     import subprocess
     import sys
 
-    path = "/tmp/hvd_autotune_gmesh_worker.py"
+    path = str(tmp_path / "hvd_autotune_gmesh_worker.py")
     with open(path, "w") as f:
         f.write(GMESH_AUTOTUNE_SCRIPT)
     log = tmp_path / "autotune_gmesh.csv"
@@ -447,7 +447,7 @@ def test_gmesh_autotune_synchronized(tmp_path):
     result = subprocess.run(
         [sys.executable, hvdrun, "-np", "2", "--global-mesh",
          sys.executable, path],
-        env=env, capture_output=True, text=True, timeout=600)
+        env=env, capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, \
         result.stdout[-2000:] + result.stderr[-3000:]
     for p in range(2):

@@ -429,8 +429,11 @@ def cell_step(v5e):
     ``benchmark/run.py`` builds it (the ``spmd`` loop's own step over
     the family's loss) at published widths and the cell's own batch,
     compiled for one described chip; with ``transformer``, the family is
-    handed that class for the program's ``Transformer``.  Each is
-    compiled once for the tests of this file."""
+    handed that class for the program's ``Transformer``; with ``depth``,
+    those keys of the cell's configuration (its counts of layers) are
+    replaced.  Each is compiled once for the tests of this file, and a
+    cell at its own depth by one test only: ``cell_step.misses`` lists
+    what was compiled."""
     import sys
 
     import optax
@@ -449,13 +452,17 @@ def cell_step(v5e):
     mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
     compiled = {}
 
-    def compile_step(workload, transformer=None, variant=None):
+    def compile_step(workload, transformer=None, variant=None, depth=None):
         """``variant`` names what the caller changed around the call: a
         step of its own in the cache."""
-        key = (workload, transformer, variant)
+        depth = depth or {}
+        key = (workload, transformer, variant, tuple(sorted(depth.items())))
         if key in compiled:
             return compiled[key]
+        compile_step.misses.append(key)
         cell = bench.load_cell(repo, workload)
+        assert set(depth) <= set(cell.config), depth
+        cell.config = {**cell.config, **depth}
         opt, step = cell.loop.make_step(
             cell, optax.adamw(**cell.job["optimizer"]["args"]), mesh)
         params, extra = jax.eval_shape(
@@ -474,6 +481,7 @@ def cell_step(v5e):
             horovod_tpu.models.Transformer = was
         return compiled[key]
 
+    compile_step.misses = []
     return compile_step
 
 
@@ -481,6 +489,26 @@ def _fits_one_chip(compiled):
     mem = compiled.memory_analysis()
     return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+
+
+def test_dense_cell_step_compiles_for_v5e(cell_step):
+    """``gpt2_medium-spmd-1chip`` at its 24 layers and the cell's 8
+    sequences of 1024, nothing recomputed: fits one chip; a flash forward
+    and the one backward kernel a block, q, k and v ``[128, 1024, 64]``
+    (8 sequences' 16 heads); the loss kernels once.  (The cell two
+    ``perf_opt`` PRs claimed in: its memory and kernels are held here, as
+    the other cells' are by the tests below.)"""
+    compiled = cell_step("gpt2_medium-spmd-1chip")
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len([line for line in kernels
+                if "[128,1024,64]" in line]) == 24 * 2
+    # and a block's two layer norms, forward and backward; the last
+    # norm's two; softmax-xent's two
+    assert len(kernels) == 24 * (2 + 4) + 2 + 2
+    assert "rematted_computation" not in text
+    assert _fits_one_chip(compiled)
 
 
 @pytest.mark.parametrize("workload,grouped_products,kernels", [
@@ -796,6 +824,48 @@ def _instructions(compiled):
     return out
 
 
+# What the comparisons below compile both of their sides at: published
+# widths and the cell's batch, and the least depth that has a layer of
+# each kind the cell has.  A step has the instructions a change added or
+# it has not, at 2 layers as at 24; the cell's own depth is compiled
+# once, by the test that reads its memory.
+SHALLOW = {
+    "gpt2_medium-spmd-1chip": {"n_layer": 2},       # a block after a block
+    # the leading dense layer and one layer of experts (and the module
+    # that predicts the token after next, which is no layer of these)
+    "joyai_llm_flash-spmd-1chip": {"num_hidden_layers": 2},
+    "olmoe_1b_7b-spmd-1chip": {},                   # one layer as it is
+    # one block, run ``total_ut_steps`` = 4 times as published
+    "ouro_2_6b-spmd-1chip": {"num_hidden_layers": 1},
+}
+
+
+@pytest.mark.parametrize("workload,flash,blocks,loops,kinds", [
+    ("gpt2_medium-spmd-1chip", "[128,1024,64]", 2, 0,
+     ("/block_0/mlp/", "/block_1/mlp/")),
+    # the dense layer, the layer of experts, and the module's block
+    ("joyai_llm_flash-spmd-1chip", "[128,4096,192]", 3, None,
+     ("/block_0/mlp/", "/block_1/moe/", "/block/moe/")),
+    # one block under the forward loop over the passes and the backward
+    ("ouro_2_6b-spmd-1chip", "[16,4096,128]", 1, 2, ("/block_0/mlp/",))],
+    ids=["gpt2_medium", "joyai_llm_flash", "ouro_2_6b"])
+def test_shallow_steps_have_a_layer_of_each_kind(cell_step, workload, flash,
+                                                 blocks, loops, kinds):
+    """``SHALLOW`` is what it says: at that depth the step (the one the
+    comparisons below take for the program as it is) has every kind of
+    layer the cell has, a flash forward and the one backward kernel a
+    block at the cell's own shape, and the loop over the passes where
+    the cell has one."""
+    text = cell_step(workload, depth=SHALLOW[workload]).as_text()
+    kernels = [line for line in text.splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    assert len([line for line in kernels if flash in line]) == blocks * 2
+    assert all(kind in text for kind in kinds)
+    if loops is not None:
+        assert len([line for line in text.splitlines()
+                    if " while(" in line]) == loops
+
+
 @pytest.mark.parametrize("workload", ["gpt2_medium-spmd-1chip",
                                       "joyai_llm_flash-spmd-1chip"])
 def test_cells_without_passes_compile_to_the_step_from_before(cell_step,
@@ -810,8 +880,10 @@ def test_cells_without_passes_compile_to_the_step_from_before(cell_step,
         from test_transformer_looped import TransformerBefore
     finally:
         sys.path.pop(0)
-    now = _instructions(cell_step(workload))
-    before = _instructions(cell_step(workload, TransformerBefore))
+    depth = SHALLOW[workload]
+    now = _instructions(cell_step(workload, depth=depth))
+    before = _instructions(cell_step(workload, TransformerBefore,
+                                     depth=depth))
     assert len(now) > 2000 and len(now) == len(before)
     assert now == before
 
@@ -823,13 +895,15 @@ def test_saved_names_are_nothing_in_a_step_without_recomputation(
     """The flash forward rule names its output and lse for the policy
     of a recomputed block; a step that recomputes nothing compiles to
     the step without the names, instruction for instruction."""
-    named = _instructions(cell_step(workload))
+    depth = SHALLOW[workload]
+    named = _instructions(cell_step(workload, depth=depth))
     # (the package's attribute of this name is the function)
     for module in ("horovod_tpu.ops.pallas.flash_attention",
                    "horovod_tpu.models.transformer"):
         monkeypatch.setattr(importlib.import_module(module),
                             "checkpoint_name", lambda x, name: x)
-    unnamed = _instructions(cell_step(workload, variant="unnamed"))
+    unnamed = _instructions(cell_step(workload, variant="unnamed",
+                                      depth=depth))
     assert len(named) > 2000 and named == unnamed
 
 
@@ -843,7 +917,8 @@ def test_looped_cell_step_keeps_what_it_kept(cell_step, monkeypatch):
 
     from horovod_tpu.models import transformer
 
-    now = _instructions(cell_step("ouro_2_6b-spmd-1chip"))
+    depth = SHALLOW["ouro_2_6b-spmd-1chip"]
+    now = _instructions(cell_step("ouro_2_6b-spmd-1chip", depth=depth))
     flash = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
     name = flash.checkpoint_name
     monkeypatch.setattr(flash, "checkpoint_name", lambda x, n: (
@@ -853,8 +928,19 @@ def test_looped_cell_step_keeps_what_it_kept(cell_step, monkeypatch):
         block, policy=jax.checkpoint_policies.save_only_these_names(
             *flash.SAVED_NAMES)))
     before = _instructions(cell_step("ouro_2_6b-spmd-1chip",
-                                     variant="policy-before"))
+                                     variant="policy-before", depth=depth))
     assert len(now) > 2000 and now == before
+
+
+def test_a_cell_is_compiled_at_its_own_depth_once(cell_step):
+    """What the tests above compiled (this one runs after them): a cell
+    at its published depth once, under no other ``Transformer`` and no
+    variant, but for OLMoE's, whose cell is one layer as it is."""
+    print("\n".join(map(str, cell_step.misses)))
+    at_depth = [key for key in cell_step.misses if not key[3]]
+    assert len(set(at_depth)) == len(at_depth)
+    assert {key for key in at_depth if key[1] or key[2]} <= {
+        ("olmoe_1b_7b-spmd-1chip", None, "unnamed", ())}
 
 
 @pytest.mark.parametrize("chips,compression,hierarchical", [

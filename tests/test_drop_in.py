@@ -63,7 +63,7 @@ def test_reference_tensorflow_keras_path():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     result = subprocess.run([sys.executable, "-c", script], env=env,
-                            capture_output=True, text=True, timeout=300)
+                            capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, result.stderr[-2000:]
     assert "TF_KERAS_PATH_OK" in result.stdout
 
@@ -119,7 +119,7 @@ def test_unmodified_reference_style_script_trains(tmp_path):
         [sys.executable, os.path.join(REPO, "bin", "hvdrun"),
          "-np", "2", sys.executable, str(script)],
         env=_clean_worker_env(), capture_output=True, text=True,
-        timeout=420)
+        timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout[-2000:]}\nstderr:\n{result.stderr[-2000:]}"
     assert "REFERENCE_STYLE_TRAIN_OK" in result.stdout
@@ -166,7 +166,7 @@ def test_unmodified_reference_style_tf_script_under_horovodrun(tmp_path):
         [sys.executable, os.path.join(REPO, "bin", "horovodrun"),
          "-np", "2", sys.executable, str(script)],
         env=_clean_worker_env(), capture_output=True, text=True,
-        timeout=420)
+        timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout[-2000:]}\nstderr:\n{result.stderr[-2000:]}"
     assert "TF_REFERENCE_STYLE_OK" in result.stdout

@@ -867,7 +867,7 @@ def test_checkpoint_writer_clean_under_race_shim(tmp_path):
         "CKPT_DIR": str(tmp_path / "store"),
     })
     out = subprocess.run([sys.executable, str(script)], env=env,
-                         capture_output=True, text=True, timeout=240,
+                         capture_output=True, text=True, timeout=180,
                          cwd=REPO)
     assert out.returncode == 0, f"{out.stdout}\n{out.stderr}"
     assert "RACE_CKPT_OK" in out.stdout

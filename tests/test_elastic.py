@@ -243,7 +243,7 @@ try:
     hvd.elastic.run(train, state)
 except hvd.HvdAbortedError as exc:
     print(f"rank {hvd.rank()} wid {wid} ABORTED "
-          f"origin={exc.origin_rank}", flush=True)
+          f"origin={exc.origin_rank} why={exc}", flush=True)
     print(f"rank {hvd.rank()} wid {wid} DONE", flush=True)
     raise SystemExit(0)
 digest = hashlib.sha1(
@@ -348,7 +348,7 @@ try:
     hvd.elastic.run(train, state)
 except hvd.HvdAbortedError as exc:
     print(f"rank {hvd.rank()} wid {wid} ABORTED "
-          f"origin={exc.origin_rank}", flush=True)
+          f"origin={exc.origin_rank} why={exc}", flush=True)
     print(f"rank {hvd.rank()} wid {wid} DONE", flush=True)
     raise SystemExit(0)
 digest = hashlib.sha1(
@@ -506,7 +506,7 @@ try:
     hvd.elastic.run(train, state)
 except hvd.HvdAbortedError as exc:
     print(f"rank {hvd.rank()} wid {wid} ABORTED "
-          f"origin={exc.origin_rank}", flush=True)
+          f"origin={exc.origin_rank} why={exc}", flush=True)
     print(f"rank {hvd.rank()} wid {wid} DONE", flush=True)
     raise SystemExit(0)
 digest = hashlib.sha1(

@@ -11,7 +11,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EXAMPLES = os.path.join(REPO, "examples")
 
 
-def _run_example(name, *args, timeout=420):
+def _run_example(name, *args, timeout=180):
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
@@ -44,7 +44,9 @@ def _run_example(name, *args, timeout=420):
                            "--n-layers", "2", "--seq-len", "32")),
     ("jax_mnist.py", ("--epochs", "1", "--batch-size", "256",
                       "--num-samples", "512")),
-    ("jax_imagenet_resnet50.py", ("--epochs", "1", "--steps", "2",
+    # (its time is the compile of ResNet-50's step at 224 x 224, which
+    # no flag of the example changes)
+    ("jax_imagenet_resnet50.py", ("--epochs", "1", "--steps", "1",
                                   "--batch-size", "1")),
     ("moe_expert_parallel.py", ("--steps", "4", "--d-model", "64",
                                 "--seq-len", "32")),
@@ -91,7 +93,7 @@ def test_synthetic_benchmark_tiny():
     result = _run_example(
         "jax_synthetic_benchmark.py", "--model", "resnet50",
         "--batch-size", "1", "--num-warmup-batches", "1",
-        "--num-batches-per-iter", "1", "--num-iters", "1", timeout=600)
+        "--num-batches-per-iter", "1", "--num-iters", "1", timeout=180)
     assert result.returncode == 0, result.stderr
     assert "Img/sec per device" in result.stdout
 
@@ -102,13 +104,13 @@ def test_synthetic_benchmark_transformer_tiny():
         "--seq-len", "64", "--d-model", "128", "--n-layers", "2",
         "--vocab-size", "512", "--batch-size", "8",
         "--num-warmup-batches", "1", "--num-batches-per-iter", "1",
-        "--num-iters", "1", timeout=600)
+        "--num-iters", "1", timeout=180)
     assert result.returncode == 0, result.stderr
     assert "Tokens/sec per device" in result.stdout
 
 
 
-def _run_example_hvdrun(name, *args, np_=2, timeout=600):
+def _run_example_hvdrun(name, *args, np_=2, timeout=180):
     """Per-process bindings (torch/TF/keras) run one process per rank."""
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
@@ -137,41 +139,40 @@ def test_torch_synthetic_benchmark_under_hvdrun():
 
 
 def test_torch_imagenet_resnet50_under_hvdrun():
+    # one step a rank, of the least batch a batch norm in training takes
     result = _run_example_hvdrun(
         "torch_imagenet_resnet50.py", "--epochs", "1", "--batch-size",
-        "2", "--num-samples", "4", "--img", "64", "--num-classes", "10")
+        "2", "--num-samples", "2", "--img", "32", "--num-classes", "10")
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("RESNET50 DONE") == 2
 
 
-def test_tf2_examples_under_hvdrun():
-    import pytest
+@pytest.mark.parametrize("name,args", [
+    # (a rank's half of the samples must hold a whole batch)
+    ("tensorflow2_mnist.py", ("--epochs", "1", "--batch-size", "64",
+                              "--num-samples", "128")),
+    ("tensorflow2_keras_mnist.py", ("--epochs", "1", "--batch-size", "64",
+                                    "--num-samples", "64")),
+    ("keras_mnist_advanced.py", ("--epochs", "2", "--batch-size", "64",
+                                 "--num-samples", "64",
+                                 "--warmup-epochs", "1")),
+    ("tensorflow2_synthetic_benchmark.py",
+     ("--model", "small", "--batch-size", "4", "--img", "32",
+      "--num-iters", "1", "--num-batches-per-iter", "1")),
+])
+def test_tf2_examples_under_hvdrun(name, args):
     pytest.importorskip("tensorflow")
-    for name, args in [
-        ("tensorflow2_mnist.py", ("--epochs", "1", "--batch-size", "64",
-                                  "--num-samples", "256")),
-        ("tensorflow2_keras_mnist.py", ("--epochs", "1",
-                                        "--batch-size", "64",
-                                        "--num-samples", "256")),
-        ("keras_mnist_advanced.py", ("--epochs", "2", "--batch-size",
-                                     "64", "--num-samples", "256",
-                                     "--warmup-epochs", "1")),
-        ("tensorflow2_synthetic_benchmark.py",
-         ("--model", "small", "--batch-size", "4", "--img", "32",
-          "--num-iters", "1", "--num-batches-per-iter", "2")),
-    ]:
-        result = _run_example_hvdrun(name, *args)
-        assert result.returncode == 0, \
-            f"{name} failed\nstdout:\n{result.stdout}\n" \
-            f"stderr:\n{result.stderr}"
+    result = _run_example_hvdrun(name, *args)
+    assert result.returncode == 0, \
+        f"{name} failed\nstdout:\n{result.stdout}\n" \
+        f"stderr:\n{result.stderr}"
 
 
 def test_keras_imagenet_resnet50_train_and_resume(tmp_path):
-    import pytest
     pytest.importorskip("tensorflow")
     ckpt_dir = str(tmp_path / "krn50")
-    args = ("--epochs", "1", "--batch-size", "2", "--num-samples", "4",
+    args = ("--epochs", "1", "--batch-size", "2", "--num-samples", "2",
             "--img", "32", "--num-classes", "4",
             "--checkpoint-dir", ckpt_dir)
     first = _run_example_hvdrun("keras_imagenet_resnet50.py", *args)
@@ -194,7 +195,7 @@ def test_spark_mnist_example():
     result = subprocess.run(
         [sys.executable, os.path.join(EXAMPLES, "spark_mnist.py"),
          "--num-proc", "2", "--epochs", "3"],
-        env=shim_env(), capture_output=True, text=True, timeout=600)
+        env=shim_env(), capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr[-3000:]}"
     assert "SPARK_MNIST_OK" in result.stdout

@@ -498,9 +498,11 @@ def test_launcher_names_culprit_rank():
     import os
     import subprocess
     import sys
+    import tempfile
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = "/tmp/hvd_culprit_worker.py"
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_culprit_worker.py")
     with open(path, "w") as f:
         f.write(r"""
 import os, sys, time
@@ -567,10 +569,17 @@ hi = hvd.new_group([2, 3], name="ft.hi")
 mine = lo if r < 2 else hi
 n_elems = int(os.environ.get("FT_SIZE", "8"))
 t = jnp.ones((n_elems,)) * (r + 1)
+# every rank leaves from one line (the faulted rank's allreduce 1),
+# whatever the machine's load did to the ranks' start-up
+hvd.barrier(name="ft.start")
 start = time.monotonic()
 try:
     hvd.allreduce(t, op=hvd.Sum, name="ft.group", group=mine)
-    # the healthy group reaches the world barrier and must ALSO die
+    # the healthy group reaches the world barrier and must ALSO die; it
+    # gets there well after rank 0's request for "ft.group" reached the
+    # coordinator, so that of two stalled entries the GROUP's is the
+    # older and its missing rank, not the barrier's, the one named
+    time.sleep(1.5)
     hvd.barrier(name="ft.join")
     print(f"rank {r} COMPLETED", flush=True)
 except hvd.HvdAbortedError as exc:
@@ -593,7 +602,7 @@ def test_injected_crash_inside_subgroup_aborts_whole_job():
         "FT_SIZE": "8",  # star path
         "HVD_TPU_LIVENESS_TIMEOUT": "2",
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "20",
-        "HVD_TPU_FAULT_SPEC": "rank1:allreduce:1:crash",
+        "HVD_TPU_FAULT_SPEC": "rank1:allreduce:2:crash",
     })
     assert results[1][0] == 1, f"crashed rank: {results[1][1]}"
     for rank in (0, 2, 3):
@@ -631,7 +640,7 @@ def test_injected_drop_inside_subgroup_promotes_stall():
         "FT_SIZE": "8",
         "HVD_TPU_LIVENESS_TIMEOUT": "30",  # must NOT fire: rank 1 lives
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "2",
-        "HVD_TPU_FAULT_SPEC": "rank1:allreduce:1:drop",
+        "HVD_TPU_FAULT_SPEC": "rank1:allreduce:2:drop",
     })
     for rank, (code, out, err) in enumerate(results):
         assert code == 0, f"rank {rank}: {out}\n{err}"
@@ -1116,9 +1125,14 @@ try:
         digest.update(np.asarray(out).tobytes())
         b = hvd.broadcast(t, root_rank=0, name=f"sess.bc.{step}")
         digest.update(np.asarray(b).tobytes())
+    # the job's end, in order: rank 0 hosts the coordinator, and a rank 0
+    # that left from its last collective without it would take along the
+    # threads that still owe slower ranks that collective's go-ahead
+    hvd.shutdown()
     print(f"rank {r} COMPLETED digest={digest.hexdigest()}", flush=True)
 except hvd.HvdAbortedError as exc:
-    print(f"rank {r} ABORTED origin={exc.origin_rank}", flush=True)
+    print(f"rank {r} ABORTED origin={exc.origin_rank} why={exc}",
+          flush=True)
 print(f"rank {r} DONE", flush=True)
 """
 

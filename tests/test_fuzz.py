@@ -72,7 +72,7 @@ def _run_cli(*argv):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONHASHSEED="random")
     return subprocess.run(
         [sys.executable, HVD_FUZZ, *argv],
-        capture_output=True, cwd=REPO, env=env, timeout=300)
+        capture_output=True, cwd=REPO, env=env, timeout=180)
 
 
 def test_report_byte_identical_across_processes():

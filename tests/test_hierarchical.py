@@ -99,7 +99,7 @@ def _run(extra_env):
     })
     env.update(extra_env)
     return subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                          capture_output=True, text=True, timeout=600)
+                          capture_output=True, text=True, timeout=180)
 
 
 def test_hierarchical_collectives_match_flat_expectation():
@@ -138,6 +138,6 @@ def test_hierarchy_degenerate_without_grouping():
         "HVD_HIERARCHICAL_ALLREDUCE": "1",
     })
     result = subprocess.run([sys.executable, "-c", code], env=env,
-                            capture_output=True, text=True, timeout=600)
+                            capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, result.stderr
     assert "DEGENERATE_OK" in result.stdout

@@ -13,6 +13,7 @@ runs can't cover on one chip.
 import os
 import subprocess
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HVDRUN = os.path.join(REPO, "bin", "hvdrun")
@@ -163,9 +164,10 @@ hvd.shutdown()
 """
 
 
-def _run_gmesh(script, np_=2, devices_per_proc=4, timeout=600,
+def _run_gmesh(script, np_=2, devices_per_proc=4, timeout=180,
                extra_env=None):
-    path = "/tmp/hvd_multihost_worker.py"
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_multihost_worker.py")
     with open(path, "w") as f:
         f.write(script)
     env = {k: v for k, v in os.environ.items()
@@ -308,7 +310,7 @@ def test_global_mesh_stall_shutdown():
     stall shutdown; the waiting process gets a per-name HvdError while
     healthy collectives complete (reference: StallInspector +
     Response::ERROR semantics, on the pod control plane)."""
-    result = _run_gmesh(STALL_WORKER, timeout=300, extra_env={
+    result = _run_gmesh(STALL_WORKER, timeout=180, extra_env={
         "HVD_STALL_CHECK_TIME_SECONDS": "1",
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "4",
     })
@@ -352,7 +354,7 @@ def test_global_mesh_four_processes():
     8-rank global mesh (the coordinator's per-process bookkeeping must
     not assume 2 hosts)."""
     result = _run_gmesh(FOURPROC_WORKER, np_=4, devices_per_proc=2,
-                        timeout=600)
+                        timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("GMESH_4P_OK") == 4
@@ -403,7 +405,7 @@ def test_global_mesh_intra_process_mismatch_errors_globally():
     """Two ranks INSIDE one process disagreeing on a tensor's shape must
     error every rank in the job (regression: the coordinator only
     validated across processes, so the misalignment executed silently)."""
-    result = _run_gmesh(LOCAL_MISMATCH_WORKER, timeout=300)
+    result = _run_gmesh(LOCAL_MISMATCH_WORKER, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("GMESH_LOCAL_MISMATCH_OK") == 2

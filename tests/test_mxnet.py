@@ -12,13 +12,15 @@ broadcast_parameters incl. the deferred-init post-hook broadcast."""
 import os
 import subprocess
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHIM = os.path.join(REPO, "tests", "_mxnet_shim")
 
 
-def _run_driver(script, timeout=420):
-    path = "/tmp/hvd_mxnet_driver.py"
+def _run_driver(script, timeout=180):
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_mxnet_driver.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)
@@ -179,7 +181,8 @@ def test_import_guard_without_mxnet():
         "except ImportError as exc:\n"
         "    assert 'MXNet' in str(exc), exc\n"
         "print('MX_GUARD_OK')\n")
-    path = "/tmp/hvd_mxnet_guard.py"
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_mxnet_guard.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)

@@ -82,7 +82,7 @@ def test_controller_parity(controller):
         "HVD_CONTROLLER": controller,
     })
     result = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                            capture_output=True, text=True, timeout=300,
+                            capture_output=True, text=True, timeout=180,
                             cwd=os.path.dirname(os.path.dirname(__file__)))
     assert result.returncode == 0, result.stderr
     expected = ("NativeController" if controller == "native"
@@ -150,7 +150,7 @@ def test_python_controller_response_cache():
         "HVD_CONTROLLER": "python",
     })
     result = subprocess.run([sys.executable, "-c", PY_CACHE_SCRIPT], env=env,
-                            capture_output=True, text=True, timeout=300,
+                            capture_output=True, text=True, timeout=180,
                             cwd=os.path.dirname(os.path.dirname(__file__)))
     assert result.returncode == 0, result.stderr
     assert "PY-CACHE OK" in result.stdout

@@ -7,6 +7,7 @@ workers and any failure propagates as a nonzero exit."""
 import os
 import subprocess
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HVDRUN = os.path.join(REPO, "bin", "hvdrun")
@@ -95,8 +96,9 @@ hvd.shutdown()
 """
 
 
-def _run_hvdrun(np_, script, extra_args=(), timeout=420):
-    path = "/tmp/hvd_process_mode_worker.py"
+def _run_hvdrun(np_, script, extra_args=(), timeout=180):
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_process_mode_worker.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)
@@ -139,7 +141,7 @@ def test_many_outstanding_out_of_order_collectives():
         "print('OOO_OK', flush=True)\n"
         "hvd.shutdown()\n"
     )
-    result = _run_hvdrun(2, script, timeout=300)
+    result = _run_hvdrun(2, script, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("OOO_OK") == 2
@@ -219,7 +221,7 @@ def test_ring_adasum_distributed_vhdd():
     numpy oracle; joined ranks fall back to the payload path with world
     tree semantics."""
     result = _run_hvdrun(4, RING_ADASUM_WORKER,
-                         extra_args=(), timeout=420)
+                         extra_args=(), timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("RING_ADASUM_OK") == 4

@@ -40,7 +40,7 @@ def _run_hvd_race(fixture, seed=7, extra=()):
     out = subprocess.run(
         [sys.executable, HVD_RACE, "--seed", str(seed), "--no-baseline",
          "--format", "json", *extra, os.path.join(FIXTURES, fixture)],
-        env=env, capture_output=True, text=True, timeout=240, cwd=REPO)
+        env=env, capture_output=True, text=True, timeout=180, cwd=REPO)
     payload = json.loads(out.stdout) if out.stdout.strip() else {}
     return out.returncode, payload, out.stderr
 
@@ -145,7 +145,7 @@ def _run_probe(race_on):
         env["HVD_TPU_RACE"] = "1"
     script = NEUTRALITY_PROBE.replace("__RACE_ON__", str(race_on))
     out = subprocess.run([sys.executable, "-c", script], env=env,
-                         capture_output=True, text=True, timeout=240)
+                         capture_output=True, text=True, timeout=180)
     assert out.returncode == 0, out.stderr
     assert "NEUTRAL-OK" in out.stdout
 
@@ -183,7 +183,7 @@ def _run_inline_under_shim(body, report_prefix, tmp_path):
         "HVD_TPU_RACE_REPORT": str(tmp_path / report_prefix),
     })
     out = subprocess.run([sys.executable, "-c", body], env=env,
-                         capture_output=True, text=True, timeout=300,
+                         capture_output=True, text=True, timeout=180,
                          cwd=REPO)
     assert out.returncode == 0, f"{out.stdout}\n{out.stderr}"
     return _nonbaselined(str(tmp_path / (report_prefix + ".*.json")))
@@ -356,7 +356,7 @@ def test_fault_harness_clean_under_shim_and_origin_deterministic(
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "30",
         "HVD_TCP_RING_THRESHOLD": "1024",
         "HVD_TPU_FAULT_SPEC": "rank1:ring:1:crash",
-    }, timeout=240)
+    }, timeout=180)
     code0, out0, err0 = results[0]
     code1, out1, _ = results[1]
     assert code1 == 1, f"crashed rank: {out1}"
@@ -412,7 +412,7 @@ def test_elastic_reconfig_path_clean_under_shim(tmp_path):
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "30",
         "HVD_TCP_RING_THRESHOLD": "1024",
         "HVD_TPU_FAULT_SPEC": "rank2:allreduce:2:crash",
-    }, timeout=240)
+    }, timeout=180)
     assert results[2][0] == 1, f"crashed rank: {results[2][1]}"
     for r in (0, 1):
         code, out, err = results[r]
@@ -443,7 +443,7 @@ def test_coord_failover_path_clean_under_shim(tmp_path):
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "30",
         "HVD_TCP_RING_THRESHOLD": "1024",
         "HVD_TPU_FAULT_SPEC": "rank0:allreduce:2:crash",
-    }, timeout=240)
+    }, timeout=180)
     assert results[0][0] == 1, f"crashed rank 0: {results[0][1]}"
     for r in (1, 2, 3):
         code, out, err = results[r]
@@ -484,7 +484,7 @@ def test_write_baseline_roundtrip(tmp_path):
         [sys.executable, HVD_RACE, "--seed", "7", "--baseline",
          str(base), "--write-baseline",
          os.path.join(FIXTURES, "bad_unlocked_counter.py")],
-        env=env, capture_output=True, text=True, timeout=240, cwd=REPO)
+        env=env, capture_output=True, text=True, timeout=180, cwd=REPO)
     assert out.returncode == 0, out.stderr
     reloaded = findings_mod.load_baseline(str(base))
     key = ("race:tests/race_fixtures/bad_unlocked_counter.py:"
@@ -511,7 +511,7 @@ def test_write_baseline_refuses_partial_run(tmp_path):
     out = subprocess.run(
         [sys.executable, HVD_RACE, "--baseline", str(base),
          "--write-baseline", str(target)],
-        env=env, capture_output=True, text=True, timeout=240, cwd=REPO)
+        env=env, capture_output=True, text=True, timeout=180, cwd=REPO)
     assert out.returncode == 3, out.stdout + out.stderr
     assert "baseline NOT rewritten" in out.stderr
     assert base.read_text() == original

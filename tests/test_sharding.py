@@ -409,7 +409,7 @@ def test_tcp_signature_separates_request_types():
 
 
 # ================================================== subprocess matrices ====
-def _run_cpu_script(script, extra_env=None, timeout=300, devices=4):
+def _run_cpu_script(script, extra_env=None, timeout=180, devices=4):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"

@@ -180,7 +180,7 @@ def test_soak_16_ranks_all_gates_pass(tmp_path):
     drained rank exits 0, survivors digest-identical to a chaos-free
     run at the same final membership."""
     proc = _run_soak(["--ranks", "16", "--steps", "8",
-                      "--report", str(tmp_path)], timeout=560)
+                      "--report", str(tmp_path)], timeout=180)
     report_path = tmp_path / "SOAK_r16.json"
     assert report_path.exists(), f"{proc.stdout}\n{proc.stderr}"
     report = json.loads(report_path.read_text())
@@ -196,7 +196,7 @@ def test_soak_64_ranks_collect_only_completes(tmp_path):
     exchange, liveness registration) and tears down clean on one
     oversubscribed host — the O(N) control-plane proof."""
     proc = _run_soak(["--ranks", "64", "--collect-only",
-                      "--report", str(tmp_path)], timeout=560)
+                      "--report", str(tmp_path)], timeout=180)
     report_path = tmp_path / "SOAK_r64.json"
     assert report_path.exists(), f"{proc.stdout}\n{proc.stderr}"
     report = json.loads(report_path.read_text())

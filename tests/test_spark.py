@@ -14,8 +14,11 @@ line of ``horovod_tpu/spark`` executes for real: the rendezvous server,
 the env contract, the tcp controller inside each task."""
 
 import os
+import re
+import signal
 import subprocess
 import sys
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SHIM = os.path.join(REPO, "tests", "_pyspark_shim")
@@ -24,11 +27,11 @@ SHIM = os.path.join(REPO, "tests", "_pyspark_shim")
 from tests.conftest import pyspark_shim_env as shim_env  # noqa: E402
 
 
-def _run_driver(script, extra_env=None, timeout=420):
-    path = "/tmp/hvd_spark_driver.py"
-    with open(path, "w") as f:
-        f.write(script)
-    return subprocess.run([sys.executable, path], env=shim_env(extra_env),
+def _run_driver(tmp_path, script, extra_env=None, timeout=180):
+    path = tmp_path / "hvd_spark_driver.py"
+    path.write_text(script)
+    return subprocess.run([sys.executable, str(path)],
+                          env=shim_env(extra_env),
                           capture_output=True, text=True, timeout=timeout)
 
 
@@ -82,8 +85,8 @@ print("SPARK_RUN_OK", flush=True)
 """
 
 
-def test_spark_run_collectives_and_contract():
-    result = _run_driver(RUN_FN_DRIVER)
+def test_spark_run_collectives_and_contract(tmp_path):
+    result = _run_driver(tmp_path, RUN_FN_DRIVER)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert "SPARK_RUN_OK" in result.stdout
@@ -126,14 +129,15 @@ print("SPARK_FAILURE_OK", flush=True)
 """
 
 
-def test_spark_task_failure_fails_job_and_driver_recovers():
-    result = _run_driver(FAILURE_DRIVER)
+def test_spark_task_failure_fails_job_and_driver_recovers(tmp_path):
+    result = _run_driver(tmp_path, FAILURE_DRIVER)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert "SPARK_FAILURE_OK" in result.stdout
 
 
 ESTIMATOR_DRIVER = r"""
+import tempfile
 import jax
 jax.config.update("jax_platforms", "cpu")  # driver builds the template
 import numpy as np
@@ -148,7 +152,7 @@ w = rng.randn(8, 3).astype(np.float32)
 y = (x @ w + 0.1 * rng.randn(64, 3)).astype(np.float32)
 
 est = JaxEstimator(MLP(features=(16, 3)), epochs=8, batch_size=16,
-                   learning_rate=0.05, store=LocalStore("/tmp/hvd_sp_store"),
+                   learning_rate=0.05, store=LocalStore(tempfile.mkdtemp()),
                    backend=SparkBackend(num_proc=2, jax_platform="cpu"))
 model, metrics = est.fit(x, y)
 assert len(metrics) == 2                      # one entry per Spark task
@@ -166,7 +170,7 @@ print("SPARK_ESTIMATOR_OK", flush=True)
 
 
 def test_estimator_fit_through_spark_backend(tmp_path):
-    result = _run_driver(ESTIMATOR_DRIVER, timeout=900)
+    result = _run_driver(tmp_path, ESTIMATOR_DRIVER)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert "SPARK_ESTIMATOR_OK" in result.stdout
@@ -204,13 +208,13 @@ print("SPARK_STREAMING_ESTIMATOR_OK", flush=True)
 def test_streaming_estimator_through_spark_backend(tmp_path):
     driver = STREAMING_ESTIMATOR_DRIVER.format(
         store_path=str(tmp_path / "pq_store"))
-    result = _run_driver(driver, timeout=900)
+    result = _run_driver(tmp_path, driver)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert "SPARK_STREAMING_ESTIMATOR_OK" in result.stdout
 
 
-def test_import_guard_without_pyspark():
+def test_import_guard_without_pyspark(tmp_path):
     """Without pyspark on the path the attachment raises the documented
     ImportError while the Spark-free estimators stay importable."""
     script = (
@@ -222,12 +226,11 @@ def test_import_guard_without_pyspark():
         "    assert 'PySpark' in str(exc), exc\n"
         "assert spark.KerasEstimator is not None\n"
         "print('GUARD_OK')\n")
-    path = "/tmp/hvd_spark_guard.py"
-    with open(path, "w") as f:
-        f.write(script)
+    path = tmp_path / "hvd_spark_guard.py"
+    path.write_text(script)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO   # note: no shim
-    result = subprocess.run([sys.executable, path], env=env,
+    result = subprocess.run([sys.executable, str(path)], env=env,
                             capture_output=True, text=True, timeout=120)
     assert result.returncode == 0, result.stdout + result.stderr
     assert "GUARD_OK" in result.stdout
@@ -285,13 +288,13 @@ print("SPARK_SCHEDULER_OK", flush=True)
 """
 
 
-def test_shim_scheduler_semantics():
+def test_shim_scheduler_semantics(tmp_path):
     """VERDICT r3 item 6: the shim reproduces Spark's scheduler-level
     behaviors — whole-stage barrier retry, per-task reschedule on
     executor loss, task.maxFailures abort, and a working
     BarrierTaskContext.barrier() (reference analog:
     ``test/test_spark.py`` barrier/task-retry coverage)."""
-    result = _run_driver(SCHEDULER_DRIVER,
+    result = _run_driver(tmp_path, SCHEDULER_DRIVER,
                          extra_env={"SPARK_SHIM_MAX_FAILURES": "2"})
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
@@ -311,7 +314,7 @@ def train(x):
     return float(out[0])
 
 
-# one slot frees only after 30s (SPARK_SHIM_HOLD_TASK below): the gang
+# one slot frees only after 120s (SPARK_SHIM_HOLD_TASK below): the gang
 # can never fully start inside start_timeout -> the documented error
 try:
     spark.run(train, args=(0,), num_proc=2, start_timeout=4,
@@ -320,18 +323,42 @@ try:
 except RuntimeError as exc:
     assert "start_timeout" in str(exc), exc
     assert "task slots" in str(exc), exc
-print("SPARK_START_TIMEOUT_OK", flush=True)
+import time
+print("SPARK_START_TIMEOUT_OK at", time.time(), flush=True)
 """
 
 
-def test_spark_start_timeout_gang_failure():
+def test_spark_start_timeout_gang_failure(tmp_path):
     """start_timeout fires when the cluster cannot schedule the full
     gang in time (reference: ``spark/runner.py`` start_timeout plumbed
-    to the driver-service wait)."""
-    result = _run_driver(START_TIMEOUT_DRIVER,
-                         extra_env={"SPARK_SHIM_HOLD_TASK": "1",
-                                    "SPARK_SHIM_HOLD_SECS": "30",
-                                    "SPARK_SHIM_STAGE_ATTEMPTS": "1"})
-    assert result.returncode == 0, \
-        f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
-    assert "SPARK_START_TIMEOUT_OK" in result.stdout
+    to the driver-service wait), and the task that did start is
+    cancelled with the job: it holds the driver's stdout, so the pipe
+    ends only when the task, waiting in its allreduce for a rank that
+    never comes, is gone too."""
+    path = tmp_path / "hvd_spark_driver.py"
+    path.write_text(START_TIMEOUT_DRIVER)
+    proc = subprocess.Popen(
+        [sys.executable, str(path)], stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, text=True, start_new_session=True,
+        env=shim_env({"SPARK_SHIM_HOLD_TASK": "1",
+                      "SPARK_SHIM_HOLD_SECS": "120",
+                      "SPARK_SHIM_STAGE_ATTEMPTS": "1"}))
+    ended = None
+    try:
+        out, err = proc.communicate(timeout=60)
+        ended = time.time()
+    except subprocess.TimeoutExpired:
+        pass
+    finally:
+        # the driver, if it is still there, and whatever task outlived it
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+    if ended is None:
+        out, err = proc.communicate()
+    assert ended is not None and proc.returncode == 0, \
+        f"stdout:\n{out}\nstderr:\n{err}"
+    said = re.search(r"SPARK_START_TIMEOUT_OK at ([\d.]+)", out)
+    assert said, f"stdout:\n{out}\nstderr:\n{err}"
+    assert ended - float(said.group(1)) < 10, (ended, said.group(1))

@@ -4,6 +4,7 @@ expect a warning, then shutdown when HVD_STALL_SHUTDOWN is exceeded)."""
 import os
 import subprocess
 import sys
+import tempfile
 
 WARN_SCRIPT = r"""
 import time
@@ -91,7 +92,7 @@ def _run(script, extra_env):
     })
     env.update(extra_env)
     return subprocess.run([sys.executable, "-c", script], env=env,
-                          capture_output=True, text=True, timeout=300,
+                          capture_output=True, text=True, timeout=180,
                           cwd=os.path.dirname(os.path.dirname(__file__)))
 
 
@@ -179,7 +180,8 @@ def test_stall_shutdown_gmesh_controller():
     import subprocess
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = "/tmp/hvd_gmesh_stall_worker.py"
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_gmesh_stall_worker.py")
     with open(path, "w") as f:
         f.write(r"""
 import os, time
@@ -230,7 +232,7 @@ else:
     result = subprocess.run(
         [sys.executable, os.path.join(repo, "bin", "hvdrun"), "-np", "2",
          "--global-mesh", sys.executable, path],
-        env=env, capture_output=True, text=True, timeout=240)
+        env=env, capture_output=True, text=True, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert "pid 0 ABORT-OK" in result.stdout, result.stdout

@@ -14,6 +14,7 @@ acceptance criterion names."""
 import os
 import subprocess
 import sys
+import tempfile
 import threading
 
 import numpy as np
@@ -26,8 +27,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HVDRUN = os.path.join(REPO, "bin", "hvdrun")
 
 
-def _run_hvdrun(np_, script, extra_env=None, timeout=600):
-    path = "/tmp/hvd_tcp_matrix_worker.py"
+def _run_hvdrun(np_, script, extra_env=None, timeout=180):
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_tcp_matrix_worker.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)
@@ -179,7 +181,7 @@ def test_tcp_stall_shutdown_with_fusion_and_join_4proc():
     result = _run_hvdrun(4, STALL_WORKER, extra_env={
         "HVD_STALL_CHECK_TIME_SECONDS": "1",
         "HVD_STALL_SHUTDOWN_TIME_SECONDS": "4",
-    }, timeout=420)
+    }, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("STALL_ABORT_OK") == 4
@@ -262,7 +264,7 @@ def test_tcp_joined_rank_does_not_satisfy_live_rank():
     """Regression: the coordinator counted a since-joined rank's request
     toward completion, finishing a collective without a live rank's
     contribution (silent wrong sum)."""
-    result = _run_hvdrun(3, JOINED_RANK_WORKER, timeout=300)
+    result = _run_hvdrun(3, JOINED_RANK_WORKER, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr}"
     assert result.stdout.count("JOINED_COUNT_OK") == 3
@@ -340,7 +342,7 @@ def test_tcp_error_sweep_and_torch_binding_4proc():
     """Cross-rank mismatch sweep per op over the tcp coordinator, error
     recovery, and the torch binding (incl. the grouped one-handle
     contract) riding the same process-mode plane."""
-    result = _run_hvdrun(4, ERROR_SWEEP_WORKER, timeout=420)
+    result = _run_hvdrun(4, ERROR_SWEEP_WORKER, timeout=180)
     assert result.returncode == 0, \
         f"stdout:\n{result.stdout}\nstderr:\n{result.stderr[-3000:]}"
     assert result.stdout.count("TCP_ERRORS_OK") == 4

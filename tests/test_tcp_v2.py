@@ -12,13 +12,15 @@ import json
 import os
 import subprocess
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HVDRUN = os.path.join(REPO, "bin", "hvdrun")
 
 
-def _run_hvdrun(np_, script, extra_env=None, timeout=600):
-    path = "/tmp/hvd_tcp_v2_worker.py"
+def _run_hvdrun(np_, script, extra_env=None, timeout=180):
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_tcp_v2_worker.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)

@@ -8,6 +8,7 @@ importable."""
 import os
 import subprocess
 import sys
+import tempfile
 
 import pytest
 
@@ -17,8 +18,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HVDRUN = os.path.join(REPO, "bin", "hvdrun")
 
 
-def _run_hvdrun(np_, script, timeout=600):
-    path = "/tmp/hvd_tf_worker.py"
+def _run_hvdrun(np_, script, timeout=180):
+    path = os.path.join(tempfile.mkdtemp(prefix="hvd_test_"),
+                        "hvd_tf_worker.py")
     with open(path, "w") as f:
         f.write(script)
     env = dict(os.environ)
@@ -478,7 +480,7 @@ probe = (
     "    ok = 'pyfunc' in str(exc).lower() or 'callback' in str(exc).lower()\n"
     "    print('CLEAN-FAIL' if ok else f'WRONG-ERROR {exc!r}')\n")
 p = subprocess.run([sys.executable, "-c", probe], capture_output=True,
-                   text=True, timeout=240)
+                   text=True, timeout=180)
 assert "CLEAN-FAIL" in p.stdout, (p.stdout, p.stderr[-500:])
 
 # and the export round must not break subsequent collectives

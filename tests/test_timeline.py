@@ -37,7 +37,7 @@ def test_timeline_events(tmp_path):
         "HVD_TIMELINE_MARK_CYCLES": "1",
     })
     result = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                            capture_output=True, text=True, timeout=300,
+                            capture_output=True, text=True, timeout=180,
                             cwd=os.path.dirname(os.path.dirname(__file__)))
     assert result.returncode == 0, result.stderr
 
@@ -68,7 +68,7 @@ def test_timeline_well_formed_and_rank_ticks(tmp_path):
         "HVD_TIMELINE": str(timeline_file),
     })
     result = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                            capture_output=True, text=True, timeout=300,
+                            capture_output=True, text=True, timeout=180,
                             cwd=os.path.dirname(os.path.dirname(__file__)))
     assert result.returncode == 0, result.stderr
     events = json.loads(timeline_file.read_text())
@@ -105,7 +105,7 @@ def test_timeline_disabled_without_env(tmp_path):
         "PYTHONPATH": repo + os.pathsep + env.get("PYTHONPATH", ""),
     })
     result = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
-                            capture_output=True, text=True, timeout=300,
+                            capture_output=True, text=True, timeout=180,
                             cwd=str(tmp_path))
     assert result.returncode == 0, result.stderr
     assert list(tmp_path.iterdir()) == [], list(tmp_path.iterdir())

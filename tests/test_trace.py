@@ -86,7 +86,7 @@ def report(request, tmp_path_factory):
         [sys.executable, "-c", DRIVER.format(repo=REPO), request.param,
          str(out_dir)],
         env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
-        text=True, timeout=300)
+        text=True, timeout=180)
     assert done.returncode == 0, done.stderr[-2000:]
     out = json.loads(done.stdout.strip().splitlines()[-1])
     out["asked_for"] = request.param
