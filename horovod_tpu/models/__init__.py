@@ -11,10 +11,13 @@ from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     BlockSpec,
+    DifferentialAttention,
     GroupedAttention,
     LatentAttention,
+    MemoryUnit,
     NextTokenModule,
     Rotary,
+    SelectiveScan,
     ShortConv,
     TopkExperts,
     Transformer,

@@ -36,7 +36,16 @@ of query heads over grouped key-value heads, a sliding window or none,
 its own rotary recipe (:class:`Rotary`), a norm over each head of q
 and k and a gate a head on the attention's output.  A block's mixer need
 not be attention: ``attention=ShortConv(...)`` is a doubly gated causal
-convolution of a few taps along the sequence (:class:`ShortConvMixer`).
+convolution of a few taps along the sequence (:class:`ShortConvMixer`),
+``attention=SelectiveScan(...)`` a mixer with a state carried along the
+sequence (:class:`SelectiveScanMixer`, Mamba's), ``attention=
+MemoryUnit(...)`` a gate on what an earlier block made.  Attention may
+be the difference of two softmaxes over paired heads
+(:class:`DifferentialAttention`), with its own keys and values or an
+earlier block's.  What one block hands a later one (``memory``,
+``keys``) travels beside ``x`` through the stack as one small pytree.
+``positions="none"`` is a model with no position encoding at all, and
+``TransformerConfig.tie_head`` one whose head is its embedding.
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
@@ -49,6 +58,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax.core import FrozenDict
 from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.parallel.ring_attention import reference_attention
@@ -56,7 +66,7 @@ from horovod_tpu.parallel.ring_attention import reference_attention
 
 NORMS = ("layer", "rms")
 NORM_PLACEMENTS = ("pre", "sandwich")
-POSITIONS = ("learned", "rope", "rope_pairs")
+POSITIONS = ("learned", "rope", "rope_pairs", "none")
 ATTENTIONS = ("full",)
 FFNS = ("gelu", "swiglu", "moe_switch", "moe_topk")
 
@@ -141,6 +151,71 @@ class ShortConv:
 
 
 @dataclasses.dataclass(frozen=True)
+class SelectiveScan:
+    """A mixer with a state carried along the sequence
+    (:class:`SelectiveScanMixer`; Mamba, arXiv:2312.00752): ``d_inner``
+    channels, each with a state of ``state`` numbers that decays and is
+    fed at a rate chosen a position (through a bottleneck of
+    ``dt_rank``), behind a causal depthwise convolution of ``taps``
+    taps.  ``publishes``: the scan's output, before the mixer's gate, is
+    handed to later blocks as ``memory``."""
+    d_inner: int
+    dt_rank: int
+    state: int = 16
+    taps: int = 4
+    publishes: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class MemoryUnit:
+    """A mixer that gates what an earlier block made
+    (:class:`MemoryUnitMixer`; a gated memory unit, arXiv:2507.06607):
+    ``(silu(x W_1) * m) W_2`` with ``m [..., T, d_inner]`` the
+    ``memory`` a :class:`SelectiveScan` block published.  No state, no
+    kernel, no positions of its own."""
+    d_inner: int
+
+
+KEYS = ("own", "published", "read")
+
+
+@dataclasses.dataclass(frozen=True)
+class DifferentialAttention:
+    """Attention that is the difference of two softmaxes
+    (:func:`differential_attention`; arXiv:2410.05258).  The ``heads``
+    query heads and ``kv_heads`` key heads of ``head_dim`` pair up as
+    neighbours ``(2i, 2i + 1)``; a pair of query heads reads pair ``i //
+    (heads / kv_heads)`` of the keys, one softmax each, and both read
+    that pair's two value heads side by side (values ``2 head_dim``
+    wide); the second softmax is taken off the first times ``lambda``,
+    learned around ``lambda_init``.  ``window``: a query sees that many
+    keys, itself included (``None``: every key before it).  No
+    rotation.  ``keys``: ``"own"`` (a k/v projection of its own),
+    ``"published"`` (its own, also handed to later blocks as ``keys``)
+    or ``"read"`` (no k/v projection: the ``keys`` an earlier block
+    published)."""
+    heads: int
+    kv_heads: int
+    head_dim: int
+    lambda_init: float
+    window: Optional[int] = None
+    keys: str = "own"
+
+    def __post_init__(self):
+        if self.heads % self.kv_heads or self.kv_heads % 2:
+            raise ValueError(
+                f"DifferentialAttention: {self.kv_heads} key-value heads "
+                f"are not pairs that divide {self.heads} query heads")
+        if self.keys not in KEYS:
+            raise ValueError(f"DifferentialAttention: keys {self.keys!r} "
+                             f"is none of {KEYS}")
+
+
+MIXERS = (LatentAttention, GroupedAttention, ShortConv, SelectiveScan,
+          MemoryUnit, DifferentialAttention)
+
+
+@dataclasses.dataclass(frozen=True)
 class TopkExperts:
     """A top-k expert layer (:func:`~horovod_tpu.parallel.moe.topk_moe`)
     told more than ``"moe_topk"`` says.  ``scoring``, ``renormalize``
@@ -167,11 +242,14 @@ class BlockSpec:
     ``"learned"`` (a table added to the embedding), ``"rope"`` (rotary
     in the rotate-half pairing, applied to q and k before the attention
     function; no table) or ``"rope_pairs"`` (rotary over the pairs
-    ``(2i, 2i + 1)``).  ``qk_norm``: a norm of the block's kind over the
+    ``(2i, 2i + 1)``) or ``"none"`` (the model has no position encoding).
+    ``qk_norm``: a norm of the block's kind over the
     whole q and k projections, before the split into heads.  ``attention``:
     the block's mixer: ``"full"`` (one fused q, k, v projection, heads of
-    one width), a :class:`LatentAttention`, a :class:`GroupedAttention`
-    or a :class:`ShortConv`, which is no attention.  ``ffn``:
+    one width), a :class:`LatentAttention`, a :class:`GroupedAttention`,
+    a :class:`DifferentialAttention`, or a :class:`ShortConv`,
+    :class:`SelectiveScan` or :class:`MemoryUnit`, which are no
+    attention.  ``ffn``:
     ``"gelu"`` (dense up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x
     gate) * (x up)`` down), ``"moe_switch"``
     (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
@@ -181,8 +259,7 @@ class BlockSpec:
     positions: str = "learned"
     qk_norm: bool = False
     ffn: Union[str, TopkExperts] = "gelu"
-    attention: Union[str, LatentAttention, GroupedAttention,
-                     ShortConv] = "full"
+    attention: Union[(str,) + MIXERS] = "full"
     norm_placement: str = "pre"
 
     def __post_init__(self):
@@ -190,8 +267,7 @@ class BlockSpec:
                 (self.norm, NORMS, ()), (self.positions, POSITIONS, ()),
                 (self.norm_placement, NORM_PLACEMENTS, ()),
                 (self.ffn, FFNS, TopkExperts),
-                (self.attention, ATTENTIONS,
-                 (LatentAttention, GroupedAttention, ShortConv))):
+                (self.attention, ATTENTIONS, MIXERS)):
             if value not in known and not isinstance(value, cls):
                 raise ValueError(f"BlockSpec: {value!r} is none of {known}")
 
@@ -248,6 +324,9 @@ class TransformerConfig:
     # passes; the model then returns the logits of EVERY exit, for
     # :func:`looped_lm_loss`
     exit_gate: bool = False
+    # the head is the embedding: logits = norm_f(x) E^T with E read in
+    # the activation dtype, one parameter with the gradient of both uses
+    tie_head: bool = False
 
     def __post_init__(self):
         once = {(spec.norm, spec.positions == "learned")
@@ -298,6 +377,7 @@ KEPT_SUM = "block_after_attention"   # x + attention(x): ln2's input
 KEPT_Q_A = "latent_q_a"              # latent attention's x W_qa
 KEPT_KV_A = "latent_kv_a"            # latent attention's x W_kva
 KEPT_NAMES = (KEPT_SUM, KEPT_Q_A, KEPT_KV_A)
+NO_ATTENTION = (ShortConv, SelectiveScan, MemoryUnit)
 
 
 def kept_names(cfg):
@@ -314,19 +394,26 @@ def kept_names(cfg):
     how the program is built, which the code reads off its own
     configuration; it is no model's name.
 
-    Where no block of ``cfg`` has a kernel (every mixer a
-    :class:`ShortConv`; ``cfg.at(layer)`` is such a configuration for a
-    conv layer of a mixed pattern) there is nothing of the flash kernel
-    to name: the sum after the mixer alone.  One policy serves a mixed
-    pattern: a name no block sets keeps nothing."""
+    Where no block of ``cfg`` is attention (every mixer a
+    :class:`ShortConv`, :class:`SelectiveScan` or :class:`MemoryUnit`;
+    ``cfg.at(layer)`` is such a configuration for such a layer of a
+    mixed pattern) there is nothing of the flash kernel to name: the sum
+    after the mixer and, of a :class:`SelectiveScan`, what its scan
+    hands its backward pass (``ops/selective_scan.py``'s
+    ``SAVED_NAMES``: its output and the state every chunk is entered
+    with).  One policy serves a mixed pattern: a name no block sets
+    keeps nothing."""
     from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                         SAVED_NAMES)
     if cfg.passes > 1:
         return SAVED_NAMES
-    if all(isinstance(spec.attention, ShortConv)
-           for spec in cfg.pattern or (cfg.block,)):
-        return (KEPT_SUM,)
-    return SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
+    mixers = [spec.attention for spec in cfg.pattern or (cfg.block,)]
+    scanned = ()
+    if any(isinstance(m, SelectiveScan) for m in mixers):
+        from horovod_tpu.ops.selective_scan import SAVED_NAMES as scanned
+    if all(isinstance(m, NO_ATTENTION) for m in mixers):
+        return scanned + (KEPT_SUM,)
+    return SAVED_NAMES + SAVED_INPUT_NAMES + scanned + KEPT_NAMES
 
 
 def recomputed(block, cfg):
@@ -344,29 +431,41 @@ def kept_bytes(cfg, batch, seq, layer=0):
     """``{name: bytes}`` of what ONE application of recomputed block
     ``layer`` keeps by name on ``[batch, seq]`` tokens through the flash
     kernel (its input, which every checkpoint keeps, is ``batch seq
-    d_model x itemsize`` more).  A layer whose mixer is a
-    :class:`ShortConv` has no kernel and no q, k or v: the sum after the
-    mixer is all it names."""
+    d_model x itemsize`` more).  A layer whose mixer is no attention
+    has no kernel and no q, k or v: the sum after the mixer is all it
+    names, and a :class:`SelectiveScan`'s what its scan keeps.  A
+    :class:`DifferentialAttention` calls the kernel twice (half the
+    heads each, the values twice as wide), so every name of the kernel
+    is there twice and counts twice."""
     from horovod_tpu.ops.pallas.flash_attention import saved_bytes
 
     cfg = cfg.at(layer)
     spec = cfg.block.attention
-    heads, groups = cfg.n_heads, cfg.n_heads
+    heads, groups, calls = cfg.n_heads, cfg.n_heads, 1
     d_qk = d_v = cfg.head_dim or cfg.d_model // cfg.n_heads
     if isinstance(spec, GroupedAttention):
         heads, groups, d_qk, d_v = (spec.heads, spec.kv_heads,
                                     spec.head_dim, spec.head_dim)
+    if isinstance(spec, DifferentialAttention):
+        heads, groups, calls = spec.heads // 2, spec.kv_heads // 2, 2
+        d_qk, d_v = spec.head_dim, 2 * spec.head_dim
     itemsize = jnp.dtype(cfg.dtype).itemsize
     kept = {KEPT_SUM: batch * seq * cfg.d_model * itemsize}
+    if isinstance(spec, SelectiveScan):
+        from horovod_tpu.ops import selective_scan
+
+        kept.update(selective_scan.saved_bytes(
+            batch, seq, spec.d_inner, spec.state, cfg.dtype))
     if isinstance(spec, LatentAttention):
         d_qk, d_v = spec.nope_dim + spec.rope_dim, spec.v_dim
         kept[KEPT_Q_A] = batch * seq * spec.q_rank * itemsize
         kept[KEPT_KV_A] = batch * seq * (spec.kv_rank
                                          + spec.rope_dim) * itemsize
-    if not isinstance(spec, ShortConv):
+    if not isinstance(spec, NO_ATTENTION):
         q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
                    for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
-        kept.update(saved_bytes(q, k, v))
+        kept.update({name: calls * n
+                     for name, n in saved_bytes(q, k, v).items()})
     return {name: kept[name] for name in kept_names(cfg) if name in kept}
 
 
@@ -513,7 +612,7 @@ def full_qkv(cfg, x):
             q, k = (make_norm(cfg, name)(
                 u.reshape(u.shape[:-2] + (h * d,))).reshape(u.shape)
                 for u, name in ((q, "q_norm"), (k, "k_norm")))
-    if cfg.block.positions != "learned":
+    if cfg.block.positions in ("rope", "rope_pairs"):
         with jax.named_scope("attn/rope"):
             q, k = (rope(u, cfg.rope_theta,
                          pairs=cfg.block.positions == "rope_pairs")
@@ -631,6 +730,21 @@ def _taps_init(key, shape, dtype=jnp.float32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
+def causal_taps(g, w, dtype):
+    """``s[t] = sum_j w[j] * g[t - (taps - 1) + j]`` in float32 for ``g
+    [..., T, d]`` and ``w [taps, d]`` (a depthwise causal convolution
+    along ``T``: the LAST tap weighs the position itself, rows before 0
+    are zero): that many shifted multiply-adds over ``g`` padded ahead
+    with zero rows, the operands in ``dtype`` as a Dense's kernel, the
+    products summed in float32; JAX differentiates them."""
+    taps, t = w.shape[0], g.shape[-2]
+    ahead = [(0, 0)] * (g.ndim - 2) + [(taps - 1, 0), (0, 0)]
+    g = jnp.pad(g, ahead)
+    w = w.astype(dtype).astype(jnp.float32)
+    return sum(g[..., j:j + t, :].astype(jnp.float32) * w[j]
+               for j in range(taps))
+
+
 class ShortConvMixer(nn.Module):
     """The mixer of a :class:`ShortConv` block on ``x [..., T, d]``: no
     attention, no bias, no non-linearity but two gates,
@@ -641,10 +755,8 @@ class ShortConvMixer(nn.Module):
 
     with ``W_in [d, 3 d]``, ``w [taps, d]`` (a depthwise causal
     convolution along ``T``: the LAST tap weighs the position itself, the
-    first the one ``taps - 1`` before it) and ``W_out [d, d]``.  The taps
-    are that many shifted multiply-adds over ``g`` padded ahead with
-    zero rows, in the activation dtype with the products summed in
-    float32; JAX differentiates them.  All of it runs under the scope
+    first the one ``taps - 1`` before it; :func:`causal_taps`) and
+    ``W_out [d, d]``.  All of it runs under the scope
     ``mixer/conv``: the two products under ``in`` and ``out``, the
     gates and the taps (elementwise, bound by HBM) under ``gate_conv``."""
     cfg: TransformerConfig
@@ -652,7 +764,7 @@ class ShortConvMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg, taps = self.cfg, self.cfg.block.attention.taps
-        d, t = cfg.d_model, x.shape[-2]
+        d = cfg.d_model
 
         def dense(features, name):
             return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
@@ -663,14 +775,164 @@ class ShortConvMixer(nn.Module):
             w = self.param("kernel", _taps_init, (taps, d), jnp.float32)
             with jax.named_scope("gate_conv"):
                 b, c, h = (bch[..., i * d:(i + 1) * d] for i in range(3))
-                ahead = [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)]
-                g = jnp.pad(b * h, ahead)
-                # operands in the activation dtype, as a Dense's kernel
-                w = w.astype(cfg.dtype).astype(jnp.float32)
-                s = sum(g[..., j:j + t, :].astype(jnp.float32) * w[j]
-                        for j in range(taps))
-                y = c * s.astype(cfg.dtype)
+                y = c * causal_taps(b * h, w, cfg.dtype).astype(cfg.dtype)
             return dense(d, "out")(y)
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Step sizes log-uniform in [0.001, 0.1] through the inverse of
+    softplus (Mamba's start, arXiv:2312.00752 section 3.6)."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                 * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -(1, 2, ..., N)`` for every channel (S4D-real)."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
+
+
+class SelectiveScanMixer(nn.Module):
+    """The mixer of a :class:`SelectiveScan` block on ``x [B, T, d]``
+    (Mamba, arXiv:2312.00752); returns ``(out, y)``:
+
+        (a, z) = split2(x W_in);  c = silu(conv(a) + b_c)
+        (r, B, C) = split(c W_x);  delta = softplus(r W_dt + b_dt)
+        h[t] = exp(delta[t] A) * h[t-1] + (delta[t] * c[t]) B[t]
+        y[t] = h[t] C[t] + D * c[t];   out = (y * silu(z)) W_out
+
+    with ``W_in [d, 2 d_inner]``, ``conv`` the causal depthwise
+    convolution of :func:`causal_taps`, ``W_x [d_inner, dt_rank + 2
+    N]``, ``W_dt [dt_rank, d_inner]``, ``A = -exp(A_log) [d_inner, N]``
+    and ``W_out [d_inner, d]``.  ``delta``, ``A`` and the state are
+    float32 (``ops/selective_scan.py``).  ``y``, the scan's output before
+    the gate, is what a block that ``publishes`` hands on as ``memory``.
+    All of it runs under the scope ``mixer/ssm``: ``in``, ``conv``,
+    ``proj`` (``W_x``, ``W_dt``, softplus), ``scan`` and ``gate_out``
+    (the gate and ``W_out``) inside it."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.ops.selective_scan import selective_scan
+
+        cfg, spec = self.cfg, self.cfg.block.attention
+        inner, n, rank = spec.d_inner, spec.state, spec.dt_rank
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        def param(name, init, *shape):
+            return self.param(name, init, shape, jnp.float32)
+
+        with jax.named_scope("mixer/ssm"):
+            az = dense(2 * inner, "in")(x)
+            with jax.named_scope("conv"):
+                w = param("conv_kernel", _taps_init, spec.taps, inner)
+                bias = param("conv_bias", nn.initializers.zeros, inner)
+                c = nn.silu(causal_taps(az[..., :inner], w, cfg.dtype)
+                            + bias).astype(cfg.dtype)
+            with jax.named_scope("proj"):
+                rbc = dense(rank + 2 * n, "x")(c)
+                w_dt = param("dt_kernel", nn.initializers.variance_scaling(
+                    1 / 3, "fan_in", "uniform"), rank, inner)
+                # operands in the activation dtype, the sum and what
+                # follows in float32
+                delta = jax.nn.softplus(jax.lax.dot_general(
+                    rbc[..., :rank], w_dt.astype(cfg.dtype),
+                    (((x.ndim - 1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    + param("dt_bias", _dt_bias_init, inner))
+            with jax.named_scope("scan"):
+                y = selective_scan(
+                    c, delta, -jnp.exp(param("A_log", _a_log_init, inner, n)),
+                    rbc[..., rank:rank + n], rbc[..., rank + n:],
+                    param("D", nn.initializers.ones, inner))
+            with jax.named_scope("gate_out"):
+                return dense(cfg.d_model, "out")(
+                    y * nn.silu(az[..., inner:])), y
+
+
+class MemoryUnitMixer(nn.Module):
+    """The mixer of a :class:`MemoryUnit` block on ``x [B, T, d]`` and
+    the ``memory [B, T, d_inner]`` an earlier block published: ``(silu(x
+    W_1) * memory) W_2``, no biases, under the scope ``mixer/gmu``."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, memory):
+        cfg, inner = self.cfg, self.cfg.block.attention.d_inner
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        with jax.named_scope("mixer/gmu"):
+            return dense(cfg.d_model, "out")(
+                nn.silu(dense(inner, "in")(x)) * memory.astype(cfg.dtype))
+
+
+class DifferentialAttentionMixer(nn.Module):
+    """Attention of a :class:`DifferentialAttention` on ``x [B, T, d]``;
+    returns ``(out, (k, v))``.  With q ``[B, T, H / 2, 2, D]`` (a
+    projection with a bias), k and v ``[B, T, G / 2, 2, D]`` (a
+    projection with a bias of this block, or the ``keys`` an earlier
+    block published) and ``vv`` the two value heads of a pair side by
+    side, ``[B, T, G / 2, 2 D]``:
+
+        a_s = attention(q[..., s, :], k[..., s, :], vv)        s = 0, 1
+        lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init
+        out = (rms(a_0 - lambda a_1) * w * (1 - lambda_init)) W_o + b_o
+
+    each attention causal, under the kind's window, through the
+    attention function at ``D`` / ``2 D`` with grouped key-value heads;
+    ``lq``, ``lk`` four learned vectors of ``D``, the norm over the ``2
+    D`` columns of a pair.  Under the scope ``attn/window``,
+    ``attn/global`` or, where the keys are read, ``attn/cross``: the two
+    calls under ``flash`` inside it, the subtraction, the norm and the
+    scale under ``diff``."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, keys=None):
+        cfg, spec = self.cfg, self.cfg.block.attention
+        pairs, kv_pairs, dim = spec.heads // 2, spec.kv_heads // 2, \
+            spec.head_dim
+        kind = ("attn/cross" if spec.keys == "read" else
+                "attn/global" if spec.window is None else "attn/window")
+
+        def dense(features, name):
+            return nn.DenseGeneral(features, dtype=cfg.dtype, name=name)
+
+        with jax.named_scope(kind):
+            q = dense((pairs, 2, dim), "q")(x)
+            if spec.keys == "read":
+                k, v = keys
+            else:
+                kv = dense((2, kv_pairs, 2, dim), "kv")(x)
+                k, v = kv[..., 0, :, :, :], kv[..., 1, :, :, :]
+            vv = v.reshape(v.shape[:-2] + (2 * dim,))
+            attn = cfg.attn_fn or default_attention()
+            with jax.named_scope("flash"):
+                window = {} if spec.window is None else {
+                    "window": spec.window}
+                first, second = (attn(q[..., s, :], k[..., s, :], vv,
+                                      causal=True, **window) for s in (0, 1))
+            with jax.named_scope("diff"):
+                lq1, lk1, lq2, lk2 = (self.param(
+                    name, nn.initializers.normal(0.1), (dim,), jnp.float32)
+                    for name in ("lambda_q1", "lambda_k1", "lambda_q2",
+                                 "lambda_k2"))
+                lam = (jnp.exp(jnp.sum(lq1 * lk1))
+                       - jnp.exp(jnp.sum(lq2 * lk2)) + spec.lambda_init)
+                o = RMSNorm(eps=cfg.norm_eps, name="subln")(
+                    first.astype(jnp.float32)
+                    - lam * second.astype(jnp.float32))
+                o = (o * (1 - spec.lambda_init)).astype(cfg.dtype)
+            return nn.Dense(cfg.d_model, dtype=cfg.dtype, name="out")(
+                o.reshape(o.shape[:-2] + (-1,))), (k, v)
 
 
 class Mlp(nn.Module):
@@ -798,14 +1060,31 @@ class Block(nn.Module):
     ffn: Union[None, str, TopkExperts] = None
 
     @nn.compact
-    def __call__(self, x, router_bias=None):
+    def __call__(self, x, router_bias=None, shared=FrozenDict()):
         """``router_bias [E]``: the balancing bias of this block's
-        router, for a :class:`TopkExperts`."""
-        cfg = self.cfg
+        router, for a :class:`TopkExperts`.  ``shared``: what earlier
+        blocks published for later ones (``{"memory": ..., "keys": (k,
+        v)}``, empty before the first publisher).  Returns ``(x,
+        shared)`` with what this block's own spec publishes put in, so
+        under :func:`recomputed` it is an input of the block that reads
+        it and a result of the block that made it; empty, it is no
+        operand and no result of the compiled block."""
+        cfg, mixer = self.cfg, self.cfg.block.attention
         sandwich = cfg.block.norm_placement == "sandwich"
         y = make_norm(cfg, "ln1")(x).astype(cfg.dtype)
-        if isinstance(cfg.block.attention, ShortConv):
+        if isinstance(mixer, ShortConv):
             y = ShortConvMixer(cfg, name="mixer")(y)
+        elif isinstance(mixer, SelectiveScan):
+            y, memory = SelectiveScanMixer(cfg, name="mixer")(y)
+            if mixer.publishes:
+                shared = {**shared, "memory": memory}
+        elif isinstance(mixer, MemoryUnit):
+            y = MemoryUnitMixer(cfg, name="mixer")(y, shared["memory"])
+        elif isinstance(mixer, DifferentialAttention):
+            y, keys = DifferentialAttentionMixer(cfg, name="attn")(
+                y, shared.get("keys"))
+            if mixer.keys == "published":
+                shared = {**shared, "keys": keys}
         else:
             y = Attention(cfg, name="attn")(y)
         if sandwich:
@@ -820,7 +1099,7 @@ class Block(nn.Module):
             y = module(cfg, name=name)(y)
         if sandwich:
             y = make_norm(cfg, "ln2_post")(y)
-        return x + y
+        return x + y, shared
 
 
 def lm_loss(logits, tokens):
@@ -964,14 +1243,22 @@ class Transformer(nn.Module):
     and N block bodies are compiled, not R N.  With ``cfg.exit_gate``
     the logits are those of EVERY exit through the one head, ``[R, B,
     T, vocab]``, and the gate's logits ``[R, B, T]`` (float32) are sown
-    as ``exit_gate_logits`` for :func:`apply_with_aux`."""
+    as ``exit_gate_logits`` for :func:`apply_with_aux`.
+
+    One pytree travels through the stack beside ``x``: every block is
+    handed what the blocks before it published and returns it with its
+    own (empty in a model none of whose blocks publishes).  With
+    ``cfg.tie_head`` the logits are ``norm_f(x) E^T`` with ``E`` the
+    embedding in the activation dtype (under the scope ``lm_head``), and
+    the model has no ``lm_head`` of its own."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, router_bias=None, return_hidden=False):
         cfg = self.cfg
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                     name="embed")(tokens)
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+                         name="embed")
+        x = embed(tokens)
         if cfg.block.positions == "learned":
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
@@ -983,12 +1270,14 @@ class Transformer(nn.Module):
             ``(exit, hidden before the norm)``."""
             x, _ = carry
             rows = 0  # blocks so far that read a row of router_bias
+            shared = {}
             for i in range(cfg.n_layers):
                 ffn = cfg.ffn_of(i)
                 bias = None
                 if router_bias is not None and isinstance(ffn, TopkExperts):
                     bias, rows = router_bias[rows], rows + 1
-                x = block_cls(cfg.at(i), ffn=ffn, name=f"block_{i}")(x, bias)
+                block = block_cls(cfg.at(i), ffn=ffn, name=f"block_{i}")
+                x, shared = block(x, bias, shared)
             out = make_norm(cfg, "ln_f")(x)
             return (out, x), (out if cfg.exit_gate else None)
 
@@ -1006,8 +1295,13 @@ class Transformer(nn.Module):
                     one_pass, variable_broadcast="params",
                     split_rngs={"params": False},
                     length=cfg.passes)(self, (x, x), None)
-        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                        name="lm_head")
+        if cfg.tie_head:
+            def head(h):
+                with jax.named_scope("lm_head"):
+                    return embed.attend(h)
+        else:
+            head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                            name="lm_head")
         if cfg.exit_gate:
             with jax.named_scope("exit_gate"):
                 self.sow("intermediates", "exit_gate_logits", nn.Dense(
@@ -1042,6 +1336,6 @@ class NextTokenModule(nn.Module):
         x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                      name="eh_proj")(x)
         block_cls = recomputed(Block, cfg) if cfg.remat else Block
-        x = block_cls(cfg, name="block")(x, router_bias)
+        x, _ = block_cls(cfg, name="block")(x, router_bias)
         x = make_norm(cfg, "ln_f")(x).astype(cfg.dtype)
         return jnp.dot(x, head.astype(cfg.dtype))

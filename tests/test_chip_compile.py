@@ -177,12 +177,16 @@ def test_flash_backward_is_one_kernel_within_the_v5e_vmem(v5e, bh, t, d_qk,
     (128, 128, 4096, 192, 128, None),   # joyai_llm_flash: 4 x 32 heads
     (48, 8, 8192, 128, 128, None),      # laguna_s_2_1, a full layer
     (72, 8, 8192, 128, 128, 512),       # laguna_s_2_1, a sliding layer
-    (64, 16, 8192, 64, 64, None)],      # lfm2_24b_a2b: 2 x 32 heads over 8
+    (64, 16, 8192, 64, 64, None),       # lfm2_24b_a2b: 2 x 32 heads over 8
+    # phi4_mini_flash: one of a differential layer's two calls, 2 x 20
+    # query heads over 10 key-value heads, values twice as wide as keys
+    (40, 20, 8192, 64, 128, None),
+    (40, 20, 8192, 64, 128, 512)],
     ids=["gpt2_medium", "ouro_2_6b", "joyai_llm_flash", "laguna-full",
-         "laguna-window", "lfm2_24b_a2b"])
+         "laguna-window", "lfm2_24b_a2b", "phi4-full", "phi4-window"])
 def test_flash_forward_within_the_default_vmem_scope(v5e, heads, groups, t,
                                                      d_qk, d_v, window):
-    """The forward pass at the six kernel shapes the cells run: ONE
+    """The forward pass at the eight kernel shapes the cells run: ONE
     custom call that takes q as ``[B H, T, d_qk]`` and k, v as ``[B G,
     T, d]`` (what the benchmark's readers find the flash kernels by) and
     gives ``out`` and the packed ``lse``.  It holds k and v of a whole
@@ -588,15 +592,21 @@ def test_looped_cell_step_compiles_for_v5e_as_one_set_of_block_bodies(
     assert _fits_one_chip(compiled)
 
 
-@pytest.mark.parametrize("heads,groups,d,window", [
-    (72, 8, 128, 512), (48, 8, 128, None), (64, 16, 64, None)],
-    ids=["sliding-72over8", "full-48over8", "full-64over16-heads-of-64"])
+@pytest.mark.parametrize("heads,groups,d,d_v,window", [
+    (72, 8, 128, 128, 512), (48, 8, 128, 128, None), (64, 16, 64, 64, None),
+    (40, 20, 64, 128, None), (40, 20, 64, 128, 512)],
+    ids=["sliding-72over8", "full-48over8", "full-64over16-heads-of-64",
+         "full-40over20-64-and-128", "sliding-40over20-64-and-128"])
 def test_flash_backward_with_grouped_heads_within_the_v5e_vmem(
-        v5e, heads, groups, d, window):
+        v5e, heads, groups, d, d_v, window):
     """The backward pass at ``laguna_s_2_1-spmd-1chip``'s two kernel
     shapes (T 8192, heads of 128, 72 or 48 query heads over 8 key-value
     heads, a window of 512 or none) and at ``lfm2_24b_a2b-spmd-1chip``'s
-    (two sequences' 32 query heads of 64 over their 8 key-value heads):
+    (two sequences' 32 query heads of 64 over their 8 key-value heads)
+    and at ``phi4_mini_flash-spmd-1chip``'s (two sequences' 20 query heads
+    of 64 over their 10 key-value heads with values of 128, the first
+    shape whose values are WIDER than its keys, with a window of 512 and
+    without):
     ONE custom call that takes q as ``[H, T, d]`` and k and v as ``[G,
     T, d]``, never repeated to q's heads, and gives dq with q's heads
     and dk, dv with G, each the sum over its group formed in float32 in
@@ -611,20 +621,23 @@ def test_flash_backward_with_grouped_heads_within_the_v5e_vmem(
         return _bwd((q, k, v, out, lse), g, scale=d ** -0.5, causal=True,
                     block_q=512, block_k=512, interpret=False, window=window)
 
-    q, kv = (_on(v5e[0], (h, t, d), jnp.bfloat16) for h in (heads, groups))
-    text = _compile(bwd, q, kv, kv, q,
-                    _on(v5e[0], (heads, t), jnp.float32), q).as_text()
+    q, k, v, out = (_on(v5e[0], (h, t, w), jnp.bfloat16) for h, w in (
+        (heads, d), (groups, d), (groups, d_v), (heads, d_v)))
+    text = _compile(bwd, q, k, v, out,
+                    _on(v5e[0], (heads, t), jnp.float32), out).as_text()
     calls = [line for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert len(calls) == 1
     wide, narrow = f"bf16[{heads},{t},{d}]", f"bf16[{groups},{t},{d}]"
+    values, outs = f"bf16[{groups},{t},{d_v}]", f"bf16[{heads},{t},{d_v}]"
     result, operands = calls[0].split(" custom-call(", 1)
-    assert re.findall(r"\w+\[[\d,]+\]", result) == [wide, narrow, narrow]
+    assert re.findall(r"\w+\[[\d,]+\]", result) == [wide, narrow, values]
     assert operands.split("operand_layout_constraints={", 1)[1].startswith(
-        f"{wide}{{2,1,0}}, {narrow}{{2,1,0}}, {narrow}{{2,1,0}}, "
-        f"{wide}{{2,1,0}}")
+        f"{wide}{{2,1,0}}, {narrow}{{2,1,0}}, {values}{{2,1,0}}, "
+        f"{outs}{{2,1,0}}")
     stated, used = _scoped_vmem(calls[0])
-    assert stated == _bwd_vmem_bytes(t, d, d, 512, 512, 2, heads // groups)
+    assert stated == _bwd_vmem_bytes(t, d, d_v, 512, 512, 2,
+                                     heads // groups)
     assert used <= stated <= 128 << 20
 
 
@@ -731,6 +744,77 @@ def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes
             ) < 8.5 * 2 ** 30
+
+
+def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
+    """``phi4_mini_flash-spmd-1chip`` at published widths and the cell's
+    two sequences of 8192, every block recomputed: fits one chip under
+    the issue's line of 15.0 GiB; the three attention layers' two calls
+    each, forward and ONE backward kernel a call, q ``[40, 8192, 64]``
+    over k ``[20, 8192, 64]`` and values ``[20, 8192, 128]`` (two
+    sequences' 20 pairs of query heads over their 10 pairs of key-value
+    heads, never repeated to q's count), none in the recomputation; the
+    cross layer (block 5) has no k/v projection and its backward kernels'
+    dk and dv reach block 3's; the two Mamba layers' scans are loops
+    under their scope with no kernel, no ``[B, T, d_inner, N]`` of states
+    anywhere in the step, and no forward loop in the recomputation (the
+    scan's output and entry states are kept: three loops a layer's
+    backward pass would be four with it); LayerNorm's kernel at 2560;
+    the loss kernels at 25,008 columns, which no tile divides."""
+    compiled = cell_step("phi4_mini_flash-spmd-1chip")
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line
+             and "/flash/" in line]
+    seen = []
+    for line in flash:
+        operands = line.split("operand_layout_constraints={", 1)[1]
+        assert re.findall(r"bf16\[[\d,]+\]\{2,1,0\}", operands)[:3] == [
+            "bf16[40,8192,64]{2,1,0}", "bf16[20,8192,64]{2,1,0}",
+            "bf16[20,8192,128]{2,1,0}"]
+        assert "rematted_computation" not in line
+        kind = re.search(r"/block_(\d)/attn/attn/(\w+)/flash/", line)
+        seen.append((int(kind.group(1)), kind.group(2),
+                     "bwd" if "jit(_bwd)" in line else "fwd"))
+    assert sorted(seen) == sorted(
+        [(block, kind, way) for block, kind in (
+            (1, "window"), (3, "global"), (5, "cross"))
+         for way in ("fwd", "bwd")] * 2)
+    # k and v are never made as wide as q, and the cross layer projects none
+    assert not [line for line in text.splitlines()
+                if " broadcast(" in line and re.search(
+                    r"= bf16\[(1,)?(2,)?(40|20),8192,(64|128)\]", line)
+                and "/flash/" in line]
+    assert "/block_5/attn/attn/cross/q/" in text
+    assert "/block_5/attn/attn/cross/kv/" not in text
+    assert "/attn/cross/diff/" in text and "/attn/window/diff/" in text
+    # the scans: loops under the scope, no kernel, no [B, T, d, N] states
+    scan = [line for line in text.splitlines() if "/mixer/ssm/scan/" in line]
+    assert scan and not [line for line in scan if "tpu_custom_call" in line]
+    for block in (0, 2):
+        for scope in ("in", "conv", "proj", "scan", "gate_out"):
+            assert f"/block_{block}/mixer/mixer/ssm/{scope}/" in text, scope
+        assert not [line for line in _recomputed(
+            text, f"/block_{block}/mixer/mixer/ssm/scan/")
+            if " while(" in line]
+    assert "/block_4/mixer/mixer/gmu/" in text
+    # forward: a loop over the chunks and one over a chunk's positions;
+    # backward: a loop over the chunks and two inside it
+    assert len([line for line in text.splitlines()
+                if " while(" in line]) == 2 * (2 + 3)
+    assert not re.search(r"\[(2,)?8192,(2,)?(5120,16|16,5120)\]", text)
+    assert not re.search(r"\[(2,)?(5120,16|16,5120),(2,)?8192\]", text)
+    kernels = [line for line in text.splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    assert [line for line in kernels if "bf16[16384,2560]" in line
+            and re.search(r"%ln\w*", line)]
+    assert len([line for line in kernels if "[16384,25008]" in line]) == 2
+    # the head is the embedding: no parameter of a head's shape
+    assert "f32[2560,25008]" not in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) < 15.0 * 2 ** 30
 
 
 def _results_in_memory(text, *scopes):

@@ -124,6 +124,28 @@ def test_thirty_two_heads_of_64_over_eight(window):
     assert got_grads[1].shape == (2, T, 8, 64)
 
 
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "w24"])
+def test_twenty_heads_of_64_over_ten_with_values_of_128(window):
+    """Values wider than keys (``d_qk`` 64, ``d_v`` 128; everything
+    before ran equal widths or 192 over 128) with 20 query heads over 10
+    key-value heads: one of the two calls of a differential-attention
+    layer (``phi4_mini_flash-spmd-1chip``), with a window and without.
+    Forward and the three gradients; dk ``[.., 10, 64]`` and dv ``[..,
+    10, 128]`` are the sums over their two query heads."""
+    q, k, v, w = inputs(20, 10, d_qk=64, d_v=128, seed=4)
+    want, want_grads = value_and_grads(
+        lambda q, k, v: dense(q, k, v, window), q, k, v, w)
+    np.testing.assert_allclose(flash(window)(q, k, v),
+                               dense(q, k, v, window), rtol=1e-5, atol=2e-6)
+    got, got_grads = value_and_grads(flash(window), q, k, v, w)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    for g, wg in zip(got_grads, want_grads):
+        assert g.shape == wg.shape
+        np.testing.assert_allclose(g, wg, rtol=1e-4, atol=1e-5)
+    assert got_grads[1].shape == (2, T, 10, 64)
+    assert got_grads[2].shape == (2, T, 10, 128)
+
+
 def test_blocks_that_differ_and_the_lse():
     """block_q != block_k, and the logsumexp of a windowed row."""
     q, k, v, _ = inputs(6, 2, t=96)

@@ -413,7 +413,7 @@ def test_the_module_predicts_the_token_after_next_from_shared_weights():
                              "kernel"]
     from horovod_tpu.models.transformer import Block
 
-    x = Block(module.cfg).apply({"params": m["block"]}, x)
+    x, _ = Block(module.cfg).apply({"params": m["block"]}, x)
     want = norm(x, m["ln_f"]) @ params["lm_head"]["kernel"]
     got = apply_with_aux(model, params, TOKENS,
                          next_token=module)[1]["next_token_logits"]

@@ -104,7 +104,7 @@ class TransformerBefore(nn.Module):
             bias = None
             if router_bias is not None and isinstance(ffn, TopkExperts):
                 bias, rows = router_bias[rows], rows + 1
-            x = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
+            x, _ = block_cls(cfg, ffn=ffn, name=f"block_{i}")(x, bias)
         hidden = x
         x = make_norm(cfg, "ln_f")(x)
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
@@ -166,7 +166,7 @@ def untied_loss(cfg, copies, tokens=TOKENS):
     exits = []
     for w in copies:
         for i in range(cfg.n_layers):
-            x = Block(one).apply({"params": w[f"block_{i}"]}, x)
+            x, _ = Block(one).apply({"params": w[f"block_{i}"]}, x)
         x = make_norm(one, None).apply({"params": w["ln_f"]}, x)
         exits.append(x)
     exits = jnp.stack(exits)
