@@ -313,8 +313,9 @@ class TransformerConfig:
     # the bytes): the flash kernel's output and lse; with one pass also
     # the sum after attention (``B T d_model x itemsize``), the kernel's
     # q, k and v as a set where none is larger than its output (``B T (H
-    # + 2 G) d x itemsize``; not 192 over 128) and latent attention's two
-    # narrow first products.  What made them is not run again
+    # + 2 G) d x itemsize``; not 192 over 128), latent attention's two
+    # narrow first products and what a routed layer decided (experts,
+    # sorted order).  What made them is not run again
     remat: bool = False
     # the stack of blocks runs this many times with ONE set of weights,
     # the final norm closing each pass: its output is that pass's exit
@@ -402,9 +403,15 @@ def kept_names(cfg):
     hands its backward pass (``ops/selective_scan.py``'s
     ``SAVED_NAMES``: its output and the state every chunk is entered
     with).  One policy serves a mixed pattern: a name no block sets
-    keeps nothing."""
+    keeps nothing.
+
+    With one pass, whatever the mixers, also what a routed layer decided
+    (``parallel/moe.py``'s ``SAVED_NAMES``: the experts chosen and the
+    sorted order of the slots, under a hundred bytes a token): ``top_k``
+    and the sorts are not made again."""
     from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                         SAVED_NAMES)
+    from horovod_tpu.parallel.moe import SAVED_NAMES as routed
     if cfg.passes > 1:
         return SAVED_NAMES
     mixers = [spec.attention for spec in cfg.pattern or (cfg.block,)]
@@ -412,8 +419,8 @@ def kept_names(cfg):
     if any(isinstance(m, SelectiveScan) for m in mixers):
         from horovod_tpu.ops.selective_scan import SAVED_NAMES as scanned
     if all(isinstance(m, NO_ATTENTION) for m in mixers):
-        return scanned + (KEPT_SUM,)
-    return SAVED_NAMES + SAVED_INPUT_NAMES + scanned + KEPT_NAMES
+        return scanned + (KEPT_SUM,) + routed
+    return SAVED_NAMES + SAVED_INPUT_NAMES + scanned + KEPT_NAMES + routed
 
 
 def recomputed(block, cfg):
@@ -436,9 +443,13 @@ def kept_bytes(cfg, batch, seq, layer=0):
     names, and a :class:`SelectiveScan`'s what its scan keeps.  A
     :class:`DifferentialAttention` calls the kernel twice (half the
     heads each, the values twice as wide), so every name of the kernel
-    is there twice and counts twice."""
+    is there twice and counts twice.  A layer whose feed-forward is
+    routed (``"moe_topk"`` or a :class:`TopkExperts`) keeps what its
+    routing decided (``parallel/moe.py:saved_bytes``)."""
     from horovod_tpu.ops.pallas.flash_attention import saved_bytes
+    from horovod_tpu.parallel import moe
 
+    ffn = cfg.ffn_of(layer)
     cfg = cfg.at(layer)
     spec = cfg.block.attention
     heads, groups, calls = cfg.n_heads, cfg.n_heads, 1
@@ -451,6 +462,9 @@ def kept_bytes(cfg, batch, seq, layer=0):
         d_qk, d_v = spec.head_dim, 2 * spec.head_dim
     itemsize = jnp.dtype(cfg.dtype).itemsize
     kept = {KEPT_SUM: batch * seq * cfg.d_model * itemsize}
+    if ffn == "moe_topk" or isinstance(ffn, TopkExperts):
+        kept.update(moe.saved_bytes(batch * seq, cfg.experts_per_token,
+                                    getattr(ffn, "held", None)))
     if isinstance(spec, SelectiveScan):
         from horovod_tpu.ops import selective_scan
 

@@ -34,6 +34,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def switch_route(router_logits, n_experts, capacity, valid=None):
@@ -405,6 +406,34 @@ def grouped_matmul(lhs, rhs, group_sizes):
 SCORINGS = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
             "sigmoid": jax.nn.sigmoid}
 
+# What :func:`topk_moe` decides carries these names: the experts chosen,
+# the sorted order of the slots (and its inverse on the path without
+# ``held``); integers, 4 (k + min(k, held)) bytes a token.  Outside
+# ``jax.checkpoint`` a name is the identity; a checkpoint whose policy
+# keeps them (``save_only_these_names(*SAVED_NAMES)``) has no use for
+# ``top_k`` or the sorts in its recomputation.  Each stands where its
+# value is made and everything after reads the NAMED value: named later,
+# the backward pass reads the un-named twin and what made it runs again.
+# The router's product and score ARE made again (0.22-0.28 ms a layer on
+# the v5e): their float32 logits ``[N, E]`` kept as well cost the Laguna
+# cell 4.1 ms a step where they saved 0.9 (PERF.md section 6, PR 48).
+SAVED_EXPERTS = "moe_experts"
+SAVED_ORDER = "moe_order"
+SAVED_INVERSE = "moe_inverse"
+SAVED_NAMES = (SAVED_EXPERTS, SAVED_ORDER, SAVED_INVERSE)
+
+
+def saved_bytes(tokens, k, held=None):
+    """``{name: bytes}`` of what one :func:`topk_moe` over ``tokens``
+    tokens keeps under ``SAVED_NAMES``, all int32: the experts ``[N,
+    k]`` and the order of the ``N min(k, count)`` rows of the buffer
+    with ``held=(first, count)``, of all ``N k`` slots and its inverse
+    without."""
+    if held is None:
+        return dict.fromkeys(SAVED_NAMES, tokens * k * 4)
+    return {SAVED_EXPERTS: tokens * k * 4,
+            SAVED_ORDER: tokens * min(k, held[1]) * 4}
+
 
 def topk_route(router_logits, k, *, scoring="softmax", bias=None,
                renormalize=False, scale=1.0):
@@ -431,9 +460,20 @@ def topk_route(router_logits, k, *, scoring="softmax", bias=None,
     probs = SCORINGS[scoring](router_logits)
     if bias is None:
         weights, experts = jax.lax.top_k(probs, k)
+        experts = checkpoint_name(experts, SAVED_EXPERTS)
     else:
-        _, experts = jax.lax.top_k(probs + bias, k)
-        weights = jnp.take_along_axis(probs, experts, axis=-1)
+        experts = checkpoint_name(
+            jax.lax.top_k(probs + bias, k)[1], SAVED_EXPERTS)
+        # ``probs[n, experts[n, j]]`` read by comparison over the E
+        # lanes, one term of each sum non-zero: a select and a sum where
+        # ``take_along_axis`` moves scalars one by one (and its
+        # transpose scatters them), the same to the bit both ways.  As
+        # ``[k, N]``, the tokens along the lanes: ``[N, 8]`` the compiler
+        # lays out 8 lanes of 128 wide, a pass of 0.42 ms where this
+        # takes 0.08 (PERF.md section 6, PR 48)
+        chosen = experts.T[..., None] == jax.lax.broadcasted_iota(
+            experts.dtype, (k, n, e), 2)
+        weights = jnp.sum(jnp.where(chosen, probs, 0), axis=-1).T
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     if scale != 1.0:
@@ -481,7 +521,8 @@ def topk_moe(x, params, *, k, held=None, **route):
     inverse permutation and summed over a token's slots: no capacity,
     no token dropped, no scatter of rows.  ``aux`` is
     :func:`topk_route`'s and ``held_rows`` (int32): the rows of the
-    buffer that exist.
+    buffer that exist.  What the routing decided carries
+    ``SAVED_NAMES``, for the policy of a recomputed block.
 
     ``held=(first, count)``: this device holds the experts ``first ...
     first + count - 1`` of the router's ``E`` (``wg``, ``wi``, ``wo``
@@ -519,10 +560,11 @@ def topk_moe(x, params, *, k, held=None, **route):
         aux = {**aux, "held_rows": jnp.sum(group_sizes)}
         order = jnp.argsort(keys, stable=True)
         if held is None:
-            inverse = jnp.argsort(order)
+            order = checkpoint_name(order, SAVED_ORDER)
+            inverse = checkpoint_name(jnp.argsort(order), SAVED_INVERSE)
             rows = _dispatch(xt, order, inverse, k)
         else:
-            order = order[:n * min(k, count)]
+            order = checkpoint_name(order[:n * min(k, count)], SAVED_ORDER)
             rows = _dispatch_held(xt, order, aux["held_rows"], k)
     with jax.named_scope("moe/experts"):
         wg, wi, wo = (params[name]["kernel"].astype(dtype)

@@ -555,6 +555,21 @@ def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
             text, "/attn/out/", "/attn/latent/q_a/", "/attn/latent/kv_a/"))
         assert len(_products(_recomputed(text, "/attn/latent/q_b/"))) == 6
         assert len(_products(_recomputed(text, "/attn/latent/kv_b/"))) == 6
+        # nor what its routing decided: of the five routed blocks the
+        # router's product and no sort (``top_k``'s over [N, E], the
+        # slots') in the recomputation; the weights are read off the
+        # scores by a comparison that writes no [N, k, E], and no float
+        # is gathered or scattered one by one
+        again = _recomputed(text, "/moe/route/", "/moe/dispatch/")
+        assert len(_products(again)) == 5
+        assert not [line for line in again if " sort(" in line]
+        sorts = [line for line in text.splitlines() if " sort(" in line
+                 and ("/moe/route/" in line or "(argsort)" in line)]
+        assert len(sorts) == 5 * 2
+        assert not [line for line in _outside_loop_bodies(text)
+                    if re.match(r"\s*(ROOT )?%[\w.\-]+ = f32\[8,16384,256\]",
+                                line) and " fusion(" in line]
+        assert "take_along_axis" not in text
     else:
         # every row exists: the three plain gathers, no loop, no scatter
         assert whole and not loops
@@ -1022,13 +1037,15 @@ def test_cells_without_passes_compile_to_the_step_from_before(cell_step,
 def test_saved_names_are_nothing_in_a_step_without_recomputation(
         cell_step, workload, monkeypatch):
     """The flash forward rule names its output and lse for the policy
-    of a recomputed block; a step that recomputes nothing compiles to
-    the step without the names, instruction for instruction."""
+    of a recomputed block, a routed layer what its routing decided; a
+    step that recomputes nothing compiles to the step without the names,
+    instruction for instruction."""
     depth = SHALLOW[workload]
     named = _instructions(cell_step(workload, depth=depth))
     # (the package's attribute of this name is the function)
     for module in ("horovod_tpu.ops.pallas.flash_attention",
-                   "horovod_tpu.models.transformer"):
+                   "horovod_tpu.models.transformer",
+                   "horovod_tpu.parallel.moe"):
         monkeypatch.setattr(importlib.import_module(module),
                             "checkpoint_name", lambda x, name: x)
     unnamed = _instructions(cell_step(workload, variant="unnamed",

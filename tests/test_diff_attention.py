@@ -28,6 +28,7 @@ from horovod_tpu.ops import selective_scan
 from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                     SAVED_NAMES,
                                                     flash_attention)
+from horovod_tpu.parallel import moe
 
 B, T, D, HEADS, KV_HEADS, DIM = 2, 32, 32, 8, 4, 8
 INNER, STATE, RANK = 64, 4, 4
@@ -288,8 +289,9 @@ def test_specs_refuse_what_they_cannot_be():
 def test_kept_names_and_bytes_of_the_new_kinds():
     cfg = config(HYBRID, dtype=jnp.bfloat16, remat=True)
     scanned = selective_scan.SAVED_NAMES
-    assert kept_names(cfg.at(0)) == scanned + (KEPT_SUM,)
-    assert kept_names(cfg.at(4)) == (KEPT_SUM,)
+    routed = moe.SAVED_NAMES          # listed; no layer here sets them
+    assert kept_names(cfg.at(0)) == scanned + (KEPT_SUM,) + routed
+    assert kept_names(cfg.at(4)) == (KEPT_SUM,) + routed
     assert set(SAVED_NAMES + SAVED_INPUT_NAMES + scanned) <= set(
         kept_names(cfg))
     sums = B * T * D * 2

@@ -8,8 +8,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.parallel.moe import (init_moe_params, moe_param_shapes,
-                                      topk_moe, topk_route)
+from horovod_tpu.parallel.moe import (SCORINGS, init_moe_params,
+                                      moe_param_shapes, topk_moe, topk_route)
 
 N, D, F, E = 24, 16, 8, 6
 
@@ -264,6 +264,71 @@ def test_the_bias_decides_the_choice_and_enters_no_weight():
         logits, 2, scoring="sigmoid", bias=b, renormalize=True)[0] ** 2))(
             bias)
     assert not np.any(np.asarray(grad))
+
+
+def gathered_route(logits, k, scoring, bias, renormalize):
+    """The weights as ``topk_route`` read them before it compared: the
+    scores of the chosen experts by ``take_along_axis``."""
+    probs = SCORINGS[scoring](logits)
+    _, experts = jax.lax.top_k(probs + bias, k)
+    weights = jnp.take_along_axis(probs, experts, axis=-1)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts
+
+
+@pytest.mark.parametrize("renormalize", [False, True],
+                         ids=["as_is", "renormalized"])
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+@pytest.mark.parametrize("k", [4, 8, 10])
+@pytest.mark.parametrize("e", [64, 256])
+def test_weights_read_by_comparison_are_the_gathered_ones_bit_for_bit(
+        e, k, scoring, renormalize):
+    """With a bias the chosen scores are read off ``probs`` by comparison
+    over the E lanes (one term of each sum non-zero) and not by a gather
+    of scalars: weights and the gradient with respect to the logits equal
+    ``take_along_axis``'s and its scatter-add's to the last bit in
+    float32, under a bias that changes the choice."""
+    logits = jax.random.normal(jax.random.PRNGKey(e + k), (40, e))
+    bias = 0.5 * jax.random.normal(jax.random.PRNGKey(12), (e,))
+    ct = jax.random.normal(jax.random.PRNGKey(13), (40, k))
+
+    def compared(lg):
+        weights, experts, _ = topk_route(
+            lg, k, scoring=scoring, bias=bias, renormalize=renormalize)
+        return jnp.sum(weights * ct), (weights, experts)
+
+    def gathered(lg):
+        weights, experts = gathered_route(lg, k, scoring, bias, renormalize)
+        return jnp.sum(weights * ct), (weights, experts)
+
+    (_, (got_w, got_e)), got_g = jax.value_and_grad(
+        compared, has_aux=True)(logits)
+    (_, (want_w, want_e)), want_g = jax.value_and_grad(
+        gathered, has_aux=True)(logits)
+    assert got_w.dtype == jnp.float32 and got_e.dtype == want_e.dtype
+    np.testing.assert_array_equal(got_e, want_e)
+    np.testing.assert_array_equal(got_w, want_w)
+    np.testing.assert_array_equal(got_g, want_g)
+    assert np.any(np.asarray(got_g))
+    unbiased = topk_route(logits, k, scoring=scoring)[1]
+    assert np.any(np.sort(got_e, -1) != np.sort(unbiased, -1))
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_without_a_bias_the_weights_are_top_ks_own_values(scoring):
+    """The branch without a bias is as it was: the weights are the
+    values ``top_k`` returns and their gradient is ``top_k``'s."""
+    logits = jax.random.normal(jax.random.PRNGKey(14), (N, 64))
+    weights, experts, _ = topk_route(logits, 8, scoring=scoring)
+    want_w, want_e = jax.lax.top_k(SCORINGS[scoring](logits), 8)
+    np.testing.assert_array_equal(weights, want_w)
+    np.testing.assert_array_equal(experts, want_e)
+    np.testing.assert_array_equal(
+        jax.grad(lambda lg: jnp.sum(
+            topk_route(lg, 8, scoring=scoring)[0] ** 2))(logits),
+        jax.grad(lambda lg: jnp.sum(
+            jax.lax.top_k(SCORINGS[scoring](lg), 8)[0] ** 2))(logits))
 
 
 def test_balance_bias_moves_by_the_rule():

@@ -243,14 +243,17 @@ def test_the_head_norm_against_a_reference_a_head_at_a_time():
 def test_kept_names_and_bytes_of_a_layer_with_no_kernel():
     """A conv layer names the sum after its mixer and nothing of the
     flash kernel; the attention layer of the same pattern keeps what it
-    kept; one policy over the mixed pattern."""
+    kept; one policy over the mixed pattern (a routed layer's names are
+    on every one-pass list and keep nothing where no layer routes)."""
+    from horovod_tpu.parallel.moe import SAVED_NAMES as routed
+
     cfg = config(dtype=jnp.bfloat16, remat=True)
     everything = SAVED_NAMES + SAVED_INPUT_NAMES
-    assert kept_names(cfg.at(0)) == (KEPT_SUM,)
+    assert kept_names(cfg.at(0)) == (KEPT_SUM,) + routed
     assert set(everything) <= set(kept_names(cfg.at(1)))
     assert set(everything) <= set(kept_names(cfg))
     assert kept_names(config(pattern=(), block=spec(ShortConv()))) == (
-        KEPT_SUM,)
+        KEPT_SUM,) + routed
     for layer in (0, 2, 3, 4):
         assert kept_bytes(cfg, 2, T, layer) == {KEPT_SUM: 2 * T * D * 2}
     full = kept_bytes(cfg, 2, T, 1)

@@ -2,9 +2,11 @@
 (``models/transformer.py:recomputed``, ``kept_names``, ``kept_bytes``):
 beside the flash kernel's output and lse, the sum after attention, the
 kernel's q, k and v where none is larger than its output, and latent
-attention's two narrow first products; under a loop over passes the
-kernel's two results alone.  The values are the ones the recomputation
-would have made, so nothing changes but what runs twice."""
+attention's two narrow first products, and of a routed layer what its
+routing decided (the experts and the sorted order of the slots);
+under a loop over passes the kernel's two results alone.  The values
+are the ones the recomputation would have made, so nothing changes but
+what runs twice."""
 
 import os
 import re
@@ -19,14 +21,15 @@ import pytest
 from jax._src import core
 
 from horovod_tpu.models import (BlockSpec, GroupedAttention, LatentAttention,
-                                Rotary, Transformer, TransformerConfig,
-                                lm_loss, transformer)
+                                Rotary, TopkExperts, Transformer,
+                                TransformerConfig, lm_loss, transformer)
 from horovod_tpu.models.transformer import (KEPT_KV_A, KEPT_NAMES, KEPT_Q_A,
                                             KEPT_SUM, kept_bytes, kept_names)
 from horovod_tpu.ops.pallas import flash_attention
 from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                     SAVED_LSE, SAVED_NAMES,
                                                     SAVED_OUT, saved_bytes)
+from horovod_tpu.parallel import moe
 
 TOKENS = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, 31)
 SIZES = dict(vocab_size=31, n_layers=2, d_model=32, n_heads=4, d_ff=48,
@@ -55,6 +58,20 @@ KINDS = {
         norm="rms", positions="rope", ffn="swiglu",
         norm_placement="sandwich")),
 }
+# routed under a bias: 3 of 8 experts a token, a shared one beside them
+ROUTED = dict(n_experts=8, experts_per_token=3, d_expert=16)
+BIAS = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (2, 8))
+
+
+def routed(held):
+    return dict(ROUTED, block=BlockSpec(
+        norm="rms", positions="rope", ffn=TopkExperts(
+            scoring="sigmoid", renormalize=True, scale=2.5, shared=1,
+            held=held)))
+
+
+KINDS["routed_held"] = routed((2, 4))
+KINDS["routed_all"] = routed(None)
 
 
 def model_and_loss(kind, **changes):
@@ -69,8 +86,9 @@ def model_and_loss(kind, **changes):
     keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
     params = tree.unflatten([leaf + 0.1 * jax.random.normal(k, leaf.shape)
                              for leaf, k in zip(leaves, keys)])
+    bias = {"router_bias": BIAS} if kind.startswith("routed") else {}
     return cfg, params, lambda p: lm_loss(
-        model.apply({"params": p}, TOKENS), TOKENS)
+        model.apply({"params": p}, TOKENS, **bias), TOKENS)
 
 
 def plain_remat(block, cfg):
@@ -81,7 +99,7 @@ def plain_remat(block, cfg):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("kind", ["grouped_window", "grouped_full", "latent",
-                                  "plain"])
+                                  "plain", "routed_held", "routed_all"])
 def test_recomputed_blocks_give_the_loss_and_gradients_of_kept_ones(
         kind, dtype, monkeypatch):
     """Instruction by instruction (no ``jit`` around the gradient; compiled
@@ -193,7 +211,92 @@ def test_a_block_under_passes_keeps_the_kernels_two_results_alone():
         * cfg.n_layers + ["attn/attn/rope/"] * 2 * cfg.n_layers)
     assert set(kept_bytes(cfg, 2, 16)) == set(SAVED_NAMES)
     one = TransformerConfig(**{**SIZES, **KINDS["looped"], "passes": 1})
-    assert kept_names(one) == SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
+    assert kept_names(one) == (SAVED_NAMES + SAVED_INPUT_NAMES + KEPT_NAMES
+                               + moe.SAVED_NAMES)
+
+
+def routing(loss, params):
+    """``(top_k, argsorts, other sorts)`` in the gradient's jaxpr.  An
+    ``argsort`` is the routing's (the ``N k`` slots by expert, and the
+    order for its inverse); the other sorts are by token, inside
+    ``_tokens_of_rows``: the combine's and the dispatch's backward
+    pass, counted apart."""
+    def walk(jaxpr, inside=""):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name, inside
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, eqn.params.get("name", inside))
+
+    eqns = list(walk(jax.make_jaxpr(jax.grad(loss))(params).jaxpr))
+    sorts = [inside for name, inside in eqns if name == "sort"]
+    return (len([name for name, _ in eqns if name == "top_k"]),
+            sorts.count("argsort"), len(sorts) - sorts.count("argsort"))
+
+
+@pytest.mark.parametrize("kind,argsorts", [("routed_held", 1),
+                                           ("routed_all", 2)])
+def test_a_routed_block_decides_once(kind, argsorts):
+    """The gradient of a recomputed routed model holds ``top_k`` once a
+    layer and the ``argsort`` of the ``N k`` slots once (and that of the
+    order, for its inverse, on the path without ``held``): as many as
+    with ``remat=False``, and every other sort as often too.  The
+    recomputation holds no ``top_k``, no ``sort``, no gather and no
+    scatter of scalars: of ``moe/route`` the router's product, the
+    score, the comparison and the count."""
+    cfg, params, loss = model_and_loss(kind, remat=True)
+    _, _, kept = model_and_loss(kind, remat=False)
+    got, want = routing(loss, params), routing(kept, params)
+    assert got == want
+    assert got[:2] == (cfg.n_layers, argsorts * cfg.n_layers)
+    assert got[2] == (2 * cfg.n_layers if kind == "routed_held" else 0)
+    again = recomputation(loss, params)
+    assert not [eqn for eqn in again if eqn[0] in ("top_k", "sort")]
+    assert products(again).count("moe/moe/route") == cfg.n_layers
+    routes = {name for name, stack, _ in again if "/moe/route" in stack}
+    assert {"dot_general", "logistic", "select_n", "eq"} <= routes
+    assert not routes & {"gather", "scatter-add"}
+
+
+def test_an_unnamed_routed_block_decides_twice(monkeypatch):
+    """The walk of ``test_a_routed_block_decides_once`` on a block under
+    a plain ``nn.remat``: ``top_k`` and the ``argsort`` run again."""
+    _, params, loss = model_and_loss("routed_held", remat=True)
+    named = routing(loss, params)
+    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    _, params, loss = model_and_loss("routed_held", remat=True)
+    plain = routing(loss, params)
+    assert plain[:2] == (2 * named[0], 2 * named[1])
+    assert plain[2] == named[2]
+
+
+@pytest.mark.parametrize("kind", ["routed_held", "routed_all"])
+def test_kept_bytes_of_a_routed_layer_are_what_the_shapes_give(kind):
+    cfg, _, _ = model_and_loss(kind, remat=True)
+    n, k = TOKENS.size, 3
+    want = {moe.SAVED_EXPERTS: n * k * 4}
+    if kind == "routed_held":
+        want[moe.SAVED_ORDER] = n * min(k, 4) * 4
+    else:
+        want.update({moe.SAVED_ORDER: n * k * 4, moe.SAVED_INVERSE: n * k * 4})
+    got = kept_bytes(cfg, *TOKENS.shape)
+    assert {name: got[name] for name in moe.SAVED_NAMES if name in got} == want
+    assert list(got)[-len(want):] == [name for name in moe.SAVED_NAMES
+                                      if name in want]
+    # a buffer narrower than k slots a token: ``min(k, count)``
+    narrow = TransformerConfig(**{**SIZES, **routed((0, 2))})
+    assert kept_bytes(narrow, 2, 16)[moe.SAVED_ORDER] == n * 2 * 4
+    # ``"moe_topk"`` is a routed layer too; a dense layer ahead of the
+    # routed ones and a layer under ``passes > 1`` name none of it
+    olmoe = TransformerConfig(**{**SIZES, **ROUTED, "block": BlockSpec(
+        norm="rms", positions="rope", ffn="moe_topk")})
+    assert set(moe.SAVED_NAMES) <= set(kept_bytes(olmoe, 2, 16))
+    dense = TransformerConfig(**{**SIZES, **KINDS[kind], "leading_dense": 1})
+    assert not set(moe.SAVED_NAMES) & set(kept_bytes(dense, 2, 16, 0))
+    assert set(want) <= set(kept_bytes(dense, 2, 16, 1))
+    assert not set(moe.SAVED_NAMES) & set(kept_bytes(
+        TransformerConfig(**{**SIZES, **KINDS["plain"]}), 2, 16))
+    looped = TransformerConfig(**{**SIZES, **KINDS["looped"]})
+    assert not set(moe.SAVED_NAMES) & set(kept_bytes(looped, 2, 16))
 
 
 @pytest.mark.parametrize("kind", sorted(KINDS))
@@ -215,12 +318,20 @@ def test_kept_bytes_are_what_the_backward_pass_is_handed(kind, monkeypatch):
     more = [cfg.passes * n for layer in range(cfg.n_layers)
             for n in kept_bytes(cfg, *TOKENS.shape, layer).values()]
     beyond = Counter(named) - Counter(plain + more)
-    assert not Counter(plain + more) - Counter(named)
-    # the reference LayerNorm's jitted ``_var`` hands its input on to its
-    # backward half: the kept sum a second time in this account, the same
-    # array in the program
-    sums = kept_bytes(cfg, *TOKENS.shape)[KEPT_SUM] if kind == "plain" else 0
-    assert beyond == Counter({sums: cfg.n_layers} if sums else {})
+    # a routed block that decides once has no use for the bias in its
+    # backward pass: the row a plain ``nn.remat`` hands on is not handed
+    unread = ({BIAS[0].nbytes: cfg.n_layers} if kind.startswith("routed")
+              else {})
+    assert Counter(plain + more) - Counter(named) == Counter(unread)
+    # a jitted function that reads a kept array hands it on to the
+    # backward half: a second time in this account, the same array in the
+    # program (the reference LayerNorm's ``_var`` the kept sum,
+    # ``one_hot`` the experts, ``floor_divide`` the order)
+    twice = {"plain": (KEPT_SUM,), "routed_held": (moe.SAVED_EXPERTS,),
+             "routed_all": (moe.SAVED_EXPERTS, moe.SAVED_ORDER)}
+    sizes = kept_bytes(cfg, *TOKENS.shape)
+    assert beyond == sum((Counter({sizes[name]: cfg.n_layers})
+                          for name in twice.get(kind, ())), Counter())
 
 
 def test_the_kernels_inputs_are_named_as_a_set_by_their_bytes():
@@ -266,19 +377,31 @@ def cell_config():
 
 
 Q, K, V = SAVED_INPUT_NAMES
+EXPERTS, ORDER = moe.SAVED_NAMES[:2]
 
 
 @pytest.mark.parametrize("workload,layer,want", [
-    # a sliding layer: q [72, 8192, 128] + k, v [8, 8192, 128] = 184.5 MB
+    # a sliding layer: q [72, 8192, 128] + k, v [8, 8192, 128] = 184.5 MB;
+    # routed, 10 of 256 a token with 8 held: experts [8192, 10] and the
+    # order of 8192 x 8 rows, int32 = 0.6 MB
     ("laguna_s_2_1-spmd-1chip", 1, {
         SAVED_OUT: 150_994_944, SAVED_LSE: 2_359_296, Q: 150_994_944,
-        K: 16_777_216, V: 16_777_216, KEPT_SUM: 50_331_648}),
-    # a full layer: q [48, 8192, 128] + k, v = 134.2 MB
+        K: 16_777_216, V: 16_777_216, KEPT_SUM: 50_331_648,
+        EXPERTS: 327_680, ORDER: 262_144}),
+    # a full layer: q [48, 8192, 128] + k, v = 134.2 MB; layer 0's
+    # feed-forward is dense
     ("laguna_s_2_1-spmd-1chip", 0, {
         SAVED_OUT: 100_663_296, SAVED_LSE: 1_572_864, Q: 100_663_296,
         K: 16_777_216, V: 16_777_216, KEPT_SUM: 50_331_648}),
-    # 192 over 128: none of the kernel's inputs; 67.1 + 50.3 + 18.9 MB
+    # 192 over 128: none of the kernel's inputs; 67.1 + 50.3 + 18.9 MB;
+    # routed, 8 of 256 a token with 16 held: experts and order [16384,
+    # 8] = 1.0 MB
     ("joyai_llm_flash-spmd-1chip", 1, {
+        SAVED_OUT: 134_217_728, SAVED_LSE: 2_097_152, KEPT_SUM: 67_108_864,
+        KEPT_Q_A: 50_331_648, KEPT_KV_A: 18_874_368,
+        EXPERTS: 524_288, ORDER: 524_288}),
+    # its leading dense layer
+    ("joyai_llm_flash-spmd-1chip", 0, {
         SAVED_OUT: 134_217_728, SAVED_LSE: 2_097_152, KEPT_SUM: 67_108_864,
         KEPT_Q_A: 50_331_648, KEPT_KV_A: 18_874_368}),
     # under the passes: what PR 34 kept, an application
@@ -286,22 +409,34 @@ Q, K, V = SAVED_INPUT_NAMES
         SAVED_OUT: 16_777_216, SAVED_LSE: 262_144}),
     # a conv layer has no kernel: the sum after its mixer, 67.1 MB
     ("lfm2_24b_a2b-spmd-1chip", 0, {KEPT_SUM: 67_108_864}),
-    ("lfm2_24b_a2b-spmd-1chip", 4, {KEPT_SUM: 67_108_864}),
+    # routed, 4 of 64 a token with 8 held: experts and order [16384, 4]
+    # = 0.5 MB
+    ("lfm2_24b_a2b-spmd-1chip", 4, {
+        KEPT_SUM: 67_108_864, EXPERTS: 262_144, ORDER: 262_144}),
     # its attention layer: q [64, 8192, 64] + k, v [16, 8192, 64]
     ("lfm2_24b_a2b-spmd-1chip", 1, {
         SAVED_OUT: 67_108_864, SAVED_LSE: 2_097_152, Q: 67_108_864,
-        K: 16_777_216, V: 16_777_216, KEPT_SUM: 67_108_864}),
-], ids=["laguna-sliding", "laguna-full", "joyai", "ouro", "lfm2-conv-dense",
-        "lfm2-conv", "lfm2-full"])
+        K: 16_777_216, V: 16_777_216, KEPT_SUM: 67_108_864,
+        EXPERTS: 262_144, ORDER: 262_144}),
+], ids=["laguna-sliding", "laguna-full", "joyai", "joyai-dense", "ouro",
+        "lfm2-conv-dense", "lfm2-conv", "lfm2-full"])
 def test_kept_bytes_of_the_cells_blocks_by_hand(cell_config, workload, layer,
                                                 want):
     cfg, batch, seq = cell_config(workload)
     assert cfg.remat
     assert kept_bytes(cfg, batch, seq, layer) == want
     if workload.startswith("laguna"):
-        # the five blocks: F S S S F, 1.00 GiB more than out and lse
+        # the five blocks: F S S S F, 1.00 GiB more than out and lse, and
+        # the four routed ones' decisions, 2.4 MB
         total = sum(sum(n for name, n in kept_bytes(cfg, batch, seq,
                                                     i).items()
                         if name not in SAVED_NAMES) for i in range(5))
-        assert total == 2 * 134_217_728 + 3 * 184_549_376 + 5 * 50_331_648
+        assert total == (2 * 134_217_728 + 3 * 184_549_376 + 5 * 50_331_648
+                         + 4 * 589_824)
         assert round(total / 2 ** 30, 2) == 1.0
+    if workload.startswith("joyai"):
+        # four routed layers of the five; the next-token module's block is
+        # a fifth: 5.2 MB a step
+        routed = [sum(n for name, n in kept_bytes(cfg, batch, seq, i).items()
+                      if name in moe.SAVED_NAMES) for i in range(5)]
+        assert routed == [0] + [1_048_576] * 4
