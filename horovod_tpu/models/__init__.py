@@ -11,6 +11,7 @@ from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mlp import MLP  # noqa: F401
 from horovod_tpu.models.transformer import (  # noqa: F401
     BlockSpec,
+    ChunkSummaryAttention,
     DifferentialAttention,
     GroupedAttention,
     LatentAttention,
@@ -25,4 +26,5 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     apply_with_aux,
     lm_loss,
     looped_lm_loss,
+    multi_offset_lm_loss,
 )

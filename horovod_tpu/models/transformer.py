@@ -42,15 +42,23 @@ sequence (:class:`SelectiveScanMixer`, Mamba's), ``attention=
 MemoryUnit(...)`` a gate on what an earlier block made.  Attention may
 be the difference of two softmaxes over paired heads
 (:class:`DifferentialAttention`), with its own keys and values or an
-earlier block's.  What one block hands a later one (``memory``,
+earlier block's, or softmax attention linearised by chunk
+(:class:`ChunkSummaryAttention`: a query reads its own window exactly and
+every earlier one through pooled summaries).  What one block hands a
+later one (``memory``,
 ``keys``) travels beside ``x`` through the stack as one small pytree.
 ``positions="none"`` is a model with no position encoding at all, and
-``TransformerConfig.tie_head`` one whose head is its embedding.
+``TransformerConfig.tie_head`` one whose head is its embedding.  The
+residual stream may be carried in another dtype than the products'
+(``residual_dtype``), the RMSNorm's scale may start at 0 and count from 1
+(``norm_unit_offset``), and the head may predict several positions ahead
+from one hidden state (``head_outputs``, :func:`multi_offset_lm_loss`).
 
 bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 """
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Optional, Tuple, Union
 
@@ -211,8 +219,31 @@ class DifferentialAttention:
                              f"is none of {KEYS}")
 
 
+@dataclasses.dataclass(frozen=True)
+class ChunkSummaryAttention:
+    """Softmax attention linearised by chunk
+    (:class:`ChunkSummaryAttentionMixer`, ``ops/chunk_attention.py``;
+    arXiv:2302.04542): ``heads`` heads of ``head_dim`` for q, k and v
+    alike.  The sequence is cut into windows of ``window`` positions
+    that do not slide; a query reads the keys of its own window up to
+    itself exactly and every EARLIER window through one learned summary
+    of k and v every ``chunk`` positions, in one softmax.  ``rotary``:
+    the recipe q and k are turned by, before the pooling."""
+    heads: int
+    head_dim: int
+    window: int
+    chunk: int
+    rotary: Rotary = Rotary()
+
+    def __post_init__(self):
+        if self.chunk < 1 or self.window % self.chunk:
+            raise ValueError(
+                f"ChunkSummaryAttention: chunks of {self.chunk} do not "
+                f"divide a window of {self.window}")
+
+
 MIXERS = (LatentAttention, GroupedAttention, ShortConv, SelectiveScan,
-          MemoryUnit, DifferentialAttention)
+          MemoryUnit, DifferentialAttention, ChunkSummaryAttention)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,9 +278,9 @@ class BlockSpec:
     whole q and k projections, before the split into heads.  ``attention``:
     the block's mixer: ``"full"`` (one fused q, k, v projection, heads of
     one width), a :class:`LatentAttention`, a :class:`GroupedAttention`,
-    a :class:`DifferentialAttention`, or a :class:`ShortConv`,
-    :class:`SelectiveScan` or :class:`MemoryUnit`, which are no
-    attention.  ``ffn``:
+    a :class:`DifferentialAttention`, a :class:`ChunkSummaryAttention`,
+    or a :class:`ShortConv`, :class:`SelectiveScan` or
+    :class:`MemoryUnit`, which are no attention.  ``ffn``:
     ``"gelu"`` (dense up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x
     gate) * (x up)`` down), ``"moe_switch"``
     (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
@@ -328,8 +359,29 @@ class TransformerConfig:
     # the head is the embedding: logits = norm_f(x) E^T with E read in
     # the activation dtype, one parameter with the gradient of both uses
     tie_head: bool = False
+    # the dtype the residual stream is embedded, summed and carried in
+    # between the blocks (None: ``dtype``); the norms read it in float32
+    # anyway and every product still takes ``dtype``
+    residual_dtype: Any = None
+    # the RMSNorms' scale starts at 0 and multiplies as ``1 + scale``
+    norm_unit_offset: bool = False
+    # the head gives this many rows of ``vocab_size`` logits a position,
+    # ``[..., T, head_outputs * vocab_size]`` from one hidden state:
+    # output r is asked for token ``t + 1 + r``
+    # (:func:`multi_offset_lm_loss`)
+    head_outputs: int = 1
+    # the dtype the head's product is summed and returned in (None:
+    # ``dtype``); its operands are ``dtype`` either way
+    logits_dtype: Any = None
 
     def __post_init__(self):
+        if self.norm_unit_offset and any(
+                spec.norm != "rms" for spec in self.pattern or (self.block,)):
+            raise ValueError("TransformerConfig.norm_unit_offset: only an "
+                             "RMSNorm has one")
+        if self.head_outputs != 1 and (self.tie_head or self.exit_gate):
+            raise ValueError("TransformerConfig.head_outputs: a tied head "
+                             "or an exit gate has one output a position")
         once = {(spec.norm, spec.positions == "learned")
                 for spec in self.pattern}
         if len(once) > 1:
@@ -408,7 +460,14 @@ def kept_names(cfg):
     With one pass, whatever the mixers, also what a routed layer decided
     (``parallel/moe.py``'s ``SAVED_NAMES``: the experts chosen and the
     sorted order of the slots, under a hundred bytes a token): ``top_k``
-    and the sorts are not made again."""
+    and the sorts are not made again.
+
+    Where a block is a :class:`ChunkSummaryAttention` also its summaries
+    (``ops/chunk_attention.py``'s ``SAVED_NAMES``, ``1 / chunk`` of k
+    and v), and NOT the kernels' inputs: its two calls read q in two
+    layouts, so q, k and v as the kernels read them would be four arrays
+    of ``out``'s size a layer, and what makes them again is three
+    products of ``d_model x d_model``."""
     from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                         SAVED_NAMES)
     from horovod_tpu.parallel.moe import SAVED_NAMES as routed
@@ -420,6 +479,9 @@ def kept_names(cfg):
         from horovod_tpu.ops.selective_scan import SAVED_NAMES as scanned
     if all(isinstance(m, NO_ATTENTION) for m in mixers):
         return scanned + (KEPT_SUM,) + routed
+    if any(isinstance(m, ChunkSummaryAttention) for m in mixers):
+        from horovod_tpu.ops.chunk_attention import SAVED_NAMES as summaries
+        return SAVED_NAMES + summaries + scanned + KEPT_NAMES + routed
     return SAVED_NAMES + SAVED_INPUT_NAMES + scanned + KEPT_NAMES + routed
 
 
@@ -443,7 +505,11 @@ def kept_bytes(cfg, batch, seq, layer=0):
     names, and a :class:`SelectiveScan`'s what its scan keeps.  A
     :class:`DifferentialAttention` calls the kernel twice (half the
     heads each, the values twice as wide), so every name of the kernel
-    is there twice and counts twice.  A layer whose feed-forward is
+    is there twice and counts twice; so does a
+    :class:`ChunkSummaryAttention` longer than one window (the local and
+    the remote call, ``out`` and ``lse`` of q's size each), beside its
+    summaries.  The sum after the mixer is in the residual stream's
+    dtype.  A layer whose feed-forward is
     routed (``"moe_topk"`` or a :class:`TopkExperts`) keeps what its
     routing decided (``parallel/moe.py:saved_bytes``)."""
     from horovod_tpu.ops.pallas.flash_attention import saved_bytes
@@ -461,7 +527,16 @@ def kept_bytes(cfg, batch, seq, layer=0):
         heads, groups, calls = spec.heads // 2, spec.kv_heads // 2, 2
         d_qk, d_v = spec.head_dim, 2 * spec.head_dim
     itemsize = jnp.dtype(cfg.dtype).itemsize
-    kept = {KEPT_SUM: batch * seq * cfg.d_model * itemsize}
+    kept = {KEPT_SUM: batch * seq * cfg.d_model * jnp.dtype(
+        cfg.residual_dtype or cfg.dtype).itemsize}
+    if isinstance(spec, ChunkSummaryAttention):
+        from horovod_tpu.ops.chunk_attention import SAVED_NAMES as summaries
+
+        heads = groups = spec.heads
+        d_qk = d_v = spec.head_dim
+        calls = 1 if seq <= spec.window else 2
+        kept.update(dict.fromkeys(
+            summaries, batch * seq // spec.chunk * heads * d_qk * itemsize))
     if ffn == "moe_topk" or isinstance(ffn, TopkExperts):
         kept.update(moe.saved_bytes(batch * seq, cfg.experts_per_token,
                                     getattr(ffn, "held", None)))
@@ -593,13 +668,19 @@ def rotate(x, recipe):
 
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2, -1) + eps) * scale`` in float32, returned
-    in ``x.dtype``.  Plain ``jax.numpy``: XLA fuses it."""
+    in ``x.dtype``.  With ``unit_offset`` the parameter starts at 0 and
+    the norm multiplies by ``1 + scale``.  Plain ``jax.numpy``: XLA
+    fuses it."""
     eps: float = 1e-6
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param(
+            "scale", nn.initializers.zeros if self.unit_offset
+            else nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        if self.unit_offset:
+            scale = 1 + scale
         x32 = x.astype(jnp.float32)
         out = x32 * jax.lax.rsqrt(
             jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
@@ -608,8 +689,10 @@ class RMSNorm(nn.Module):
 
 def make_norm(cfg, name):
     """The norm ``cfg.block`` names."""
-    cls = RMSNorm if cfg.block.norm == "rms" else FusedLayerNorm
-    return cls(eps=cfg.norm_eps, name=name)
+    if cfg.block.norm == "rms":
+        return RMSNorm(eps=cfg.norm_eps, unit_offset=cfg.norm_unit_offset,
+                       name=name)
+    return FusedLayerNorm(eps=cfg.norm_eps, name=name)
 
 
 def full_qkv(cfg, x):
@@ -949,6 +1032,67 @@ class DifferentialAttentionMixer(nn.Module):
                 o.reshape(o.shape[:-2] + (-1,))), (k, v)
 
 
+def _summary_init(key, shape, dtype=jnp.float32):
+    """``clip(normal, -1, 1) / sqrt(head_dim)``: the start of the two
+    learned vectors a head of a :class:`ChunkSummaryAttention`."""
+    return (jnp.clip(jax.random.normal(key, shape, dtype), -1, 1)
+            / math.sqrt(shape[-1]))
+
+
+def default_chunk_attention():
+    """The attention of a :class:`ChunkSummaryAttention`: the flash
+    kernels joined by their ``lse`` on TPU, the dense masked softmax
+    elsewhere (as :func:`default_attention`)."""
+    from horovod_tpu.ops import chunk_attention
+    if jax.default_backend() == "tpu":
+        return chunk_attention.chunk_summary_attention
+    return chunk_attention.reference_chunk_summary_attention
+
+
+class ChunkSummaryAttentionMixer(nn.Module):
+    """Attention of a :class:`ChunkSummaryAttention` on ``x [B, T, d]``:
+
+        q, k, v = x W_q, x W_k, x W_v  [H heads of D], q and k turned
+        (kt, vt) = pool_chunks(k, v, phi, mu)        phi, mu [H, D] learned
+        out = chunk_summary_attention(q, k, v, kt, vt) W_o
+
+    (``ops/chunk_attention.py``), no biases.  All of it runs under the
+    scope ``attn/eva``: ``qkv``, ``rope``, ``pool``, ``flash`` (the two
+    kernel calls under ``local`` and ``remote`` and their ``join``
+    inside it) and ``out``."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.ops.chunk_attention import pool_chunks
+
+        cfg, spec = self.cfg, self.cfg.block.attention
+        scale = 1 / math.sqrt(spec.head_dim)
+
+        def dense(features, name):
+            return nn.DenseGeneral(features, use_bias=False, dtype=cfg.dtype,
+                                   name=name)
+
+        with jax.named_scope("attn/eva"):
+            with jax.named_scope("qkv"):
+                q, k, v = (dense((spec.heads, spec.head_dim), name)(x)
+                           for name in ("q", "k", "v"))
+            with jax.named_scope("rope"):
+                q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
+            with jax.named_scope("pool"):
+                phi, mu = (self.param(name, _summary_init,
+                                      (spec.heads, spec.head_dim),
+                                      jnp.float32) for name in ("phi", "mu"))
+                kt, vt = pool_chunks(k, v, phi, mu, spec.chunk, scale)
+            with jax.named_scope("flash"):
+                o = default_chunk_attention()(
+                    q, k, v, kt, vt, window=spec.window, chunk=spec.chunk,
+                    scale=scale)
+            with jax.named_scope("out"):
+                return dense(cfg.d_model, "out")(
+                    o.reshape(o.shape[:-2] + (-1,)))
+
+
 class Mlp(nn.Module):
     cfg: TransformerConfig
 
@@ -1099,6 +1243,8 @@ class Block(nn.Module):
                 y, shared.get("keys"))
             if mixer.keys == "published":
                 shared = {**shared, "keys": keys}
+        elif isinstance(mixer, ChunkSummaryAttention):
+            y = ChunkSummaryAttentionMixer(cfg, name="attn")(y)
         else:
             y = Attention(cfg, name="attn")(y)
         if sandwich:
@@ -1127,6 +1273,22 @@ def lm_loss(logits, tokens):
     with jax.named_scope("loss"):
         return jnp.mean(_token_losses(logits,
                                       jnp.roll(tokens, -1, axis=-1)))
+
+
+def multi_offset_lm_loss(logits, tokens, outputs):
+    """The loss of a head with ``outputs`` rows of logits a position
+    (``TransformerConfig.head_outputs``): ``logits [..., T, outputs *
+    V]`` read as ``[..., T, outputs, V]``, output ``r`` at position ``t``
+    asked for token ``t + 1 + r`` (the roll of :func:`lm_loss`, by ``1 +
+    r``: the last ``1 + r`` positions are asked for the first tokens),
+    the mean over positions and outputs with equal weights; through the
+    same kernel on ``[... T outputs, V]`` rows.  With one output it is
+    :func:`lm_loss`."""
+    with jax.named_scope("loss"):
+        labels = jnp.stack([jnp.roll(tokens, -(1 + r), axis=-1)
+                            for r in range(outputs)], axis=-1)
+        return jnp.mean(_token_losses(
+            logits.reshape(logits.shape[:-1] + (outputs, -1)), labels))
 
 
 def _token_losses(logits, labels):
@@ -1264,18 +1426,22 @@ class Transformer(nn.Module):
     own (empty in a model none of whose blocks publishes).  With
     ``cfg.tie_head`` the logits are ``norm_f(x) E^T`` with ``E`` the
     embedding in the activation dtype (under the scope ``lm_head``), and
-    the model has no ``lm_head`` of its own."""
+    the model has no ``lm_head`` of its own.  With ``cfg.head_outputs``
+    R > 1 the logits are ``[B, T, R vocab]``, R rows a position
+    (:func:`multi_offset_lm_loss`)."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, router_bias=None, return_hidden=False):
         cfg = self.cfg
-        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+        # the stream's dtype: x + branch keeps it, the wider of the two
+        stream = cfg.residual_dtype or cfg.dtype
+        embed = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=stream,
                          name="embed")
         x = embed(tokens)
         if cfg.block.positions == "learned":
             x = x + nn.Embed(
-                cfg.max_len, cfg.d_model, dtype=cfg.dtype,
+                cfg.max_len, cfg.d_model, dtype=stream,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
         block_cls = recomputed(Block, cfg) if cfg.remat else Block
 
@@ -1314,8 +1480,12 @@ class Transformer(nn.Module):
                 with jax.named_scope("lm_head"):
                     return embed.attend(h)
         else:
-            head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                            name="lm_head")
+            summed = {} if cfg.logits_dtype is None else {
+                "dot_general": functools.partial(
+                    jax.lax.dot_general,
+                    preferred_element_type=cfg.logits_dtype)}
+            head = nn.Dense(cfg.head_outputs * cfg.vocab_size, use_bias=False,
+                            dtype=cfg.dtype, name="lm_head", **summed)
         if cfg.exit_gate:
             with jax.named_scope("exit_gate"):
                 self.sow("intermediates", "exit_gate_logits", nn.Dense(

@@ -37,6 +37,13 @@ framework ships one).  Design per the TPU architecture:
   and a window of 512: two, whatever T), only the blocks an edge (the
   diagonal or the window's far edge) crosses run the masked body, and
   the backward's k block visits only the q blocks inside its window;
+- a third shape of key set, **stairs** (``stairs=(step_q, step_k)``: query
+  i sees the keys ``j < (i // step_q) * step_k`` of a k and v of another
+  length, what attention linearised by chunk reads of its summaries,
+  ``ops/chunk_attention.py``), is loop bounds alone: the blocks divide the
+  steps, so a q block visits the k blocks under its stair whole and no
+  tile is crossed by an edge; the backward's k block visits the q blocks
+  of every later stair;
 - **grouped key-value heads** are read through the index map: q has
   ``H`` heads, k and v ``G`` (``H = G x group``), head ``h`` of q reads
   head ``h // group`` of k and v where they lie, so k and v are never
@@ -170,11 +177,20 @@ def _causal_loops(body, carry, bounds):
     return carry
 
 
-def _k_bounds(iq, *, causal, block_q, block_k, t_kv, window=None):
+def _k_bounds(iq, *, causal, block_q, block_k, t_kv, window=None,
+              stairs=None):
     """K-block ranges for q block ``iq``: whole blocks, then the blocks
     the diagonal crosses; blocks past it are not visited.  With a
     ``window`` the blocks wholly before it are not visited either, and
-    the blocks its far edge crosses come first, masked."""
+    the blocks its far edge crosses come first, masked.  With ``stairs
+    = (step_q, step_k)`` (block_q divides the one, block_k the other) a
+    q block lies on one stair and sees the k blocks under it whole: no
+    block is crossed by an edge."""
+    if stairs is not None:
+        step_q, step_k = stairs
+        return [(0, jnp.minimum((iq * block_q // step_q)
+                                * (step_k // block_k), t_kv // block_k),
+                 False)]
     if not causal:
         return [(0, t_kv // block_k, False)]
     seen = jnp.minimum((iq + 1) * block_q + block_k - 1, t_kv) // block_k
@@ -192,12 +208,18 @@ def _k_bounds(iq, *, causal, block_q, block_k, t_kv, window=None):
             (whole, seen, True)]
 
 
-def _q_bounds(ik, *, causal, block_q, block_k, nq, window=None):
+def _q_bounds(ik, *, causal, block_q, block_k, nq, window=None,
+              stairs=None):
     """Q-block ranges for k block ``ik`` (the backward's loop): q blocks
     before this k block see none of it, the blocks the diagonal crosses
     run masked, the rest see all of it; with a ``window`` the q blocks
     wholly past it are not visited and the blocks its far edge crosses
-    come last, masked."""
+    come last, masked.  With ``stairs`` the mirror of ``_k_bounds``: the
+    q blocks of every LATER stair, whole."""
+    if stairs is not None:
+        step_q, step_k = stairs
+        return [(jnp.minimum((ik * block_k // step_k + 1)
+                             * (step_q // block_q), nq), nq, False)]
     if not causal:
         return [(0, nq, False)]
     first = (ik * block_k) // block_q
@@ -223,7 +245,7 @@ def _allowed(q_pos, k_pos, window):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k, window=None):
+                block_q, block_k, window=None, stairs=None):
     # q_ref: [block_q, d_qk]; k_ref: [t_kv, d_qk]; v_ref: [t_kv, d_v];
     # o_ref: [block_q, d_v]; lse_ref: [1, block_q], one lane per row.
     # Scores are held transposed, [block_k, block_q], as in the backward:
@@ -281,7 +303,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
          jnp.zeros((1, block_q), jnp.float32),
          jnp.zeros((d_v, block_q), jnp.float32)),
         _k_bounds(iq, causal=causal, block_q=block_q, block_k=block_k,
-                  t_kv=t_kv, window=window))
+                  t_kv=t_kv, window=window, stairs=stairs))
 
     l_safe = jnp.where(l > 0, l, 1.0)
     o_ref[0] = (o_t * (1.0 / l_safe)).T.astype(o_ref.dtype)
@@ -298,7 +320,7 @@ def _kv_head(group):
 
 
 def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret,
-         window=None):
+         window=None, stairs=None):
     """Returns ``(out [bh, t, d_v], lse [bh, t])``; k3 and v3 may have
     fewer heads than q3 (``bh`` a multiple of theirs)."""
     bh, t, d_qk = q3.shape
@@ -308,7 +330,8 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret,
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, window=window),
+                          block_q=block_q, block_k=block_k, window=window,
+                          stairs=stairs),
         grid=(bh, nq),
         in_specs=[
             _vmem_spec((1, block_q, d_qk), lambda b, i: (b, i, 0)),
@@ -332,7 +355,7 @@ def _fwd(q3, k3, v3, *, scale, causal, block_q, block_k, interpret,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale, causal,
-                block_q, block_k, window=None, group=1):
+                block_q, block_k, window=None, group=1, stairs=None):
     # One k block of one head a grid step.  Scores are held transposed,
     # [block_k, block_q]: a q block's lse and delta broadcast down the
     # sublanes from the lane rows they are stored as, and p.T @ dO,
@@ -392,7 +415,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return dk, dv
 
     bounds = _q_bounds(ik, causal=causal, block_q=block_q, block_k=block_k,
-                       nq=nq, window=window)
+                       nq=nq, window=window, stairs=stairs)
     dk, dv = _causal_loops(
         body, (jnp.zeros(k_blk.shape, jnp.float32),
                jnp.zeros(v_blk.shape, jnp.float32)), bounds)
@@ -461,7 +484,7 @@ def _bwd_vmem_bytes(t, d_qk, d_v, block_q, block_k, itemsize, group=1,
 
 
 def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
-         g_lse=None, window=None):
+         g_lse=None, window=None, stairs=None):
     q3, k3, v3, out, lse = res
     bh, t, d_qk = q3.shape
     t_kv, d_v = v3.shape[1:]
@@ -503,7 +526,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret,
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, window=window,
-                          group=group),
+                          group=group, stairs=stairs),
         grid=(bh, nk),
         in_specs=[
             whole_qk,
@@ -555,7 +578,8 @@ def _pick_block(t, want):
 # A transformer calls this once a layer with the same shapes: under
 # ``jit`` the kernels are traced once and lowered once for all of them,
 # not once a call (GPT-2 medium's step: 72 kernel bodies down to 3).
-_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "window")
+_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "window",
+           "stairs")
 _fwd_once = jax.jit(_fwd, static_argnames=_STATIC)
 _bwd_once = jax.jit(_bwd, static_argnames=_STATIC)
 
@@ -601,54 +625,57 @@ def _fwd_named(q3, k3, v3, **static):
     return checkpoint_name(out, SAVED_OUT), checkpoint_name(lse, SAVED_LSE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, window):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q3, k3, v3, scale, causal, block_q, block_k, interpret, window,
+           stairs):
     out, _ = _fwd_once(q3, k3, v3, scale=scale, causal=causal,
                        block_q=block_q, block_k=block_k, interpret=interpret,
-                       window=window)
+                       window=window, stairs=stairs)
     return out
 
 
 def _flash_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
-               window):
+               window, stairs):
     out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret, window=window)
+                          interpret=interpret, window=window, stairs=stairs)
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, g):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, stairs,
+               res, g):
     return _bwd_once(res, g, scale=scale, causal=causal, block_q=block_q,
-                     block_k=block_k, interpret=interpret, window=window)
+                     block_k=block_k, interpret=interpret, window=window,
+                     stairs=stairs)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_lse(q3, k3, v3, scale, causal, block_q, block_k, interpret,
-               window):
+               window, stairs):
     """Like ``_flash`` but also returns the logsumexp — the streaming-
     softmax state ring attention needs to combine per-block results."""
     return _fwd_once(q3, k3, v3, scale=scale, causal=causal,
                      block_q=block_q, block_k=block_k, interpret=interpret,
-                     window=window)
+                     window=window, stairs=stairs)
 
 
 def _flash_lse_fwd(q3, k3, v3, scale, causal, block_q, block_k, interpret,
-                   window):
+                   window, stairs):
     out, lse = _fwd_named(q3, k3, v3, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret, window=window)
+                          interpret=interpret, window=window, stairs=stairs)
     return (out, lse), (q3, k3, v3, out, lse)
 
 
-def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, window, res,
-                   g):
+def _flash_lse_bwd(scale, causal, block_q, block_k, interpret, window,
+                   stairs, res, g):
     g_out, g_lse = g
     return _bwd_once(res, g_out, scale=scale, causal=causal,
                      block_q=block_q, block_k=block_k, interpret=interpret,
-                     g_lse=g_lse, window=window)
+                     g_lse=g_lse, window=window, stairs=stairs)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -663,7 +690,7 @@ def _env_block(name, default):
 
 def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, return_lse=False,
-                    window=None):
+                    window=None, stairs=None):
     """Flash multi-head attention: q ``[B, T, H, d_qk]``, k ``[B, T_kv,
     G, d_qk]``, v ``[B, T_kv, G, d_v]`` -> ``[B, T, H, d_v]``; ``scale``
     defaults to ``1 / sqrt(d_qk)``.
@@ -674,6 +701,13 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
     ``window``: with ``causal``, query i sees the keys ``i - window < j
     <= i`` (``window`` of them, itself included) of a k and v as long as
     q; the kernels visit the blocks that hold such a pair and no other.
+    ``stairs = (step_q, step_k)``, without ``causal``: a third shape of
+    key set, over a k and v of any length: query i sees the keys ``j <
+    (i // step_q) * step_k``, none on the first stair (its rows of the
+    output are 0 and its ``lse`` a finite floor of -1e30, so a caller
+    that joins this call with another by ``lse`` gives it weight 0 with
+    no ``inf - inf``).  The blocks are taken to divide the steps, so no
+    tile is crossed by an edge and the stairs are loop bounds alone.
 
     Differentiable (custom VJP with Pallas backward kernels).  On
     non-TPU backends runs in Pallas interpret mode (tests);
@@ -709,6 +743,11 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
             f"flash_attention: a window ({window}) is at least 1 and "
             f"needs causal=True and keys as long as the queries "
             f"({t_kv} for {t})")
+    if stairs is not None and (causal or window is not None
+                               or min(stairs) < 1):
+        raise ValueError(
+            f"flash_attention: stairs {stairs} are two steps of at least 1 "
+            f"and a key set of their own, without causal or a window")
     if scale is None:
         scale = 1.0 / math.sqrt(d_qk)
     if interpret is None:
@@ -726,8 +765,11 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
         block_q = _env_block("HVD_FLASH_BLOCK_Q", _BLOCK)
     if block_k is None:
         block_k = _env_block("HVD_FLASH_BLOCK_K", _BLOCK)
-    block_q = _pick_block(t, block_q)
-    block_k = _pick_block(t_kv, block_k)
+    # under stairs a block divides its step too, so that none straddles two
+    block_q = _pick_block(t if stairs is None else math.gcd(t, stairs[0]),
+                          block_q)
+    block_k = _pick_block(
+        t_kv if stairs is None else math.gcd(t_kv, stairs[1]), block_k)
 
     def to3(x):
         tt, heads = x.shape[1:3]
@@ -738,9 +780,10 @@ def flash_attention(q, k, v, *, causal=False, scale=None, block_q=None,
         qkv3 = map(checkpoint_name, qkv3, SAVED_INPUT_NAMES)
     if return_lse:
         out3, lse3 = _flash_lse(*qkv3, scale, causal, block_q, block_k,
-                                interpret, window)
+                                interpret, window, stairs)
         out = out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)
         return out, lse3.reshape(b, h, t)
 
-    out3 = _flash(*qkv3, scale, causal, block_q, block_k, interpret, window)
+    out3 = _flash(*qkv3, scale, causal, block_q, block_k, interpret, window,
+                  stairs)
     return out3.reshape(b, h, t, d_v).transpose(0, 2, 1, 3)

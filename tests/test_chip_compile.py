@@ -217,6 +217,54 @@ def test_flash_forward_within_the_default_vmem_scope(v5e, heads, groups, t,
     assert stated is None and used <= _VMEM_DEFAULT, (stated, used)
 
 
+def test_chunk_summary_kernels_at_the_cell_shapes(v5e):
+    """``evabyte_6_5b-spmd-1chip``'s two calls a layer, alone.  The local
+    part: 8 windows x 32 heads as ``[256, 2048, 128]``, causal, the
+    forward within the default scope.  The remote part: q ``[32, 16384,
+    128]`` over the summaries ``[32, 1024, 128]`` under ``stairs=(2048,
+    128)`` in blocks of 512 x 128 (a k block divides a window's 128
+    summaries): the forward holds k and v of 1,024 rows and stays within
+    the default scope; the backward is ONE kernel that holds q, dO and dq
+    of a head of 16,384 rows, states what that takes from its own blocks
+    (``_bwd_vmem_bytes`` with the keys' own length) and is given no more
+    than the v5e has."""
+    from horovod_tpu.ops.pallas.flash_attention import (_VMEM_DEFAULT, _bwd,
+                                                        _bwd_vmem_bytes, _fwd)
+
+    def calls_of(fn, *shapes):
+        text = _compile(fn, *shapes).as_text()
+        return [line for line in text.splitlines()
+                if " custom-call(" in line and "tpu_custom_call" in line]
+
+    def forward(stairs, block_k):
+        return lambda q, k, v: _fwd(
+            q, k, v, scale=128 ** -0.5, causal=stairs is None, block_q=512,
+            block_k=block_k, interpret=False, stairs=stairs)
+
+    def backward(stairs, block_k):
+        return lambda q, k, v, out, lse, g: _bwd(
+            (q, k, v, out, lse), g, scale=128 ** -0.5, causal=stairs is None,
+            block_q=512, block_k=block_k, interpret=False, stairs=stairs,
+            g_lse=lse)
+
+    for bh, t, t_kv, stairs, block_k in ((256, 2048, 2048, None, 512),
+                                         (32, 16384, 1024, (2048, 128), 128)):
+        q, kv = (_on(v5e[0], (bh, n, 128), jnp.bfloat16) for n in (t, t_kv))
+        lse = _on(v5e[0], (bh, t), jnp.float32)
+        call, = calls_of(forward(stairs, block_k), q, kv, kv)
+        stated, used = _scoped_vmem(call)
+        assert stated is None and used <= _VMEM_DEFAULT, (stated, used)
+        call, = calls_of(backward(stairs, block_k), q, kv, kv, q, lse, q)
+        result, operands = call.split(" custom-call(", 1)
+        assert re.findall(r"\w+\[[\d,]+\]", result) == [
+            f"bf16[{bh},{t},128]", f"bf16[{bh},{t_kv},128]",
+            f"bf16[{bh},{t_kv},128]"]
+        stated, used = _scoped_vmem(call)
+        assert stated == _bwd_vmem_bytes(t, 128, 128, 512, block_k, 2, 1,
+                                         t_kv)
+        assert used <= stated <= 128 << 20, (used, stated)
+
+
 def test_latent_expert_block_and_module_compile_for_v5e(v5e):
     """The block the latent-attention cell is made of, at a small size
     (its head widths, fewer heads, narrower layers): a dense layer, an
@@ -286,8 +334,11 @@ def test_layer_norm_compiles_for_v5e(v5e):
     (B, T, LM["vocab"]), chip_smoke.OLMOE_LOGITS[1],
     # two vocabularies of the models queued next, neither a multiple of
     # the column tile: the tail compiles, and the tile fits VMEM
-    (1, 8192, 151936), (1, 8192, 201024)],
-    ids=["lm", "olmoe", "v151936", "v201024"])
+    (1, 8192, 151936), (1, 8192, 201024),
+    # a vocabulary of bytes under one tile, float32 logits, the rows of
+    # a head of 8 outputs a position at 16384 positions
+    (1, 16384, 8, 320)],
+    ids=["lm", "olmoe", "v151936", "v201024", "bytes_8_outputs"])
 def test_softmax_xent_compiles_for_v5e(v5e, shape):
     from horovod_tpu.ops.pallas.softmax_xent import softmax_xent
 
@@ -295,7 +346,8 @@ def test_softmax_xent_compiles_for_v5e(v5e, shape):
         return jax.value_and_grad(lambda lg: jnp.mean(
             softmax_xent(lg, labels, False)))(logits)
 
-    text = _compile(fwd_bwd, _on(v5e[0], shape, jnp.bfloat16),
+    dtype = jnp.float32 if shape[-1] == 320 else jnp.bfloat16
+    text = _compile(fwd_bwd, _on(v5e[0], shape, dtype),
                     _on(v5e[0], shape[:-1], jnp.int32)).as_text()
     assert text.count("tpu_custom_call") >= 2
 
@@ -836,6 +888,54 @@ def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes
             ) <= 13.614 * 2 ** 30 < 15.0 * 2 ** 30
+
+
+def test_chunk_summary_cell_step_compiles_for_v5e(cell_step):
+    """``evabyte_6_5b-spmd-1chip`` at published widths, four layers and
+    the cell's one sequence of 16384, every block recomputed: under the
+    issue's line of 15.0 GiB; in every layer two forward and two backward
+    flash calls under ``attn/eva/flash``, the local part on ``[256, 2048,
+    128]`` (8 windows x 32 heads as batch rows) and the remote part on q
+    ``[32, 16384, 128]`` over the summaries ``[32, 1024, 128]``, none in
+    the recomputation (``out`` and ``lse`` are kept); the pooling and the
+    join under their scopes and no kernel; the residual stream float32
+    between the blocks; the head's logits float32 and the loss kernels
+    on 131,072 rows of 320 columns."""
+    compiled = cell_step("evabyte_6_5b-spmd-1chip")
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if " custom-call(" in line and "tpu_custom_call" in line]
+    flash = [line for line in kernels if "/attn/eva/flash/" in line]
+    seen = []
+    for line in flash:
+        assert "rematted_computation" not in line
+        operands = line.split("operand_layout_constraints={", 1)[1]
+        part = re.search(r"/block_(\d)/attn/attn/eva/flash/(\w+)/", line)
+        shapes = re.findall(r"bf16\[[\d,]+\]\{2,1,0\}", operands)[:3]
+        assert shapes == {
+            "local": ["bf16[256,2048,128]{2,1,0}"] * 3,
+            "remote": ["bf16[32,16384,128]{2,1,0}"]
+            + ["bf16[32,1024,128]{2,1,0}"] * 2}[part.group(2)]
+        seen.append((int(part.group(1)), part.group(2),
+                     "bwd" if "jit(_bwd)" in line else "fwd"))
+    assert sorted(seen) == sorted(
+        (block, part, way) for block in range(4)
+        for part in ("local", "remote") for way in ("fwd", "bwd"))
+    for scope in ("qkv", "rope", "pool", "flash/join", "out"):
+        assert f"/block_3/attn/attn/eva/{scope}/" in text, scope
+    assert not [line for line in kernels if "/attn/eva/pool/" in line
+                or "/attn/eva/flash/join/" in line]
+    # the stream between the blocks, and the sum after attention, float32
+    assert "f32[1,16384,4096]" in text and "bf16[1,16384,4096]" in text
+    # the loss: a forward and a backward kernel on float32 rows
+    loss = [line for line in kernels if "f32[131072,320]" in line]
+    assert len(loss) == 2 and all("(loss)" in line for line in loss)
+    assert "f32[4096,2560]" in text
+    assert " while(" not in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            ) <= 14.886 * 2 ** 30 + 2 ** 20 < 15.0 * 2 ** 30
 
 
 def test_selective_scan_is_two_kernels_within_the_default_vmem_scope(v5e):

@@ -228,7 +228,8 @@ def test_a_sliding_layer_visits_two_key_blocks_a_query_block():
         assert seen == {iq, iq + 1} - {16} and not unmasked
 
 
-def k_bounds_before(iq, *, causal, block_q, block_k, t_kv, window=None):
+def k_bounds_before(iq, *, causal, block_q, block_k, t_kv, window=None,
+                    stairs=None):
     """``_k_bounds`` from before it knew a window, written out."""
     if not causal:
         return [(0, t_kv // block_k, False)]
@@ -237,7 +238,8 @@ def k_bounds_before(iq, *, causal, block_q, block_k, t_kv, window=None):
     return [(0, whole, False), (whole, seen, True)]
 
 
-def q_bounds_before(ik, *, causal, block_q, block_k, nq, window=None):
+def q_bounds_before(ik, *, causal, block_q, block_k, nq, window=None,
+                    stairs=None):
     """The backward's bounds from before, written out."""
     if not causal:
         return [(0, nq, False)]

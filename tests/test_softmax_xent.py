@@ -174,6 +174,22 @@ def test_bf16_logits_with_a_tail(forced_tile, v, cols):
                                 rtol=1e-2, atol=1e-3, gatol=1e-3)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_a_vocabulary_of_bytes_under_one_tile(dtype):
+    """320 columns (bytes and specials), fewer than any column tile and
+    no multiple of 128 lanes: a row fits whole, so the tile is exactly the
+    row and nothing is masked; rows as a head of several outputs a
+    position gives them, ``[B, T, outputs, V]``."""
+    assert sx._pick_tile(8 * 16384, 320, 4) == (256, 320)
+    assert sx._pick_tile(48, 320, 2) == (16, 320)
+    logits, labels = _data((2, 8, 3), 320, seed=8)
+    loose = dtype == jnp.bfloat16
+    _check_forward_and_gradient(
+        logits.astype(dtype), labels, **(dict(
+            rtol=1e-2, atol=1e-3, gatol=1e-3) if loose else {}))
+
+
 # The vocabularies of the models queued next (ROADMAP R2-R7; the
 # catalog's file is not in the repo) and of the two LM configurations.
 VOCABULARIES = [32000, 50257, 50304, 100352, 128256, 128815, 129280, 131072,
