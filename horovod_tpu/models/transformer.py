@@ -70,6 +70,7 @@ from flax.core import FrozenDict
 from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu.parallel.ring_attention import reference_attention
+from horovod_tpu.utils.logging import get_logger
 
 
 NORMS = ("layer", "rms")
@@ -336,17 +337,21 @@ class TransformerConfig:
     d_expert: Optional[int] = None
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
-    # rematerialize each block's activations in backward (jax.checkpoint):
-    # trades ~1/3 more FLOPs for O(layers) less activation HBM — the
-    # lever for pushing per-chip batch (and usually MFU) once
-    # activations, not weights, bound the batch size.  Kept of a block
-    # are its input and what ``kept_names`` lists (``kept_bytes`` gives
-    # the bytes): the flash kernel's output and lse; with one pass also
-    # the sum after attention (``B T d_model x itemsize``), the kernel's
-    # q, k and v as a set where none is larger than its output (``B T (H
-    # + 2 G) d x itemsize``; not 192 over 128), latent attention's two
-    # narrow first products and what a routed layer decided (experts,
-    # sorted order).  What made them is not run again
+    # recompute in the backward pass what does not fit (jax.checkpoint
+    # around every block): trades up to ~1/3 more FLOPs for O(layers)
+    # less activation HBM, the lever for pushing per-chip batch (and
+    # usually MFU) once activations, not weights, bound the batch size.
+    # What a block keeps is planned a layer from bytes (``kept_plan``:
+    # the shapes, the parameters, the device's memory; no option).  Every
+    # block keeps its input and what ``kept_names`` lists (``kept_bytes``
+    # gives the bytes): the flash kernel's output and lse; with one pass
+    # also the sum after attention (``B T d_model x itemsize``), the
+    # kernel's q, k and v as a set where none is larger than its output
+    # (``B T (H + 2 G) d x itemsize``; not 192 over 128), latent
+    # attention's two narrow first products and what a routed layer
+    # decided (experts, sorted order).  A layer the device has room for
+    # keeps the results of its products as well (``kept_products``).
+    # What made a kept array is not run again
     remat: bool = False
     # the stack of blocks runs this many times with ONE set of weights,
     # the final norm closing each pass: its output is that pass's exit
@@ -422,7 +427,7 @@ def default_attention():
     return reference_attention
 
 
-# What a block names for the policy of ``recomputed``: results the
+# What a block names for the policy of ``keeping``: results the
 # backward pass reads (or that stand between it and what it reads), cheap
 # to hold and dear to make again.  Outside a checkpoint a name is the
 # identity.
@@ -430,15 +435,27 @@ KEPT_SUM = "block_after_attention"   # x + attention(x): ln2's input
 KEPT_Q_A = "latent_q_a"              # latent attention's x W_qa
 KEPT_KV_A = "latent_kv_a"            # latent attention's x W_kva
 KEPT_NAMES = (KEPT_SUM, KEPT_Q_A, KEPT_KV_A)
+# The products a recomputed block makes again carry these; no block keeps
+# them but one that ``kept_plan`` found room for (``kept_products``).
+KEPT_GATE = "mlp_gate"               # x W_gate of a SwiGLU, shared or not
+KEPT_UP = "mlp_up"                   # a SwiGLU's or a GELU layer's x W_up
+KEPT_IN = "mixer_in"                 # a NO_ATTENTION mixer's first product
+KEPT_Q_B = "latent_q_b"              # latent attention's norm(c_q) W_qb
+KEPT_KV_B = "latent_kv_b"            # latent attention's norm(c_kv) W_kvb
+# chunk-summary attention's x W_q, x W_k, x W_v
+KEPT_QKV = ("chunk_q", "chunk_k", "chunk_v")
 NO_ATTENTION = (ShortConv, SelectiveScan, MemoryUnit)
+# A recomputed step is planned to this share of the device's memory
+BUDGET_SHARE = 0.95
 
 
 def kept_names(cfg):
-    """The names a recomputed block of ``cfg`` keeps from its forward
-    pass: the flash kernel's ``SAVED_NAMES`` (its output and lse), its
-    ``SAVED_INPUT_NAMES`` (q, k and v as it reads them, which the
-    kernel's call carries only where they are no larger than its
-    output) and the model's own ``KEPT_NAMES``.
+    """The names EVERY recomputed block of ``cfg`` keeps from its
+    forward pass, room or none (rung 0 of ``kept_plan``; what a layer
+    keeps beyond them is the plan's): the flash kernel's ``SAVED_NAMES``
+    (its output and lse), its ``SAVED_INPUT_NAMES`` (q, k and v as it
+    reads them, which the kernel's call carries only where they are no
+    larger than its output) and the model's own ``KEPT_NAMES``.
 
     Under a loop over passes (``cfg.passes > 1``) ``SAVED_NAMES`` alone:
     there every kept array is stacked once a pass and the loop's tuple
@@ -454,7 +471,7 @@ def kept_names(cfg):
     after the mixer and, of a :class:`SelectiveScan`, what its scan
     hands its backward pass (``ops/selective_scan.py``'s
     ``SAVED_NAMES``: its output and the state every chunk is entered
-    with).  One policy serves a mixed pattern: a name no block sets
+    with).  One list serves a mixed pattern: a name no block sets
     keeps nothing.
 
     With one pass, whatever the mixers, also what a routed layer decided
@@ -466,8 +483,8 @@ def kept_names(cfg):
     (``ops/chunk_attention.py``'s ``SAVED_NAMES``, ``1 / chunk`` of k
     and v), and NOT the kernels' inputs: its two calls read q in two
     layouts, so q, k and v as the kernels read them would be four arrays
-    of ``out``'s size a layer, and what makes them again is three
-    products of ``d_model x d_model``."""
+    of ``out``'s size a layer; what makes them again is three products
+    of ``d_model x d_model``, whose results are the plan's to keep."""
     from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                         SAVED_NAMES)
     from horovod_tpu.parallel.moe import SAVED_NAMES as routed
@@ -485,22 +502,78 @@ def kept_names(cfg):
     return SAVED_NAMES + SAVED_INPUT_NAMES + scanned + KEPT_NAMES + routed
 
 
-def recomputed(block, cfg):
+def keeping(block, names):
     """``block`` under ``jax.checkpoint``: its forward pass runs again in
-    the backward pass, all but what made the arrays ``kept_names(cfg)``
-    names, which are kept: the flash forward kernel, the output
-    projection and, where the kernel's inputs are kept, everything ahead
-    of the kernel but the norm.  A block that names nothing keeps
-    nothing, as under a plain ``nn.remat``."""
+    the backward pass, all but what made the arrays ``names`` name, which
+    are kept.  A block that sets none of the names keeps nothing, as
+    under a plain ``nn.remat``.  With ``kept_names(cfg)`` (rung 0 of
+    ``kept_plan``) what is not made again is the flash forward kernel,
+    the output projection and, where the kernel's inputs are kept,
+    everything ahead of the kernel but the norm; a layer the plan found
+    room for keeps the results of its products too."""
     return nn.remat(block, policy=jax.checkpoint_policies
-                    .save_only_these_names(*kept_names(cfg)))
+                    .save_only_these_names(*names))
 
 
-def kept_bytes(cfg, batch, seq, layer=0):
+def kept_products(cfg, layer=0):
+    """``[(names, worth)]``: the products recomputed block ``layer``
+    makes again whose results carry a name, in the order the block makes
+    them, and what keeping a byte of each saves: the product's
+    contracting width (a ``[rows, K] x [K, N]`` result buys ``K``
+    multiply-adds a number kept) times the share of the result's rows
+    that exist (all, but in a routed layer's buffer of held experts:
+    ``moe.product_bytes``).  Names that one gradient reads together are
+    one entry (a SwiGLU's ``gate`` and ``up``).  None under
+    ``cfg.passes > 1``, for the reason ``kept_names`` gives.  Not here:
+    what ``kept_names`` keeps already (attention's q, k and v where the
+    kernel names them)."""
+    from horovod_tpu.parallel import moe
+
+    if cfg.passes > 1:
+        return []
+    ffn = cfg.ffn_of(layer)
+    cfg = cfg.at(layer)
+    spec = cfg.block.attention
+    found = []
+    if isinstance(spec, NO_ATTENTION):
+        found.append(((KEPT_IN,), cfg.d_model))
+    if isinstance(spec, LatentAttention):
+        found += [((KEPT_Q_B,), spec.q_rank), ((KEPT_KV_B,), spec.kv_rank)]
+    if isinstance(spec, ChunkSummaryAttention):
+        found += [((name,), cfg.d_model) for name in KEPT_QKV]
+    if ffn == "gelu":
+        found.append(((KEPT_UP,), cfg.d_model))
+    if ffn == "swiglu" or getattr(ffn, "shared", 0):
+        found.append(((KEPT_GATE, KEPT_UP), cfg.d_model))
+    if ffn == "moe_topk" or isinstance(ffn, TopkExperts):
+        gate, up, down = moe.PRODUCT_NAMES
+        _, share = _routed_products(cfg, ffn, 1, 1)
+        found += [((gate, up), cfg.d_model * share),
+                  ((down,), (cfg.d_expert or cfg.d_ff) * share)]
+    return found
+
+
+def _routed_products(cfg, ffn, batch, seq):
+    """``moe.product_bytes`` of the routed layer ``ffn`` of ``cfg``."""
+    from horovod_tpu.parallel import moe
+
+    held = getattr(ffn, "held", None)
+    return moe.product_bytes(
+        batch * seq, cfg.experts_per_token, cfg.d_model,
+        cfg.d_expert or cfg.d_ff, jnp.dtype(cfg.dtype).itemsize,
+        held and held + (cfg.n_experts,))
+
+
+def kept_bytes(cfg, batch, seq, layer=0, names=None):
     """``{name: bytes}`` of what ONE application of recomputed block
     ``layer`` keeps by name on ``[batch, seq]`` tokens through the flash
     kernel (its input, which every checkpoint keeps, is ``batch seq
-    d_model x itemsize`` more).  A layer whose mixer is no attention
+    d_model x itemsize`` more): of ``kept_names(cfg)``, what every
+    recomputed block keeps, or of ``names`` (``kept_products``'s: the
+    results of the layer's products, ``rows x columns x itemsize``
+    each; a name this layer has no bytes for is a ``KeyError``, so a
+    product listed there and forgotten here is not planned as free).
+    A layer whose mixer is no attention
     has no kernel and no q, k or v: the sum after the mixer is all it
     names, and a :class:`SelectiveScan`'s what its scan keeps.  A
     :class:`DifferentialAttention` calls the kernel twice (half the
@@ -527,6 +600,7 @@ def kept_bytes(cfg, batch, seq, layer=0):
         heads, groups, calls = spec.heads // 2, spec.kv_heads // 2, 2
         d_qk, d_v = spec.head_dim, 2 * spec.head_dim
     itemsize = jnp.dtype(cfg.dtype).itemsize
+    row = batch * seq * itemsize  # a column of a product's result
     kept = {KEPT_SUM: batch * seq * cfg.d_model * jnp.dtype(
         cfg.residual_dtype or cfg.dtype).itemsize}
     if isinstance(spec, ChunkSummaryAttention):
@@ -537,25 +611,42 @@ def kept_bytes(cfg, batch, seq, layer=0):
         calls = 1 if seq <= spec.window else 2
         kept.update(dict.fromkeys(
             summaries, batch * seq // spec.chunk * heads * d_qk * itemsize))
+        kept.update(dict.fromkeys(KEPT_QKV, row * heads * d_qk))
     if ffn == "moe_topk" or isinstance(ffn, TopkExperts):
         kept.update(moe.saved_bytes(batch * seq, cfg.experts_per_token,
                                     getattr(ffn, "held", None)))
+        kept.update(_routed_products(cfg, ffn, batch, seq)[0])
+    # a dense feed-forward's width, or the shared expert's (a SwiGLU too)
+    width = (cfg.d_ff if ffn in ("gelu", "swiglu") else
+             getattr(ffn, "shared", 0) * (cfg.d_expert or cfg.d_ff))
+    if width:
+        kept[KEPT_UP] = row * width
+        if ffn != "gelu":
+            kept[KEPT_GATE] = row * width
     if isinstance(spec, SelectiveScan):
         from horovod_tpu.ops import selective_scan
 
         kept.update(selective_scan.saved_bytes(
             batch, seq, spec.d_inner, spec.state, cfg.dtype))
+    if isinstance(spec, NO_ATTENTION):
+        kept[KEPT_IN] = row * (
+            3 * cfg.d_model if isinstance(spec, ShortConv) else
+            2 * spec.d_inner if isinstance(spec, SelectiveScan) else
+            spec.d_inner)
     if isinstance(spec, LatentAttention):
         d_qk, d_v = spec.nope_dim + spec.rope_dim, spec.v_dim
-        kept[KEPT_Q_A] = batch * seq * spec.q_rank * itemsize
-        kept[KEPT_KV_A] = batch * seq * (spec.kv_rank
-                                         + spec.rope_dim) * itemsize
+        kept[KEPT_Q_A] = row * spec.q_rank
+        kept[KEPT_KV_A] = row * (spec.kv_rank + spec.rope_dim)
+        kept[KEPT_Q_B] = row * cfg.n_heads * d_qk
+        kept[KEPT_KV_B] = row * cfg.n_heads * (spec.nope_dim + d_v)
     if not isinstance(spec, NO_ATTENTION):
         q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
                    for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
         kept.update({name: calls * n
                      for name, n in saved_bytes(q, k, v).items()})
-    return {name: kept[name] for name in kept_names(cfg) if name in kept}
+    if names is None:  # one list serves every layer: some it does not set
+        return {name: kept[name] for name in kept_names(cfg) if name in kept}
+    return {name: kept[name] for name in names}
 
 
 @jax.custom_vjp
@@ -738,11 +829,13 @@ def latent_qkv(cfg, x):
         # the norms are made again from the two narrow results kept
         c_q = make_norm(cfg, "q_a_norm")(checkpoint_name(
             dense(spec.q_rank, "q_a")(x), KEPT_Q_A))
-        q = dense((h, spec.nope_dim + spec.rope_dim), "q_b")(c_q)
+        q = checkpoint_name(
+            dense((h, spec.nope_dim + spec.rope_dim), "q_b")(c_q), KEPT_Q_B)
         kv_a = checkpoint_name(
             dense(spec.kv_rank + spec.rope_dim, "kv_a")(x), KEPT_KV_A)
         c_kv = make_norm(cfg, "kv_a_norm")(kv_a[..., :spec.kv_rank])
-        kv = dense((h, spec.nope_dim + spec.v_dim), "kv_b")(c_kv)
+        kv = checkpoint_name(
+            dense((h, spec.nope_dim + spec.v_dim), "kv_b")(c_kv), KEPT_KV_B)
         k_rope = rope(kv_a[..., None, spec.kv_rank:], cfg.rope_theta, pairs)
         # the whole head in one pass: its first nope_dim columns pass
         q = turn(q, *rotary_operands(
@@ -868,7 +961,7 @@ class ShortConvMixer(nn.Module):
                             name=name)
 
         with jax.named_scope("mixer/conv"):
-            bch = dense(3 * d, "in")(x)
+            bch = checkpoint_name(dense(3 * d, "in")(x), KEPT_IN)
             w = self.param("kernel", _taps_init, (taps, d), jnp.float32)
             with jax.named_scope("gate_conv"):
                 b, c, h = (bch[..., i * d:(i + 1) * d] for i in range(3))
@@ -925,7 +1018,7 @@ class SelectiveScanMixer(nn.Module):
             return self.param(name, init, shape, jnp.float32)
 
         with jax.named_scope("mixer/ssm"):
-            az = dense(2 * inner, "in")(x)
+            az = checkpoint_name(dense(2 * inner, "in")(x), KEPT_IN)
             with jax.named_scope("conv"):
                 w = param("conv_kernel", _taps_init, spec.taps, inner)
                 bias = param("conv_bias", nn.initializers.zeros, inner)
@@ -967,8 +1060,9 @@ class MemoryUnitMixer(nn.Module):
                             name=name)
 
         with jax.named_scope("mixer/gmu"):
+            gate = checkpoint_name(dense(inner, "in")(x), KEPT_IN)
             return dense(cfg.d_model, "out")(
-                nn.silu(dense(inner, "in")(x)) * memory.astype(cfg.dtype))
+                nn.silu(gate) * memory.astype(cfg.dtype))
 
 
 class DifferentialAttentionMixer(nn.Module):
@@ -1075,8 +1169,9 @@ class ChunkSummaryAttentionMixer(nn.Module):
 
         with jax.named_scope("attn/eva"):
             with jax.named_scope("qkv"):
-                q, k, v = (dense((spec.heads, spec.head_dim), name)(x)
-                           for name in ("q", "k", "v"))
+                q, k, v = (checkpoint_name(
+                    dense((spec.heads, spec.head_dim), name)(x), kept)
+                    for name, kept in zip(("q", "k", "v"), KEPT_QKV))
             with jax.named_scope("rope"):
                 q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
             with jax.named_scope("pool"):
@@ -1099,8 +1194,8 @@ class Mlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        x = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
-                     name="up")(x)
+        x = checkpoint_name(nn.Dense(cfg.d_ff, use_bias=False,
+                                     dtype=cfg.dtype, name="up")(x), KEPT_UP)
         x = nn.gelu(x)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="down")(x)
@@ -1120,7 +1215,10 @@ class SwigluMlp(nn.Module):
                             name=name)
 
         width = self.width or cfg.d_ff
-        hidden = nn.silu(dense(width, "gate")(x)) * dense(width, "up")(x)
+        # a pair: the backward of ``silu(g) * u`` reads both
+        gate = checkpoint_name(dense(width, "gate")(x), KEPT_GATE)
+        up = checkpoint_name(dense(width, "up")(x), KEPT_UP)
+        hidden = nn.silu(gate) * up
         return dense(cfg.d_model, "down")(hidden)
 
 
@@ -1224,7 +1322,7 @@ class Block(nn.Module):
         blocks published for later ones (``{"memory": ..., "keys": (k,
         v)}``, empty before the first publisher).  Returns ``(x,
         shared)`` with what this block's own spec publishes put in, so
-        under :func:`recomputed` it is an input of the block that reads
+        under :func:`keeping` it is an input of the block that reads
         it and a result of the block that made it; empty, it is no
         operand and no result of the compiled block."""
         cfg, mixer = self.cfg, self.cfg.block.attention
@@ -1406,6 +1504,184 @@ def apply_with_aux(model, params, tokens, *, router_bias=None,
     return out, aux
 
 
+def device_memory_bytes():
+    """The bytes of memory the device a step runs on has (``bytes_limit``
+    of ``memory_stats`` of THIS process's first device, read while the
+    step is traced); ``None`` where the backend tells none, as the CPU
+    does.  So a step lowered on a CPU host for a described topology is
+    planned for no limit (rung 0 everywhere, the program of before),
+    not for the chip it describes, unless the caller stands in for this
+    function as ``tests/test_chip_compile.py`` does."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+@functools.lru_cache(maxsize=None)
+def parameter_bytes(cfg, seq, next_token=False):
+    """The bytes of ``Transformer(cfg)``'s parameter tree as it is
+    initialized for sequences of ``seq`` (with ``next_token`` a
+    :class:`NextTokenModule`'s beside it), from shapes alone."""
+    cfg = dataclasses.replace(cfg, remat=False)
+    key = jax.random.PRNGKey(0)
+    tokens = jax.ShapeDtypeStruct((1, seq), jnp.int32)
+    trees = [jax.eval_shape(Transformer(cfg, parent=None).init, key, tokens)]
+    if next_token:
+        table = jax.ShapeDtypeStruct((cfg.vocab_size, cfg.d_model),
+                                     jnp.float32)
+        trees.append(jax.eval_shape(
+            NextTokenModule(cfg, parent=None).init, key,
+            jax.ShapeDtypeStruct((1, seq, cfg.d_model), cfg.dtype), tokens,
+            table, jax.ShapeDtypeStruct(table.shape[::-1], jnp.float32)))
+    return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+               for leaf in jax.tree.leaves([t["params"] for t in trees]))
+
+
+@dataclasses.dataclass(frozen=True)
+class KeptPlan:
+    """What :func:`kept_plan` found, a layer an entry (the next-token
+    module's block last): ``names``, what the layer keeps beside
+    ``kept_names(cfg)`` (empty: rung 0); ``kept``, the bytes the layer
+    holds from its forward pass to its backward pass (its input and
+    everything it keeps by name); ``params``, the bytes of the
+    parameter tree as the plan reckons it from shapes, ``peak``, the
+    step's predicted peak, and ``budget``, what it was filled to
+    (``None``: no limit known, nothing predicted)."""
+    names: Tuple[Tuple[str, ...], ...]
+    kept: Tuple[int, ...]
+    params: Optional[int] = None
+    peak: Optional[int] = None
+    budget: Optional[int] = None
+
+    @property
+    def rungs(self):
+        """0 where a layer keeps ``kept_names(cfg)`` alone, 1 where its
+        products' results too."""
+        return tuple(int(bool(names)) for names in self.names)
+
+    def __str__(self):
+        def gib(n):
+            return "unknown" if n is None else f"{n / 2 ** 30:.3f} GiB"
+
+        layers = ", ".join(
+            f"{i}: {rung}" + (f" (+ {' '.join(names)})" if names else "")
+            for i, (rung, names) in enumerate(zip(self.rungs, self.names)))
+        return (f"kept_plan: rung of each layer [{layers}], kept "
+                f"{gib(sum(self.kept))}, predicted peak {gib(self.peak)} "
+                f"of a budget of {gib(self.budget)}")
+
+
+def kept_plan(cfg, batch, seq, device_bytes, next_token=False):
+    """What every layer of a recomputed model (``cfg.remat``) holds for
+    its backward pass on ``[batch, seq]`` tokens a device, planned from
+    bytes: a :class:`KeptPlan` over the ``cfg.n_layers`` blocks and,
+    with ``next_token``, the block of a :class:`NextTokenModule` beside
+    them.  A pure function of its arguments.
+
+    Every layer starts on rung 0, today's names (``kept_names``).  Rung
+    1 adds the results of the layer's products (``kept_products``), the
+    one whose byte saves most first, ties in the order of the layers,
+    while the predicted peak of the step stays within ``BUDGET_SHARE``
+    of ``device_bytes``; what does not fit is passed over for what
+    does.  (A rung 2, a block not recomputed at all, is not built: what
+    the compiler holds of such a block is no closed form of shapes.)
+
+    The predicted peak is that of the moment the backward pass enters a
+    block: the float32 parameter tree FOUR times (parameter, gradient,
+    Adam's two moments: 16 bytes a parameter), what every layer keeps
+    (its input and its names' ``kept_bytes``), and of the ONE block
+    being differentiated every result it has a name for, made again,
+    and as much for the cotangents, less what it keeps on rung 0 (the
+    largest such; what rung 1 keeps is counted whole, the safe side);
+    beside a next-token module also the model's own logits, which wait
+    for their turn.  Or, where that is more, the head's moment: the
+    logits and their gradient.
+
+    Which steps that is conservative for.  A step whose optimizer eats a
+    weight's gradient where it is made (one fused ``optax.adam`` under
+    one ``jit``) compiles to the parameters three times, so the plan
+    leaves a parameter tree of room unused there; that room is what a
+    step needs that holds the whole gradient at once
+    (``clip_by_global_norm``, gradient accumulation, an all-reduce of
+    the tree as ``DistributedOptimizer`` makes).  An optimizer with more
+    state than two moments, or anything else resident on the device, the
+    plan cannot see: it reads ``cfg``, the shapes and one number.
+
+    With ``device_bytes`` ``None`` (a backend that tells no limit) and
+    under ``cfg.passes > 1`` (``kept_names`` says why) every layer
+    stays on rung 0 and nothing is predicted."""
+    layers = [(cfg, i) for i in range(cfg.n_layers)]
+    if next_token:  # its block is built from ``cfg`` as it is
+        layers.append((dataclasses.replace(
+            cfg, pattern=(), leading_dense=0, moe_every=0), 0))
+    rows = batch * seq
+    x = rows * cfg.d_model * jnp.dtype(
+        cfg.residual_dtype or cfg.dtype).itemsize
+    kept = [x + sum(kept_bytes(of, batch, seq, i).values())
+            for of, i in layers]
+    names = [()] * len(layers)
+    if device_bytes is None or cfg.passes > 1:
+        return KeptPlan(tuple(names), tuple(kept))
+
+    products = [(-worth, at, group, sum(kept_bytes(
+        of, batch, seq, i, group).values()))
+        for at, (of, i) in enumerate(layers)
+        for group, worth in kept_products(of, i)]
+    # a block's own moment, on rung 0: what it keeps is there already,
+    # its products' results are made again, and every named result has a
+    # cotangent.  What a name keeps beyond that is counted whole on top:
+    # whether the block would have held it at that moment anyway is the
+    # compiler's schedule
+    made = [sum(n for _, at, _, n in products if at == layer)
+            for layer in range(len(layers))]
+    moment = max(k + 2 * n for k, n in zip(kept, made))
+    params = parameter_bytes(cfg, seq, next_token)
+    logits = rows * cfg.head_outputs * cfg.vocab_size * jnp.dtype(
+        cfg.logits_dtype or cfg.dtype).itemsize
+    head = 2 * logits
+    if next_token:  # the model's logits wait while the module's are read
+        moment += logits
+        head += 2 * rows * cfg.vocab_size * jnp.dtype(cfg.dtype).itemsize
+    fixed = 4 * params + max(head, moment)
+    budget = int(BUDGET_SHARE * device_bytes)
+
+    for _, at, group, n in sorted(products, key=lambda p: p[:2]):
+        if fixed + sum(kept) + n <= budget:
+            kept[at] += n
+            names[at] += group
+    return KeptPlan(tuple(names), tuple(kept), params, fixed + sum(kept),
+                    budget)
+
+
+def planned_blocks(module, tokens, next_token=False):
+    """``(classes, plan)`` for ``module`` (a :class:`Transformer` or a
+    :class:`NextTokenModule`) on ``tokens [..., T]``: the class every
+    layer's block is made of (the next-token module's last): ``Block``
+    itself where the model is not recomputed (``cfg.remat``: recompute
+    what does not fit), else ``Block`` recomputed, keeping what
+    :func:`kept_plan` says for the device's memory (while the module is
+    initialized, which differentiates nothing: for none).  ``plan`` is
+    ``None`` where nothing is recomputed.
+
+    The plan is made where the step is traced, for the shapes the trace
+    sees and ONE device of this process.  Inside ``shard_map`` (the
+    ``spmd`` loop's step) ``tokens`` is a device's own batch and the
+    plan is that device's.  Under a plain ``jit`` over a sharded batch
+    ``tokens.shape`` is the GLOBAL batch and the parameters count whole
+    however they are sharded: the plan then reckons every device's
+    activations on one, keeps less than there is room for and never
+    more (``tests/test_transformer_kept.py`` compiles such a step)."""
+    cfg = module.cfg
+    if not cfg.remat:
+        return [Block] * (cfg.n_layers + bool(next_token)), None
+    plan = kept_plan(
+        cfg, math.prod(tokens.shape[:-1]), tokens.shape[-1],
+        None if module.is_initializing() else device_memory_bytes(),
+        next_token)
+    made = {names: keeping(Block, kept_names(cfg) + names)
+            for names in set(plan.names)}
+    return [made[names] for names in plan.names], plan
+
+
 class Transformer(nn.Module):
     """Token ids ``[B, T]`` -> logits ``[B, T, vocab]`` (causal LM).
     ``router_bias [layers, E]``: see :func:`apply_with_aux`.  With
@@ -1443,7 +1719,14 @@ class Transformer(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=stream,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = recomputed(Block, cfg) if cfg.remat else Block
+        # ``return_hidden`` is how ``apply_with_aux`` says that it applies
+        # a NextTokenModule to the result: that module's block is one
+        # layer more of the plan, and the module plans with the same
+        # arguments.  Another caller that asks for the hidden state has
+        # a block counted that is not there, and keeps less
+        blocks, plan = planned_blocks(self, tokens, next_token=return_hidden)
+        if plan is not None and not self.is_initializing():
+            get_logger().info("%s", plan)
 
         def one_pass(mdl, carry, _):
             """The stack once, closed by the final norm; the carry is
@@ -1456,7 +1739,7 @@ class Transformer(nn.Module):
                 bias = None
                 if router_bias is not None and isinstance(ffn, TopkExperts):
                     bias, rows = router_bias[rows], rows + 1
-                block = block_cls(cfg.at(i), ffn=ffn, name=f"block_{i}")
+                block = blocks[i](cfg.at(i), ffn=ffn, name=f"block_{i}")
                 x, shared = block(x, bias, shared)
             out = make_norm(cfg, "ln_f")(x)
             return (out, x), (out if cfg.exit_gate else None)
@@ -1519,7 +1802,7 @@ class NextTokenModule(nn.Module):
              make_norm(cfg, "hnorm")(hidden)], axis=-1)
         x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                      name="eh_proj")(x)
-        block_cls = recomputed(Block, cfg) if cfg.remat else Block
-        x, _ = block_cls(cfg, name="block")(x, router_bias)
+        blocks, _ = planned_blocks(self, tokens, next_token=True)
+        x, _ = blocks[-1](cfg, name="block")(x, router_bias)
         x = make_norm(cfg, "ln_f")(x).astype(cfg.dtype)
         return jnp.dot(x, head.astype(cfg.dtype))

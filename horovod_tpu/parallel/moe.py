@@ -435,6 +435,35 @@ def saved_bytes(tokens, k, held=None):
             SAVED_ORDER: tokens * min(k, held[1]) * 4}
 
 
+# The results of :func:`topk_moe`'s three grouped products, over the
+# whole buffer of rows, carry these: a recomputed block keeps them only
+# where its plan found room (``models/transformer.py:kept_plan``).  The
+# names stand outside the passes of dynamic extent and change none.
+PRODUCT_GATE = "moe_gate"
+PRODUCT_UP = "moe_up"
+PRODUCT_DOWN = "moe_down"
+PRODUCT_NAMES = (PRODUCT_GATE, PRODUCT_UP, PRODUCT_DOWN)
+
+
+def product_bytes(tokens, k, d_model, d_expert, itemsize, held=None):
+    """``({name: bytes}, share)`` of one :func:`topk_moe` over ``tokens``
+    tokens: what its grouped products' results take under
+    ``PRODUCT_NAMES`` (the buffer's ``N k`` rows, ``N min(k, count)``
+    with ``held=(first, count, of)`` where ``of`` is the router's count
+    of experts; ``d_expert`` columns for gate and up, ``d_model`` for
+    down), and the share of those rows expected to exist: all of them
+    without ``held``, ``k count / of`` of ``min(k, count)`` a token under
+    a router that spreads its tokens evenly."""
+    slots, share = k, 1.0
+    if held is not None:
+        _, count, of = held
+        slots = min(k, count)
+        share = k * count / of / slots
+    rows = tokens * slots * itemsize
+    return {PRODUCT_GATE: rows * d_expert, PRODUCT_UP: rows * d_expert,
+            PRODUCT_DOWN: rows * d_model}, share
+
+
 def topk_route(router_logits, k, *, scoring="softmax", bias=None,
                renormalize=False, scale=1.0):
     """Token-choice top-k routing from ``[N, E]`` float32 logits.
@@ -569,9 +598,12 @@ def topk_moe(x, params, *, k, held=None, **route):
     with jax.named_scope("moe/experts"):
         wg, wi, wo = (params[name]["kernel"].astype(dtype)
                       for name in ("wg", "wi", "wo"))
-        hidden = (jax.nn.silu(grouped_matmul(rows, wg, group_sizes))
-                  * grouped_matmul(rows, wi, group_sizes))
-        y = grouped_matmul(hidden, wo, group_sizes)
+        gate = checkpoint_name(grouped_matmul(rows, wg, group_sizes),
+                               PRODUCT_GATE)
+        up = checkpoint_name(grouped_matmul(rows, wi, group_sizes),
+                             PRODUCT_UP)
+        y = checkpoint_name(grouped_matmul(jax.nn.silu(gate) * up, wo,
+                                           group_sizes), PRODUCT_DOWN)
     with jax.named_scope("moe/combine"):
         if held is None:
             y = _unsort(y, order, inverse).reshape(n, k, d)

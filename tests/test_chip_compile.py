@@ -26,6 +26,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
 import chip_smoke
 
 HBM_BYTES = 16 * 2 ** 30  # one v5e chip
+# what its ``memory_stats()["bytes_limit"]`` says: a recomputed model
+# plans what it keeps from it (``models/transformer.py:kept_plan``)
+V5E_BYTES_LIMIT = 16_911_433_728
 
 
 @pytest.fixture(scope="module")
@@ -489,12 +492,18 @@ def cell_step(v5e):
     those keys of the cell's configuration (its counts of layers) are
     replaced.  Each is compiled once for the tests of this file, and a
     cell at its own depth by one test only: ``cell_step.misses`` lists
-    what was compiled."""
+    what was compiled.  A cell at its own depth is compiled as on the
+    chip, whose memory the model asks for (``device_memory_bytes``
+    stands at a v5e's limit); at another depth with no limit told, as
+    the comparisons of instructions want it: the program of every
+    backend that tells none.  ``cell_step.plan(workload, next_token)``
+    is the ``kept_plan`` the model made at the cell's own depth."""
     import sys
 
     import optax
 
     import horovod_tpu.models
+    from horovod_tpu.models import transformer as program
     from horovod_tpu.parallel import make_mesh
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -526,25 +535,102 @@ def cell_step(v5e):
             jax.random.PRNGKey(0))
         tokens = jax.ShapeDtypeStruct(
             (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
-        was = horovod_tpu.models.Transformer
-        horovod_tpu.models.Transformer = transformer or was
+        was = horovod_tpu.models.Transformer, program.device_memory_bytes
+        horovod_tpu.models.Transformer = transformer or was[0]
+        program.device_memory_bytes = lambda: (
+            None if depth else V5E_BYTES_LIMIT)
         try:
             compiled[key] = step.lower(
                 _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
                 _shaped(mesh, jax.eval_shape(opt.init, params), P()),
                 _shaped(mesh, tokens, P("hvd"))).compile()
         finally:
-            horovod_tpu.models.Transformer = was
+            (horovod_tpu.models.Transformer,
+             program.device_memory_bytes) = was
         return compiled[key]
 
+    def plan(workload, next_token=False):
+        cell = bench.load_cell(repo, workload)
+        return program.kept_plan(
+            cell.family._program_config(cell.config),
+            cell.job["per_chip_batch"], cell.job["seq_len"], V5E_BYTES_LIMIT,
+            next_token)
+
     compile_step.misses = []
+    compile_step.plan = plan
     return compile_step
 
 
-def _fits_one_chip(compiled):
+def _bytes(compiled):
     mem = compiled.memory_analysis()
     return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < HBM_BYTES
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def _fits_one_chip(compiled):
+    return _bytes(compiled) < HBM_BYTES
+
+
+def _filled_as_planned(compiled, plan, parent_gib):
+    """A recomputed cell's step fills the chip as its plan says: at most
+    the line of 15.0 GiB and at least what it took before blocks kept
+    what there is room for (``parent_gib``, compiled at the parent of PR
+    50); the plan keeps a name only under its budget, and its predicted
+    peak is no more than 0.3 GiB under what the compiler found.  (It is
+    well over it: the plan holds the parameters four times, this step's
+    fused Adam three.)"""
+    gib = 2 ** 30
+    assert parent_gib * gib - 2 ** 20 <= _bytes(compiled) <= 15.0 * gib
+    assert plan.budget == int(0.95 * V5E_BYTES_LIMIT)
+    assert plan.peak <= plan.budget or not any(plan.names)
+    assert plan.peak >= _bytes(compiled) - 0.3 * gib
+    return True
+
+
+def test_a_step_that_holds_its_gradients_fits_its_plan(v5e, monkeypatch):
+    """A recomputed toy's step under ``clip_by_global_norm`` ahead of
+    Adam, which wants the whole gradient tree before the first update,
+    compiled for one described chip that has room for ONE layer's ``up``
+    of two: the first layer keeps it, the second makes it again, and the
+    step takes no more than the plan predicted.  (Here the head's
+    logits outweigh the parameters, so the prediction is close: the
+    account of a block's moment is held, not the parameters' count.)"""
+    import optax
+
+    from horovod_tpu.models import (Transformer, TransformerConfig, lm_loss,
+                                    transformer)
+
+    batch, seq = 16, 1024
+    cfg = TransformerConfig(vocab_size=8192, n_layers=2, d_model=512,
+                            n_heads=4, d_ff=2048, max_len=seq, remat=True,
+                            dtype=jnp.bfloat16)
+    up = transformer.kept_bytes(cfg, batch, seq, 0, ("mlp_up",))["mlp_up"]
+    floor = transformer.kept_plan(cfg, batch, seq, 1)
+    room = -(-(floor.peak + up + up // 2) * 20 // 19)
+    monkeypatch.setattr(transformer, "device_memory_bytes", lambda: room)
+    plan = transformer.kept_plan(cfg, batch, seq, room)
+    assert plan.names == (("mlp_up",), ()) and plan.peak == floor.peak + up
+    model = Transformer(cfg)
+    opt = optax.chain(optax.clip_by_global_norm(1.0), optax.adam(1e-3))
+
+    def step(params, state, tokens):
+        grads = jax.grad(lambda p: lm_loss(
+            model.apply({"params": p}, tokens), tokens))(params)
+        updates, state = opt.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
+    tokens = _on(v5e[0], (batch, seq), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
+    placed = SingleDeviceSharding(v5e[0])
+    params, state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=placed),
+        (params, jax.eval_shape(opt.init, params)))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, state, tokens).compile()
+    again = _products(_recomputed(compiled.as_text(), "/mlp/up/"))
+    assert len(again) == 1 and "block_1" in again[0]
+    assert 0.9 * plan.peak < _bytes(compiled) <= plan.peak
 
 
 def test_dense_cell_step_compiles_for_v5e(cell_step):
@@ -605,8 +691,16 @@ def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
         # again
         assert not _products(_recomputed(
             text, "/attn/out/", "/attn/latent/q_a/", "/attn/latent/kv_a/"))
+        # the plan: at 16 bytes a parameter the 1.1 GiB under the line are
+        # the gradient tree's, so every block (the module's too) stands
+        # on rung 0 and its products run again
+        plan = cell_step.plan(workload, next_token=True)
+        assert not any(plan.names) and len(plan.names) == 6
         assert len(_products(_recomputed(text, "/attn/latent/q_b/"))) == 6
         assert len(_products(_recomputed(text, "/attn/latent/kv_b/"))) == 6
+        assert len(_products(_recomputed(text, "/mlp/gate/"))) == 1
+        assert len(_products(_recomputed(text, "/moe/shared/"))) == 5 * 2
+        assert _filled_as_planned(compiled, plan, 13.87)
         # nor what its routing decided: of the five routed blocks the
         # router's product and no sort (``top_k``'s over [N, E], the
         # slots') in the recomputation; the weights are read off the
@@ -747,8 +841,15 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     # projections' scopes are their weights' casts to bfloat16, which the
     # backward products read, and under ``rope`` the tables ``[T, D]`` the
     # backward turn reads (``turn`` keeps nothing else): no array with
-    # heads in it
+    # heads in it.  The plan: at 16 bytes a parameter the 2.1 GiB under
+    # the line are the gradient tree's, so every block stands on rung 0
+    # and the feed-forwards' products run again
+    plan = cell_step.plan("laguna_s_2_1-spmd-1chip")
+    assert not any(plan.names) and len(plan.names) == 5
     assert _products(_recomputed(text, "/mlp/up/"))
+    # (a grouped product carries no scope: three forward, three again,
+    # six backward in each of the four routed layers)
+    assert len(_grouped_products(text)) == 4 * 12
     ahead = _recomputed(
         text, "/attn/window/q/", "/attn/window/kv/", "/attn/window/out/",
         "/attn/global/q/", "/attn/global/kv/", "/attn/global/out/")
@@ -757,19 +858,22 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     assert not [line for line in _recomputed(text, "/rope/") if re.search(
         r"\[(1,)?(8192,(72|48|8)|(72|48|8),8192),\d+\]", line)]
     assert _fits_one_chip(compiled)
+    assert _filled_as_planned(compiled, plan, 12.85)
 
 
 def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
     """``lfm2_24b_a2b-spmd-1chip`` at published widths and the cell's
-    two sequences of 8192, every block recomputed: fits one chip with
-    room (8.3 GiB); ONE flash forward and ONE backward kernel, the
+    two sequences of 8192, every block recomputed and keeping the
+    results of all its products, for which a v5e has room (10.9 GiB
+    where it took 8.3); ONE flash forward and ONE backward kernel, the
     attention layer's, q ``[64, 8192, 64]`` over k and v ``[16, 8192,
     64]`` (two sequences' 32 query heads over their 8 key-value heads,
     never repeated to q's count) and no forward kernel in the
     recomputation; the four conv mixers under their scopes, forward and
     backward, with no kernel and no convolution primitive of their own:
-    the taps are elementwise; a conv block's recomputation makes ``in``
-    again and not ``out``, whose result is in the kept sum."""
+    the taps are elementwise; a conv block's recomputation makes
+    neither ``in``, whose result the plan keeps, nor ``out``, whose
+    result is in the kept sum: the gates and the taps alone."""
     compiled = cell_step("lfm2_24b_a2b-spmd-1chip")
     text = compiled.as_text()
     flash = [line for line in text.splitlines()
@@ -797,20 +901,28 @@ def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
             assert f"jvp(Transformer)){path}" in text or (
                 f"jvp(Transformer)/checkpoint{path}" in text), path
         again = _recomputed(text, f"/block_{block}/mixer/mixer/conv/")
-        assert len(_products(again)) == 1
-        assert _products(again) == _products(_recomputed(
-            text, f"/block_{block}/mixer/mixer/conv/in/"))
+        assert again and not _products(again)
     # the taps are shifted multiply-adds: no convolution under their scope
     assert not [line for line in text.splitlines()
                 if " convolution(" in line and "/gate_conv/" in line]
     assert "/attn/global/qk_norm/" in text
     # the held experts' grouped products, forward, recomputed, backward
     assert text.count("%ragged-dot-none") >= 4 * 9
+    # the plan: every product's result, 3.97 GiB; of the feed-forwards
+    # and the experts nothing runs again but the router's small product
+    # and the passes that move rows
+    plan = cell_step.plan("lfm2_24b_a2b-spmd-1chip")
+    experts = ("moe_gate", "moe_up", "moe_down")
+    assert list(plan.names) == [
+        ("mixer_in", "mlp_gate", "mlp_up"), experts] + [
+            ("mixer_in",) + experts] * 3
+    # (a grouped product carries no scope: three forward and six
+    # backward in each of the four routed layers, none again)
+    assert not _products(_recomputed(text, "/mlp/"))
+    assert len(_grouped_products(text)) == 4 * 9
+    assert len(_products(_recomputed(text, "/moe/route/"))) == 4
     assert _fits_one_chip(compiled)
-    mem = compiled.memory_analysis()
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes
-            ) < 8.5 * 2 ** 30
+    assert _filled_as_planned(compiled, plan, 8.293)
 
 
 def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
@@ -883,11 +995,17 @@ def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
     assert len([line for line in kernels if "[16384,25008]" in line]) == 2
     # the head is the embedding: no parameter of a head's shape
     assert "f32[2560,25008]" not in text
-    mem = compiled.memory_analysis()
-    # under the issue's line, and no more than with the scans as loops
-    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes
-            ) <= 13.614 * 2 ** 30 < 15.0 * 2 ** 30
+    # the plan: at 16 bytes a parameter the 1.85 GiB under the line are
+    # the gradient tree's, so every block stands on rung 0: the mixers'
+    # first products and the six SwiGLUs' pairs run again.  No more
+    # memory than with the scans as loops
+    plan = cell_step.plan("phi4_mini_flash-spmd-1chip")
+    assert not any(plan.names) and len(plan.names) == 6
+    assert len(_products(_recomputed(text, "/mixer/ssm/in/"))) == 2
+    for name in ("gate", "up"):
+        assert len(_products(_recomputed(text, f"/mlp/{name}/"))) == 6
+    assert _filled_as_planned(compiled, plan, 13.145)
+    assert _bytes(compiled) <= 13.614 * 2 ** 30
 
 
 def test_chunk_summary_cell_step_compiles_for_v5e(cell_step):
@@ -1053,6 +1171,14 @@ def _products(lines):
             if " convolution(" in line and '/dot_general"' in line]
 
 
+def _grouped_products(text):
+    """The grouped products' instructions of a compiled step (the
+    ``%ragged-dot-none`` custom calls, not the metadata calls beside
+    them)."""
+    return re.findall(r"^\s*(?:ROOT )?%ragged-dot-none[.\d]* = .*$", text,
+                      re.M)
+
+
 def _instructions(compiled):
     """The optimized program's instructions without what names a source
     line: metadata, the tables of files and functions ahead of the
@@ -1170,7 +1296,7 @@ def test_looped_cell_step_keeps_what_it_kept(cell_step, monkeypatch):
     monkeypatch.setattr(flash, "checkpoint_name", lambda x, n: (
         name(x, n) if n in flash.SAVED_NAMES else x))
     monkeypatch.setattr(transformer, "checkpoint_name", lambda x, n: x)
-    monkeypatch.setattr(transformer, "recomputed", lambda block, cfg: nn.remat(
+    monkeypatch.setattr(transformer, "keeping", lambda block, names: nn.remat(
         block, policy=jax.checkpoint_policies.save_only_these_names(
             *flash.SAVED_NAMES)))
     before = _instructions(cell_step("ouro_2_6b-spmd-1chip",

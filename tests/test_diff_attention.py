@@ -148,7 +148,7 @@ def test_a_cross_layers_gradient_reaches_the_publishing_layers_k_and_v(remat):
     and the memory unit (block 4) block 2's scan output through the
     pytree beside ``x``; with the cross layer's and the memory unit's
     outputs cut off, less gradient reaches the publishers' weights, and
-    under ``recomputed`` the gradients are the plain model's."""
+    under ``keeping`` the gradients are the plain model's."""
     cfg = config(HYBRID, tie_head=True, remat=remat)
     model = Transformer(cfg)
     params = noisy(model.init(jax.random.PRNGKey(1), TOKENS)["params"], 2,
@@ -330,8 +330,8 @@ def test_kept_bytes_are_what_the_backward_pass_is_handed(monkeypatch):
                                            TOKENS), params)]
 
     cfg, named = handed()
-    monkeypatch.setattr(transformer, "recomputed",
-                        lambda block, cfg: nn.remat(block))
+    monkeypatch.setattr(transformer, "keeping",
+                        lambda block, names: nn.remat(block))
     _, plain = handed()
     more = []
     for layer in range(cfg.n_layers):

@@ -19,8 +19,8 @@ from jax._src import core
 from horovod_tpu.models import (BlockSpec, GroupedAttention, Rotary,
                                 ShortConv, Transformer, TransformerConfig)
 from horovod_tpu.models.transformer import (KEPT_SUM, Attention, Block,
-                                            ShortConvMixer, kept_bytes,
-                                            kept_names, recomputed, rotate)
+                                            ShortConvMixer, keeping,
+                                            kept_bytes, kept_names, rotate)
 from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                     SAVED_NAMES)
 from horovod_tpu.utils import trace
@@ -278,7 +278,7 @@ def test_recomputed_blocks_of_a_mixed_pattern_give_the_same_gradients():
     got = jax.grad(loss(Transformer(config(remat=True))))(params)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
-    assert issubclass(recomputed(Block, cfg), nn.Module)
+    assert issubclass(keeping(Block, kept_names(cfg)), nn.Module)
     # the recomputation of a conv block makes ``in`` again and not
     # ``out``, whose result is in the kept sum
     jaxpr = jax.make_jaxpr(jax.grad(loss(Transformer(config(remat=True)))))(

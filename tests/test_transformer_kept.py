@@ -1,12 +1,14 @@
 """What a recomputed block keeps from its forward pass
-(``models/transformer.py:recomputed``, ``kept_names``, ``kept_bytes``):
+(``models/transformer.py:keeping``, ``kept_names``, ``kept_bytes``):
 beside the flash kernel's output and lse, the sum after attention, the
 kernel's q, k and v where none is larger than its output, and latent
 attention's two narrow first products, and of a routed layer what its
 routing decided (the experts and the sorted order of the slots);
-under a loop over passes the kernel's two results alone.  The values
-are the ones the recomputation would have made, so nothing changes but
-what runs twice."""
+under a loop over passes the kernel's two results alone.  And what a
+layer keeps beyond that where the device has room (``kept_plan``,
+``kept_products``): the results of its products, planned from bytes.
+The values are the ones the recomputation would have made, so nothing
+changes but what runs twice."""
 
 import os
 import re
@@ -23,8 +25,11 @@ from jax._src import core
 from horovod_tpu.models import (BlockSpec, GroupedAttention, LatentAttention,
                                 Rotary, TopkExperts, Transformer,
                                 TransformerConfig, lm_loss, transformer)
-from horovod_tpu.models.transformer import (KEPT_KV_A, KEPT_NAMES, KEPT_Q_A,
-                                            KEPT_SUM, kept_bytes, kept_names)
+from horovod_tpu.models.transformer import (
+    KEPT_GATE, KEPT_IN, KEPT_KV_A, KEPT_KV_B, KEPT_NAMES, KEPT_Q_A, KEPT_Q_B,
+    KEPT_QKV, KEPT_SUM, KEPT_UP, ChunkSummaryAttention, MemoryUnit,
+    SelectiveScan, ShortConv, kept_bytes, kept_names, kept_plan,
+    kept_products)
 from horovod_tpu.ops.pallas import flash_attention
 from horovod_tpu.ops.pallas.flash_attention import (SAVED_INPUT_NAMES,
                                                     SAVED_LSE, SAVED_NAMES,
@@ -58,6 +63,17 @@ KINDS = {
         norm="rms", positions="rope", ffn="swiglu",
         norm_placement="sandwich")),
 }
+# mixers that are no attention, and attention by chunk summaries (two
+# windows of 8 in the 16 positions): what the plan's names are for
+KINDS["conv"] = dict(block=BlockSpec(norm="rms", positions="rope",
+                                     ffn="swiglu", attention=ShortConv()))
+KINDS["hybrid"] = dict(pattern=tuple(
+    BlockSpec(positions="none", ffn="swiglu", attention=mixer) for mixer in (
+        SelectiveScan(d_inner=48, dt_rank=4, state=4, publishes=True),
+        MemoryUnit(48))))
+KINDS["chunk"] = dict(block=BlockSpec(
+    norm="rms", positions="rope", ffn="swiglu",
+    attention=ChunkSummaryAttention(heads=4, head_dim=8, window=8, chunk=4)))
 # routed under a bias: 3 of 8 experts a token, a shared one beside them
 ROUTED = dict(n_experts=8, experts_per_token=3, d_expert=16)
 BIAS = 0.3 * jax.random.normal(jax.random.PRNGKey(5), (2, 8))
@@ -91,17 +107,40 @@ def model_and_loss(kind, **changes):
         model.apply({"params": p}, TOKENS, **bias), TOKENS)
 
 
-def plain_remat(block, cfg):
-    """What ``recomputed`` was before it had a policy."""
-    return nn.remat(block)
+def keep_nothing(monkeypatch):
+    """Every block under a plain ``nn.remat``, whatever its plan."""
+    monkeypatch.setattr(transformer, "keeping",
+                        lambda block, names: nn.remat(block))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("kind", ["grouped_window", "grouped_full", "latent",
-                                  "plain", "routed_held", "routed_all"])
+ROOM = 2 ** 40  # a device on which every product's result fits
+# what a layer of each kind keeps beyond ``kept_names`` where all fits, in
+# the order the plan adds it (by worth, then as the block makes it)
+PRODUCTS = {
+    "grouped_full": (KEPT_GATE, KEPT_UP), "plain": (KEPT_UP,),
+    "latent": (KEPT_GATE, KEPT_UP, KEPT_Q_B, KEPT_KV_B),
+    "conv": (KEPT_IN, KEPT_GATE, KEPT_UP),
+    "chunk": KEPT_QKV + (KEPT_GATE, KEPT_UP),
+    # the shared expert's pair; all rows of the buffer exist without
+    # ``held``, 3 * 4 / 8 of 3 a token with 4 of 8 held
+    "routed_all": (KEPT_GATE, KEPT_UP) + moe.PRODUCT_NAMES,
+    "routed_held": (KEPT_GATE, KEPT_UP) + moe.PRODUCT_NAMES}
+
+
+def with_room(monkeypatch, room=ROOM):
+    monkeypatch.setattr(transformer, "device_memory_bytes", lambda: room)
+
+
+@pytest.mark.parametrize("kind,dtype,room", [
+    (kind, dtype, False)
+    for kind in ("grouped_window", "grouped_full", "latent", "plain",
+                 "routed_held", "routed_all")
+    for dtype in (jnp.float32, jnp.bfloat16)] + [
+    (kind, jnp.float32, True) for kind in sorted(PRODUCTS) + ["hybrid"]],
+    ids=lambda v: {jnp.float32: "f32", jnp.bfloat16: "bf16", True: "room",
+                   False: "no-limit"}.get(v, v))
 def test_recomputed_blocks_give_the_loss_and_gradients_of_kept_ones(
-        kind, dtype, monkeypatch):
+        kind, dtype, room, monkeypatch):
     """Instruction by instruction (no ``jit`` around the gradient; compiled
     whole, two programs round a sum in different places) every kept array
     is the array the recomputation would have made: against a plain
@@ -109,16 +148,22 @@ def test_recomputed_blocks_give_the_loss_and_gradients_of_kept_ones(
     differs in either type; against ``remat=False`` they agree as
     ``test_transformer_remat_matches_dense`` has it (to the last bit in
     all but the latent kind's loss, with or without this policy), in
-    bfloat16 to that type's step."""
+    bfloat16 to that type's step.  With ``room`` the device tells a
+    limit under which everything fits, and every layer keeps the results
+    of its products too."""
     def run(remat):
         _, params, loss = model_and_loss(kind, remat=remat, dtype=dtype)
         return jax.value_and_grad(loss)(params)
 
+    if room:
+        with_room(monkeypatch)
+        cfg, _, _ = model_and_loss(kind, remat=True, dtype=dtype)
+        assert all(kept_plan(cfg, *TOKENS.shape, ROOM).names)
     got, want = run(True), run(False)
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    keep_nothing(monkeypatch)
     plain = run(True)
-    assert float(jnp.max(jnp.abs(
-        want[1]["block_0"]["attn"]["out"]["kernel"]))) > 0
+    assert max(float(jnp.max(jnp.abs(leaf)))
+               for leaf in jax.tree.leaves(want[1]["block_0"])) > 0
     tolerance = (dict(rtol=2e-5, atol=2e-6) if dtype == jnp.float32
                  else dict(rtol=2 ** -7, atol=2 ** -9))
     for (path, g), w, p in zip(jax.tree_util.tree_flatten_with_path(got)[0],
@@ -262,7 +307,7 @@ def test_an_unnamed_routed_block_decides_twice(monkeypatch):
     a plain ``nn.remat``: ``top_k`` and the ``argsort`` run again."""
     _, params, loss = model_and_loss("routed_held", remat=True)
     named = routing(loss, params)
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    keep_nothing(monkeypatch)
     _, params, loss = model_and_loss("routed_held", remat=True)
     plain = routing(loss, params)
     assert plain[:2] == (2 * named[0], 2 * named[1])
@@ -299,12 +344,17 @@ def test_kept_bytes_of_a_routed_layer_are_what_the_shapes_give(kind):
     assert not set(moe.SAVED_NAMES) & set(kept_bytes(looped, 2, 16))
 
 
-@pytest.mark.parametrize("kind", sorted(KINDS))
-def test_kept_bytes_are_what_the_backward_pass_is_handed(kind, monkeypatch):
+@pytest.mark.parametrize("kind,room", [(kind, False) for kind in sorted(
+    set(KINDS) - {"conv", "hybrid", "chunk"})] + [
+        (kind, True) for kind in sorted(PRODUCTS)],
+    ids=lambda v: {True: "room", False: "no-limit"}.get(v, v))
+def test_kept_bytes_are_what_the_backward_pass_is_handed(kind, room,
+                                                         monkeypatch):
     """``kept_bytes`` against ``jax.ad_checkpoint``'s own account of the
     residuals: what a recomputed block hands its backward pass beyond a
     plain ``nn.remat``'s has the bytes the function gives by name
-    (stacked over the passes under the scan)."""
+    (stacked over the passes under the scan); with ``room`` also the
+    results of the layer's products, each once a layer."""
     from jax._src.ad_checkpoint import saved_residuals
 
     def handed():
@@ -312,16 +362,27 @@ def test_kept_bytes_are_what_the_backward_pass_is_handed(kind, monkeypatch):
         return cfg, [int(np.prod(aval.shape)) * aval.dtype.itemsize
                      for aval, _ in saved_residuals(loss, params)]
 
+    if room:
+        with_room(monkeypatch)
     cfg, named = handed()
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    keep_nothing(monkeypatch)
     _, plain = handed()
+    plan = kept_plan(cfg, *TOKENS.shape, ROOM if room else None)
+    assert plan.names == ((PRODUCTS[kind] if room else ()),) * cfg.n_layers
+    # (the dense reference the CPU takes for chunk-summary attention is no
+    # kernel and names no ``out`` and ``lse``)
     more = [cfg.passes * n for layer in range(cfg.n_layers)
-            for n in kept_bytes(cfg, *TOKENS.shape, layer).values()]
+            for name, n in {
+                **kept_bytes(cfg, *TOKENS.shape, layer),
+                **kept_bytes(cfg, *TOKENS.shape, layer,
+                             plan.names[layer])}.items()
+            if kind != "chunk" or name not in SAVED_NAMES]
     beyond = Counter(named) - Counter(plain + more)
     # a routed block that decides once has no use for the bias in its
-    # backward pass: the row a plain ``nn.remat`` hands on is not handed
+    # backward pass: the row a plain ``nn.remat`` hands on is not handed;
+    # nor is ``mu [4, 8]``, which made the value summaries that are kept
     unread = ({BIAS[0].nbytes: cfg.n_layers} if kind.startswith("routed")
-              else {})
+              else {4 * 8 * 4: cfg.n_layers} if kind == "chunk" else {})
     assert Counter(plain + more) - Counter(named) == Counter(unread)
     # a jitted function that reads a kept array hands it on to the
     # backward half: a second time in this account, the same array in the
@@ -440,3 +501,190 @@ def test_kept_bytes_of_the_cells_blocks_by_hand(cell_config, workload, layer,
         routed = [sum(n for name, n in kept_bytes(cfg, batch, seq, i).items()
                       if name in moe.SAVED_NAMES) for i in range(5)]
         assert routed == [0] + [1_048_576] * 4
+
+
+V5E = 16_911_433_728  # what a v5e's ``memory_stats()["bytes_limit"]`` says
+GATE_UP = (KEPT_GATE, KEPT_UP)
+EXPERT_GATE, EXPERT_UP, EXPERT_DOWN = moe.PRODUCT_NAMES
+
+
+@pytest.mark.parametrize("workload,names,bytes_of,kept,params,peak", [
+    # room for every product there is: ``in`` [2, 8192, 6144] of the four
+    # conv mixers, the dense layer's pair [2, 8192, 11776] and the experts'
+    # three results over the buffer of 65,536 rows; 3.97 GiB more than the
+    # 0.785 of rung 0.  The peak: 16 bytes a parameter, what is kept, and
+    # block 0's moment (its input and sum, 2 x 67.1 MB, and ``in`` and the
+    # pair made again and their cotangents, 2 x 973.1 MB)
+    ("lfm2_24b_a2b-spmd-1chip",
+     [(KEPT_IN,) + GATE_UP, moe.PRODUCT_NAMES]
+     + [(KEPT_IN,) + moe.PRODUCT_NAMES] * 3,
+     {(0, KEPT_IN): 201_326_592, (0, KEPT_GATE): 385_875_968,
+      (1, EXPERT_UP): 201_326_592, (2, EXPERT_DOWN): 268_435_456},
+     5_104_467_968, 486_062_208,
+     16 * 486_062_208 + 5_104_467_968 + 134_217_728 + 2 * 973_078_528),
+    # the four cells below: at 16 bytes a parameter rung 0 alone is over
+    # the line of 14.96 GiB (their steps compile to 13.1-14.9 with a fused
+    # Adam, which holds the parameters three times: the room under the line
+    # there is the gradient tree's), so nothing is kept
+    ("phi4_mini_flash-spmd-1chip", [()] * 6,
+     {(0, KEPT_IN): 335_544_320, (0, KEPT_UP): 335_544_320,
+      (4, KEPT_IN): 167_772_160, (1, KEPT_GATE): 335_544_320},
+     2_650_275_840, 697_094_272, 16_236_480_512),
+    ("laguna_s_2_1-spmd-1chip", [()] * 5,
+     {(0, KEPT_GATE): 201_326_592, (1, KEPT_GATE): 16_777_216,
+      (1, EXPERT_GATE): 134_217_728, (1, EXPERT_DOWN): 402_653_184},
+     1_992_294_400, 811_017_216, 16_817_012_736),
+    # the next-token module's block is the sixth
+    ("joyai_llm_flash-spmd-1chip", [()] * 6,
+     {(0, KEPT_GATE): 234_881_024, (0, KEPT_Q_B): 201_326_592,
+      (0, KEPT_KV_B): 268_435_456, (1, KEPT_UP): 25_165_824,
+      (1, EXPERT_GATE): 201_326_592},
+     2_043_674_624, 680_439_808, 16_720_265_216),
+    ("evabyte_6_5b-spmd-1chip", [()] * 4,
+     {(0, KEPT_QKV[0]): 134_217_728, (3, KEPT_UP): 360_710_144},
+     3_305_111_552, 821_366_784, 19_521_404_928),
+    # under the passes the plan is ``kept_names`` whatever the room
+    ("ouro_2_6b-spmd-1chip", [()] * 8, {}, 8 * 33_816_576, None, None),
+], ids=["lfm2", "phi4", "laguna", "joyai", "evabyte", "ouro"])
+def test_kept_plan_of_the_cells_by_hand(cell_config, monkeypatch, workload,
+                                        names, bytes_of, kept, params, peak):
+    """What the plan keeps in each recomputing cell on a v5e, the bytes
+    of the new names in closed form, the parameters as ``PERF.md`` section
+    4 counts them, and the plan's shape: rung 0 everywhere where the
+    backend tells no limit, never less kept on a larger device, the
+    predicted peak within 95% of the device wherever a name is kept."""
+    cfg, batch, seq = cell_config(workload)
+    module = workload.startswith("joyai")
+    plan = kept_plan(cfg, batch, seq, V5E, next_token=module)
+    assert list(plan.names) == [tuple(n) for n in names]
+    assert plan.rungs == tuple(int(bool(n)) for n in names)
+    assert (sum(plan.kept), plan.peak) == (kept, peak)
+    for (layer, name), n in bytes_of.items():
+        assert kept_bytes(cfg, batch, seq, layer, (name,)) == {name: n}
+    nothing = kept_plan(cfg, batch, seq, None, next_token=module)
+    assert not any(nothing.names) and nothing.peak is None
+    assert [k - sum(kept_bytes(cfg, batch, seq, i).values())
+            for i, k in enumerate(nothing.kept[:cfg.n_layers])] == [
+                batch * seq * cfg.d_model * jnp.dtype(
+                    cfg.residual_dtype or cfg.dtype).itemsize] * cfg.n_layers
+    if peak is None:
+        return
+    assert plan.params == 4 * params
+    assert plan.budget == int(0.95 * V5E)
+    assert plan.peak <= plan.budget or not any(plan.names)
+    # the chips of PR 50's runs said 2 MiB less: the same plan
+    assert kept_plan(cfg, batch, seq, 16_909_336_064, module).names == (
+        plan.names)
+    last = None
+    for gib in (8, 13, 14.5, 15, 15.5, 15.75, 16, 18, 32):
+        at = kept_plan(cfg, batch, seq, int(gib * 2 ** 30), module)
+        assert at.peak <= at.budget or not any(at.names)
+        assert last is None or sum(at.kept) >= sum(last.kept)
+        last = at
+    assert not any(kept_plan(cfg, batch, seq, 8 << 30, module).names)
+    # a device with room for the gradient tree beside what these steps
+    # take today keeps something in every cell
+    assert any(kept_plan(cfg, batch, seq, 24 << 30, module).names)
+
+
+def test_a_budget_for_one_layer_of_two_keeps_the_first_alone(monkeypatch):
+    """Two equal layers and a device that has room for one ``up``: the
+    first layer stands on rung 1, the second on rung 0, the model's
+    blocks are built so, and the gradient is the gradient."""
+    cfg, params, loss = model_and_loss("plain", remat=True)
+    up = kept_bytes(cfg, *TOKENS.shape, 0, (KEPT_UP,))[KEPT_UP]
+    assert up == TOKENS.size * cfg.d_ff * 4
+    assert kept_products(cfg, 0) == kept_products(cfg, 1) == [
+        ((KEPT_UP,), cfg.d_model)]
+    floor = kept_plan(cfg, *TOKENS.shape, 1)
+    assert floor.rungs == (0, 0) and floor.peak > floor.budget == 0
+    room = -(-(floor.peak + up + up // 2) * 20 // 19)  # / 0.95, rounded up
+    plan = kept_plan(cfg, *TOKENS.shape, room)
+    assert plan.names == ((KEPT_UP,), ()) and plan.rungs == (1, 0)
+    assert plan.kept == (floor.kept[0] + up, floor.kept[1])
+    assert plan.peak == floor.peak + up <= plan.budget < floor.peak + 2 * up
+    assert "0: 1 (+ mlp_up), 1: 0" in str(plan)
+    with_room(monkeypatch, room)
+    again = products(recomputation(loss, params))
+    assert again == ["mlp/up"]
+    assert "block_1" in [stack for name, stack, _ in recomputation(
+        loss, params) if name == "dot_general"][0]
+    want = jax.grad(model_and_loss("plain")[2])(params)
+    for g, w in zip(jax.tree.leaves(jax.grad(loss)(params)),
+                    jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("kind,name", [("plain", KEPT_GATE),
+                                       ("conv", KEPT_Q_B),
+                                       ("latent", KEPT_IN)])
+def test_a_name_a_layer_has_no_bytes_for_is_an_error(kind, name):
+    """A product listed for a layer (``kept_products``) whose bytes
+    ``kept_bytes`` does not know is no free product: asked for by name
+    it is a ``KeyError``.  Of ``kept_names(cfg)``, one list for every
+    layer of a pattern, a layer gives what it sets."""
+    cfg, _, _ = model_and_loss(kind, remat=True)
+    with pytest.raises(KeyError, match=name):
+        kept_bytes(cfg, *TOKENS.shape, 0, (name,))
+    assert set(kept_bytes(cfg, *TOKENS.shape)) < set(kept_names(cfg))
+    for group, _ in kept_products(cfg, 0):
+        assert set(kept_bytes(cfg, *TOKENS.shape, 0, group)) == set(group)
+
+
+def test_a_batch_sharded_under_jit_is_planned_whole(monkeypatch):
+    """Under a plain ``jit`` whose batch is sharded over four devices the
+    trace sees the GLOBAL batch, so the plan reckons all four devices'
+    activations (and the parameters whole) on one: it keeps no more than
+    the plan of a device's own quarter would, each device of the compiled
+    step holds less than one device would of the whole batch, and less
+    than the plan predicts.  (This backend's buffers do not follow what
+    is kept, so its bytes are no yardstick for one device's plan:
+    ``tests/test_chip_compile.py`` holds that against a described
+    chip's compiler.)"""
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    batch, seq = 64, 16
+    cfg = TransformerConfig(vocab_size=64, n_layers=2, d_model=64, n_heads=4,
+                            d_ff=512, max_len=seq, remat=True,
+                            dtype=jnp.float32)
+    model = Transformer(cfg)
+    opt = optax.chain(optax.clip_by_global_norm(1.0), optax.adam(1e-3))
+    up = kept_bytes(cfg, batch, seq, 0, (KEPT_UP,))[KEPT_UP]
+    # room for one layer's ``up`` of the whole batch: for both of a quarter
+    room = -(-(kept_plan(cfg, batch, seq, 1).peak + up + up // 2) * 20 // 19)
+    with_room(monkeypatch, room)
+    planned = []
+    monkeypatch.setattr(transformer, "kept_plan", lambda *args: planned.append(
+        (args[1:4], kept_plan(*args))) or planned[-1][1])
+
+    def compiled(devices):
+        def step(params, state, tokens):  # a trace of its own a call
+            grads = jax.grad(lambda p: lm_loss(
+                model.apply({"params": p}, tokens), tokens))(params)
+            updates, state = opt.update(grads, state, params)
+            return optax.apply_updates(params, updates), state
+
+        mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+
+        def over(tree, spec):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+        tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                tokens)["params"]
+        mem = jax.jit(step, donate_argnums=(0, 1)).lower(
+            over(params, P()), over(jax.eval_shape(opt.init, params), P()),
+            over(tokens, P("dp"))).compile().memory_analysis()
+        return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+    one, four = compiled(1), compiled(4)
+    # (an initialization differentiates nothing and is told no limit)
+    assert [args for args, _ in planned if args[2]] == [
+        (batch, seq, room)] * 2
+    plan = planned[-1][1]
+    assert plan.names == ((KEPT_UP,), ())
+    assert kept_plan(cfg, batch // 4, seq, room).names == ((KEPT_UP,),) * 2
+    assert four < one and four < plan.peak

@@ -20,7 +20,8 @@ from horovod_tpu.models import (BlockSpec, GroupedAttention,
                                 Transformer, TransformerConfig,
                                 apply_with_aux, lm_loss, looped_lm_loss,
                                 transformer)
-from horovod_tpu.models.transformer import Block, make_norm, recomputed
+from horovod_tpu.models.transformer import (Block, keeping, kept_names,
+                                            make_norm)
 from horovod_tpu.ops.pallas import flash_attention
 from horovod_tpu.parallel import reference_attention
 
@@ -85,7 +86,7 @@ class TransformerBefore(nn.Module):
     """``Transformer.__call__`` as it stood before the passes, the
     sandwich norm and the gate, written out: what the cells that have
     none of them ran (a recomputed block saves what the model's does:
-    ``recomputed``)."""
+    ``keeping`` what ``kept_names`` lists)."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -97,7 +98,8 @@ class TransformerBefore(nn.Module):
             x = x + nn.Embed(
                 cfg.max_len, cfg.d_model, dtype=cfg.dtype,
                 name="pos_embed")(jnp.arange(tokens.shape[-1]))
-        block_cls = recomputed(Block, cfg) if cfg.remat else Block
+        block_cls = (keeping(Block, kept_names(cfg)) if cfg.remat
+                     else Block)
         rows = 0
         for i in range(cfg.n_layers):
             ffn = cfg.ffn_of(i)
@@ -343,7 +345,7 @@ RECOMPUTED = {
 }
 
 
-def plain_remat(block, cfg):
+def plain_remat(block, names):
     return nn.remat(block)
 
 
@@ -379,7 +381,7 @@ def test_a_recomputed_block_runs_each_flash_kernel_once(kind, monkeypatch):
     cfg, params, loss = recomputed_loss(kind)
     n = cfg.n_layers
     assert kernel_calls(loss, params) == (n, n)
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    monkeypatch.setattr(transformer, "keeping", plain_remat)
     assert kernel_calls(loss, params) == (2 * n, n)
 
 
@@ -393,7 +395,7 @@ def test_saving_the_kernels_results_changes_no_bit(kind, monkeypatch):
     order, 1e-7 apart in float32.)"""
     _, params, loss = recomputed_loss(kind)
     got = jax.value_and_grad(loss)(params)
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    monkeypatch.setattr(transformer, "keeping", plain_remat)
     want = jax.value_and_grad(loss)(params)
     assert float(jnp.max(jnp.abs(got[1]["block_0"]["attn"]["out"]["kernel"]
                                  ))) > 0
@@ -423,7 +425,7 @@ def test_a_recomputed_block_saves_what_its_attention_names(kind,
     cfg, params, flash = recomputed_loss(kind)
     _, _, dense = recomputed_loss(kind, reference_attention)
     named, unnamed = saved(flash, params), saved(dense, params)
-    monkeypatch.setattr(transformer, "recomputed", plain_remat)
+    monkeypatch.setattr(transformer, "keeping", plain_remat)
     plain, plain_dense = saved(flash, params), saved(dense, params)
     b, t = TOKENS.shape
     spec = cfg.block.attention

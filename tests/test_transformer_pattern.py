@@ -14,7 +14,7 @@ import pytest
 
 from horovod_tpu.models import (BlockSpec, GroupedAttention, Rotary,
                                 TopkExperts, Transformer, TransformerConfig)
-from horovod_tpu.models.transformer import Block, recomputed
+from horovod_tpu.models.transformer import Block, keeping, kept_names
 from horovod_tpu.parallel.ring_attention import reference_attention
 from horovod_tpu.utils import trace
 
@@ -153,7 +153,7 @@ def test_recomputed_blocks_of_a_pattern_give_the_same_gradients():
     got = jax.grad(loss(Transformer(config(remat=True))))(params)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
-    assert issubclass(recomputed(Block, cfg), nn.Module)
+    assert issubclass(keeping(Block, kept_names(cfg)), nn.Module)
 
 
 def test_the_compiled_step_carries_the_four_scopes():
