@@ -107,4 +107,4 @@ from horovod_tpu.sharding import (  # noqa: F401
     reshard_zero_state,
 )
 from horovod_tpu.common.compression import Compression  # noqa: F401
-from horovod_tpu.utils.trace import eager_stats  # noqa: F401
+from horovod_tpu.utils.trace import eager_stats, input_stats  # noqa: F401

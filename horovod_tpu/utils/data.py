@@ -269,6 +269,8 @@ def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
     """
     import jax
 
+    from horovod_tpu.utils import trace
+
     if size <= 0:
         raise ValueError(f"size must be > 0, got {size}")
     if sharding is not None and mesh is not None:
@@ -304,13 +306,35 @@ def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
                 continue
         return False
 
+    # What the path records about itself (utils/trace.py, always on):
+    # three spans on the profiler's clock, and per batch the producer's
+    # stamps, which ride through the queue beside the batch and become
+    # one record of trace.BATCHES when the consumer takes it.
+    def stage(batch_id, batch, t_next_start):
+        # a frame of its own: nothing of the device batch outlives the
+        # _put below in the producer's locals
+        t_host_ready = trace.now()
+        with trace.span("hvd.data.put", batch=batch_id):
+            staged = jax.tree.map(put, batch)
+        return staged, trace.batch_staged(batch_id, staged, t_next_start,
+                                          t_host_ready)
+
     def producer():
         try:
-            for batch in iterator:
+            source = iter(iterator)
+            while True:
+                batch_id = next(trace.batch_ids)
+                t_next_start = trace.now()
+                with trace.span("hvd.data.next", batch=batch_id):
+                    # the host batch lives until this next() returns,
+                    # as under ``for batch in iterator``
+                    batch = next(source, sentinel)
+                if batch is sentinel:
+                    break
                 if stop.is_set() or \
-                        not _put(jax.tree.map(put, batch)):
+                        not _put(stage(batch_id, batch, t_next_start)):
                     return
-            _put(sentinel)
+            _put((sentinel, None))
         except BaseException as exc:  # noqa: BLE001 — re-raised consumer-side
             _put((sentinel, exc))
 
@@ -322,13 +346,18 @@ def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
     def consume():
         try:
             while True:
-                item = q.get()
-                if item is sentinel:
+                t_asked = trace.now()
+                depth_at_ask = q.qsize()
+                with trace.span("hvd.data.wait") as wait:
+                    batch, staged = q.get()
+                    if batch is not sentinel:
+                        wait.set_metadata(batch=staged[0])
+                if batch is sentinel:
+                    if staged is not None:
+                        raise staged
                     return
-                if isinstance(item, tuple) and len(item) == 2 \
-                        and item[0] is sentinel:
-                    raise item[1]
-                yield item
+                trace.batch_taken(staged, t_asked, depth_at_ask)
+                yield batch
         finally:
             # consumer done (exhausted, errored, or closed early):
             # release the producer and any queued device batches.  One

@@ -1,8 +1,10 @@
 """What the program records about itself: the eager plane's request
-lifecycle where the work happens, and the compiled SPMD step by phase
-and scope from the names its own instructions carry.
+lifecycle and the input path's batches where the work happens, and the
+compiled SPMD step by phase and scope from the names its own
+instructions carry.
 
-The eager plane has two instruments, both always on
+The eager plane and the input path (``utils/data.py``'s
+``prefetch_to_device``) have two instruments each, always on
 (docs/observability.md); the SPMD step's is at the end of this module
 (:func:`step_phases`):
 
@@ -17,6 +19,8 @@ The eager plane has two instruments, both always on
       dispatcher  hvd.wait_batch  hvd.decode  hvd.mark_done
                   hvd.execute > hvd.exec.{assemble, lookup, launch,
                                           complete}
+      step loop   hvd.data.wait
+      producer    hvd.data.next  hvd.data.put
 
   An allreduce response is those four under its ``hvd.execute``:
   ``assemble`` hands the ranks' own tensors to the collective program
@@ -24,7 +28,11 @@ The eager plane has two instruments, both always on
   The other collectives stage a buffer first: ``hvd.exec.fuse_in``
   (reduce_scatter, broadcast, adasum: a small jitted program a rank) and
   ``hvd.exec.stack`` (those and allgather, alltoall) in ``assemble``'s
-  place.
+  place.  The three of the input path carry their batch's id as the
+  annotation's argument ``batch``: ``next`` is the source iterator's
+  work for one host batch, ``put`` the batch onto the device, both on
+  the prefetcher's thread; ``wait`` is what one ``next()`` on the
+  prefetcher cost the loop that trains.
 
 - **A request log.**  One tuple per finished request, in
   ``time.perf_counter_ns()``:
@@ -38,11 +46,27 @@ The eager plane has two instruments, both always on
   fused response share its response id.  The log is the module's, so it
   outlives ``hvd.shutdown()`` (a benchmark reads it after closing its
   loop); ``hvd.init()`` and :func:`reset` empty it.
+
+- **A batch log**, :data:`BATCHES`, kept like the request log.  One
+  tuple per batch a consumer TOOK from a prefetcher, on the same clock,
+  appended on the consumer's thread (a batch drained at shutdown leaves
+  none):
+
+      (batch id, bytes,
+       t_next_start, t_host_ready, t_put_end,   the producer's
+       t_asked, t_taken,                        the consumer's
+       depth_at_ask, ready_at_take)
+
+  ``depth_at_ask`` is the queue's length when the loop asked (0: nothing
+  was staged, the loop waits); ``ready_at_take`` is ``is_ready()`` of
+  the batch's largest array when it is handed over (``device_put`` may
+  return before the copy ends; nothing blocks to find out).
 """
 
 import collections
 import itertools
 import re
+import statistics
 import time
 
 import jax
@@ -51,8 +75,10 @@ span = jax.profiler.TraceAnnotation
 now = time.perf_counter_ns
 
 LOG = collections.deque(maxlen=65536)
+BATCHES = collections.deque(maxlen=65536)
 _request_ids = itertools.count(1)
 _response_ids = itertools.count(1)
+batch_ids = itertools.count(1)  # one sequence for every prefetcher
 
 
 def submitted(handle, t_submit):
@@ -84,6 +110,7 @@ def finished(handle):
 
 def reset():
     LOG.clear()
+    BATCHES.clear()
 
 
 def eager_stats():
@@ -104,6 +131,47 @@ def eager_stats():
             submit_us=sum(r[3] - r[2] for r in records) / n / 1e3,
             queue_wait_us=sum(r[4] - r[3] for r in records) / n / 1e3,
             execute_us=sum(responses.values()) / len(responses) / 1e3)
+    return stats
+
+
+def batch_staged(batch_id, batch, t_next_start, t_host_ready):
+    """``put`` has returned ``batch``: the producer's half of its
+    record, which rides through the prefetcher's queue beside it (with
+    the batch's largest array in the last place, for
+    :func:`batch_taken` to ask whether the copy has ended)."""
+    t_put_end = now()
+    leaves = jax.tree.leaves(batch)
+    sizes = [leaf.nbytes for leaf in leaves]
+    return (batch_id, sum(sizes), t_next_start, t_host_ready, t_put_end,
+            leaves[sizes.index(max(sizes))] if leaves else None)
+
+
+def batch_taken(staged, t_asked, depth_at_ask):
+    """The consumer has the batch whose producer's half is ``staged``
+    in hand: the batch's line of the log, on the consumer's thread."""
+    *stamps, largest = staged
+    BATCHES.append((*stamps, t_asked, now(), depth_at_ask,
+                    largest is None or largest.is_ready()))
+
+
+def input_stats():
+    """The input path's read-out over the batch log (the last 65,536
+    batches taken since ``hvd.init()``): how many and how many bytes,
+    the share a loop had to wait for (nothing staged when it asked, or
+    the copy still under way when it took), what one ``next()`` cost
+    the loop on average (``wait_ms``), and the medians of the source
+    iterator's work (``source_ms``) and of the move onto the device
+    (``put_ms``) for one batch.  Beside :func:`eager_stats`."""
+    records = list(BATCHES)
+    stats = {"batches": len(records),
+             "bytes": sum(r[1] for r in records)}
+    if records:
+        stats.update(
+            starved_share=sum(r[7] == 0 or not r[8]
+                              for r in records) / len(records),
+            wait_ms=sum(r[6] - r[5] for r in records) / len(records) / 1e6,
+            source_ms=statistics.median(r[3] - r[2] for r in records) / 1e6,
+            put_ms=statistics.median(r[4] - r[3] for r in records) / 1e6)
     return stats
 
 

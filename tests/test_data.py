@@ -289,3 +289,151 @@ def test_prefetch_close_releases_all_staged_batches(monkeypatch):
     alive = sum(r() is not None for r in refs)
     assert alive == 0, f"{alive} staged device batches still pinned"
     assert it is not None
+
+
+# ------------------------- what the path records about itself
+# (horovod_tpu/utils/trace.py: the hvd.data.* spans and the batch log)
+@pytest.fixture
+def batch_log():
+    from horovod_tpu.utils import trace
+
+    trace.reset()
+    yield trace.BATCHES
+    trace.reset()
+
+
+def _small_batches(n, sleep=0.0):
+    import time
+
+    for i in range(n):
+        time.sleep(sleep)
+        yield {"x": np.full((4, 2), i, np.float32),
+               "y": np.full((4,), i, np.int32)}
+
+
+def test_prefetch_spans_join_by_batch_id_across_two_threads(
+        batch_log, tmp_path):
+    """Under a profiler trace the three spans are in the profiler's own
+    file, on ``/host:CPU``: ``hvd.data.next`` and ``hvd.data.put`` on
+    the producer's line, ``hvd.data.wait`` on the caller's, and the
+    spans of one batch carry one id."""
+    import glob
+    import os
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        taken = list(prefetch_to_device(_small_batches(4)))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(taken) == 4
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    lines = [
+        [(e.name, dict(e.stats).get("batch"), e.start_ns,
+          e.start_ns + e.duration_ns)
+         for e in line.events if e.name.startswith("hvd.data.")]
+        for plane in jax.profiler.ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU" for line in plane.lines]
+    (caller,) = [line for line in lines
+                 if any(name == "hvd.data.wait" for name, *_ in line)]
+    (producer,) = [line for line in lines if line and line is not caller]
+    assert {name for name, *_ in caller} == {"hvd.data.wait"}
+    assert {name for name, *_ in producer} == {"hvd.data.next",
+                                                "hvd.data.put"}
+    ids = [record[0] for record in batch_log]
+    assert len(ids) == 4 and ids == sorted(set(ids))
+    by_name = {name: {batch: (start, end) for n, batch, start, end in line
+                      if n == name and batch is not None}
+               for line in (caller, producer) for name, *_ in line}
+    for batch in ids:
+        source, put, wait = (by_name[name][batch] for name in (
+            "hvd.data.next", "hvd.data.put", "hvd.data.wait"))
+        # made, moved, then handed over; the wait may have begun early
+        assert source[1] <= put[0] and put[1] <= wait[1]
+    # the asks that ended the epoch: a source call and a wait, no batch
+    assert len(caller) == 5 and len(producer) == 4 * 2 + 1
+    assert [batch for _, batch, *_ in caller].count(None) == 1
+
+
+def test_prefetch_log_is_ordered_and_every_stamp_monotone(batch_log):
+    taken = list(prefetch_to_device(_small_batches(6), size=2))
+    assert len(taken) == len(batch_log) == 6
+    ids = [record[0] for record in batch_log]
+    assert ids == sorted(set(ids))
+    for record in batch_log:
+        assert len(record) == 9
+        assert record[1] == 4 * 2 * 4 + 4 * 4  # the batch's bytes
+        t_next_start, t_host_ready, t_put_end, t_asked, t_taken = record[2:7]
+        assert 0 < t_next_start <= t_host_ready <= t_put_end <= t_taken
+        assert t_asked <= t_taken
+        assert 0 <= record[7] <= 2 and isinstance(record[8], bool)
+    # one producer: a batch is made after the one before it was staged
+    for before, after in zip(batch_log, list(batch_log)[1:]):
+        assert before[4] <= after[2] and before[6] <= after[5]
+
+
+def test_a_slow_source_reads_as_a_starved_loop(batch_log):
+    """The loop asks, finds nothing staged and waits about as long as
+    the source takes for one batch."""
+    import horovod_tpu as hvd
+
+    list(prefetch_to_device(_small_batches(5, sleep=0.05)))
+    assert [record[7] for record in batch_log] == [0] * 5
+    for record in batch_log:
+        assert 0.045e9 <= record[3] - record[2]   # the source's work
+        assert 0.03e9 <= record[6] - record[5] <= 0.5e9  # the loop's wait
+    stats = hvd.input_stats()
+    assert stats["batches"] == 5 and stats["starved_share"] == 1.0
+    assert stats["source_ms"] >= 45 and stats["wait_ms"] >= 30
+
+
+def test_a_slow_consumer_finds_the_queue_full_and_does_not_wait(batch_log):
+    import time
+
+    import horovod_tpu as hvd
+
+    import jax
+
+    jax.device_put(0)  # the backend is up before the clock matters
+    it = prefetch_to_device(_small_batches(8), size=2)
+    for _ in range(4):
+        time.sleep(0.15)  # the step; the producer refills meanwhile
+        next(it)
+    it.close()
+    assert [record[7] for record in batch_log] == [2] * 4
+    assert all(record[8] for record in batch_log)
+    assert max(record[6] - record[5] for record in batch_log) < 0.05e9
+    assert hvd.input_stats()["starved_share"] == 0.0
+
+
+def test_a_prefetcher_closed_early_logs_only_the_batches_taken(batch_log):
+    import time
+
+    it = prefetch_to_device(_small_batches(100), size=2)
+    first = next(it)["y"]
+    time.sleep(0.2)  # two more are staged, a third is in the producer's hand
+    it.close()
+    assert [int(first[0])] == [0]
+    assert len(batch_log) == 1
+    # the drained batches took ids, and left no record
+    following = list(prefetch_to_device(_small_batches(1)))
+    assert len(following) == 1 and len(batch_log) == 2
+    assert batch_log[1][0] > batch_log[0][0] + 1
+
+
+def test_a_source_error_reaches_the_loop_after_the_batches_before_it(
+        batch_log):
+    def source():
+        yield from _small_batches(2)
+        raise RuntimeError("row group unreadable")
+
+    it = prefetch_to_device(source())
+    assert [int(next(it)["y"][0]) for _ in range(2)] == [0, 1]
+    with pytest.raises(RuntimeError, match="row group unreadable"):
+        next(it)
+    assert len(batch_log) == 2
