@@ -13,10 +13,22 @@ sits on the critical path.  ``prefetch_to_device`` overlaps the copy
 with compute via a background thread and a bounded queue, handing the
 step loop batches that are already device-resident ``jax.Array``s.
 
+**Who owns a host batch** (docs/data.md).  A batch a caller takes with
+``next()`` is the caller's: fresh arrays, as ever.  A source that can
+gather into memory it is handed says so by a ``take_into`` method (the
+cursor ``iter(BatchIterator(...))`` returns has one), and then
+``prefetch_to_device`` owns the host memory: a few sets of arrays of the
+batch's shapes that it hands the source again and again, each only once
+every device array staged from it has landed (``is_ready()``:
+``device_put`` returns before the copy ends) and shares no memory with
+it.  A fresh array of a batch's size is mapped anew and its pages are
+faulted in one by one, which on some hosts costs ten times the copy.
+
 Pieces:
 
 - :class:`BatchIterator` — batches over in-memory arrays (the
-  ``read_shard`` output), per-epoch seeded reshuffle.
+  ``read_shard`` output), per-epoch seeded reshuffle; its cursor can
+  gather into arrays the caller keeps.
 - :class:`ParquetShardIterator` — streams THIS rank's Parquet row groups
   (``rg % shard_count == cur_shard``) one group at a time, so the shard
   never has to fit in host memory at once.
@@ -35,14 +47,26 @@ __all__ = ["BatchIterator", "ParquetShardIterator", "prefetch_to_device",
            "require_sharded_store"]
 
 
-def _tree_rows(data):
-    """Leading-dim length of a {name: array} dict / tuple / array."""
+def _leaves(tree):
+    """The arrays of a {name: array} dict / tuple / array."""
+    if isinstance(tree, dict):
+        return list(tree.values())
+    return list(tree) if isinstance(tree, (tuple, list)) else [tree]
+
+
+def _map(fn, data, *rest):
+    """``fn`` over the arrays of ``data`` (and, beside each, those of
+    ``rest``: structures of the same kind), in ``data``'s structure."""
     if isinstance(data, dict):
-        arrays = list(data.values())
-    elif isinstance(data, (tuple, list)):
-        arrays = list(data)
-    else:
-        arrays = [data]
+        return {k: fn(v, *(r[k] for r in rest)) for k, v in data.items()}
+    if isinstance(data, (tuple, list)):
+        return type(data)(fn(*vs) for vs in zip(data, *rest))
+    return fn(data, *rest)
+
+
+def _tree_rows(data):
+    """Leading-dim length of a batch structure."""
+    arrays = _leaves(data)
     if not arrays:
         raise ValueError("empty batch structure")
     rows = {int(np.shape(a)[0]) for a in arrays}
@@ -52,11 +76,7 @@ def _tree_rows(data):
 
 
 def _tree_take(data, idx):
-    if isinstance(data, dict):
-        return {k: v[idx] for k, v in data.items()}
-    if isinstance(data, (tuple, list)):
-        return type(data)(v[idx] for v in data)
-    return data[idx]
+    return _map(lambda v: v[idx], data)
 
 
 class BatchIterator:
@@ -96,7 +116,8 @@ class BatchIterator:
             return self._rows // self.batch_size
         return -(-self._rows // self.batch_size)
 
-    def __iter__(self):
+    def _batch_rows(self):
+        """The rows of each batch in turn, as index arrays."""
         epoch = 0
         while self.epochs is None or epoch < self.epochs:
             if self.shuffle:
@@ -107,9 +128,61 @@ class BatchIterator:
             stop = (self._rows - self._rows % self.batch_size
                     if self.drop_remainder else self._rows)
             for lo in range(0, stop, self.batch_size):
-                yield _tree_take(self._data,
-                                 order[lo:lo + self.batch_size])
+                yield order[lo:lo + self.batch_size]
             epoch += 1
+
+    def __iter__(self):
+        return _BatchCursor(self._data, self.batch_size, self._batch_rows())
+
+
+class _BatchCursor:
+    """One pass over a :class:`BatchIterator`'s batches.
+
+    An ordinary iterator: ``next()`` gives a batch of fresh arrays, the
+    caller's to keep.  :meth:`take_into` gives the same batch (same
+    order, same rows, same dtype) in arrays the caller keeps and passes
+    again, for a loop that is done with a batch before it takes the
+    next but one (``prefetch_to_device``, once the batch is on the
+    device): a fresh array of a batch's size is mapped and unmapped by
+    the allocator on every batch and its pages faulted in one by one.
+    """
+
+    def __init__(self, data, batch_size, batch_rows):
+        self._data = data
+        self._batch_size = batch_size
+        self._batch_rows = batch_rows
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return _tree_take(self._data, next(self._batch_rows))
+
+    def take_into(self, out=None):
+        """Gather the next batch into ``out`` and return ``(batch,
+        out)``; ``StopIteration`` where ``next()`` would raise it.
+
+        ``out``: arrays of a FULL batch's shapes, leaf for leaf in the
+        batch's structure, as an earlier call returned them; ``None``
+        makes a new set.  ``batch`` is views of ``out``'s leading rows:
+        all of them, but for an epoch's short last batch.  Whatever
+        ``out`` held is overwritten: pass a set again only when nothing
+        reads the batch taken into it any more.
+        """
+        idx = next(self._batch_rows)
+        if out is None:
+            out = _map(lambda v: np.empty(
+                (self._batch_size,) + np.shape(v)[1:], v.dtype), self._data)
+
+        def take(v, o):
+            o = o[:len(idx)]
+            # mode="clip" writes straight into ``o``; the default
+            # ("raise") gathers into a fresh array of the batch's size
+            # first.  ``idx`` is a permutation's: nothing to clip
+            np.take(v, idx, axis=0, out=o, mode="clip")
+            return o
+
+        return _map(take, self._data, out), out
 
 
 class ParquetShardIterator:
@@ -246,6 +319,62 @@ def lockstep_shard_batches(store, rank, num_ranks, batch_size, epochs):
                                   epochs=None)), steps)
 
 
+class _HostSets:
+    """The host memory ``prefetch_to_device`` keeps for a source that
+    gathers into memory it is handed (``take_into``): sets of arrays of
+    the batch's shapes, each handed out again only when every device
+    array staged from it has landed and shares no memory with it.
+
+    ``device_put`` may return before the copy ends, and the runtime
+    reads the host memory until then; a backend may also hand back an
+    array that IS the host memory (the CPU client does, for memory
+    aligned to 64 bytes).  Both are asked of the staged arrays
+    (``is_ready()``, the shards' addresses), never assumed.  One thread
+    uses it, the producer's.
+    """
+
+    def __init__(self, limit):
+        self._limit = limit  # sets alive at once: free, under way, in hand
+        self._free = []
+        self._under_way = []  # (set, its device arrays), oldest first
+
+    def free_set(self):
+        """A set nothing reads any more, or ``None`` where the source is
+        to make a new one.  Waits, for the oldest copy, only where
+        ``limit`` sets are all under way."""
+        import jax
+
+        self._collect()
+        if not self._free and len(self._under_way) >= self._limit:
+            jax.block_until_ready(self._under_way[0][1])
+            self._collect()
+        return self._free.pop() if self._free else None
+
+    def staged(self, held, arrays):
+        """``arrays`` are what ``put`` made of the batch in ``held``."""
+        self._under_way.append((held, arrays))
+
+    def _collect(self):
+        under_way = []
+        for held, arrays in self._under_way:
+            if not all(array.is_ready() for array in arrays):
+                under_way.append((held, arrays))
+            elif not _shares_memory(held, arrays):
+                self._free.append(held)
+            # else: the device arrays ARE this set; it is theirs now
+        self._under_way = under_way
+
+
+def _shares_memory(held, arrays):
+    """Whether a shard of a device array lies inside a host array of
+    ``held`` (asked once the arrays are ready)."""
+    spans = [(a.ctypes.data, a.ctypes.data + a.nbytes)
+             for a in _leaves(held)]
+    return any(lo <= shard.data.unsafe_buffer_pointer() < hi
+               for array in arrays for shard in array.addressable_shards
+               for lo, hi in spans)
+
+
 def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
                        axis=None):
     """Stage batches onto device ahead of the training loop.
@@ -262,6 +391,14 @@ def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
     to lay the batch out for SPMD, or ``mesh`` (+ optional ``axis``) to
     build a multi-host GLOBAL batch from per-process local rows via
     :func:`horovod_tpu.parallel.mesh.shard_global_batch`.
+
+    Host memory: a source with a ``take_into`` method (the cursor of a
+    :class:`BatchIterator`; found on ``iter(iterator)``) gathers into
+    sets of arrays this prefetcher keeps, at most ``size + 2`` of them
+    (one being filled, one whose copy is under way, ``size`` queued),
+    each filled again only once the device arrays staged from it have
+    landed and share no memory with it (:class:`_HostSets`).  Any other
+    iterator's batches are its own fresh arrays, as ever.
 
     Source-iterator exceptions re-raise at the consuming ``next()`` —
     a data-path failure must fail the step loop, not silently end the
@@ -306,33 +443,48 @@ def prefetch_to_device(iterator, size=2, *, sharding=None, mesh=None,
                 continue
         return False
 
+    sets = _HostSets(size + 2)
+
     # What the path records about itself (utils/trace.py, always on):
     # three spans on the profiler's clock, and per batch the producer's
     # stamps, which ride through the queue beside the batch and become
     # one record of trace.BATCHES when the consumer takes it.
-    def stage(batch_id, batch, t_next_start):
+    def stage(batch_id, batch, held, reused, t_next_start):
         # a frame of its own: nothing of the device batch outlives the
-        # _put below in the producer's locals
+        # _put below in the producer's locals (``sets`` holds its arrays
+        # until their copy has landed, where the host set is kept)
         t_host_ready = trace.now()
         with trace.span("hvd.data.put", batch=batch_id):
             staged = jax.tree.map(put, batch)
+        if held is not None:
+            sets.staged(held, jax.tree.leaves(staged))
         return staged, trace.batch_staged(batch_id, staged, t_next_start,
-                                          t_host_ready)
+                                          t_host_ready, reused)
 
     def producer():
         try:
             source = iter(iterator)
+            # where the host memory comes from: a source that can fill
+            # memory it is handed gets a kept set (or makes one to keep),
+            # any other hands over arrays of its own
+            take_into = getattr(source, "take_into", None) or (
+                lambda out: (next(source), None))
             while True:
                 batch_id = next(trace.batch_ids)
                 t_next_start = trace.now()
                 with trace.span("hvd.data.next", batch=batch_id):
-                    # the host batch lives until this next() returns,
-                    # as under ``for batch in iterator``
-                    batch = next(source, sentinel)
-                if batch is sentinel:
-                    break
-                if stop.is_set() or \
-                        not _put(stage(batch_id, batch, t_next_start)):
+                    # a wait for a set to come free is part of what the
+                    # batch costs before its put.  The host batch lives
+                    # until this call returns, as under ``for batch in
+                    # iterator``
+                    free = sets.free_set()
+                    try:
+                        batch, held = take_into(free)
+                    except StopIteration:
+                        break
+                if stop.is_set() or not _put(stage(
+                        batch_id, batch, held, free is not None,
+                        t_next_start)):
                     return
             _put((sentinel, None))
         except BaseException as exc:  # noqa: BLE001 — re-raised consumer-side

@@ -55,12 +55,18 @@ The eager plane and the input path (``utils/data.py``'s
       (batch id, bytes,
        t_next_start, t_host_ready, t_put_end,   the producer's
        t_asked, t_taken,                        the consumer's
-       depth_at_ask, ready_at_take)
+       depth_at_ask, ready_at_take,
+       reused)                                  the producer's
 
   ``depth_at_ask`` is the queue's length when the loop asked (0: nothing
   was staged, the loop waits); ``ready_at_take`` is ``is_ready()`` of
   the batch's largest array when it is handed over (``device_put`` may
-  return before the copy ends; nothing blocks to find out).
+  return before the copy ends; nothing blocks to find out); ``reused``,
+  the tenth and last, is true when the host batch was gathered into
+  memory the prefetcher had kept from an earlier batch, false when the
+  source handed over arrays of its own or the kept set was new (its
+  pages still to be touched).  Readers take fields by position: a new
+  one goes at the end.
 """
 
 import collections
@@ -134,7 +140,7 @@ def eager_stats():
     return stats
 
 
-def batch_staged(batch_id, batch, t_next_start, t_host_ready):
+def batch_staged(batch_id, batch, t_next_start, t_host_ready, reused):
     """``put`` has returned ``batch``: the producer's half of its
     record, which rides through the prefetcher's queue beside it (with
     the batch's largest array in the last place, for
@@ -143,15 +149,15 @@ def batch_staged(batch_id, batch, t_next_start, t_host_ready):
     leaves = jax.tree.leaves(batch)
     sizes = [leaf.nbytes for leaf in leaves]
     return (batch_id, sum(sizes), t_next_start, t_host_ready, t_put_end,
-            leaves[sizes.index(max(sizes))] if leaves else None)
+            reused, leaves[sizes.index(max(sizes))] if leaves else None)
 
 
 def batch_taken(staged, t_asked, depth_at_ask):
     """The consumer has the batch whose producer's half is ``staged``
     in hand: the batch's line of the log, on the consumer's thread."""
-    *stamps, largest = staged
+    *stamps, reused, largest = staged
     BATCHES.append((*stamps, t_asked, now(), depth_at_ask,
-                    largest is None or largest.is_ready()))
+                    largest is None or largest.is_ready(), reused))
 
 
 def input_stats():
@@ -161,7 +167,9 @@ def input_stats():
     the copy still under way when it took), what one ``next()`` cost
     the loop on average (``wait_ms``), and the medians of the source
     iterator's work (``source_ms``) and of the move onto the device
-    (``put_ms``) for one batch.  Beside :func:`eager_stats`."""
+    (``put_ms``) for one batch, and the share whose host batch was
+    gathered into memory kept from an earlier one (``reused_share``).
+    Beside :func:`eager_stats`."""
     records = list(BATCHES)
     stats = {"batches": len(records),
              "bytes": sum(r[1] for r in records)}
@@ -171,7 +179,8 @@ def input_stats():
                               for r in records) / len(records),
             wait_ms=sum(r[6] - r[5] for r in records) / len(records) / 1e6,
             source_ms=statistics.median(r[3] - r[2] for r in records) / 1e6,
-            put_ms=statistics.median(r[4] - r[3] for r in records) / 1e6)
+            put_ms=statistics.median(r[4] - r[3] for r in records) / 1e6,
+            reused_share=sum(r[9] for r in records) / len(records))
     return stats
 
 

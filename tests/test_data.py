@@ -10,6 +10,7 @@ import pytest
 
 from horovod_tpu.utils.data import (BatchIterator, ParquetShardIterator,
                                     prefetch_to_device)
+from horovod_tpu.utils.data import _leaves as _arrays, _map
 
 
 def _shard(rows=20, feat=3):
@@ -366,12 +367,13 @@ def test_prefetch_log_is_ordered_and_every_stamp_monotone(batch_log):
     ids = [record[0] for record in batch_log]
     assert ids == sorted(set(ids))
     for record in batch_log:
-        assert len(record) == 9
+        assert len(record) == 10
         assert record[1] == 4 * 2 * 4 + 4 * 4  # the batch's bytes
         t_next_start, t_host_ready, t_put_end, t_asked, t_taken = record[2:7]
         assert 0 < t_next_start <= t_host_ready <= t_put_end <= t_taken
         assert t_asked <= t_taken
         assert 0 <= record[7] <= 2 and isinstance(record[8], bool)
+        assert record[9] is False  # a generator hands over its own arrays
     # one producer: a batch is made after the one before it was staged
     for before, after in zip(batch_log, list(batch_log)[1:]):
         assert before[4] <= after[2] and before[6] <= after[5]
@@ -437,3 +439,350 @@ def test_a_source_error_reaches_the_loop_after_the_batches_before_it(
     with pytest.raises(RuntimeError, match="row group unreadable"):
         next(it)
     assert len(batch_log) == 2
+
+
+# ------------------------- who owns a host batch (docs/data.md): the
+# cursor of a BatchIterator gathers into memory it is handed, and
+# prefetch_to_device keeps that memory and hands it out again once the
+# device arrays staged from it have landed and share no memory with it
+def _pool(structure, rows=22):
+    x = np.random.default_rng(5).standard_normal((rows, 3, 2)).astype(
+        np.float32)
+    y = np.arange(rows, dtype=np.int32)
+    return {"dict": {"x": x, "y": y}, "tuple": (x, y), "array": x}[structure]
+
+
+def _assert_same_batches(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert isinstance(w, np.ndarray) or type(g) is type(w)
+        if isinstance(w, dict):
+            assert list(g) == list(w)
+        for a, b in zip(_arrays(g), _arrays(w)):
+            a = np.asarray(a)
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+
+
+def _copying_put(x, sharding=None):
+    """A ``device_put`` that never hands the host memory back as the
+    device array (the CPU client does, for memory aligned to 64 bytes):
+    what a chip's runtime does."""
+    import jax
+    import jax.numpy as jnp
+
+    out = jnp.array(x, copy=True)
+    return out if sharding is None else jax.device_put(out, sharding)
+
+
+def _aligned_like(batch, align=64):
+    """Arrays of ``batch``'s shapes whose memory the CPU client hands
+    back as the device array, uncopied (it does for 64 bytes)."""
+    def one(a):
+        raw = np.empty(a.nbytes + align, np.uint8)
+        start = -raw.ctypes.data % align
+        return raw[start:start + a.nbytes].view(a.dtype).reshape(a.shape)
+
+    return _map(one, batch)
+
+
+class _Spy:
+    """A cursor that notes the host addresses ``take_into`` is handed
+    (``None`` where it is asked to make a new set, which is then made
+    like ``aligned``, a full batch, where one is given)."""
+
+    def __init__(self, cursor, aligned=None):
+        self._cursor, self._aligned = cursor, aligned
+        self.handed = []
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        return next(self._cursor)
+
+    def take_into(self, out=None):
+        self.handed.append(None if out is None else tuple(
+            a.ctypes.data for a in _arrays(out)))
+        if out is None and self._aligned is not None:
+            out = _aligned_like(self._aligned)
+        return self._cursor.take_into(out)
+
+
+@pytest.mark.parametrize("backend", ["as_it_is", "copies", "aliases"])
+@pytest.mark.parametrize("structure", ["dict", "tuple", "array"])
+def test_prefetch_over_a_cursor_gives_the_iterators_own_batches(
+        structure, backend, batch_log, monkeypatch):
+    """Two shuffled epochs with a short last batch, whatever the
+    backend does with the host memory (a copy, as a chip's runtime; the
+    memory itself, as the CPU client for aligned arrays; this
+    sandbox's mix of both): compared only after EVERY batch is taken,
+    so that a set filled again too early shows."""
+    import jax
+
+    if backend == "copies":
+        monkeypatch.setattr(jax, "device_put", _copying_put)
+
+    def batches():
+        return BatchIterator(_pool(structure), 4, shuffle=True, seed=11,
+                             drop_remainder=False, epochs=2)
+
+    want = list(batches())
+    assert [len(_arrays(b)[0]) for b in want] == [4, 4, 4, 4, 4, 2] * 2
+    source = _Spy(iter(batches()),
+                  aligned=want[0] if backend == "aliases" else None)
+    got = list(prefetch_to_device(source, size=2))
+    _assert_same_batches(got, want)
+    assert len(batch_log) == 12
+    reused = [record[9] for record in batch_log]
+    assert reused == [handed is not None for handed in source.handed[:12]]
+    if backend == "copies":
+        assert sum(reused) >= 12 - 4
+    if backend == "aliases":
+        assert not any(reused)
+    # the iterator itself is a source too, not only its cursor
+    _assert_same_batches(list(prefetch_to_device(batches(), size=1)), want)
+
+
+def test_take_into_fills_the_arrays_it_is_handed_bit_for_bit():
+    data = _pool("dict")
+    fresh = list(BatchIterator(data, 4, shuffle=True, seed=3,
+                               drop_remainder=False))
+    cursor = iter(BatchIterator(data, 4, shuffle=True, seed=3,
+                                drop_remainder=False))
+    batch, kept = cursor.take_into()
+    assert {k: (v.shape, v.dtype) for k, v in kept.items()} == {
+        "x": ((4, 3, 2), np.float32), "y": ((4,), np.int32)}
+    where = {k: v.ctypes.data for k, v in kept.items()}
+    taken = [{k: v.copy() for k, v in batch.items()}]
+    for _ in fresh[1:]:
+        batch, again = cursor.take_into(kept)
+        assert again is kept
+        for k, v in batch.items():  # the set's own leading rows
+            assert v.ctypes.data == where[k] and v.base is kept[k]
+        taken.append({k: v.copy() for k, v in batch.items()})
+    _assert_same_batches(taken, fresh)
+    assert len(taken[-1]["y"]) == 2  # the short one, in a full-size set
+    with pytest.raises(StopIteration):
+        cursor.take_into(kept)
+    with pytest.raises(StopIteration):
+        next(cursor)
+
+
+def test_plain_next_of_the_cursor_still_yields_arrays_of_its_own():
+    data = _pool("dict")
+    cursor = iter(BatchIterator(data, 4, epochs=None))
+    assert iter(cursor) is cursor
+    taken = [next(cursor) for _ in range(8)]
+    arrays = [a for batch in taken for a in _arrays(batch)]
+    for i, a in enumerate(arrays):
+        assert a.flags.owndata
+        assert not any(np.shares_memory(a, b) for b in arrays[i + 1:])
+        assert not any(np.shares_memory(a, d) for d in data.values())
+    # ... and a batch taken into a set does not disturb them
+    before = [a.copy() for a in arrays]
+    cursor.take_into()
+    for a, b in zip(arrays, before):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_warm_ring_allocates_nothing_of_a_batchs_size(batch_log,
+                                                        monkeypatch):
+    """After the first batches the producer gathers into host memory it
+    has used before: the same addresses come round again and no array of
+    the batch's size is allocated, whichever thread one looks at."""
+    import time
+    import tracemalloc
+
+    import jax
+
+    put_from = []
+
+    def put(x, sharding=None):
+        put_from.append(x.ctypes.data)
+        # landed when the call returns: the set is free for the next
+        # gather, so that one set serves, however the threads are timed
+        return _copying_put(x).block_until_ready()
+
+    monkeypatch.setattr(jax, "device_put", put)
+    pool = np.random.default_rng(0).standard_normal((64, 64, 256)).astype(
+        np.float32)
+    batch_bytes = 8 * 64 * 256 * 4  # 512 KiB
+    spy = _Spy(iter(BatchIterator(pool, 8, shuffle=True, epochs=None)))
+    it = prefetch_to_device(spy, size=2)
+    got = [next(it) for _ in range(8)]  # warm: every set has been made
+    time.sleep(0.2)  # the producer is parked on a full queue
+    made = spy.handed.count(None)
+    assert made == 1
+    tracemalloc.start()
+    try:
+        got += [next(it) for _ in range(24)]
+        time.sleep(0.2)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    it.close()
+    assert peak < batch_bytes, peak
+    assert spy.handed.count(None) == made  # no further set
+    assert len(set(put_from)) == made and len(put_from) >= 32
+    assert [record[9] for record in batch_log].count(False) == made
+    want = iter(BatchIterator(pool, 8, shuffle=True, epochs=None))
+    _assert_same_batches(got, [next(want) for _ in got])
+
+
+class _Unready:
+    """A device array whose copy lands when the test says so."""
+
+    def __init__(self, array, landed):
+        self._array, self._landed = array, landed
+        self.nbytes = array.nbytes
+
+    def is_ready(self):
+        return self._landed.is_set()
+
+    def block_until_ready(self):
+        assert self._landed.wait(30)
+        return self
+
+    @property
+    def addressable_shards(self):
+        return self._array.addressable_shards
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self._array)
+
+
+def test_a_set_is_not_filled_again_before_its_copy_has_landed(
+        batch_log, monkeypatch):
+    """``device_put`` returns before the copy ends and the runtime reads
+    the host memory until then: the prefetcher makes ``size + 2`` sets
+    and no more, waits for the OLDEST copy, and only then gathers into
+    that set again (inside the batch's ``next`` stamps)."""
+    import threading
+    import time
+
+    import jax
+
+    landed, host_views, all_land = [], [], []
+
+    def put(x, sharding=None):
+        landed.append(threading.Event())
+        if all_land:
+            landed[-1].set()
+        host_views.append(x)  # what the runtime would still be reading
+        return _Unready(_copying_put(x), landed[-1])
+
+    monkeypatch.setattr(jax, "device_put", put)
+    pool = np.arange(40 * 6, dtype=np.float32).reshape(40, 6)
+    want = list(BatchIterator(pool, 4))
+    spy = _Spy(iter(BatchIterator(pool, 4)))
+    it = prefetch_to_device(spy, size=2)
+    def until(done):
+        deadline = time.monotonic() + 10
+        while not done() and time.monotonic() < deadline:
+            time.sleep(0.01)
+
+    got = [next(it) for _ in range(2)]
+    until(lambda: len(host_views) == 4)  # the fourth batch is being put
+    time.sleep(0.3)  # a fifth gather would have begun by now
+    assert spy.handed == [None] * 4  # size + 2 sets, every copy under way
+    for view, batch in zip(host_views, want):
+        np.testing.assert_array_equal(view, batch)  # none overwritten
+    landed[1].set()  # not the oldest: the producer waits for that one
+    time.sleep(0.2)
+    assert len(spy.handed) == 4
+    waited_from = time.perf_counter_ns()
+    landed[0].set()
+    got += [next(it) for _ in range(3)]  # 5 taken: the fifth was made
+    until(lambda: len(spy.handed) == 6)
+    # the two sets whose copies have landed, and no new one
+    assert set(spy.handed[4:6]) == {(view.ctypes.data,)
+                                    for view in host_views[:2]}
+    all_land.append(True)
+    for event in landed:
+        event.set()
+    got += list(it)
+    _assert_same_batches(got, want)
+    # the wait lies between t_next_start and t_host_ready of batch 5
+    fifth = batch_log[4]
+    assert fifth[2] < waited_from < fifth[3] and fifth[9] is True
+    assert [record[9] for record in batch_log][:4] == [False] * 4
+
+
+def test_a_set_the_device_array_aliases_is_never_handed_out_again(
+        batch_log):
+    """The CPU client hands memory aligned to 64 bytes back as the
+    device array, uncopied: the batch the loop holds IS that set."""
+    pool = np.arange(40 * 6, dtype=np.float32).reshape(40, 6)
+    spy = _Spy(iter(BatchIterator(pool, 4)), aligned=pool[:4])
+    got = list(prefetch_to_device(spy, size=2))
+    assert spy.handed == [None] * 11  # ten batches and the end's ask
+    where = {a.addressable_shards[0].data.unsafe_buffer_pointer()
+             for a in got}
+    assert len(where) == 10 and all(at % 64 == 0 for at in where)
+    _assert_same_batches(got, list(BatchIterator(pool, 4)))
+    assert [record[9] for record in batch_log] == [False] * 10
+
+
+def test_sources_that_fill_no_memory_they_are_given_read_reused_false(
+        batch_log, parquet_store):
+    taken = list(prefetch_to_device(_small_batches(3)))
+    taken += list(prefetch_to_device(iter(ParquetShardIterator(
+        parquet_store, 0, 2, batch_size=4))))
+    assert len(taken) == len(batch_log) == 3 + 6
+    assert [record[9] for record in batch_log] == [False] * 9
+
+
+@pytest.mark.parametrize("placement", ["sharding", "mesh"])
+def test_the_ring_holds_or_stands_aside_under_either_placement(
+        placement, batch_log):
+    """Eight CPU devices: the shards of a staged array may lie inside
+    the host set (aligned rows) or not, and the arrays say which."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from horovod_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh({"hvd": 8})
+    where = ({"mesh": mesh} if placement == "mesh" else
+             {"sharding": NamedSharding(mesh, PartitionSpec("hvd"))})
+    pool = _pool("dict", rows=64)
+    want = list(BatchIterator(pool, 16, shuffle=True, seed=2, epochs=3))
+    got = list(prefetch_to_device(
+        iter(BatchIterator(pool, 16, shuffle=True, seed=2, epochs=3)),
+        **where))
+    assert len(got[0]["x"].addressable_shards) == 8
+    _assert_same_batches(got, want)
+
+
+def test_early_close_releases_the_producer_with_a_ring_in_hand(
+        monkeypatch):
+    import threading
+    import time
+    import weakref
+
+    import jax
+
+    refs = []
+
+    def put(x, sharding=None):
+        out = _copying_put(x)
+        refs.append(weakref.ref(out))
+        return out
+
+    monkeypatch.setattr(jax, "device_put", put)
+    before = set(threading.enumerate())
+    spy = _Spy(iter(BatchIterator(_pool("array", rows=64), 4, epochs=None)))
+    it = prefetch_to_device(spy, size=2)
+    first = [next(it) for _ in range(6)]
+    (producer,) = set(threading.enumerate()) - before
+    it.close()
+    del first
+    producer.join(timeout=3)
+    assert not producer.is_alive()
+    n = len(spy.handed)
+    assert any(handed is not None for handed in spy.handed)  # a ring
+    deadline = time.time() + 3.0
+    while any(r() is not None for r in refs) and time.time() < deadline:
+        time.sleep(0.05)
+    assert not any(r() is not None for r in refs)
+    assert len(spy.handed) == n

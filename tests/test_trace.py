@@ -269,48 +269,65 @@ def test_input_stats_of_an_empty_log(batches):
     import horovod_tpu as hvd
 
     assert hvd.input_stats is trace.input_stats
+    # no batch, no share of batches: ``reused_share`` is left out too
     assert hvd.input_stats() == {"batches": 0, "bytes": 0}
 
 
 def test_input_stats_shares_means_and_medians(batches):
     """Four batches of 100 bytes: one the loop found nothing staged for,
-    one whose copy was still under way when it was taken."""
+    one whose copy was still under way when it was taken; the first
+    gathered into a new set of host arrays, the rest into kept ones."""
     ms = 1_000_000
     batches.extend([
-        # id, bytes, next, host, put, asked, taken, depth, ready
-        (1, 100, 0, 50 * ms, 80 * ms, 70 * ms, 82 * ms, 0, True),
-        (2, 100, 80 * ms, 120 * ms, 160 * ms, 200 * ms, 201 * ms, 1, True),
-        (3, 100, 160 * ms, 220 * ms, 240 * ms, 300 * ms, 301 * ms, 2, False),
-        (4, 100, 240 * ms, 310 * ms, 360 * ms, 400 * ms, 402 * ms, 2, True)])
+        # id, bytes, next, host, put, asked, taken, depth, ready, reused
+        (1, 100, 0, 50 * ms, 80 * ms, 70 * ms, 82 * ms, 0, True, False),
+        (2, 100, 80 * ms, 120 * ms, 160 * ms, 200 * ms, 201 * ms, 1, True,
+         True),
+        (3, 100, 160 * ms, 220 * ms, 240 * ms, 300 * ms, 301 * ms, 2, False,
+         True),
+        (4, 100, 240 * ms, 310 * ms, 360 * ms, 400 * ms, 402 * ms, 2, True,
+         True)])
     assert trace.input_stats() == {
         "batches": 4, "bytes": 400, "starved_share": 0.5,
         "wait_ms": pytest.approx((12 + 1 + 1 + 2) / 4),
         "source_ms": pytest.approx((50 + 60) / 2),
-        "put_ms": pytest.approx((30 + 40) / 2)}
+        "put_ms": pytest.approx((30 + 40) / 2),
+        "reused_share": 0.75}
+
+
+@pytest.mark.parametrize("reused,share", [
+    ([False] * 3, 0.0), ([True] * 3, 1.0)])
+def test_input_stats_reused_share_counts_the_tenth_field(batches, reused,
+                                                         share):
+    batches.extend((i, 8, 0, 1, 2, 3, 4, 1, True, r)
+                   for i, r in enumerate(reused))
+    assert trace.input_stats()["reused_share"] == share
 
 
 def test_a_batch_is_logged_when_it_is_taken_not_when_it_is_staged(batches):
     import jax.numpy as jnp
 
     batch = {"x": jnp.zeros((4, 8), jnp.float32), "y": jnp.zeros(4, jnp.int32)}
-    staged = trace.batch_staged(9, batch, 10, 20)
+    staged = trace.batch_staged(9, batch, 10, 20, True)
     assert staged[:4] == (9, 4 * 8 * 4 + 4 * 4, 10, 20)
-    assert staged[5] is batch["x"]  # the largest array
+    assert staged[5] is True  # gathered into a kept set
+    assert staged[6] is batch["x"]  # the largest array
     assert not batches
     asked = trace.now()
     trace.batch_taken(staged, asked, 2)
     (record,) = batches
     assert record[:4] == staged[:4] and record[4] == staged[4]
-    assert record[5] == asked and record[7:] == (2, True)
+    # ready_at_take in the ninth place as ever, reused after it
+    assert record[5] == asked and record[7:] == (2, True, True)
     assert staged[4] <= asked <= record[6] <= trace.now()
     # an empty batch has no array to ask: nothing is still under way
-    trace.batch_taken(trace.batch_staged(10, {}, 10, 20), asked, 0)
-    assert batches[-1][1] == 0 and batches[-1][8] is True
+    trace.batch_taken(trace.batch_staged(10, {}, 10, 20, False), asked, 0)
+    assert batches[-1][1] == 0 and batches[-1][8:] == (True, False)
 
 
 def test_both_logs_are_bounded_and_reset_together(batches):
     assert trace.BATCHES.maxlen == trace.LOG.maxlen == 65536
-    batches.append((1, 1, 0, 1, 2, 3, 4, 0, True))
+    batches.append((1, 1, 0, 1, 2, 3, 4, 0, True, False))
     trace.LOG.append((1, 1, 0, 1, 2, 3))
     trace.reset()
     assert not trace.BATCHES and not trace.LOG
@@ -339,7 +356,7 @@ def test_the_input_paths_instruments_cost_under_20_us_a_batch(batches):
         with trace.span("hvd.data.put", batch=batch_id):
             pass
         staged = trace.batch_staged(batch_id, batch, t_next_start,
-                                    t_host_ready)
+                                    t_host_ready, True)
         t_asked = trace.now()
         with trace.span("hvd.data.wait") as wait:
             wait.set_metadata(batch=staged[0])
