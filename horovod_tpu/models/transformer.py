@@ -34,7 +34,10 @@ built from ``pattern[l % len(pattern)]``; with
 ``attention=GroupedAttention(...)`` a kind of layer has its own count
 of query heads over grouped key-value heads, a sliding window or none,
 its own rotary recipe (:class:`Rotary`), a norm over each head of q
-and k and a gate a head on the attention's output.  A block's mixer need
+and k and a gate a head on the attention's output (or no rotation at
+all: ``rotary=None``); an expert layer's router may read the block's
+input and decide before the mixer runs (``TopkExperts(route_from=
+"input")``) and its experts may gate by ReLU.  A block's mixer need
 not be attention: ``attention=ShortConv(...)`` is a doubly gated causal
 convolution of a few taps along the sequence (:class:`ShortConvMixer`),
 ``attention=SelectiveScan(...)`` a mixer with a state carried along the
@@ -121,7 +124,9 @@ class GroupedAttention:
     ``h // (heads / kv_heads)``), through a q projection and a k/v
     projection of their own.  ``window``: a query sees that many keys,
     itself included (``None``: every key before it).  ``rotary``: the
-    recipe q and k are turned by.  ``gate``: ``"softplus"`` scales every
+    recipe q and k are turned by (``None``: they are read as projected,
+    a layer without positions, and no table is built).  ``gate``:
+    ``"softplus"`` scales every
     head's output by ``softplus(x W_g)``, one scalar a head and position
     in float32, from the input the projections read (``None``: no
     gate).  ``qk_norm``: an RMSNorm over the ``head_dim`` columns of
@@ -132,7 +137,7 @@ class GroupedAttention:
     kv_heads: int
     head_dim: int
     window: Optional[int] = None
-    rotary: Rotary = Rotary()
+    rotary: Optional[Rotary] = Rotary()
     gate: Optional[str] = None
     qk_norm: bool = False
 
@@ -144,6 +149,9 @@ class GroupedAttention:
         if self.gate not in (None, "softplus"):
             raise ValueError(f"GroupedAttention: gate {self.gate!r} is "
                              f"neither None nor 'softplus'")
+        if not isinstance(self.rotary, (Rotary, type(None))):
+            raise ValueError(f"GroupedAttention: rotary {self.rotary!r} is "
+                             f"neither None nor a Rotary")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,6 +253,7 @@ class ChunkSummaryAttention:
 
 MIXERS = (LatentAttention, GroupedAttention, ShortConv, SelectiveScan,
           MemoryUnit, DifferentialAttention, ChunkSummaryAttention)
+ROUTES_FROM = ("ffn_input", "input")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -256,12 +265,34 @@ class TopkExperts:
     model); ``shared``: that many SwiGLU experts of the same width
     every token goes through, beside the routed ones; ``held``:
     ``(first, count)``, the routed experts this device holds of the
-    router's ``n_experts``."""
+    router's ``n_experts``.  ``route_from``: the array the router
+    reads: ``"ffn_input"``, what the experts read (the block's second
+    norm's output), or ``"input"``, the BLOCK's input ahead of its first
+    norm: :class:`Block` then decides before its mixer runs, under the
+    scope ``route_ahead``, and the experts are handed the decision.
+    ``activation``: the routed experts' gate function, ``"silu"``
+    (SwiGLU) or ``"relu"`` (ReGLU); the shared experts are SwiGLU."""
     scoring: str = "softmax"
     renormalize: bool = False
     scale: float = 1.0
     shared: int = 0
     held: Optional[Tuple[int, int]] = None
+    route_from: str = "ffn_input"
+    activation: str = "silu"
+
+    def __post_init__(self):
+        from horovod_tpu.parallel.moe import ACTIVATIONS
+
+        if self.route_from not in ROUTES_FROM:
+            raise ValueError(f"TopkExperts: route_from {self.route_from!r} "
+                             f"is none of {ROUTES_FROM}")
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"TopkExperts: activation {self.activation!r} "
+                             f"is none of {sorted(ACTIVATIONS)}")
+        if self.shared and self.activation != "silu":
+            raise ValueError(
+                f"TopkExperts: the {self.shared} shared experts are SwiGLU, "
+                f"the routed ones are asked for {self.activation!r}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -853,8 +884,10 @@ def grouped_attention(cfg, x):
     (submodules of the :class:`Attention` that calls it).  Under the
     scope ``attn/window`` where the kind has a window and
     ``attn/global`` where it has none, the norm of the heads under
-    ``qk_norm`` and the attention function's call under ``flash``
-    inside it, and the gate under ``attn/gate``."""
+    ``qk_norm``, the rotation under ``rope`` (no such scope where the
+    kind's ``rotary`` is ``None``: nothing is turned) and the attention
+    function's call under ``flash`` inside it, and the gate under
+    ``attn/gate``."""
     spec = cfg.block.attention
 
     def kind():
@@ -874,8 +907,9 @@ def grouped_attention(cfg, x):
                 # over each head's columns, one scale shared by the heads
                 q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
                 k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
-        with jax.named_scope("rope"):
-            q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
+        if spec.rotary is not None:
+            with jax.named_scope("rope"):
+                q, k = rotate(q, spec.rotary), rotate(k, spec.rotary)
         attn = cfg.attn_fn or default_attention()
         with jax.named_scope("flash"):
             # a function that knows no window is not asked for one
@@ -1247,33 +1281,55 @@ class TopkMoeMlp(nn.Module):
     (a :class:`TopkExperts`; the default is ``"moe_topk"``) says: the
     weights of the ``spec.held`` experts alone, ``spec.shared`` experts
     every token goes through, the choice made through ``router_bias
-    [E]`` where one is given.  Sows its load-balancing loss
-    (``moe_aux_loss``), its router z-loss (``moe_z_loss``) and the
-    counter ``moe_tokens_per_expert`` for :func:`apply_with_aux`."""
+    [E]`` where one is given.  The choice is made here, from ``x``,
+    unless the caller hands a ``decision``: what :meth:`route` gave it
+    for another array of the same tokens (``spec.route_from``).  Sows
+    its load-balancing loss (``moe_aux_loss``), its router z-loss
+    (``moe_z_loss``) and the counter ``moe_tokens_per_expert`` for
+    :func:`apply_with_aux`."""
     cfg: TransformerConfig
     spec: TopkExperts = TopkExperts()
 
-    @nn.compact
-    def __call__(self, x, router_bias=None):
-        from horovod_tpu.parallel.moe import (
-            moe_kernel_init, moe_param_shapes, topk_moe)
+    def setup(self):
+        from horovod_tpu.parallel.moe import moe_kernel_init, moe_param_shapes
 
         cfg, spec = self.cfg, self.spec
         width = cfg.d_expert or cfg.d_ff
         held = spec.held[1] if spec.held else cfg.n_experts
         shapes = moe_param_shapes(cfg.d_model, width, held, gated=True)
         shapes["router"] = (cfg.d_model, cfg.n_experts)
-        params = {name: {"kernel": self.param(
+        self.kernels = {name: {"kernel": self.param(
             f"{name}_kernel", moe_kernel_init, shape)}
             for name, shape in shapes.items()}
+        if spec.shared:
+            self.shared = SwigluMlp(cfg, width=spec.shared * width)
+
+    def route(self, x, router_bias=None):
+        """The router's decision on ``x [..., d_model]``
+        (``parallel/moe.py:route_tokens``)."""
+        from horovod_tpu.parallel.moe import route_tokens
+
+        return route_tokens(
+            x, self.kernels["router"]["kernel"], self.cfg.experts_per_token,
+            **self._route(router_bias))
+
+    def _route(self, router_bias):
+        """``topk_route``'s keyword arguments."""
+        spec = self.spec
+        return dict(scoring=spec.scoring, bias=router_bias,
+                    renormalize=spec.renormalize, scale=spec.scale)
+
+    def __call__(self, x, router_bias=None, decision=None):
+        from horovod_tpu.parallel.moe import topk_moe
+
+        cfg, spec = self.cfg, self.spec
+        route = {} if decision is not None else self._route(router_bias)
         out, aux = topk_moe(
-            x, params, k=cfg.experts_per_token, held=spec.held,
-            scoring=spec.scoring, bias=router_bias,
-            renormalize=spec.renormalize, scale=spec.scale)
+            x, self.kernels, k=cfg.experts_per_token, held=spec.held,
+            activation=spec.activation, decision=decision, **route)
         if spec.shared:
             with jax.named_scope("moe/shared"):
-                out = out + SwigluMlp(cfg, width=spec.shared * width,
-                                      name="shared")(x)
+                out = out + self.shared(x)
         self.sow("intermediates", "moe_aux_loss", aux["load_balancing"])
         self.sow("intermediates", "moe_z_loss", aux["router_z"])
         self.sow("intermediates", "moe_tokens_per_expert",
@@ -1327,6 +1383,15 @@ class Block(nn.Module):
         operand and no result of the compiled block."""
         cfg, mixer = self.cfg, self.cfg.block.attention
         sandwich = cfg.block.norm_placement == "sandwich"
+        ffn = self.ffn or cfg.block.ffn
+        experts = decision = None
+        if isinstance(ffn, TopkExperts):
+            experts = TopkMoeMlp(cfg, ffn, name="moe")
+            if ffn.route_from == "input":
+                # decided from what the block was handed, ahead of its
+                # first norm; the mixer stands between decision and use
+                with jax.named_scope("route_ahead"):
+                    decision = experts.route(x, router_bias)
         y = make_norm(cfg, "ln1")(x).astype(cfg.dtype)
         if isinstance(mixer, ShortConv):
             y = ShortConvMixer(cfg, name="mixer")(y)
@@ -1349,9 +1414,8 @@ class Block(nn.Module):
             y = make_norm(cfg, "ln1_post")(y)
         x = checkpoint_name(x + y, KEPT_SUM)
         y = make_norm(cfg, "ln2")(x).astype(cfg.dtype)
-        ffn = self.ffn or cfg.block.ffn
-        if isinstance(ffn, TopkExperts):
-            y = TopkMoeMlp(cfg, ffn, name="moe")(y, router_bias)
+        if experts is not None:
+            y = experts(y, router_bias, decision)
         else:
             module, name = FEED_FORWARDS[ffn]
             y = module(cfg, name=name)(y)

@@ -485,14 +485,23 @@ def topk_route(router_logits, k, *, scoring="softmax", bias=None,
     - ``router_z``: mean of ``logsumexp(logits)^2`` (ST-MoE);
     - ``tokens_per_expert [E]`` int32, summing to ``N * k``.
     """
+    return _topk_route(router_logits, k, scoring, bias, renormalize, scale,
+                       reread=bias is not None)
+
+
+def _topk_route(router_logits, k, scoring, bias, renormalize, scale, reread):
+    """:func:`topk_route`.  ``reread``: the weights are read off the
+    scores by comparison with the chosen experts and not taken from
+    ``top_k``'s values, the same numbers; a ``bias`` leaves no other
+    way."""
     n, e = router_logits.shape
     probs = SCORINGS[scoring](router_logits)
-    if bias is None:
+    if not reread:
         weights, experts = jax.lax.top_k(probs, k)
         experts = checkpoint_name(experts, SAVED_EXPERTS)
     else:
-        experts = checkpoint_name(
-            jax.lax.top_k(probs + bias, k)[1], SAVED_EXPERTS)
+        experts = checkpoint_name(jax.lax.top_k(
+            probs if bias is None else probs + bias, k)[1], SAVED_EXPERTS)
         # ``probs[n, experts[n, j]]`` read by comparison over the E
         # lanes, one term of each sum non-zero: a select and a sum where
         # ``take_along_axis`` moves scalars one by one (and its
@@ -529,7 +538,35 @@ def balance_bias(bias, tokens_per_expert, rate):
     return bias + rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
 
 
-def topk_moe(x, params, *, k, held=None, **route):
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _router_logits(x, router):
+    """``x [..., D]`` (leading dims folded into N tokens) through
+    ``router [D, E]``: the product in float32 at ``HIGHEST`` whatever
+    ``x`` is, so that rounding does not flip a near-tie between the
+    k-th and the next expert."""
+    return jnp.dot(
+        x.reshape(-1, x.shape[-1]).astype(jnp.float32),
+        router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+
+
+def route_tokens(x, router, k, *, scoring="softmax", bias=None,
+                 renormalize=False, scale=1.0):
+    """:func:`topk_route` of ``x [..., D]`` through ``router [D, E]``,
+    for a caller that decides from another array or at another place
+    than the experts read and hands :func:`topk_moe` the ``decision``.
+    Such a decision is carried past whatever stands between (a block's
+    mixer), and a checkpoint keeps it by its experts' name alone: the
+    weights are read off the scores by comparison with them, the same
+    numbers as ``top_k``'s values, so that the recomputation has no use
+    for ``top_k``."""
+    return _topk_route(_router_logits(x, router), k, scoring, bias,
+                       renormalize, scale, reread=True)
+
+
+def topk_moe(x, params, *, k, held=None, activation="silu", decision=None,
+             **route):
     """Dropless top-``k`` MoE FFN with gated experts on ``x [..., D]``
     (leading dims folded into N tokens); returns ``(out, aux)``.
 
@@ -538,14 +575,18 @@ def topk_moe(x, params, *, k, held=None, **route):
     ``init_moe_params(..., gated=True)``).  Per token, with ``S`` the
     ``k`` experts :func:`topk_route` chooses and ``w`` their weights
     (``route``: its keyword arguments; by default the softmax
-    probabilities as they are):
+    probabilities as they are) and ``act`` the ``activation``
+    (``"silu"``, a SwiGLU expert, or ``"relu"``, a ReGLU one):
 
-        out = sum_{e in S} w_e * (silu(x wg_e) * (x wi_e)) wo_e
+        out = sum_{e in S} w_e * (act(x wg_e) * (x wi_e)) wo_e
 
-    The router's product and scores run in float32 whatever ``x`` is,
-    so that rounding does not flip a near-tie between the k-th and the
-    next expert.  The ``N * k`` token-slots are sorted by expert
-    (stable), their rows gathered, three grouped products run with
+    ``S`` and ``w`` are decided from ``x`` (the router's product in
+    float32 at ``HIGHEST``, under the scope ``moe/route``) unless the
+    caller hands a ``decision``: what :func:`route_tokens` returned for
+    the same N tokens, read from whatever array and at whatever place
+    the model decides from (the router's kernel is then not read here
+    and ``route`` is empty).  The ``N * k`` token-slots are sorted by
+    expert (stable), their rows gathered, three grouped products run with
     group sizes = tokens per expert, and the result is un-sorted by the
     inverse permutation and summed over a token's slots: no capacity,
     no token dropped, no scatter of rows.  ``aux`` is
@@ -571,12 +612,17 @@ def topk_moe(x, params, *, k, held=None, **route):
     xt = x.reshape(-1, d)
     n = xt.shape[0]
     dtype = x.dtype
-    with jax.named_scope("moe/route"):
-        logits = jnp.dot(
-            xt.astype(jnp.float32),
-            params["router"]["kernel"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
-        weights, experts, aux = topk_route(logits, k, **route)
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"topk_moe: activation {activation!r} is none of "
+                         f"{sorted(ACTIVATIONS)}")
+    if decision is None:
+        with jax.named_scope("moe/route"):
+            decision = topk_route(
+                _router_logits(xt, params["router"]["kernel"]), k, **route)
+    elif route:
+        raise ValueError(f"topk_moe: a decision is handed in, and "
+                         f"{sorted(route)} would decide again")
+    weights, experts, aux = decision
     with jax.named_scope("moe/dispatch"):
         group_sizes = aux["tokens_per_expert"]
         keys = experts.reshape(-1)
@@ -602,8 +648,9 @@ def topk_moe(x, params, *, k, held=None, **route):
                                PRODUCT_GATE)
         up = checkpoint_name(grouped_matmul(rows, wi, group_sizes),
                              PRODUCT_UP)
-        y = checkpoint_name(grouped_matmul(jax.nn.silu(gate) * up, wo,
-                                           group_sizes), PRODUCT_DOWN)
+        y = checkpoint_name(grouped_matmul(
+            ACTIVATIONS[activation](gate) * up, wo, group_sizes),
+            PRODUCT_DOWN)
     with jax.named_scope("moe/combine"):
         if held is None:
             y = _unsort(y, order, inverse).reshape(n, k, d)
