@@ -62,6 +62,7 @@ bfloat16 activations by default (MXU-native), fp32 layernorm/softmax.
 
 import dataclasses
 import functools
+import itertools
 import math
 from typing import Any, Callable, Optional, Tuple, Union
 
@@ -1569,35 +1570,53 @@ def apply_with_aux(model, params, tokens, *, router_bias=None,
 
 
 def device_memory_bytes():
-    """The bytes of memory the device a step runs on has (``bytes_limit``
-    of ``memory_stats`` of THIS process's first device, read while the
-    step is traced); ``None`` where the backend tells none, as the CPU
-    does.  So a step lowered on a CPU host for a described topology is
-    planned for no limit (rung 0 everywhere, the program of before),
-    not for the chip it describes, unless the caller stands in for this
+    """``(bytes_limit, bytes_in_use)`` of ``memory_stats`` of THIS
+    process's first device, read while the step is traced: the memory
+    the device a step runs on has, and what is placed on it at that
+    moment.  In a loop that places its state before it lowers the step
+    (``benchmark/loops/spmd*.py``, ``examples/``) the second is the
+    parameters, the optimizer's WHOLE state (an accumulator, a third
+    moment) and the inputs that wait: what stays resident through the
+    step, which :func:`planned_blocks` hands :func:`kept_plan`.  A
+    number the backend does not tell is ``None``, as both are on the
+    CPU.  So a step lowered on a CPU host for a described topology is
+    planned for no limit (rung 0 everywhere, the program of before), not
+    for the chip it describes, unless the caller stands in for this
     function as ``tests/test_chip_compile.py`` does."""
-    stats = jax.devices()[0].memory_stats()
-    return stats.get("bytes_limit") if stats else None
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit"), stats.get("bytes_in_use")
 
 
 @functools.lru_cache(maxsize=None)
 def parameter_bytes(cfg, seq, next_token=False):
-    """The bytes of ``Transformer(cfg)``'s parameter tree as it is
-    initialized for sequences of ``seq`` (with ``next_token`` a
-    :class:`NextTokenModule`'s beside it), from shapes alone."""
+    """``(whole, blocks)``: the bytes of ``Transformer(cfg)``'s parameter
+    tree as it is initialized for sequences of ``seq`` (with
+    ``next_token`` a :class:`NextTokenModule`'s beside it), and of that
+    tree the bytes of each block's own parameters, in the order of the
+    layers (the module's block last); from shapes alone."""
     cfg = dataclasses.replace(cfg, remat=False)
     key = jax.random.PRNGKey(0)
     tokens = jax.ShapeDtypeStruct((1, seq), jnp.int32)
-    trees = [jax.eval_shape(Transformer(cfg, parent=None).init, key, tokens)]
+
+    def nbytes(tree):
+        return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                   for leaf in jax.tree.leaves(tree))
+
+    model = jax.eval_shape(Transformer(cfg, parent=None).init, key,
+                           tokens)["params"]
+    whole = nbytes(model)
+    blocks = [nbytes(model[f"block_{i}"]) for i in range(cfg.n_layers)]
     if next_token:
         table = jax.ShapeDtypeStruct((cfg.vocab_size, cfg.d_model),
                                      jnp.float32)
-        trees.append(jax.eval_shape(
+        module = jax.eval_shape(
             NextTokenModule(cfg, parent=None).init, key,
             jax.ShapeDtypeStruct((1, seq, cfg.d_model), cfg.dtype), tokens,
-            table, jax.ShapeDtypeStruct(table.shape[::-1], jnp.float32)))
-    return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
-               for leaf in jax.tree.leaves([t["params"] for t in trees]))
+            table, jax.ShapeDtypeStruct(table.shape[::-1], jnp.float32)
+        )["params"]
+        whole += nbytes(module)
+        blocks.append(nbytes(module["block"]))
+    return whole, tuple(blocks)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1607,20 +1626,37 @@ class KeptPlan:
     ``kept_names(cfg)`` (empty: rung 0); ``kept``, the bytes the layer
     holds from its forward pass to its backward pass (its input and
     everything it keeps by name); ``params``, the bytes of the
-    parameter tree as the plan reckons it from shapes, ``peak``, the
-    step's predicted peak, and ``budget``, what it was filled to
-    (``None``: no limit known, nothing predicted)."""
+    parameter tree as the plan reckons it from shapes, ``budget``, what
+    the plan was filled to, ``resident``, what it took to stay on the
+    device through the whole step, and ``moments``, the bytes predicted
+    for each moment of the step in its order (``"head"``, ``"block
+    <l>"`` as the backward pass enters block ``l``, from the last to the
+    first, ``"end"``); ``None`` and none where no limit is known and
+    nothing is predicted."""
     names: Tuple[Tuple[str, ...], ...]
     kept: Tuple[int, ...]
     params: Optional[int] = None
-    peak: Optional[int] = None
     budget: Optional[int] = None
+    resident: Optional[int] = None
+    moments: Tuple[Tuple[str, int], ...] = ()
 
     @property
     def rungs(self):
         """0 where a layer keeps ``kept_names(cfg)`` alone, 1 where its
         products' results too."""
         return tuple(int(bool(names)) for names in self.names)
+
+    @property
+    def moment(self):
+        """The name of the moment the predicted peak stands at (the
+        first of the largest)."""
+        return max(self.moments, key=lambda m: m[1])[0] if self.moments else (
+            None)
+
+    @property
+    def peak(self):
+        """The step's predicted peak: the largest of its moments."""
+        return max(n for _, n in self.moments) if self.moments else None
 
     def __str__(self):
         def gib(n):
@@ -1631,10 +1667,12 @@ class KeptPlan:
             for i, (rung, names) in enumerate(zip(self.rungs, self.names)))
         return (f"kept_plan: rung of each layer [{layers}], kept "
                 f"{gib(sum(self.kept))}, predicted peak {gib(self.peak)} "
-                f"of a budget of {gib(self.budget)}")
+                f"at {self.moment or 'no moment'} of a budget of "
+                f"{gib(self.budget)}, resident {gib(self.resident)}")
 
 
-def kept_plan(cfg, batch, seq, device_bytes, next_token=False):
+def kept_plan(cfg, batch, seq, device_bytes, next_token=False,
+              resident=None):
     """What every layer of a recomputed model (``cfg.remat``) holds for
     its backward pass on ``[batch, seq]`` tokens a device, planned from
     bytes: a :class:`KeptPlan` over the ``cfg.n_layers`` blocks and,
@@ -1649,26 +1687,57 @@ def kept_plan(cfg, batch, seq, device_bytes, next_token=False):
     does.  (A rung 2, a block not recomputed at all, is not built: what
     the compiler holds of such a block is no closed form of shapes.)
 
-    The predicted peak is that of the moment the backward pass enters a
-    block: the float32 parameter tree FOUR times (parameter, gradient,
-    Adam's two moments: 16 bytes a parameter), what every layer keeps
-    (its input and its names' ``kept_bytes``), and of the ONE block
-    being differentiated every result it has a name for, made again,
-    and as much for the cotangents, less what it keeps on rung 0 (the
-    largest such; what rung 1 keeps is counted whole, the safe side);
-    beside a next-token module also the model's own logits, which wait
-    for their turn.  Or, where that is more, the head's moment: the
-    logits and their gradient.
+    The predicted peak is the largest of the step's moments, each
+    counting what exists at THAT moment beside what stays on the device
+    through the whole step: ``resident`` as the caller read it, or the
+    float32 parameter tree three times (a parameter and Adam's two
+    moments) where that is more or nothing was read (``None``).
+    With the blocks differentiated from the last to the first:
 
-    Which steps that is conservative for.  A step whose optimizer eats a
-    weight's gradient where it is made (one fused ``optax.adam`` under
-    one ``jit``) compiles to the parameters three times, so the plan
-    leaves a parameter tree of room unused there; that room is what a
-    step needs that holds the whole gradient at once
-    (``clip_by_global_norm``, gradient accumulation, an all-reduce of
-    the tree as ``DistributedOptimizer`` makes).  An optimizer with more
-    state than two moments, or anything else resident on the device, the
-    plan cannot see: it reads ``cfg``, the shapes and one number.
+    - the head's moment: what every layer keeps (its input and its
+      names' ``kept_bytes``), the stack's output and its norm, the
+      logits and their gradient (with a next-token module both heads')
+      and four statistics of a row, 512 bytes each;
+    - the backward pass entering block ``l``: the gradients that exist
+      by then (those of the blocks after ``l`` and of everything that is
+      no block: the head, the embedding, the final norm, the next-token
+      module's own), what the blocks up to ``l`` keep, and of block
+      ``l`` itself every result it has a name for, made again, and as
+      much for the cotangents, less what it keeps on rung 0 (what rung 1
+      keeps is counted whole, the safe side), the stream's cotangent in
+      and out, and the logits' gradient and the head's input, which wait
+      for the head's weight gradient (to the end of the backward pass
+      where the head is the embedding); in the module's block also the
+      model's own logits, which wait for their turn;
+    - the end: the whole gradient tree and nothing kept.
+
+    A gradient is counted as held from the moment it is made to the end
+    of the step: as a step has them that holds its gradients
+    (``clip_by_global_norm``, an all-reduce of the tree as
+    ``DistributedOptimizer`` makes), and more than one has whose
+    optimizer eats a weight's gradient where it is made (one fused
+    ``optax.adam`` under one ``jit``), which compiles to less by the
+    gradients of the blocks already differentiated.  The gradients and
+    what is kept are arithmetic.  What waits for what at a block's
+    moment is the compiler's schedule, read off the live ranges XLA
+    dumps of compiled steps (``tests/xla_live.py``;
+    ``tests/test_chip_compile.py`` holds a toy's step to the head's
+    moment and the first block's, and the cells' steps to the peak), and
+    the plan counts no fragments
+    of the allocator, no transient of a block's forward pass and no
+    moment inside the head's backward (eight cotangents of the stream
+    under ``head_outputs=8``): a moment of the plan is an upper bound of
+    the compiled steps it was read from, not of every step.
+    ``BUDGET_SHARE`` is the room for the rest, and a step over it fails
+    where it is compiled.  What ``resident``
+    leaves out the plan cannot see: it reads ``cfg``, the shapes and two
+    numbers.  ``planned_blocks`` reads it off the device
+    (``device_memory_bytes``), so an optimizer with more state than two
+    moments or a resident accumulator
+    (``DistributedOptimizer(backward_passes_per_step > 1)``) is counted
+    wherever the state is placed before the step is lowered; a step
+    lowered from shapes before its state exists is planned for Adam, and
+    one that accumulates then fails at compile, loudly, not at run time.
 
     With ``device_bytes`` ``None`` (a backend that tells no limit) and
     under ``cfg.passes > 1`` (``kept_names`` says why) every layer
@@ -1690,57 +1759,103 @@ def kept_plan(cfg, batch, seq, device_bytes, next_token=False):
         of, batch, seq, i, group).values()))
         for at, (of, i) in enumerate(layers)
         for group, worth in kept_products(of, i)]
+    params, blocks = parameter_bytes(cfg, seq, next_token)
+    resident = max(3 * params, resident or 0)
+    logits = rows * cfg.head_outputs * cfg.vocab_size * jnp.dtype(
+        cfg.logits_dtype or cfg.dtype).itemsize
+    # the stack's output and its norm, which the final norm's and the
+    # head's backward read, stand beside the logits and their gradient,
+    # and four statistics of a row (the norm's two, the loss's log-sum
+    # and its cotangent) as the kernels write them, a lane tile each
+    head = 2 * logits + 2 * x + 4 * rows * 128 * 4
     # a block's own moment, on rung 0: what it keeps is there already,
     # its products' results are made again, and every named result has a
     # cotangent.  What a name keeps beyond that is counted whole on top:
     # whether the block would have held it at that moment anyway is the
-    # compiler's schedule
-    made = [sum(n for _, at, _, n in products if at == layer)
-            for layer in range(len(layers))]
-    moment = max(k + 2 * n for k, n in zip(kept, made))
-    params = parameter_bytes(cfg, seq, next_token)
-    logits = rows * cfg.head_outputs * cfg.vocab_size * jnp.dtype(
-        cfg.logits_dtype or cfg.dtype).itemsize
-    head = 2 * logits
+    # compiler's schedule.  The stream's cotangent enters and leaves; the
+    # logits' gradient and the head's input wait for the head's weight
+    # gradient, which the compiler may make last (with a tied head it
+    # does: the embedding's gradient has both uses)
+    own = [k + 2 * sum(n for _, at, _, n in products if at == layer)
+           + 3 * x + logits for layer, k in enumerate(kept)]
     if next_token:  # the model's logits wait while the module's are read
-        moment += logits
+        own[-1] += logits
         head += 2 * rows * cfg.vocab_size * jnp.dtype(cfg.dtype).itemsize
-    fixed = 4 * params + max(head, moment)
     budget = int(BUDGET_SHARE * device_bytes)
+    # the gradients that exist as the backward pass enters each block:
+    # everything that is no block's, and the blocks' after it
+    after = list(itertools.accumulate(
+        reversed(blocks), initial=params - sum(blocks)))[-2::-1]
+
+    def moments(kept):
+        """``[(moment, bytes)]`` of the step with ``kept``: the head,
+        the blocks from the last to the first, the end."""
+        held = list(itertools.accumulate(kept))
+        return ([("head", resident + held[-1] + head)]
+                + [(f"block {layer}", resident + after[layer] + held[layer]
+                    + own[layer]) for layer in reversed(range(len(layers)))]
+                + [("end", resident + params)])
 
     for _, at, group, n in sorted(products, key=lambda p: p[:2]):
-        if fixed + sum(kept) + n <= budget:
-            kept[at] += n
+        with_it = kept[:at] + [kept[at] + n] + kept[at + 1:]
+        if max(m for _, m in moments(with_it)) <= budget:
+            kept = with_it
             names[at] += group
-    return KeptPlan(tuple(names), tuple(kept), params, fixed + sum(kept),
-                    budget)
+    return KeptPlan(tuple(names), tuple(kept), params, budget, resident,
+                    tuple(moments(kept)))
 
 
-def planned_blocks(module, tokens, next_token=False):
+def planned_blocks(module, tokens, next_token=False, say=False):
     """``(classes, plan)`` for ``module`` (a :class:`Transformer` or a
     :class:`NextTokenModule`) on ``tokens [..., T]``: the class every
     layer's block is made of (the next-token module's last): ``Block``
     itself where the model is not recomputed (``cfg.remat``: recompute
     what does not fit), else ``Block`` recomputed, keeping what
-    :func:`kept_plan` says for the device's memory (while the module is
-    initialized, which differentiates nothing: for none).  ``plan`` is
-    ``None`` where nothing is recomputed.
+    :func:`kept_plan` says for the device's memory and what is placed on
+    it (``device_memory_bytes``: its ``bytes_in_use`` is the plan's
+    ``resident``; while the module is initialized, which differentiates
+    nothing, no limit is told).  ``plan`` is ``None`` where nothing is
+    recomputed.  With ``say`` the plan is logged (``kept_plan:``, at
+    ``info``), and at ``warning`` that what was in use on the device made
+    the layers keep less than the parameters and Adam's two moments
+    alone would have.
 
     The plan is made where the step is traced, for the shapes the trace
-    sees and ONE device of this process.  Inside ``shard_map`` (the
-    ``spmd`` loop's step) ``tokens`` is a device's own batch and the
-    plan is that device's.  Under a plain ``jit`` over a sharded batch
-    ``tokens.shape`` is the GLOBAL batch and the parameters count whole
-    however they are sharded: the plan then reckons every device's
-    activations on one, keeps less than there is room for and never
-    more (``tests/test_transformer_kept.py`` compiles such a step)."""
+    sees and ONE device of this process, FROM THE DEVICE'S STATE AT THAT
+    MOMENT, which ``jit``'s cache does not key on: trace (and trace
+    again) with the device at rest, holding the step's state and inputs
+    and nothing else.  Arrays left over from other work, or a step in
+    flight whose activations are still there, read as ``resident``: the
+    blocks then keep less, the step is slower by what is made again, and
+    the warning is the only sign.  (An accumulator or a third moment
+    gives the same warning, rightly: the line says what was read.)
+    Inside ``shard_map`` (the ``spmd`` loop's step) ``tokens`` is a
+    device's own batch and the plan is that device's.  Under a plain
+    ``jit`` over a sharded batch ``tokens.shape`` is the GLOBAL batch
+    and the parameters count whole however they are sharded: the plan
+    then reckons every device's activations on one, keeps less than
+    there is room for and never more
+    (``tests/test_transformer_kept.py`` compiles such a step)."""
     cfg = module.cfg
     if not cfg.remat:
         return [Block] * (cfg.n_layers + bool(next_token)), None
-    plan = kept_plan(
-        cfg, math.prod(tokens.shape[:-1]), tokens.shape[-1],
-        None if module.is_initializing() else device_memory_bytes(),
-        next_token)
+    limit, in_use = (None, None) if module.is_initializing() else (
+        device_memory_bytes())
+    shape = (math.prod(tokens.shape[:-1]), tokens.shape[-1], limit, next_token)
+    plan = kept_plan(cfg, *shape, in_use)
+    if say and not module.is_initializing():
+        log = get_logger()
+        log.info("%s", plan)
+        alone = plan if in_use is None else kept_plan(cfg, *shape)
+        if plan.names != alone.names:
+            log.warning(
+                "kept_plan: %.3f GiB were in use on the device while the "
+                "step was traced, more than the parameters three times "
+                "(%.3f GiB), and the layers keep %.3f GiB where they would "
+                "have kept %.3f: right for an optimizer with more state "
+                "than Adam's; otherwise trace the step with the device at "
+                "rest", in_use / 2 ** 30, 3 * plan.params / 2 ** 30,
+                sum(plan.kept) / 2 ** 30, sum(alone.kept) / 2 ** 30)
     made = {names: keeping(Block, kept_names(cfg) + names)
             for names in set(plan.names)}
     return [made[names] for names in plan.names], plan
@@ -1788,9 +1903,8 @@ class Transformer(nn.Module):
         # layer more of the plan, and the module plans with the same
         # arguments.  Another caller that asks for the hidden state has
         # a block counted that is not there, and keeps less
-        blocks, plan = planned_blocks(self, tokens, next_token=return_hidden)
-        if plan is not None and not self.is_initializing():
-            get_logger().info("%s", plan)
+        blocks, _ = planned_blocks(self, tokens, next_token=return_hidden,
+                                   say=True)
 
         def one_pass(mdl, carry, _):
             """The stack once, closed by the final norm; the carry is

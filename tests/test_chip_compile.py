@@ -16,6 +16,7 @@ topology at once collide on libtpu's lock file.  Code that asks
 import importlib
 import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -517,11 +518,16 @@ def cell_step(v5e):
     mesh = make_mesh({"hvd": 1}, devices=v5e[:1])
     compiled = {}
 
-    def compile_step(workload, transformer=None, variant=None, depth=None):
+    def compile_step(workload, transformer=None, variant=None, depth=None,
+                     limit=V5E_BYTES_LIMIT):
         """``variant`` names what the caller changed around the call: a
-        step of its own in the cache."""
+        step of its own in the cache.  ``limit``: what the device says
+        it has at the cell's own depth (``None``: it tells none, and
+        nothing is planned)."""
         depth = depth or {}
-        key = (workload, transformer, variant, tuple(sorted(depth.items())))
+        limit = None if depth else limit
+        key = (workload, transformer, variant, tuple(sorted(depth.items())),
+               limit)
         if key in compiled:
             return compiled[key]
         compile_step.misses.append(key)
@@ -537,8 +543,7 @@ def cell_step(v5e):
             (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
         was = horovod_tpu.models.Transformer, program.device_memory_bytes
         horovod_tpu.models.Transformer = transformer or was[0]
-        program.device_memory_bytes = lambda: (
-            None if depth else V5E_BYTES_LIMIT)
+        program.device_memory_bytes = lambda: (limit, None)
         try:
             compiled[key] = step.lower(
                 _shaped(mesh, params, P()), _shaped(mesh, extra, P()),
@@ -571,54 +576,79 @@ def _fits_one_chip(compiled):
     return _bytes(compiled) < HBM_BYTES
 
 
-def _filled_as_planned(compiled, plan, parent_gib):
+def _filled_as_planned(compiled, plan, parent_gib, more_gib=0.0):
     """A recomputed cell's step fills the chip as its plan says: at most
-    the line of 15.0 GiB and at least what it took before blocks kept
-    what there is room for (``parent_gib``, compiled at the parent of PR
-    50); the plan keeps a name only under its budget, and its predicted
-    peak is no more than 0.3 GiB under what the compiler found.  (It is
-    well over it: the plan holds the parameters four times, this step's
-    fused Adam three.)"""
+    the line of 15.0 GiB and at least what it took at the parent of PR 54
+    (``parent_gib``, compiled there, where one gradient tree was charged
+    whole at the backward's first block and Laguna, JoyAI and Phi-4 kept
+    nothing) and four fifths of what the plan keeps beyond that
+    (``more_gib``); the plan keeps a name only under its budget, and its
+    predicted peak, the largest of the step's moments, is no less than
+    what the compiler found (the issue allowed it 0.3 GiB under; it is
+    over in every cell: the plan holds each gradient from where it is
+    made to the end, this step's fused Adam eats it there)."""
     gib = 2 ** 30
-    assert parent_gib * gib - 2 ** 20 <= _bytes(compiled) <= 15.0 * gib
+    assert (parent_gib + 0.8 * more_gib) * gib - 2 ** 20 <= _bytes(
+        compiled) <= 15.0 * gib
     assert plan.budget == int(0.95 * V5E_BYTES_LIMIT)
     assert plan.peak <= plan.budget or not any(plan.names)
-    assert plan.peak >= _bytes(compiled) - 0.3 * gib
+    assert plan.peak >= _bytes(compiled)
     return True
 
 
-def test_a_step_that_holds_its_gradients_fits_its_plan(v5e, monkeypatch):
-    """A recomputed toy's step under ``clip_by_global_norm`` ahead of
-    Adam, which wants the whole gradient tree before the first update,
+@pytest.mark.parametrize("held", ["clip", "accumulate", "tied"])
+def test_a_step_that_holds_its_gradients_fits_its_plan(v5e, monkeypatch,
+                                                       tmp_path, held):
+    """A recomputed toy's step that holds its whole gradient tree,
     compiled for one described chip that has room for ONE layer's ``up``
     of two: the first layer keeps it, the second makes it again, and the
-    step takes no more than the plan predicted.  (Here the head's
-    logits outweigh the parameters, so the prediction is close: the
-    account of a block's moment is held, not the parameters' count.)"""
+    step takes what the plan predicted, as a whole and moment by moment
+    (``tests/xla_live.py`` reads what is live where off XLA's dump).
+    ``clip``: under ``clip_by_global_norm`` ahead of Adam, which wants
+    the whole tree before the first update; ``tied``: the same with the
+    embedding for a head.  ``accumulate``: under
+    ``DistributedOptimizer(backward_passes_per_step=2)``, whose
+    accumulator is a fourth tree resident on the device; the plan is
+    handed what the device would say is in use once the state is placed
+    (``device_memory_bytes``' second number), and without it the same
+    device would have been planned a tree short.
+
+    Here the logits outweigh the parameters and a block's moment, so the
+    plan's peak stands at the head, where the compiled step's does, and
+    that moment is arithmetic: the plan's and at most 4% less.  As the
+    backward pass enters its first block (the last layer), where the
+    peak of every cell of the benchmark stands, the compiled step holds
+    no more than the plan charges, and under a tied head the two things
+    the plan charges there for the compiler's schedule alone are live
+    through that whole block: the logits' gradient and the head's input,
+    which wait for the head's weight gradient, the embedding's second
+    use.  (A jaxlib that makes that product earlier fails here, and the
+    two terms can go.  Under a head of its own the compiler is free:
+    this step makes the product at once, the same toy with room for both
+    layers' ``up`` makes it after block 1, so the plan charges it and
+    nothing is held here.  Nor is the LAST block's moment: with room to
+    spare the scheduler puts weight gradients off, and one block's
+    leftovers stand in the next one's backward pass.)"""
     import optax
 
+    import horovod_tpu as hvd
     from horovod_tpu.models import (Transformer, TransformerConfig, lm_loss,
                                     transformer)
 
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    try:
+        import xla_live
+    finally:
+        sys.path.pop(0)
     batch, seq = 16, 1024
     cfg = TransformerConfig(vocab_size=8192, n_layers=2, d_model=512,
                             n_heads=4, d_ff=2048, max_len=seq, remat=True,
-                            dtype=jnp.bfloat16)
-    up = transformer.kept_bytes(cfg, batch, seq, 0, ("mlp_up",))["mlp_up"]
-    floor = transformer.kept_plan(cfg, batch, seq, 1)
-    room = -(-(floor.peak + up + up // 2) * 20 // 19)
-    monkeypatch.setattr(transformer, "device_memory_bytes", lambda: room)
-    plan = transformer.kept_plan(cfg, batch, seq, room)
-    assert plan.names == (("mlp_up",), ()) and plan.peak == floor.peak + up
+                            dtype=jnp.bfloat16, tie_head=held == "tied")
     model = Transformer(cfg)
-    opt = optax.chain(optax.clip_by_global_norm(1.0), optax.adam(1e-3))
-
-    def step(params, state, tokens):
-        grads = jax.grad(lambda p: lm_loss(
-            model.apply({"params": p}, tokens), tokens))(params)
-        updates, state = opt.update(grads, state, params)
-        return optax.apply_updates(params, updates), state
-
+    opt = (hvd.DistributedOptimizer(
+        optax.adam(1e-3), named_axes=(), backward_passes_per_step=2)
+        if held == "accumulate" else optax.chain(
+            optax.clip_by_global_norm(1.0), optax.adam(1e-3)))
     tokens = _on(v5e[0], (batch, seq), jnp.int32)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                             tokens)["params"]
@@ -626,11 +656,50 @@ def test_a_step_that_holds_its_gradients_fits_its_plan(v5e, monkeypatch):
     params, state = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=placed),
         (params, jax.eval_shape(opt.init, params)))
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, state, tokens).compile()
+    in_use = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, state))) if held == "accumulate" else None
+    up = transformer.kept_bytes(cfg, batch, seq, 0, ("mlp_up",))["mlp_up"]
+    floor = transformer.kept_plan(cfg, batch, seq, 1, resident=in_use)
+    assert floor.moment == "head"
+    room = -(-(floor.peak + up + up // 2) * 20 // 19)
+    monkeypatch.setattr(transformer, "device_memory_bytes",
+                        lambda: (room, in_use))
+    plan = transformer.kept_plan(cfg, batch, seq, room, resident=in_use)
+    assert plan.names == (("mlp_up",), ()) and plan.peak == floor.peak + up
+    if held == "accumulate":
+        assert plan.resident == in_use > 4 * plan.params
+        assert transformer.kept_plan(cfg, batch, seq, room).names == (
+            ("mlp_up",),) * 2
+
+    def step(params, state, tokens):
+        grads = jax.grad(lambda p: lm_loss(
+            model.apply({"params": p}, tokens), tokens))(params)
+        updates, state = opt.update(grads, state, params)
+        return optax.apply_updates(params, updates), state
+
+    compiled = xla_live.compile_with_dump(jax.jit(
+        step, donate_argnums=(0, 1)).lower(params, state, tokens), tmp_path)
     again = _products(_recomputed(compiled.as_text(), "/mlp/up/"))
     assert len(again) == 1 and "block_1" in again[0]
     assert 0.9 * plan.peak < _bytes(compiled) <= plan.peak
+    # moment by moment
+    dump = xla_live.Dump(tmp_path)
+    windows, found, want = dump.windows(), dump.moments(), dict(plan.moments)
+    assert list(windows) == ["head", "block 1", "block 0"]
+    assert dump.arguments <= plan.resident + tokens.size * 4 + 2 ** 16
+    assert max(found, key=found.get) == plan.moment == "head"
+    # (what the step is short of the head's moment lies in VMEM at these
+    # sizes: the head's input and two of a row's statistics)
+    assert 0.96 * want["head"] <= found["head"][0] <= want["head"]
+    assert found["block 1"][0] <= want["block 1"]
+    # what waits for the head's weight gradient
+    x = batch * seq * cfg.d_model * 2
+    waiting = {"transpose(jvp(loss))/": x * cfg.vocab_size // cfg.d_model,
+               "/jvp(Transformer)/ln_f/": x}
+    for scope, size in waiting.items() if held == "tied" else ():
+        (first, last), = [at[2:] for name, at in dump.heap.items()
+                          if at[1] == size and scope in dump.source(name)]
+        assert first < windows["head"][1] and last >= windows["block 0"][0]
 
 
 def test_dense_cell_step_compiles_for_v5e(cell_step):
@@ -691,16 +760,19 @@ def test_sparse_cell_step_compiles_for_v5e(cell_step, workload,
         # again
         assert not _products(_recomputed(
             text, "/attn/out/", "/attn/latent/q_a/", "/attn/latent/kv_a/"))
-        # the plan: at 16 bytes a parameter the 1.1 GiB under the line are
-        # the gradient tree's, so every block (the module's too) stands
-        # on rung 0 and its products run again
+        # the plan, moment by moment: the dense layer's pair, the five
+        # shared experts' (the module's among them) and block 0's ``q_b``
+        # are kept, 0.86 GiB; the other five ``q_b``, every ``kv_b`` and
+        # the held experts' products run again
         plan = cell_step.plan(workload, next_token=True)
-        assert not any(plan.names) and len(plan.names) == 6
-        assert len(_products(_recomputed(text, "/attn/latent/q_b/"))) == 6
+        pair = ("mlp_gate", "mlp_up")
+        assert list(plan.names) == [pair + ("latent_q_b",)] + [pair] * 5
+        assert plan.moment == "block 5"
+        assert len(_products(_recomputed(text, "/attn/latent/q_b/"))) == 5
         assert len(_products(_recomputed(text, "/attn/latent/kv_b/"))) == 6
-        assert len(_products(_recomputed(text, "/mlp/gate/"))) == 1
-        assert len(_products(_recomputed(text, "/moe/shared/"))) == 5 * 2
-        assert _filled_as_planned(compiled, plan, 13.87)
+        assert not _products(_recomputed(text, "/mlp/gate/", "/mlp/up/",
+                                         "/moe/shared/"))
+        assert _filled_as_planned(compiled, plan, 13.873, 0.86)
         # nor what its routing decided: of the five routed blocks the
         # router's product and no sort (``top_k``'s over [N, E], the
         # slots') in the recomputation; the weights are read off the
@@ -841,15 +913,18 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     # projections' scopes are their weights' casts to bfloat16, which the
     # backward products read, and under ``rope`` the tables ``[T, D]`` the
     # backward turn reads (``turn`` keeps nothing else): no array with
-    # heads in it.  The plan: at 16 bytes a parameter the 2.1 GiB under
-    # the line are the gradient tree's, so every block stands on rung 0
-    # and the feed-forwards' products run again
+    # heads in it.  The plan, moment by moment: every SwiGLU's pair
+    # (the dense layer's, the shared experts') and the held experts'
+    # ``gate`` and ``up`` are kept, 1.50 GiB; the experts' ``down`` runs
+    # again
     plan = cell_step.plan("laguna_s_2_1-spmd-1chip")
-    assert not any(plan.names) and len(plan.names) == 5
-    assert _products(_recomputed(text, "/mlp/up/"))
-    # (a grouped product carries no scope: three forward, three again,
+    pair = ("mlp_gate", "mlp_up")
+    assert list(plan.names) == [pair] + [pair + ("moe_gate", "moe_up")] * 4
+    assert plan.moment == "block 4"
+    assert not _products(_recomputed(text, "/mlp/", "/moe/shared/"))
+    # (a grouped product carries no scope: three forward, ``down`` again,
     # six backward in each of the four routed layers)
-    assert len(_grouped_products(text)) == 4 * 12
+    assert len(_grouped_products(text)) == 4 * 10
     ahead = _recomputed(
         text, "/attn/window/q/", "/attn/window/kv/", "/attn/window/out/",
         "/attn/global/q/", "/attn/global/kv/", "/attn/global/out/")
@@ -858,7 +933,7 @@ def test_window_and_full_layer_cell_step_compiles_for_v5e(cell_step):
     assert not [line for line in _recomputed(text, "/rope/") if re.search(
         r"\[(1,)?(8192,(72|48|8)|(72|48|8),8192),\d+\]", line)]
     assert _fits_one_chip(compiled)
-    assert _filled_as_planned(compiled, plan, 12.85)
+    assert _filled_as_planned(compiled, plan, 12.849, 1.50)
 
 
 def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
@@ -922,7 +997,8 @@ def test_conv_and_attention_cell_step_compiles_for_v5e(cell_step):
     assert len(_grouped_products(text)) == 4 * 9
     assert len(_products(_recomputed(text, "/moe/route/"))) == 4
     assert _fits_one_chip(compiled)
-    assert _filled_as_planned(compiled, plan, 8.293)
+    assert plan.moment == "block 4"
+    assert _filled_as_planned(compiled, plan, 10.948)
 
 
 def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
@@ -995,17 +1071,24 @@ def test_state_space_and_differential_cell_step_compiles_for_v5e(cell_step):
     assert len([line for line in kernels if "[16384,25008]" in line]) == 2
     # the head is the embedding: no parameter of a head's shape
     assert "f32[2560,25008]" not in text
-    # the plan: at 16 bytes a parameter the 1.85 GiB under the line are
-    # the gradient tree's, so every block stands on rung 0: the mixers'
-    # first products and the six SwiGLUs' pairs run again.  No more
-    # memory than with the scans as loops
+    # the plan, moment by moment: the three mixers' first products and
+    # block 0's SwiGLU pair are kept, 1.41 GiB; the other five pairs run
+    # again
     plan = cell_step.plan("phi4_mini_flash-spmd-1chip")
-    assert not any(plan.names) and len(plan.names) == 6
-    assert len(_products(_recomputed(text, "/mixer/ssm/in/"))) == 2
+    assert list(plan.names) == [
+        ("mixer_in", "mlp_gate", "mlp_up"), (), ("mixer_in",), (),
+        ("mixer_in",), ()]
+    assert plan.moment == "block 2"
+    assert not _products(_recomputed(text, "/mixer/ssm/in/", "/mixer/gmu/in/"))
     for name in ("gate", "up"):
-        assert len(_products(_recomputed(text, f"/mlp/{name}/"))) == 6
-    assert _filled_as_planned(compiled, plan, 13.145)
-    assert _bytes(compiled) <= 13.614 * 2 ** 30
+        assert len(_products(_recomputed(text, f"/mlp/{name}/"))) == 5
+    assert _filled_as_planned(compiled, plan, 13.145, 1.41)
+    # no more memory than with the scans as loops (13.614 GiB at the
+    # parent of PR 47), read where no plan fills the chip: the step of a
+    # device that tells no limit, every block on rung 0
+    bare = cell_step("phi4_mini_flash-spmd-1chip", limit=None)
+    assert len(_products(_recomputed(bare.as_text(), "/mlp/gate/"))) == 6
+    assert _bytes(bare) <= 13.614 * 2 ** 30 < _bytes(compiled)
 
 
 def test_chunk_summary_cell_step_compiles_for_v5e(cell_step):
@@ -1307,12 +1390,15 @@ def test_looped_cell_step_keeps_what_it_kept(cell_step, monkeypatch):
 def test_a_cell_is_compiled_at_its_own_depth_once(cell_step):
     """What the tests above compiled (this one runs after them): a cell
     at its published depth once, under no other ``Transformer`` and no
-    variant, but for OLMoE's, whose cell is one layer as it is."""
+    variant, but for OLMoE's, whose cell is one layer as it is (and
+    Phi-4's once more for a device that tells no limit)."""
     print("\n".join(map(str, cell_step.misses)))
     at_depth = [key for key in cell_step.misses if not key[3]]
     assert len(set(at_depth)) == len(at_depth)
-    assert {key for key in at_depth if key[1] or key[2]} <= {
+    assert {key[:4] for key in at_depth if key[1] or key[2]} <= {
         ("olmoe_1b_7b-spmd-1chip", None, "unnamed", ())}
+    assert [key[0] for key in at_depth if key[4] is None] == [
+        "phi4_mini_flash-spmd-1chip"]
 
 
 @pytest.mark.parametrize("chips,compression,hierarchical", [

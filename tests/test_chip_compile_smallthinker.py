@@ -71,7 +71,8 @@ def step(v5e):
         (cell.job["per_chip_batch"], cell.job["seq_len"]), jnp.int32)
     patch = pytest.MonkeyPatch()
     patch.setattr(jax, "default_backend", lambda: "tpu")
-    patch.setattr(program, "device_memory_bytes", lambda: V5E_BYTES_LIMIT)
+    patch.setattr(program, "device_memory_bytes",
+                  lambda: (V5E_BYTES_LIMIT, None))
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -107,21 +108,24 @@ def _scoped_vmem(call):
 
 
 def test_the_step_fits_one_chip_as_its_plan_says(step):
-    """12.6 GiB compiled: under a chip's 16 GiB and the plan's line of
-    15.0, over the 9.78 GiB of state alone; the plan (16 bytes a
-    parameter, four trees where this step's fused Adam holds three)
-    predicts no less than 0.3 GiB under the compiled step and keeps
-    every layer's ``moe_gate`` and ``moe_up`` within its budget."""
+    """13.1 GiB compiled: under a chip's 16 GiB and the plan's line of
+    15.0, over the 9.78 GiB of state alone; the plan (the largest of the
+    step's moments, each gradient held from where it is made) predicts
+    no less than the compiled step and keeps every layer's
+    ``moe_gate`` and ``moe_up`` and block 0's ``moe_down`` within its
+    budget (12.6 GiB and no ``moe_down`` while a whole gradient tree was
+    charged at the backward's first block)."""
     from horovod_tpu.parallel import moe
 
     compiled, plan = step
     gib = 2 ** 30
-    assert 12.0 * gib <= _bytes(compiled) <= 15.0 * gib < HBM_BYTES
+    assert 13.0 * gib <= _bytes(compiled) <= 15.0 * gib < HBM_BYTES
     assert plan.params == 4 * 656_529_920
     assert plan.budget == int(0.95 * V5E_BYTES_LIMIT)
-    assert plan.peak <= plan.budget
-    assert plan.peak >= _bytes(compiled) - 0.3 * gib
-    assert plan.names == ((moe.PRODUCT_GATE, moe.PRODUCT_UP),) * 4
+    assert plan.peak <= plan.budget and plan.moment == "block 3"
+    assert plan.peak >= _bytes(compiled)
+    assert plan.names == (moe.PRODUCT_NAMES,) + (
+        (moe.PRODUCT_GATE, moe.PRODUCT_UP),) * 3
 
 
 def test_the_flash_calls_take_grouped_heads_at_16384(step):

@@ -127,8 +127,9 @@ PRODUCTS = {
     "routed_held": (KEPT_GATE, KEPT_UP) + moe.PRODUCT_NAMES}
 
 
-def with_room(monkeypatch, room=ROOM):
-    monkeypatch.setattr(transformer, "device_memory_bytes", lambda: room)
+def with_room(monkeypatch, room=ROOM, in_use=None):
+    monkeypatch.setattr(transformer, "device_memory_bytes",
+                        lambda: (room, in_use))
 
 
 @pytest.mark.parametrize("kind,dtype,room", [
@@ -508,57 +509,191 @@ GATE_UP = (KEPT_GATE, KEPT_UP)
 EXPERT_GATE, EXPERT_UP, EXPERT_DOWN = moe.PRODUCT_NAMES
 
 
-@pytest.mark.parametrize("workload,names,bytes_of,kept,params,peak", [
+def beside(rows, x):
+    """What stands at the head beside the logits and their gradient: the
+    stack's output and its norm, and four statistics of a row (the
+    norm's two, the loss's log-sum and its cotangent), a lane tile of
+    float32 each."""
+    return 2 * x + 4 * rows * 128 * 4
+
+
+def phi4_moments():
+    """Phi-4's plan, each moment's sum written out: the mixers' first
+    products in blocks 0, 2 and 4 and block 0's SwiGLU pair."""
+    state = 3 * 2_788_377_088           # a parameter and Adam's two moments
+    grads = (479_580_160, 393_289_216, 479_580_160, 393_289_216,
+             419_471_360, 367_064_576)  # the blocks' own parameters
+    rest = 256_102_400                  # the tied embedding, the final norm
+    logits, x = 16384 * 25008 * 2, 16384 * 2560 * 2
+    wide, half = 335_544_320, 167_772_160  # [2, 8192, 10240], [.., 5120]
+    ssm, attn, gmu = 419_430_400, 547_880_960, 167_772_160  # rung 0
+    kept = (ssm + wide + 2 * wide, attn, ssm + wide, attn, gmu + half, attn)
+    # the logits' gradient and the head's input, which wait for the head's
+    # weight gradient, and the stream's two cotangents
+    waits = logits + 3 * x
+    return {
+        "block 2": state + rest + sum(grads[3:]) + sum(kept[:3])
+        + ssm + 2 * (wide + 2 * wide) + waits,
+        "head": state + sum(kept) + 2 * logits + beside(16384, x),
+        "block 5": state + rest + sum(kept) + attn + 2 * 2 * wide + waits,
+        "block 4": state + rest + grads[5] + sum(kept[:5])
+        + gmu + 2 * (half + 2 * wide) + waits,
+        "block 3": state + rest + sum(grads[4:]) + sum(kept[:4])
+        + attn + 2 * 2 * wide + waits,
+        "block 1": state + rest + sum(grads[2:]) + sum(kept[:2])
+        + attn + 2 * 2 * wide + waits,
+        "block 0": state + rest + sum(grads[1:]) + kept[0]
+        + ssm + 2 * (wide + 2 * wide) + waits,
+        "end": state + 2_788_377_088}
+
+
+def laguna_moments():
+    """Laguna's: every SwiGLU pair there is (the dense layer's, the
+    shared experts') and the held experts' ``gate`` and ``up``."""
+    state = 3 * 3_244_068_864
+    grads = (629_760_000, 595_451_904, 595_451_904, 595_451_904,
+             519_659_520)
+    rest = 308_293_632      # embedding, head, final norm
+    logits, x = 8192 * 12544 * 2, 8192 * 3072 * 2
+    dense, shared, held, down = (402_653_184, 33_554_432, 268_435_456,
+                                 402_653_184)
+    full, sliding, last = 337_117_184, 439_156_736, 337_707_008  # rung 0
+    kept = (full + dense,) + (sliding + shared + held,) * 3 + (
+        last + shared + held,)
+    routed = 2 * (shared + held + down)
+    waits = logits + 3 * x
+    return {
+        "block 4": state + rest + sum(kept) + last + routed + waits,
+        "head": state + sum(kept) + 2 * logits + beside(8192, x),
+        "block 3": state + rest + grads[4] + sum(kept[:4])
+        + sliding + routed + waits,
+        "block 2": state + rest + sum(grads[3:]) + sum(kept[:3])
+        + sliding + routed + waits,
+        "block 1": state + rest + sum(grads[2:]) + sum(kept[:2])
+        + sliding + routed + waits,
+        "block 0": state + rest + sum(grads[1:]) + kept[0]
+        + full + 2 * dense + waits,
+        "end": state + 3_244_068_864}
+
+
+def joyai_moments():
+    """JoyAI's: the dense layer's pair, the five shared experts' and ONE
+    ``q_b`` of six; the next-token module's block is the sixth, and the
+    backward pass enters it first, while the model's logits wait."""
+    state = 3 * 2_721_759_232
+    grads = (281_567_232,) + (428_367_872,) * 5
+    rest = 298_352_640      # embedding, head, two final norms, the
+    #                         module's own norms and projection
+    logits, x = 16384 * 16160 * 2, 16384 * 2048 * 2
+    q_b, kv_b, dense, shared, held, down = (
+        201_326_592, 268_435_456, 469_762_048, 50_331_648, 402_653_184,
+        536_870_912)
+    first, routed = 339_738_624, 340_787_200  # rung 0
+    kept = (first + q_b + dense,) + (routed + shared,) * 5
+    made = 2 * (q_b + kv_b + shared + held + down)
+    waits = logits + 3 * x
+    return {
+        "block 5": state + rest + sum(kept) + routed + made + waits + logits,
+        "head": state + sum(kept) + 2 * logits + 2 * logits + beside(16384, x),
+        **{f"block {i}": state + rest + sum(grads[i + 1:])
+           + sum(kept[:i + 1]) + routed + made + waits for i in (4, 3, 2, 1)},
+        "block 0": state + rest + sum(grads[1:]) + kept[0]
+        + first + 2 * (q_b + kv_b + dense) + waits,
+        "end": state + 2_721_759_232}
+
+
+def smallthinker_moments():
+    """SmallThinker's: ``gate`` and ``up`` of the held experts in all four
+    blocks, as under four trees, and block 0's ``down`` beside them."""
+    state = 3 * 2_626_119_680
+    grads = (462_049_280,) * 4
+    rest = 777_922_560      # embedding, head, final norm
+    logits, x = 16384 * 37984 * 2, 16384 * 2560 * 2
+    held, down, rung0 = 301_989_888, 503_316_480, 438_829_056
+    kept = (rung0 + held + down,) + (rung0 + held,) * 3
+    own = rung0 + 2 * (held + down) + logits + 3 * x
+    return {
+        "block 3": state + rest + sum(kept) + own,
+        "head": state + sum(kept) + 2 * logits + beside(16384, x),
+        "block 2": state + rest + grads[3] + sum(kept[:3]) + own,
+        "block 1": state + rest + sum(grads[2:]) + sum(kept[:2]) + own,
+        "block 0": state + rest + sum(grads[1:]) + kept[0] + own,
+        "end": state + 2_626_119_680}
+
+
+@pytest.mark.parametrize("workload,names,bytes_of,kept,params,moments", [
     # room for every product there is: ``in`` [2, 8192, 6144] of the four
     # conv mixers, the dense layer's pair [2, 8192, 11776] and the experts'
     # three results over the buffer of 65,536 rows; 3.97 GiB more than the
-    # 0.785 of rung 0.  The peak: 16 bytes a parameter, what is kept, and
-    # block 0's moment (its input and sum, 2 x 67.1 MB, and ``in`` and the
-    # pair made again and their cotangents, 2 x 973.1 MB)
+    # 0.785 of rung 0.  The peak, as the backward pass enters the LAST
+    # block: 12 bytes a parameter, the gradients of what is no block (the
+    # embedding, the head, the final norm), everything kept, and block 4's
+    # moment (its input and sum, 2 x 67.1 MB, ``in`` and the experts'
+    # three made again and their cotangents, 2 x 872.4 MB, the logits'
+    # gradient, the head's input and the stream's two cotangents)
     ("lfm2_24b_a2b-spmd-1chip",
      [(KEPT_IN,) + GATE_UP, moe.PRODUCT_NAMES]
      + [(KEPT_IN,) + moe.PRODUCT_NAMES] * 3,
      {(0, KEPT_IN): 201_326_592, (0, KEPT_GATE): 385_875_968,
       (1, EXPERT_UP): 201_326_592, (2, EXPERT_DOWN): 268_435_456},
      5_104_467_968, 486_062_208,
-     16 * 486_062_208 + 5_104_467_968 + 134_217_728 + 2 * 973_078_528),
-    # the four cells below: at 16 bytes a parameter rung 0 alone is over
-    # the line of 14.96 GiB (their steps compile to 13.1-14.9 with a fused
-    # Adam, which holds the parameters three times: the room under the line
-    # there is the gradient tree's), so nothing is kept
-    ("phi4_mini_flash-spmd-1chip", [()] * 6,
+     {"block 4": 12 * 486_062_208 + 134_225_920 + 5_104_467_968
+      + 134_742_016 + 2 * 872_415_232 + 268_435_456 + 3 * 67_108_864}),
+    # the three cells below kept nothing while a whole gradient tree was
+    # charged at the backward's first block (16 bytes a parameter put rung
+    # 0 alone over the line of 14.96 GiB); moment by moment they keep what
+    # PR 50's first round kept with three trees, 1.41 / 1.50 / 0.86 GiB,
+    # and compile to 14.628 / 14.082 / 14.690 GiB
+    ("phi4_mini_flash-spmd-1chip",
+     [(KEPT_IN,) + GATE_UP, (), (KEPT_IN,), (), (KEPT_IN,), ()],
      {(0, KEPT_IN): 335_544_320, (0, KEPT_UP): 335_544_320,
       (4, KEPT_IN): 167_772_160, (1, KEPT_GATE): 335_544_320},
-     2_650_275_840, 697_094_272, 16_236_480_512),
-    ("laguna_s_2_1-spmd-1chip", [()] * 5,
+     4_160_225_280, 697_094_272, phi4_moments()),
+    ("laguna_s_2_1-spmd-1chip",
+     [GATE_UP] + [GATE_UP + (EXPERT_GATE, EXPERT_UP)] * 4,
      {(0, KEPT_GATE): 201_326_592, (1, KEPT_GATE): 16_777_216,
       (1, EXPERT_GATE): 134_217_728, (1, EXPERT_DOWN): 402_653_184},
-     1_992_294_400, 811_017_216, 16_817_012_736),
+     3_602_907_136, 811_017_216, laguna_moments()),
     # the next-token module's block is the sixth
-    ("joyai_llm_flash-spmd-1chip", [()] * 6,
+    ("joyai_llm_flash-spmd-1chip",
+     [GATE_UP + (KEPT_Q_B,)] + [GATE_UP] * 5,
      {(0, KEPT_GATE): 234_881_024, (0, KEPT_Q_B): 201_326_592,
       (0, KEPT_KV_B): 268_435_456, (1, KEPT_UP): 25_165_824,
       (1, EXPERT_GATE): 201_326_592},
-     2_043_674_624, 680_439_808, 16_720_265_216),
+     2_966_421_504, 680_439_808, joyai_moments()),
+    # rung 0 is over the line at every block (its float32 stream's
+    # cotangents and the head's input are 3 x 268.4 MB of each): nothing
+    # is kept
     ("evabyte_6_5b-spmd-1chip", [()] * 4,
      {(0, KEPT_QKV[0]): 134_217_728, (3, KEPT_UP): 360_710_144},
-     3_305_111_552, 821_366_784, 19_521_404_928),
+     3_305_111_552, 821_366_784,
+     {"block 3": 12 * 821_366_784 + 47_202_304 + 3_305_111_552
+      + 826_277_888 + 2 * (3 * 134_217_728 + 721_420_288)
+      + 16384 * 320 * 32 + 3 * 268_435_456}),
+    ("smallthinker_21b_a3b-spmd-1chip",
+     [moe.PRODUCT_NAMES] + [(EXPERT_GATE, EXPERT_UP)] * 3,
+     {(0, EXPERT_GATE): 150_994_944, (0, EXPERT_DOWN): 503_316_480},
+     3_466_592_256, 656_529_920, smallthinker_moments()),
     # under the passes the plan is ``kept_names`` whatever the room
     ("ouro_2_6b-spmd-1chip", [()] * 8, {}, 8 * 33_816_576, None, None),
-], ids=["lfm2", "phi4", "laguna", "joyai", "evabyte", "ouro"])
+], ids=["lfm2", "phi4", "laguna", "joyai", "evabyte", "smallthinker", "ouro"])
 def test_kept_plan_of_the_cells_by_hand(cell_config, monkeypatch, workload,
-                                        names, bytes_of, kept, params, peak):
+                                        names, bytes_of, kept, params,
+                                        moments):
     """What the plan keeps in each recomputing cell on a v5e, the bytes
     of the new names in closed form, the parameters as ``PERF.md`` section
-    4 counts them, and the plan's shape: rung 0 everywhere where the
-    backend tells no limit, never less kept on a larger device, the
-    predicted peak within 95% of the device wherever a name is kept."""
+    4 counts them, the moments of the step by hand (``moments``: the
+    first is the one the peak stands at; the whole list where the plan
+    is new with the moments) and the plan's shape: rung 0 everywhere
+    where the backend tells no limit, never less kept on a larger
+    device, the predicted peak within 95% of the device wherever a name
+    is kept."""
     cfg, batch, seq = cell_config(workload)
     module = workload.startswith("joyai")
     plan = kept_plan(cfg, batch, seq, V5E, next_token=module)
     assert list(plan.names) == [tuple(n) for n in names]
     assert plan.rungs == tuple(int(bool(n)) for n in names)
-    assert (sum(plan.kept), plan.peak) == (kept, peak)
+    assert sum(plan.kept) == kept
     for (layer, name), n in bytes_of.items():
         assert kept_bytes(cfg, batch, seq, layer, (name,)) == {name: n}
     nothing = kept_plan(cfg, batch, seq, None, next_token=module)
@@ -567,9 +702,17 @@ def test_kept_plan_of_the_cells_by_hand(cell_config, monkeypatch, workload,
             for i, k in enumerate(nothing.kept[:cfg.n_layers])] == [
                 batch * seq * cfg.d_model * jnp.dtype(
                     cfg.residual_dtype or cfg.dtype).itemsize] * cfg.n_layers
-    if peak is None:
+    if moments is None:
+        assert (plan.peak, plan.moment, plan.moments) == (None, None, ())
         return
-    assert plan.params == 4 * params
+    found = dict(plan.moments)
+    assert {name: found[name] for name in moments} == moments
+    assert (plan.moment, plan.peak) == next(iter(moments.items()))
+    assert list(found) == ["head"] + [
+        f"block {i}" for i in reversed(range(len(names)))] + ["end"]
+    assert len(moments) in (1, len(found))
+    assert plan.params == 4 * params and plan.resident == 12 * params
+    assert found["end"] == 16 * params
     assert plan.budget == int(0.95 * V5E)
     assert plan.peak <= plan.budget or not any(plan.names)
     # the chips of PR 50's runs said 2 MiB less: the same plan
@@ -582,15 +725,38 @@ def test_kept_plan_of_the_cells_by_hand(cell_config, monkeypatch, workload,
         assert last is None or sum(at.kept) >= sum(last.kept)
         last = at
     assert not any(kept_plan(cfg, batch, seq, 8 << 30, module).names)
-    # a device with room for the gradient tree beside what these steps
-    # take today keeps something in every cell
     assert any(kept_plan(cfg, batch, seq, 24 << 30, module).names)
+    # what stays on the device beside the parameters and Adam's moments
+    # (an accumulator: a fourth tree) is room the plan does not fill
+    # (every moment stands a tree higher: where the plan's peak then
+    # passes the budget it keeps less, down to nothing where rung 0 is
+    # over the line by then; LFM2 has the room and EvaByte nothing to give
+    # up)
+    held = kept_plan(cfg, batch, seq, V5E, module, resident=16 * params)
+    assert held.resident == 16 * params
+    if any(plan.names) and plan.peak + 4 * params > plan.budget:
+        assert workload.split("_")[0] in ("phi4", "laguna", "joyai",
+                                          "smallthinker")
+        assert sum(held.kept) < kept
+        assert held.peak <= held.budget or not any(held.names)
+    else:
+        assert workload.split("_")[0] in ("lfm2", "evabyte")
+        assert held.names == plan.names
+        assert held.peak == plan.peak + 4 * params
+    assert kept_plan(cfg, batch, seq, V5E, module,
+                     resident=params).resident == 12 * params
 
 
 def test_a_budget_for_one_layer_of_two_keeps_the_first_alone(monkeypatch):
     """Two equal layers and a device that has room for one ``up``: the
     first layer stands on rung 1, the second on rung 0, the model's
-    blocks are built so, and the gradient is the gradient."""
+    blocks are built so, and the gradient is the gradient.  What the
+    first layer keeps stands at the head and in both blocks' moments,
+    what the second would keep at the head and in its own block's alone
+    (it is released before the backward pass enters the first), so the
+    device is one byte short of the larger of those two with both kept
+    (at these sizes the head's: a row's four statistics outweigh its 32
+    columns)."""
     cfg, params, loss = model_and_loss("plain", remat=True)
     up = kept_bytes(cfg, *TOKENS.shape, 0, (KEPT_UP,))[KEPT_UP]
     assert up == TOKENS.size * cfg.d_ff * 4
@@ -598,12 +764,21 @@ def test_a_budget_for_one_layer_of_two_keeps_the_first_alone(monkeypatch):
         ((KEPT_UP,), cfg.d_model)]
     floor = kept_plan(cfg, *TOKENS.shape, 1)
     assert floor.rungs == (0, 0) and floor.peak > floor.budget == 0
-    room = -(-(floor.peak + up + up // 2) * 20 // 19)  # / 0.95, rounded up
+    moments = dict(floor.moments)
+    assert floor.moment == "head" and floor.peak == moments["head"]
+    assert moments["block 1"] < moments["block 0"] < moments["head"]
+    both = moments["head"] + 2 * up
+    assert floor.peak + up < both
+    room = -(-(both - 1) * 20 // 19)  # / 0.95, rounded up
     plan = kept_plan(cfg, *TOKENS.shape, room)
     assert plan.names == ((KEPT_UP,), ()) and plan.rungs == (1, 0)
     assert plan.kept == (floor.kept[0] + up, floor.kept[1])
-    assert plan.peak == floor.peak + up <= plan.budget < floor.peak + 2 * up
+    assert plan.peak == floor.peak + up <= plan.budget < both
+    assert dict(plan.moments) == {
+        "head": moments["head"] + up, "block 1": moments["block 1"] + up,
+        "block 0": moments["block 0"] + up, "end": moments["end"]}
     assert "0: 1 (+ mlp_up), 1: 0" in str(plan)
+    assert "at head of a budget" in str(plan)
     with_room(monkeypatch, room)
     again = products(recomputation(loss, params))
     assert again == ["mlp/up"]
@@ -613,6 +788,94 @@ def test_a_budget_for_one_layer_of_two_keeps_the_first_alone(monkeypatch):
     for g, w in zip(jax.tree.leaves(jax.grad(loss)(params)),
                     jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-6)
+
+
+def toy_moments(**sizes):
+    """``(cfg, floor, up)`` of a plain recomputed toy on ``TOKENS``: its
+    plan with nothing kept (a device of one byte) and a layer's ``up``."""
+    cfg = TransformerConfig(**{**SIZES, "dtype": jnp.float32, **sizes},
+                            remat=True)
+    return (cfg, kept_plan(cfg, *TOKENS.shape, 1),
+            kept_bytes(cfg, *TOKENS.shape, 0, (KEPT_UP,))[KEPT_UP])
+
+
+def test_wide_blocks_on_a_short_sequence_are_bound_by_the_end():
+    """Where the parameters outweigh the activations the step's largest
+    moment is its END, the whole gradient tree beside the state: a device
+    one byte short of it keeps nothing, although the head's moment and
+    every block's would admit a product (they would with a gradient tree
+    charged nowhere), and the line says which moment that was."""
+    cfg, floor, up = toy_moments(d_model=128, d_ff=512)
+    moments = dict(floor.moments)
+    assert floor.moment == "end" and floor.peak == 4 * floor.params
+    assert floor.resident == 3 * floor.params
+    assert max(n for name, n in moments.items() if name != "end") + 2 * up < (
+        moments["end"])
+    short = kept_plan(cfg, *TOKENS.shape, (moments["end"] - 1) * 20 // 19)
+    assert moments["end"] - 8 < short.budget < moments["end"]
+    assert short.peak == moments["end"] and not any(short.names)
+    assert "at end of a budget" in str(short)
+    room = kept_plan(cfg, *TOKENS.shape, -(-(moments["end"] + 1) * 20 // 19))
+    assert room.names == ((KEPT_UP,),) * 2 and room.moment == "end"
+    assert room.peak == moments["end"] <= room.budget < moments["end"] + 8
+
+
+def test_a_large_vocabulary_is_bound_by_the_head():
+    """Where the logits outweigh a block the peak stands at the head, the
+    logits and their gradient beside everything kept: a product is kept
+    while THAT moment has room, and no longer."""
+    cfg, floor, up = toy_moments(vocab_size=4096, d_model=16, d_ff=32,
+                                 tie_head=True)
+    logits = TOKENS.size * 4096 * 4
+    assert floor.moment == "head"
+    assert floor.peak == 3 * floor.params + sum(floor.kept) + 2 * logits + (
+        beside(TOKENS.size, TOKENS.size * 16 * 4))
+    one = kept_plan(cfg, *TOKENS.shape, -(-(floor.peak + up) * 20 // 19))
+    assert one.names == ((KEPT_UP,), ()) and one.moment == "head"
+    assert one.peak == floor.peak + up <= one.budget < floor.peak + 2 * up
+    assert "at head of a budget" in str(one)
+
+
+def test_what_is_placed_on_the_device_is_resident(monkeypatch):
+    """``resident`` is what the caller read off the device, and no less
+    than the parameters and Adam's two moments: an accumulator beside
+    them (a fourth tree in ``bytes_in_use``) takes room a product had,
+    ``None`` and a device that holds less than the state plan for the
+    state, and the model hands the plan what ``device_memory_bytes``
+    says."""
+    cfg, floor, up = toy_moments()
+    room = -(-(floor.peak + 2 * up) * 20 // 19)
+    plain = kept_plan(cfg, *TOKENS.shape, room)
+    assert plain.names == ((KEPT_UP,),) * 2
+    assert plain.resident == floor.resident == 3 * plain.params
+    assert kept_plan(cfg, *TOKENS.shape, room, resident=17) == plain
+    accumulating = kept_plan(cfg, *TOKENS.shape, room,
+                             resident=4 * plain.params)
+    assert accumulating.resident == 4 * plain.params
+    assert not any(accumulating.names)
+    assert accumulating.peak == floor.peak + plain.params
+    assert f"resident {4 * plain.params / 2 ** 30:.3f} GiB" in str(
+        accumulating)
+    planned, said = [], []
+    monkeypatch.setattr(transformer, "kept_plan", lambda *args: planned.append(
+        kept_plan(*args)) or planned[-1])
+    monkeypatch.setattr(transformer.get_logger(), "warning",
+                        lambda line, *values: said.append(line % values))
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0), TOKENS)["params"]
+    # the plan is made with what was read and, to say what that cost,
+    # without it: a warning where the layers keep less for it
+    for in_use, want, warned in ((None, plain, False),
+                                 (plain.params, plain, False),
+                                 (4 * plain.params, accumulating, True)):
+        with_room(monkeypatch, room, in_use)
+        del planned[:], said[:]
+        model.apply({"params": params}, TOKENS)
+        assert planned[0].names == want.names
+        assert planned[1:] == [plain] * (in_use is not None)
+        assert planned[0].resident == max(in_use or 0, 3 * plain.params)
+        assert len(said) == warned
+    assert "the layers keep" in said[0] and "device at rest" in said[0]
 
 
 @pytest.mark.parametrize("kind,name", [("plain", KEPT_GATE),

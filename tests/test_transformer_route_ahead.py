@@ -162,7 +162,7 @@ def test_kept_bytes_are_what_the_backward_pass_is_handed(monkeypatch):
     room = 2 ** 40
     for device_bytes in (None, room):
         monkeypatch.setattr(transformer, "device_memory_bytes",
-                            lambda: device_bytes)
+                            lambda: (device_bytes, None))
         named = handed()
         plan = kept_plan(cfg, *TOKENS.shape, device_bytes)
         assert plan.names == ((moe.PRODUCT_NAMES if device_bytes else ()),) * 2
