@@ -15,6 +15,7 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     DifferentialAttention,
     GroupedAttention,
     LatentAttention,
+    Mamba2,
     MemoryUnit,
     NextTokenModule,
     Rotary,

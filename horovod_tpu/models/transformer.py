@@ -41,8 +41,14 @@ input and decide before the mixer runs (``TopkExperts(route_from=
 not be attention: ``attention=ShortConv(...)`` is a doubly gated causal
 convolution of a few taps along the sequence (:class:`ShortConvMixer`),
 ``attention=SelectiveScan(...)`` a mixer with a state carried along the
-sequence (:class:`SelectiveScanMixer`, Mamba's), ``attention=
-MemoryUnit(...)`` a gate on what an earlier block made.  Attention may
+sequence (:class:`SelectiveScanMixer`, Mamba's: a vector a channel),
+``attention=Mamba2(...)`` one whose state is a matrix a head, computed
+by chunks as matrix products (:class:`Mamba2Mixer`, ``ops/ssd.py``),
+``attention=MemoryUnit(...)`` a gate on what an earlier block made.  A
+block may be ONE branch: ``attention=None`` is a layer that is its
+feed-forward alone (``x + ffn(norm(x))``), ``ffn=None`` one that is its
+mixer alone; an expert layer's experts may have no gate
+(``TopkExperts(activation="relu2")``).  Attention may
 be the difference of two softmaxes over paired heads
 (:class:`DifferentialAttention`), with its own keys and values or an
 earlier block's, or softmax attention linearised by chunk
@@ -185,6 +191,40 @@ class SelectiveScan:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2:
+    """A mixer with a MATRIX state a head carried along the sequence
+    (:class:`Mamba2Mixer`; Mamba-2, arXiv:2405.21060): ``heads`` heads
+    of ``head_dim`` channels, each with a state ``[head_dim, state]``
+    that decays by one scalar a head and position and is fed through
+    ``B``, read through ``C``, which the ``heads / groups`` heads of a
+    group share; behind a causal depthwise convolution of ``taps`` taps
+    over the channels, ``B`` and ``C`` together, and ahead of a gated
+    RMSNorm over each group's channels.  ``chunk``: the positions a
+    chunk of ``ops/ssd.py`` holds."""
+    heads: int
+    head_dim: int
+    groups: int
+    state: int = 128
+    taps: int = 4
+    chunk: int = 128
+
+    def __post_init__(self):
+        if self.heads % self.groups:
+            raise ValueError(f"Mamba2: {self.groups} groups do not divide "
+                             f"{self.heads} heads")
+
+    @property
+    def d_inner(self):
+        return self.heads * self.head_dim
+
+    @property
+    def in_width(self):
+        """Columns of the first product: the gate ``z``, the channels
+        with ``B`` and ``C`` (what the convolution reads) and ``dt``."""
+        return 2 * self.d_inner + 2 * self.groups * self.state + self.heads
+
+
+@dataclasses.dataclass(frozen=True)
 class MemoryUnit:
     """A mixer that gates what an earlier block made
     (:class:`MemoryUnitMixer`; a gated memory unit, arXiv:2507.06607):
@@ -253,7 +293,7 @@ class ChunkSummaryAttention:
 
 
 MIXERS = (LatentAttention, GroupedAttention, ShortConv, SelectiveScan,
-          MemoryUnit, DifferentialAttention, ChunkSummaryAttention)
+          Mamba2, MemoryUnit, DifferentialAttention, ChunkSummaryAttention)
 ROUTES_FROM = ("ffn_input", "input")
 
 
@@ -263,8 +303,9 @@ class TopkExperts:
     told more than ``"moe_topk"`` says.  ``scoring``, ``renormalize``
     and ``scale`` are :func:`~horovod_tpu.parallel.moe.topk_route`'s
     (its ``bias`` is the row of ``router_bias`` the caller hands the
-    model); ``shared``: that many SwiGLU experts of the same width
-    every token goes through, beside the routed ones; ``held``:
+    model); ``shared``: that many experts of the routed ones' form and
+    width (of ``shared_width`` each where one is given) every token
+    goes through, beside the routed ones; ``held``:
     ``(first, count)``, the routed experts this device holds of the
     router's ``n_experts``.  ``route_from``: the array the router
     reads: ``"ffn_input"``, what the experts read (the block's second
@@ -272,7 +313,10 @@ class TopkExperts:
     norm: :class:`Block` then decides before its mixer runs, under the
     scope ``route_ahead``, and the experts are handed the decision.
     ``activation``: the routed experts' gate function, ``"silu"``
-    (SwiGLU) or ``"relu"`` (ReGLU); the shared experts are SwiGLU."""
+    (SwiGLU) or ``"relu"`` (ReGLU), or ``"relu2"``: experts with NO
+    gate, ``relu(x W_up)^2 W_down``, two grouped products for three.
+    The shared experts are SwiGLU beside SwiGLU experts and of the same
+    non-gated form beside ``"relu2"`` ones."""
     scoring: str = "softmax"
     renormalize: bool = False
     scale: float = 1.0
@@ -280,20 +324,34 @@ class TopkExperts:
     held: Optional[Tuple[int, int]] = None
     route_from: str = "ffn_input"
     activation: str = "silu"
+    shared_width: Optional[int] = None
 
     def __post_init__(self):
-        from horovod_tpu.parallel.moe import ACTIVATIONS
+        from horovod_tpu.parallel.moe import EXPERT_ACTIVATIONS
 
         if self.route_from not in ROUTES_FROM:
             raise ValueError(f"TopkExperts: route_from {self.route_from!r} "
                              f"is none of {ROUTES_FROM}")
-        if self.activation not in ACTIVATIONS:
+        if self.activation not in EXPERT_ACTIVATIONS:
             raise ValueError(f"TopkExperts: activation {self.activation!r} "
-                             f"is none of {sorted(ACTIVATIONS)}")
-        if self.shared and self.activation != "silu":
+                             f"is none of {sorted(EXPERT_ACTIVATIONS)}")
+        if self.shared and self.activation == "relu":
             raise ValueError(
-                f"TopkExperts: the {self.shared} shared experts are SwiGLU, "
-                f"the routed ones are asked for {self.activation!r}")
+                f"TopkExperts: the {self.shared} shared experts are SwiGLU "
+                f"or have no gate, the routed ones are asked for "
+                f"{self.activation!r}")
+
+    @property
+    def gated(self):
+        """Whether an expert has a gate (three products) or none (two)."""
+        from horovod_tpu.parallel.moe import ACTIVATIONS
+
+        return self.activation in ACTIVATIONS
+
+    def shared_columns(self, width):
+        """The columns of the shared experts' hidden layer, side by side,
+        beside routed experts of ``width``."""
+        return self.shared * (self.shared_width or width)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -312,8 +370,12 @@ class BlockSpec:
     the block's mixer: ``"full"`` (one fused q, k, v projection, heads of
     one width), a :class:`LatentAttention`, a :class:`GroupedAttention`,
     a :class:`DifferentialAttention`, a :class:`ChunkSummaryAttention`,
-    or a :class:`ShortConv`, :class:`SelectiveScan` or
-    :class:`MemoryUnit`, which are no attention.  ``ffn``:
+    or a :class:`ShortConv`, :class:`SelectiveScan`, :class:`Mamba2` or
+    :class:`MemoryUnit`, which are no attention; or ``None``: the block
+    has no mixer and is ``x + ffn(norm(x))`` alone (its norm is ``ln2``).
+    ``ffn`` (``None``: the block has no feed-forward and is ``x +
+    mixer(norm(x))`` alone, its norm ``ln1``; one of the two branches is
+    always there):
     ``"gelu"`` (dense up-GELU-down), ``"swiglu"`` (dense gated, ``silu(x
     gate) * (x up)`` down), ``"moe_switch"``
     (:func:`~horovod_tpu.parallel.moe.switch_moe`), ``"moe_topk"``
@@ -322,18 +384,21 @@ class BlockSpec:
     norm: str = "layer"
     positions: str = "learned"
     qk_norm: bool = False
-    ffn: Union[str, TopkExperts] = "gelu"
-    attention: Union[(str,) + MIXERS] = "full"
+    ffn: Union[None, str, TopkExperts] = "gelu"
+    attention: Union[(None, str) + MIXERS] = "full"
     norm_placement: str = "pre"
 
     def __post_init__(self):
         for value, known, cls in (
                 (self.norm, NORMS, ()), (self.positions, POSITIONS, ()),
                 (self.norm_placement, NORM_PLACEMENTS, ()),
-                (self.ffn, FFNS, TopkExperts),
-                (self.attention, ATTENTIONS, MIXERS)):
+                (self.ffn, FFNS + (None,), TopkExperts),
+                (self.attention, ATTENTIONS + (None,), MIXERS)):
             if value not in known and not isinstance(value, cls):
                 raise ValueError(f"BlockSpec: {value!r} is none of {known}")
+        if self.ffn is None and self.attention is None:
+            raise ValueError("BlockSpec: a block with neither a mixer nor a "
+                             "feed-forward")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -470,13 +535,14 @@ KEPT_NAMES = (KEPT_SUM, KEPT_Q_A, KEPT_KV_A)
 # The products a recomputed block makes again carry these; no block keeps
 # them but one that ``kept_plan`` found room for (``kept_products``).
 KEPT_GATE = "mlp_gate"               # x W_gate of a SwiGLU, shared or not
-KEPT_UP = "mlp_up"                   # a SwiGLU's or a GELU layer's x W_up
+KEPT_UP = "mlp_up"                   # x W_up of a SwiGLU, a GELU layer or
+#                                      a shared expert without a gate
 KEPT_IN = "mixer_in"                 # a NO_ATTENTION mixer's first product
 KEPT_Q_B = "latent_q_b"              # latent attention's norm(c_q) W_qb
 KEPT_KV_B = "latent_kv_b"            # latent attention's norm(c_kv) W_kvb
 # chunk-summary attention's x W_q, x W_k, x W_v
 KEPT_QKV = ("chunk_q", "chunk_k", "chunk_v")
-NO_ATTENTION = (ShortConv, SelectiveScan, MemoryUnit)
+NO_ATTENTION = (ShortConv, SelectiveScan, Mamba2, MemoryUnit)
 # A recomputed step is planned to this share of the device's memory
 BUDGET_SHARE = 0.95
 
@@ -503,8 +569,12 @@ def kept_names(cfg):
     after the mixer and, of a :class:`SelectiveScan`, what its scan
     hands its backward pass (``ops/selective_scan.py``'s
     ``SAVED_NAMES``: its output and the state every chunk is entered
-    with).  One list serves a mixed pattern: a name no block sets
-    keeps nothing.
+    with) or of a :class:`Mamba2` its scan's output (``ops/ssd.py``'s
+    ``SAVED_NAMES``).  One list serves a mixed pattern: a name no block
+    sets keeps nothing.  A layer that has no mixer (``attention=None``)
+    has nothing of a kernel to name and sets no ``KEPT_SUM``: its one
+    sum is its output, the next layer's input, which every checkpoint
+    keeps; nor does a layer that is its mixer alone.
 
     With one pass, whatever the mixers, also what a routed layer decided
     (``parallel/moe.py``'s ``SAVED_NAMES``: the experts chosen and the
@@ -522,10 +592,14 @@ def kept_names(cfg):
     from horovod_tpu.parallel.moe import SAVED_NAMES as routed
     if cfg.passes > 1:
         return SAVED_NAMES
-    mixers = [spec.attention for spec in cfg.pattern or (cfg.block,)]
+    mixers = [spec.attention for spec in cfg.pattern or (cfg.block,)
+              if spec.attention is not None]
     scanned = ()
     if any(isinstance(m, SelectiveScan) for m in mixers):
         from horovod_tpu.ops.selective_scan import SAVED_NAMES as scanned
+    if any(isinstance(m, Mamba2) for m in mixers):
+        from horovod_tpu.ops.ssd import SAVED_NAMES as by_chunks
+        scanned += by_chunks
     if all(isinstance(m, NO_ATTENTION) for m in mixers):
         return scanned + (KEPT_SUM,) + routed
     if any(isinstance(m, ChunkSummaryAttention) for m in mixers):
@@ -555,7 +629,9 @@ def kept_products(cfg, layer=0):
     multiply-adds a number kept) times the share of the result's rows
     that exist (all, but in a routed layer's buffer of held experts:
     ``moe.product_bytes``).  Names that one gradient reads together are
-    one entry (a SwiGLU's ``gate`` and ``up``).  None under
+    one entry (a SwiGLU's ``gate`` and ``up``).  A layer of one branch
+    has the products of that branch alone, and experts without a gate no
+    ``gate``.  None under
     ``cfg.passes > 1``, for the reason ``kept_names`` gives.  Not here:
     what ``kept_names`` keeps already (attention's q, k and v where the
     kernel names them)."""
@@ -575,14 +651,22 @@ def kept_products(cfg, layer=0):
         found += [((name,), cfg.d_model) for name in KEPT_QKV]
     if ffn == "gelu":
         found.append(((KEPT_UP,), cfg.d_model))
+    gated = _gated(ffn)
     if ffn == "swiglu" or getattr(ffn, "shared", 0):
-        found.append(((KEPT_GATE, KEPT_UP), cfg.d_model))
+        found.append(((KEPT_GATE, KEPT_UP) if gated else (KEPT_UP,),
+                      cfg.d_model))
     if ffn == "moe_topk" or isinstance(ffn, TopkExperts):
         gate, up, down = moe.PRODUCT_NAMES
         _, share = _routed_products(cfg, ffn, 1, 1)
-        found += [((gate, up), cfg.d_model * share),
+        found += [((gate, up) if gated else (up,), cfg.d_model * share),
                   ((down,), (cfg.d_expert or cfg.d_ff) * share)]
     return found
+
+
+def _gated(ffn):
+    """Whether the experts of feed-forward ``ffn`` have a gate: all but a
+    :class:`TopkExperts` that says otherwise."""
+    return getattr(ffn, "gated", True)
 
 
 def _routed_products(cfg, ffn, batch, seq):
@@ -593,7 +677,7 @@ def _routed_products(cfg, ffn, batch, seq):
     return moe.product_bytes(
         batch * seq, cfg.experts_per_token, cfg.d_model,
         cfg.d_expert or cfg.d_ff, jnp.dtype(cfg.dtype).itemsize,
-        held and held + (cfg.n_experts,))
+        held and held + (cfg.n_experts,), gated=_gated(ffn))
 
 
 def kept_bytes(cfg, batch, seq, layer=0, names=None):
@@ -616,7 +700,10 @@ def kept_bytes(cfg, batch, seq, layer=0, names=None):
     summaries.  The sum after the mixer is in the residual stream's
     dtype.  A layer whose feed-forward is
     routed (``"moe_topk"`` or a :class:`TopkExperts`) keeps what its
-    routing decided (``parallel/moe.py:saved_bytes``)."""
+    routing decided (``parallel/moe.py:saved_bytes``).  A layer of one
+    branch has that branch's names alone and no sum after the mixer: a
+    layer with no mixer nothing of a kernel or a scan, a layer with no
+    feed-forward no product of one."""
     from horovod_tpu.ops.pallas.flash_attention import saved_bytes
     from horovod_tpu.parallel import moe
 
@@ -633,8 +720,10 @@ def kept_bytes(cfg, batch, seq, layer=0, names=None):
         d_qk, d_v = spec.head_dim, 2 * spec.head_dim
     itemsize = jnp.dtype(cfg.dtype).itemsize
     row = batch * seq * itemsize  # a column of a product's result
-    kept = {KEPT_SUM: batch * seq * cfg.d_model * jnp.dtype(
-        cfg.residual_dtype or cfg.dtype).itemsize}
+    kept = {}
+    if spec is not None and ffn is not None:
+        kept[KEPT_SUM] = batch * seq * cfg.d_model * jnp.dtype(
+            cfg.residual_dtype or cfg.dtype).itemsize
     if isinstance(spec, ChunkSummaryAttention):
         from horovod_tpu.ops.chunk_attention import SAVED_NAMES as summaries
 
@@ -648,22 +737,30 @@ def kept_bytes(cfg, batch, seq, layer=0, names=None):
         kept.update(moe.saved_bytes(batch * seq, cfg.experts_per_token,
                                     getattr(ffn, "held", None)))
         kept.update(_routed_products(cfg, ffn, batch, seq)[0])
-    # a dense feed-forward's width, or the shared expert's (a SwiGLU too)
+    # a dense feed-forward's width, or the shared experts' (of the
+    # routed ones' form: a SwiGLU, or no gate)
     width = (cfg.d_ff if ffn in ("gelu", "swiglu") else
-             getattr(ffn, "shared", 0) * (cfg.d_expert or cfg.d_ff))
+             ffn.shared_columns(cfg.d_expert or cfg.d_ff)
+             if isinstance(ffn, TopkExperts) else 0)
     if width:
         kept[KEPT_UP] = row * width
-        if ffn != "gelu":
+        if ffn != "gelu" and _gated(ffn):
             kept[KEPT_GATE] = row * width
     if isinstance(spec, SelectiveScan):
         from horovod_tpu.ops import selective_scan
 
         kept.update(selective_scan.saved_bytes(
             batch, seq, spec.d_inner, spec.state, cfg.dtype))
+    if isinstance(spec, Mamba2):
+        from horovod_tpu.ops import ssd
+
+        kept.update(ssd.saved_bytes(batch, seq, spec.heads, spec.head_dim,
+                                    cfg.dtype))
     if isinstance(spec, NO_ATTENTION):
         kept[KEPT_IN] = row * (
             3 * cfg.d_model if isinstance(spec, ShortConv) else
             2 * spec.d_inner if isinstance(spec, SelectiveScan) else
+            spec.in_width if isinstance(spec, Mamba2) else
             spec.d_inner)
     if isinstance(spec, LatentAttention):
         d_qk, d_v = spec.nope_dim + spec.rope_dim, spec.v_dim
@@ -671,7 +768,7 @@ def kept_bytes(cfg, batch, seq, layer=0, names=None):
         kept[KEPT_KV_A] = row * (spec.kv_rank + spec.rope_dim)
         kept[KEPT_Q_B] = row * cfg.n_heads * d_qk
         kept[KEPT_KV_B] = row * cfg.n_heads * (spec.nope_dim + d_v)
-    if not isinstance(spec, NO_ATTENTION):
+    if spec is not None and not isinstance(spec, NO_ATTENTION):
         q, k, v = (jax.ShapeDtypeStruct((batch, seq, h, d), cfg.dtype)
                    for h, d in ((heads, d_qk), (groups, d_qk), (groups, d_v)))
         kept.update({name: calls * n
@@ -1080,6 +1177,85 @@ class SelectiveScanMixer(nn.Module):
                     y * nn.silu(az[..., inner:])), y
 
 
+def _a_log_uniform_init(key, shape, dtype=jnp.float32):
+    """``A = -uniform[1, 16]`` a head (Mamba-2's start)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class Mamba2Mixer(nn.Module):
+    """The mixer of a :class:`Mamba2` block on ``x [B, T, d]`` (Mamba-2,
+    arXiv:2405.21060), ``H`` heads of ``P``, ``G`` groups, state ``N``:
+
+        (z, xBC, dt) = split(x W_in) at H P, H P + 2 G N, H
+        xBC = silu(conv(xBC) + b_c);  (xs, B, C) = split(xBC)
+        dt = softplus(dt + b_dt);  A = -exp(A_log) [H]
+        h[t] = exp(dt[t] A) h[t-1] + (dt[t] xs[t]) (outer) B[t]   a head
+        y[t] = h[t] C[t] + D xs[t]
+        out = (group_rms(y * silu(z)) * w) W_out
+
+    with ``conv`` the causal depthwise convolution of
+    :func:`causal_taps` over the channels, ``B`` and ``C`` together, a
+    head reading the ``B`` and ``C`` of group ``h // (H / G)``, and
+    ``group_rms`` an RMSNorm over each group's ``H P / G`` channels,
+    after the gate.  ``dt``, ``A`` and the state ``[P, N]`` are float32
+    (``ops/ssd.py``, which computes the scan by chunks of
+    ``spec.chunk`` positions as matrix products).  No biases but the
+    convolution's and ``dt``'s.  All of it runs under the scope
+    ``mixer/ssm``: ``in``, ``conv``, ``proj`` (``dt``'s bias and
+    softplus), ``scan`` (``intra`` and ``inter`` inside it) and
+    ``gate_out`` (the gate, the norm and ``W_out``)."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.ops.ssd import ssd
+
+        cfg, spec = self.cfg, self.cfg.block.attention
+        heads, groups, n = spec.heads, spec.groups, spec.state
+        inner, bc = spec.d_inner, spec.groups * spec.state
+        lead = x.shape[:-1]
+
+        def dense(features, name):
+            return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
+                            name=name)
+
+        def param(name, init, *shape):
+            return self.param(name, init, shape, jnp.float32)
+
+        with jax.named_scope("mixer/ssm"):
+            zxd = checkpoint_name(dense(spec.in_width, "in")(x), KEPT_IN)
+            z, xbc, dt = (zxd[..., :inner], zxd[..., inner:2 * inner + 2 * bc],
+                          zxd[..., 2 * inner + 2 * bc:])
+            with jax.named_scope("conv"):
+                w = param("conv_kernel", _taps_init, spec.taps,
+                          inner + 2 * bc)
+                bias = param("conv_bias", nn.initializers.zeros,
+                             inner + 2 * bc)
+                xbc = nn.silu(causal_taps(xbc, w, cfg.dtype)
+                              + bias).astype(cfg.dtype)
+            with jax.named_scope("proj"):
+                dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                     + param("dt_bias", _dt_bias_init, heads))
+            with jax.named_scope("scan"):
+                y = ssd(xbc[..., :inner].reshape(lead + (heads, -1)), dt,
+                        -jnp.exp(param("A_log", _a_log_uniform_init, heads)),
+                        xbc[..., inner:inner + bc].reshape(
+                            lead + (groups, n)),
+                        xbc[..., inner + bc:].reshape(lead + (groups, n)),
+                        param("D", nn.initializers.ones, heads),
+                        chunk=spec.chunk)
+            with jax.named_scope("gate_out"):
+                # the gate first, then the norm over each group's channels
+                gated = (y.reshape(lead + (inner,)) * nn.silu(z)).astype(
+                    jnp.float32).reshape(lead + (groups, -1))
+                normed = gated * jax.lax.rsqrt(jnp.mean(
+                    jnp.square(gated), axis=-1, keepdims=True) + cfg.norm_eps)
+                scale = param("norm_scale", nn.initializers.ones, inner)
+                return dense(cfg.d_model, "out")(
+                    (normed.reshape(lead + (inner,)) * scale).astype(
+                        cfg.dtype))
+
+
 class MemoryUnitMixer(nn.Module):
     """The mixer of a :class:`MemoryUnit` block on ``x [B, T, d]`` and
     the ``memory [B, T, d_inner]`` an earlier block published: ``(silu(x
@@ -1257,6 +1433,24 @@ class SwigluMlp(nn.Module):
         return dense(cfg.d_model, "down")(hidden)
 
 
+class UngatedMlp(nn.Module):
+    """``act(x up) down`` with ``act`` one of ``parallel/moe.py``'s
+    ``UNGATED`` (``"relu2"``: the square of ReLU), no gate, no biases."""
+    cfg: TransformerConfig
+    width: int
+    activation: str = "relu2"
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.parallel.moe import UNGATED
+
+        cfg = self.cfg
+        up = checkpoint_name(nn.Dense(self.width, use_bias=False,
+                                      dtype=cfg.dtype, name="up")(x), KEPT_UP)
+        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                        name="down")(UNGATED[self.activation](up))
+
+
 class MoeMlp(nn.Module):
     cfg: TransformerConfig
 
@@ -1277,12 +1471,14 @@ class MoeMlp(nn.Module):
 
 class TopkMoeMlp(nn.Module):
     """The dropless top-k expert layer (``parallel/moe.py:topk_moe``):
-    a router over ``cfg.n_experts`` gated experts of width
+    a router over ``cfg.n_experts`` experts of width
     ``cfg.d_expert``, ``cfg.experts_per_token`` a token, as ``spec``
-    (a :class:`TopkExperts`; the default is ``"moe_topk"``) says: the
+    (a :class:`TopkExperts`; the default is ``"moe_topk"``) says: gated
+    experts or, under ``activation="relu2"``, experts with no gate (no
+    ``wg``), the
     weights of the ``spec.held`` experts alone, ``spec.shared`` experts
-    every token goes through, the choice made through ``router_bias
-    [E]`` where one is given.  The choice is made here, from ``x``,
+    of the same form every token goes through, the choice made through
+    ``router_bias [E]`` where one is given.  The choice is made here, from ``x``,
     unless the caller hands a ``decision``: what :meth:`route` gave it
     for another array of the same tokens (``spec.route_from``).  Sows
     its load-balancing loss (``moe_aux_loss``), its router z-loss
@@ -1297,13 +1493,17 @@ class TopkMoeMlp(nn.Module):
         cfg, spec = self.cfg, self.spec
         width = cfg.d_expert or cfg.d_ff
         held = spec.held[1] if spec.held else cfg.n_experts
-        shapes = moe_param_shapes(cfg.d_model, width, held, gated=True)
+        shapes = moe_param_shapes(cfg.d_model, width, held,
+                                  gated=spec.gated)
         shapes["router"] = (cfg.d_model, cfg.n_experts)
         self.kernels = {name: {"kernel": self.param(
             f"{name}_kernel", moe_kernel_init, shape)}
             for name, shape in shapes.items()}
-        if spec.shared:
-            self.shared = SwigluMlp(cfg, width=spec.shared * width)
+        if spec.shared and spec.gated:
+            self.shared = SwigluMlp(cfg, width=spec.shared_columns(width))
+        elif spec.shared:
+            self.shared = UngatedMlp(cfg, spec.shared_columns(width),
+                                     spec.activation)
 
     def route(self, x, router_bias=None):
         """The router's decision on ``x [..., d_model]``
@@ -1381,7 +1581,9 @@ class Block(nn.Module):
         shared)`` with what this block's own spec publishes put in, so
         under :func:`keeping` it is an input of the block that reads
         it and a result of the block that made it; empty, it is no
-        operand and no result of the compiled block."""
+        operand and no result of the compiled block.  A block of one
+        branch (``attention=None`` or ``ffn=None`` in its spec) is ``x +
+        ffn(ln2(x))`` or ``x + mixer(ln1(x))`` alone."""
         cfg, mixer = self.cfg, self.cfg.block.attention
         sandwich = cfg.block.norm_placement == "sandwich"
         ffn = self.ffn or cfg.block.ffn
@@ -1393,27 +1595,34 @@ class Block(nn.Module):
                 # first norm; the mixer stands between decision and use
                 with jax.named_scope("route_ahead"):
                     decision = experts.route(x, router_bias)
-        y = make_norm(cfg, "ln1")(x).astype(cfg.dtype)
-        if isinstance(mixer, ShortConv):
-            y = ShortConvMixer(cfg, name="mixer")(y)
-        elif isinstance(mixer, SelectiveScan):
-            y, memory = SelectiveScanMixer(cfg, name="mixer")(y)
-            if mixer.publishes:
-                shared = {**shared, "memory": memory}
-        elif isinstance(mixer, MemoryUnit):
-            y = MemoryUnitMixer(cfg, name="mixer")(y, shared["memory"])
-        elif isinstance(mixer, DifferentialAttention):
-            y, keys = DifferentialAttentionMixer(cfg, name="attn")(
-                y, shared.get("keys"))
-            if mixer.keys == "published":
-                shared = {**shared, "keys": keys}
-        elif isinstance(mixer, ChunkSummaryAttention):
-            y = ChunkSummaryAttentionMixer(cfg, name="attn")(y)
-        else:
-            y = Attention(cfg, name="attn")(y)
-        if sandwich:
-            y = make_norm(cfg, "ln1_post")(y)
-        x = checkpoint_name(x + y, KEPT_SUM)
+        if mixer is not None:
+            y = make_norm(cfg, "ln1")(x).astype(cfg.dtype)
+            if isinstance(mixer, ShortConv):
+                y = ShortConvMixer(cfg, name="mixer")(y)
+            elif isinstance(mixer, Mamba2):
+                y = Mamba2Mixer(cfg, name="mixer")(y)
+            elif isinstance(mixer, SelectiveScan):
+                y, memory = SelectiveScanMixer(cfg, name="mixer")(y)
+                if mixer.publishes:
+                    shared = {**shared, "memory": memory}
+            elif isinstance(mixer, MemoryUnit):
+                y = MemoryUnitMixer(cfg, name="mixer")(y, shared["memory"])
+            elif isinstance(mixer, DifferentialAttention):
+                y, keys = DifferentialAttentionMixer(cfg, name="attn")(
+                    y, shared.get("keys"))
+                if mixer.keys == "published":
+                    shared = {**shared, "keys": keys}
+            elif isinstance(mixer, ChunkSummaryAttention):
+                y = ChunkSummaryAttentionMixer(cfg, name="attn")(y)
+            else:
+                y = Attention(cfg, name="attn")(y)
+            if sandwich:
+                y = make_norm(cfg, "ln1_post")(y)
+            x = x + y
+        if ffn is None:  # the mixer alone: the sum is what the caller keeps
+            return x, shared
+        if mixer is not None:
+            x = checkpoint_name(x, KEPT_SUM)
         y = make_norm(cfg, "ln2")(x).astype(cfg.dtype)
         if experts is not None:
             y = experts(y, router_bias, decision)

@@ -435,7 +435,8 @@ def saved_bytes(tokens, k, held=None):
             SAVED_ORDER: tokens * min(k, held[1]) * 4}
 
 
-# The results of :func:`topk_moe`'s three grouped products, over the
+# The results of :func:`topk_moe`'s grouped products (three with gated
+# experts, two without: no ``moe_gate`` where there is no gate), over the
 # whole buffer of rows, carry these: a recomputed block keeps them only
 # where its plan found room (``models/transformer.py:kept_plan``).  The
 # names stand outside the passes of dynamic extent and change none.
@@ -445,23 +446,28 @@ PRODUCT_DOWN = "moe_down"
 PRODUCT_NAMES = (PRODUCT_GATE, PRODUCT_UP, PRODUCT_DOWN)
 
 
-def product_bytes(tokens, k, d_model, d_expert, itemsize, held=None):
+def product_bytes(tokens, k, d_model, d_expert, itemsize, held=None,
+                  gated=True):
     """``({name: bytes}, share)`` of one :func:`topk_moe` over ``tokens``
     tokens: what its grouped products' results take under
     ``PRODUCT_NAMES`` (the buffer's ``N k`` rows, ``N min(k, count)``
     with ``held=(first, count, of)`` where ``of`` is the router's count
     of experts; ``d_expert`` columns for gate and up, ``d_model`` for
-    down), and the share of those rows expected to exist: all of them
-    without ``held``, ``k count / of`` of ``min(k, count)`` a token under
-    a router that spreads its tokens evenly."""
+    down; no gate's where the experts are not ``gated``), and the share
+    of those rows expected to exist: all of them without ``held``, ``k
+    count / of`` of ``min(k, count)`` a token under a router that
+    spreads its tokens evenly."""
     slots, share = k, 1.0
     if held is not None:
         _, count, of = held
         slots = min(k, count)
         share = k * count / of / slots
     rows = tokens * slots * itemsize
-    return {PRODUCT_GATE: rows * d_expert, PRODUCT_UP: rows * d_expert,
-            PRODUCT_DOWN: rows * d_model}, share
+    found = {PRODUCT_GATE: rows * d_expert, PRODUCT_UP: rows * d_expert,
+             PRODUCT_DOWN: rows * d_model}
+    if not gated:
+        del found[PRODUCT_GATE]
+    return found, share
 
 
 def topk_route(router_logits, k, *, scoring="softmax", bias=None,
@@ -538,7 +544,12 @@ def balance_bias(bias, tokens_per_expert, rate):
     return bias + rate * jnp.sign(jnp.mean(c, axis=-1, keepdims=True) - c)
 
 
+# an expert's non-linearity by name: the gate function of a gated expert,
+# ``(act(x wg) * (x wi)) wo``, or the whole of one that has no gate,
+# ``act(x wi) wo``
 ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+UNGATED = {"relu2": lambda u: jnp.square(jax.nn.relu(u))}
+EXPERT_ACTIVATIONS = (*ACTIVATIONS, *UNGATED)
 
 
 def _router_logits(x, router):
@@ -567,8 +578,8 @@ def route_tokens(x, router, k, *, scoring="softmax", bias=None,
 
 def topk_moe(x, params, *, k, held=None, activation="silu", decision=None,
              **route):
-    """Dropless top-``k`` MoE FFN with gated experts on ``x [..., D]``
-    (leading dims folded into N tokens); returns ``(out, aux)``.
+    """Dropless top-``k`` MoE FFN on ``x [..., D]`` (leading dims folded
+    into N tokens); returns ``(out, aux)``.
 
     params: ``router/kernel [D, E]``, ``wg/kernel`` and ``wi/kernel``
     ``[E, D, F]`` (gate and up), ``wo/kernel [E, F, D]`` (create with
@@ -580,13 +591,19 @@ def topk_moe(x, params, *, k, held=None, activation="silu", decision=None,
 
         out = sum_{e in S} w_e * (act(x wg_e) * (x wi_e)) wo_e
 
+    or, with an ``activation`` of ``UNGATED`` (``"relu2"``, the square of
+    ReLU), experts that have no gate and no ``wg``, two grouped products
+    where the gated ones run three:
+
+        out = sum_{e in S} w_e * act(x wi_e) wo_e
+
     ``S`` and ``w`` are decided from ``x`` (the router's product in
     float32 at ``HIGHEST``, under the scope ``moe/route``) unless the
     caller hands a ``decision``: what :func:`route_tokens` returned for
     the same N tokens, read from whatever array and at whatever place
     the model decides from (the router's kernel is then not read here
     and ``route`` is empty).  The ``N * k`` token-slots are sorted by
-    expert (stable), their rows gathered, three grouped products run with
+    expert (stable), their rows gathered, the grouped products run with
     group sizes = tokens per expert, and the result is un-sorted by the
     inverse permutation and summed over a token's slots: no capacity,
     no token dropped, no scatter of rows.  ``aux`` is
@@ -612,9 +629,9 @@ def topk_moe(x, params, *, k, held=None, activation="silu", decision=None,
     xt = x.reshape(-1, d)
     n = xt.shape[0]
     dtype = x.dtype
-    if activation not in ACTIVATIONS:
+    if activation not in EXPERT_ACTIVATIONS:
         raise ValueError(f"topk_moe: activation {activation!r} is none of "
-                         f"{sorted(ACTIVATIONS)}")
+                         f"{sorted(EXPERT_ACTIVATIONS)}")
     if decision is None:
         with jax.named_scope("moe/route"):
             decision = topk_route(
@@ -642,15 +659,22 @@ def topk_moe(x, params, *, k, held=None, activation="silu", decision=None,
             order = checkpoint_name(order[:n * min(k, count)], SAVED_ORDER)
             rows = _dispatch_held(xt, order, aux["held_rows"], k)
     with jax.named_scope("moe/experts"):
-        wg, wi, wo = (params[name]["kernel"].astype(dtype)
-                      for name in ("wg", "wi", "wo"))
-        gate = checkpoint_name(grouped_matmul(rows, wg, group_sizes),
-                               PRODUCT_GATE)
-        up = checkpoint_name(grouped_matmul(rows, wi, group_sizes),
-                             PRODUCT_UP)
-        y = checkpoint_name(grouped_matmul(
-            ACTIVATIONS[activation](gate) * up, wo, group_sizes),
-            PRODUCT_DOWN)
+        if activation in UNGATED:
+            wi, wo = (params[name]["kernel"].astype(dtype)
+                      for name in ("wi", "wo"))
+            up = checkpoint_name(grouped_matmul(rows, wi, group_sizes),
+                                 PRODUCT_UP)
+            hidden = UNGATED[activation](up)
+        else:
+            wg, wi, wo = (params[name]["kernel"].astype(dtype)
+                          for name in ("wg", "wi", "wo"))
+            gate = checkpoint_name(grouped_matmul(rows, wg, group_sizes),
+                                   PRODUCT_GATE)
+            up = checkpoint_name(grouped_matmul(rows, wi, group_sizes),
+                                 PRODUCT_UP)
+            hidden = ACTIVATIONS[activation](gate) * up
+        y = checkpoint_name(grouped_matmul(hidden, wo, group_sizes),
+                            PRODUCT_DOWN)
     with jax.named_scope("moe/combine"):
         if held is None:
             y = _unsort(y, order, inverse).reshape(n, k, d)

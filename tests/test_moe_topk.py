@@ -507,3 +507,100 @@ def test_held_rows_of_any_extent_match_the_per_token_loop(h, unwritten,
             got[1][name]["kernel"], want[1][name]["kernel"], rtol=1e-4,
             atol=1e-5, err_msg=name)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-4, atol=1e-5)
+
+
+# ------------------------------------------------ experts with no gate
+def ungated_loop(x, params, k, held=(0, E), **route):
+    """A dense loop over the experts, every expert on every token:
+    ``sum_e w_e relu(x wi_e)^2 wo_e`` over the chosen AND held experts,
+    the weights :func:`topk_route`'s."""
+    weights, experts, _ = topk_route(x @ params["router"]["kernel"], k,
+                                     **route)
+    first, count = held
+    out = jnp.zeros_like(x)
+    for e in range(first, first + count):
+        w = jnp.sum(jnp.where(experts == e, weights, 0), -1)
+        hidden = jnp.square(jax.nn.relu(
+            x @ params["wi"]["kernel"][e - first]))
+        out += w[:, None] * (hidden @ params["wo"]["kernel"][e - first])
+    return out
+
+
+def ungated_inputs(held=None):
+    x, params, ct = inputs(seed=4)
+    params = dict(params)
+    del params["wg"]  # no gate: ``moe_param_shapes(gated=False)``
+    if held is not None:
+        first, count = held
+        params = {"router": params["router"], **{
+            n: {"kernel": params[n]["kernel"][first:first + count]}
+            for n in ("wi", "wo")}}
+    return x, params, ct
+
+
+@pytest.mark.parametrize("held", [None, (2, 3)], ids=["all", "held"])
+@pytest.mark.parametrize("k", [1, 3])
+def test_experts_without_a_gate_match_a_dense_loop(k, held):
+    """``activation="relu2"``: ``relu(x W_up)^2 W_down``, two grouped
+    products and no ``wg``; output and the gradient of every leaf, with
+    all the experts and with a share of them, under a sigmoid router
+    that renormalises and scales."""
+    route = dict(scoring="sigmoid", renormalize=True, scale=2.5)
+    x, params, ct = ungated_inputs(held)
+    assert set(params) == set(moe_param_shapes(D, F, E)) == {
+        "router", "wi", "wo"}
+
+    def ours(x, params):
+        return jnp.vdot(topk_moe(x, params, k=k, held=held,
+                                 activation="relu2", **route)[0], ct)
+
+    def theirs(x, params):
+        return jnp.vdot(ungated_loop(x, params, k, held or (0, E), **route),
+                        ct)
+
+    np.testing.assert_allclose(
+        topk_moe(x, params, k=k, held=held, activation="relu2", **route)[0],
+        ungated_loop(x, params, k, held or (0, E), **route),
+        rtol=1e-5, atol=1e-6)
+    got = jax.grad(ours, (0, 1))(x, params)
+    want = jax.grad(theirs, (0, 1))(x, params)
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                            jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5,
+                                   err_msg=str(path))
+    assert float(jnp.max(jnp.abs(want[1]["wi"]["kernel"]))) > 0
+
+
+def grouped_products(activation, params, x):
+    """``(forward ragged_dot calls, the names the products carry)`` of
+    one :func:`topk_moe`'s jaxpr."""
+    from horovod_tpu.parallel import moe
+
+    text = str(jax.make_jaxpr(lambda x, p: topk_moe(
+        x, p, k=2, activation=activation)[0])(x, params))
+    return text.count("= ragged_dot_general["), [
+        name for name in moe.PRODUCT_NAMES if f"name={name}" in text]
+
+
+def test_two_grouped_products_without_a_gate_and_three_with():
+    """The gated path is the program it was: three grouped products
+    named gate, up and down; without a gate two, and no ``moe_gate``
+    (``product_bytes(gated=False)`` has no bytes for one)."""
+    from horovod_tpu.parallel import moe
+
+    x, params, _ = inputs()
+    assert grouped_products("silu", params, x) == (
+        3, list(moe.PRODUCT_NAMES))
+    assert grouped_products("relu", params, x) == (
+        3, list(moe.PRODUCT_NAMES))
+    x, params, _ = ungated_inputs()
+    assert grouped_products("relu2", params, x) == (
+        2, [moe.PRODUCT_UP, moe.PRODUCT_DOWN])
+    with_gate, share = moe.product_bytes(64, 6, 32, 24, 2, (0, 8, 128))
+    without, same = moe.product_bytes(64, 6, 32, 24, 2, (0, 8, 128),
+                                      gated=False)
+    assert share == same == 6 * 8 / 128 / 6
+    assert set(with_gate) - set(without) == {moe.PRODUCT_GATE}
+    assert all(without[name] == with_gate[name] for name in without)
+    with pytest.raises(ValueError, match="activation 'gelu' is none of"):
+        topk_moe(x, params, k=2, activation="gelu")
